@@ -1,0 +1,548 @@
+#!/usr/bin/env python
+"""chip_smoke.py — does the round program still start on the chip?
+
+One process, one chip (or one four-chip host), a few minutes: the bench
+headline configuration (``bench.headline_config``: smallcnn at full width,
+64 clients, batch 128, 6 steps a round, bf16 activations, presharded data,
+random weights from a seed) through the entry points a user calls, every
+leg asserted, nothing caught and carried past. Any failed leg is a
+traceback and a non-zero exit; the last line of stdout is the JSON verdict
+only when every leg passed.
+
+  engine   ``Federation``: fused ``run_on_device(10)`` dispatches, ``step()``,
+           ``evaluate()``; each steady dispatch timed once to
+           ``jax.block_until_ready`` and once to a fetched loss.
+  cli      ``fedtpu.cli.run.main`` with ``--platform tpu --fused 5
+           --delta-layout flat --compression topk``: flat pack -> codec (a
+           Mosaic kernel) -> aggregate -> server step.
+  kernels  every ``pallas_call`` reachable from ``fedtpu.ops.compression``
+           through Mosaic at the shapes the codecs hand it, bitwise against
+           the jnp bodies; the Hadamard rotation at the 2^20-column row
+           against the wire codec's numpy butterfly.
+  rotq     ``Federation(compression="rotq", delta_layout="flat")`` rounds.
+  grpc     an in-process ``PrimaryServer`` + four ``serve_client`` agents
+           over real localhost gRPC, flat layout, stream pipeline, top-k.
+  mesh     with more than one chip: the headline under ``client_mesh(n)``
+           and the CLI under ``--mesh auto``, shardings checked.
+
+There is no CPU mode: with no TPU, or a TPU the peak table does not know,
+it says why and exits non-zero within seconds, before any model is built.
+Times printed are smoke walls, not throughput claims.
+"""
+
+import json
+import logging
+import math
+import os
+import socket
+import sys
+import tempfile
+import threading
+import time
+
+ROUNDS_FUSED = 10
+GRPC_CLIENTS = 4
+GRPC_ROUNDS = 3
+
+
+def require(cond, message):
+    """An assertion that survives ``python -O``."""
+    if not cond:
+        raise AssertionError(message)
+
+
+def preflight():
+    """Refuse anything but a known TPU, before fedtpu is even imported."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "tpu":
+        sys.exit(
+            f"chip_smoke: jax initialised the {backend!r} backend, not 'tpu' "
+            f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}); this "
+            "script has no CPU mode and nothing was run"
+        )
+    from importlib.metadata import version
+
+    import jaxlib
+
+    from fedtpu.obs.profile import device_peaks
+    from fedtpu.utils.platform import enable_compile_cache
+
+    devices = jax.devices()
+    kind = devices[0].device_kind
+    peak_flops, peak_hbm = device_peaks(kind)  # raises on an unknown TPU
+    cache = enable_compile_cache()
+    print(
+        f"chip_smoke: backend=tpu device_kind={kind!r} devices={len(devices)} "
+        f"peak_bf16={peak_flops:.3g}FLOP/s peak_hbm={peak_hbm:.3g}B/s"
+    )
+    print(
+        f"chip_smoke: python={sys.version.split()[0]} jax={jax.__version__} "
+        f"jaxlib={jaxlib.__version__} libtpu={version('libtpu')}"
+    )
+    print(f"chip_smoke: compile cache dir = {cache}")
+    return {"platform": devices[0].platform, "kind": kind, "count": len(devices)}
+
+
+class CompileStats:
+    """Compile accounting from ``jax.monitoring``: the wall spent inside
+    compile-or-fetch-from-cache, and how many of the cacheable requests the
+    persistent cache answered. Listeners stay registered for the life of
+    the process; ``take()`` returns the delta since the last call."""
+
+    def __init__(self):
+        from jax import monitoring
+
+        self._lock = threading.Lock()  # the gRPC leg compiles on 4 threads
+        self._seconds = 0.0
+        self._requests = 0
+        self._hits = 0
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+        monitoring.register_event_listener(self._on_event)
+
+    def _on_duration(self, event, seconds, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            with self._lock:
+                self._seconds += seconds
+
+    def _on_event(self, event, **_):
+        with self._lock:
+            if event == "/jax/compilation_cache/compile_requests_use_cache":
+                self._requests += 1
+            elif event == "/jax/compilation_cache/cache_hits":
+                self._hits += 1
+
+    def take(self):
+        with self._lock:
+            out = {
+                "compile_s": round(self._seconds, 2),
+                "cache_requests": self._requests,
+                "cache_hits": self._hits,
+            }
+            self._seconds, self._requests, self._hits = 0.0, 0, 0
+        return out
+
+
+def timed(fn, sync):
+    """Wall seconds of ``fn()`` through ``sync(result)``, and the result."""
+    t0 = time.perf_counter()
+    out = fn()
+    sync(out)
+    return time.perf_counter() - t0, out
+
+
+def finished(fed):
+    """A ``sync`` for :func:`timed`: wait until the dispatch that returned
+    these metrics (and the state it donated into) is done on the device."""
+    import jax
+
+    return lambda metrics: jax.block_until_ready((fed.state, metrics))
+
+
+def require_on_tpu(tree, what):
+    import jax
+
+    for leaf in jax.tree.leaves(tree):
+        platforms = {d.platform for d in leaf.devices()}
+        require(platforms == {"tpu"}, f"{what} lives on {platforms}, not the TPU")
+
+
+def require_learning(losses, what):
+    require(all(math.isfinite(x) for x in losses), f"{what}: non-finite loss in {losses}")
+    require(losses[-1] < losses[0], f"{what}: loss did not fall: {losses}")
+
+
+def sync_pair(fed, dispatch, what):
+    """Two steady dispatches, one ending in ``block_until_ready`` and one in
+    a fetched loss. If the first did not really wait for the device it
+    would come back in milliseconds; they must agree within 2x."""
+    import numpy as np
+
+    t_block, m_block = timed(dispatch, finished(fed))
+    t_fetch, m_fetch = timed(dispatch, lambda m: np.asarray(m.loss))
+    require(
+        0.5 <= t_block / t_fetch <= 2.0,
+        f"{what}: block_until_ready wall {t_block:.4f}s vs fetched-loss wall "
+        f"{t_fetch:.4f}s disagree",
+    )
+    return t_block, t_fetch, [m_block, m_fetch]
+
+
+def leg_engine():
+    import numpy as np
+
+    import bench
+    from fedtpu.core import Federation
+    from fedtpu.data import load
+
+    cfg = bench.headline_config()
+    fed = Federation(cfg, seed=0)
+    fused = lambda: fed.run_on_device(ROUNDS_FUSED)
+    t_fused_first, m0 = timed(fused, finished(fed))
+    t_fused_block, t_fused_fetch, ms = sync_pair(fed, fused, "fused dispatch")
+    losses = [float(x) for m in [m0] + ms for x in np.asarray(m.loss)]
+    t_step_first, s0 = timed(fed.step, finished(fed))
+    t_step_block, t_step_fetch, ss = sync_pair(fed, fed.step, "single-round dispatch")
+    losses += [float(m.loss) for m in [s0] + ss]
+    rounds = 3 * ROUNDS_FUSED + 3
+    require(
+        int(fed.state.round_idx) == rounds,
+        f"round_idx {int(fed.state.round_idx)} after {rounds} rounds",
+    )
+    require_learning(losses, "engine")
+    require_on_tpu(fed.state.params, "global model")
+    t_eval, (test_loss, test_acc) = timed(
+        lambda: fed.evaluate(*load("cifar10", "test", seed=cfg.data.seed, num=2000)),
+        lambda _: None,  # evaluate() returns host floats: already synced
+    )
+    require(math.isfinite(test_loss), f"evaluate loss {test_loss}")
+    return {
+        "rounds": rounds,
+        "loss_first": round(losses[0], 4),
+        "loss_last": round(losses[-1], 4),
+        "test_acc": round(test_acc, 4),
+        "fused10_first_s": round(t_fused_first, 3),
+        "fused10_block_until_ready_s": round(t_fused_block, 4),
+        "fused10_fetched_loss_s": round(t_fused_fetch, 4),
+        "step_first_s": round(t_step_first, 3),
+        "step_block_until_ready_s": round(t_step_block, 4),
+        "step_fetched_loss_s": round(t_step_fetch, 4),
+        "evaluate_first_s": round(t_eval, 3),
+    }
+
+
+def run_cli(extra, rounds=10):
+    """``fedtpu.cli.run.main`` on the headline shapes; returns the per-round
+    records it wrote and every log line it emitted."""
+    import bench
+    from fedtpu.cli import run as cli_run
+
+    class Collect(logging.Handler):
+        def __init__(self):
+            super().__init__(logging.INFO)
+            self.lines = []
+
+        def emit(self, record):
+            self.lines.append(record.getMessage())
+
+    collect = Collect()
+    logging.getLogger().addHandler(collect)
+    try:
+        with tempfile.TemporaryDirectory() as tmp:
+            metrics = os.path.join(tmp, "rounds.jsonl")
+            argv = [
+                "--platform", "tpu",
+                "--model", bench.BENCH_MODEL,
+                "--dataset", "cifar10",
+                "--partition", "iid",
+                "--num-clients", str(bench.NUM_CLIENTS),
+                "--batch-size", str(bench.BATCH),
+                "--steps-per-round", str(bench.STEPS_PER_ROUND),
+                "--num-examples",
+                str(bench.NUM_CLIENTS * bench.STEPS_PER_ROUND * bench.BATCH),
+                "--rounds", str(rounds),
+                "--fused", "5",
+                "--eval-every", "5",
+                "--delta-layout", "flat",
+                "--compression", "topk",
+                "--metrics", metrics,
+                *extra,
+            ]
+            t0 = time.perf_counter()
+            rc = cli_run.main(argv)
+            wall = time.perf_counter() - t0
+            with open(metrics) as f:
+                records = [json.loads(line) for line in f]
+    finally:
+        logging.getLogger().removeHandler(collect)
+    require(rc == 0, f"fedtpu.cli.run exited {rc}")
+    require(len(records) == rounds, f"{len(records)} round records, asked for {rounds}")
+    require_learning([r["loss"] for r in records], "cli")
+    require(
+        all(0 < r["mfu"] <= 1 for r in records),
+        f"cli: per-round MFU outside (0, 1]: {[r.get('mfu') for r in records]}",
+    )
+    require(
+        any("backend=tpu" in line for line in collect.lines),
+        "the CLI's device line does not say backend=tpu",
+    )
+    return records, collect.lines, wall
+
+
+def leg_cli():
+    records, _, wall = run_cli(["--mesh", "off"])
+    return {
+        "rounds": len(records),
+        "loss_first": round(records[0]["loss"], 4),
+        "loss_last": round(records[-1]["loss"], 4),
+        "test_acc": records[-1].get("test_acc"),
+        "wall_s": round(wall, 2),
+    }
+
+
+def leg_kernels():
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import bench
+    from fedtpu import models
+    from fedtpu.ops import flat as flat_ops
+    from fedtpu.ops import pallas_kernels as pk
+    from fedtpu.transport.sparse import _fwht_np
+
+    def layouts(name):
+        model = models.create(name, num_classes=10)
+        params = jax.eval_shape(
+            lambda r: model.init(r, jnp.zeros((1, 32, 32, 3)), train=False),
+            jax.random.PRNGKey(0),
+        )["params"]
+        return flat_ops.make_layout(params), flat_ops.make_layout(params, pow2=True)
+
+    lay, lay_pow2 = layouts(bench.BENCH_MODEL)
+    rows = bench.NUM_CLIENTS
+    rng = np.random.default_rng(0)
+    out = {}
+    # The two elementwise kernels: the flat row and the smallest leaf.
+    for cols in (lay.padded, min(lay.sizes)):
+        y = jnp.asarray(rng.normal(size=(rows, cols)).astype(np.float32))
+        # Per-row thresholds that keep a few percent of N(0, 1) draws — what
+        # a top-k threshold looks like, without compiling a sort to find it.
+        thresh = jnp.linspace(1.5, 2.5, rows, dtype=jnp.float32)
+        scale = jnp.max(jnp.abs(y), axis=1) / 127.0
+        for name, kernel, body, arg in (
+            ("threshold_with_feedback", pk.threshold_with_feedback,
+             pk.threshold_with_feedback_jnp, thresh),
+            ("quantdequant_int8", pk.quantdequant_int8,
+             pk.quantdequant_int8_jnp, scale),
+        ):
+            require(
+                "tpu_custom_call" in kernel.lower(y, arg).as_text(),
+                f"{name} did not lower through Mosaic",
+            )
+            got = jax.tree.leaves(kernel(y, arg))
+            want = jax.tree.leaves(jax.jit(body)(y, arg))
+            for g, w in zip(got, want):
+                require(
+                    np.array_equal(np.asarray(g), np.asarray(w)),
+                    f"{name} [{rows}, {cols}]: Mosaic and jnp differ",
+                )
+            t, _ = timed(lambda: kernel(y, arg), jax.block_until_ready)
+            out[f"{name}[{rows},{cols}]_s"] = round(t, 5)
+    # The rotation (plain jnp on every backend) at the pow2-padded rows.
+    widths = {lay_pow2.padded, layouts("densenet_cifar")[1].padded}
+    for h in sorted(widths):
+        y = rng.normal(size=(rows, h)).astype(np.float32)
+        signs = (rng.integers(0, 2, size=h) * 2 - 1).astype(np.float32)
+        yd, sd = jnp.asarray(y), jnp.asarray(signs)
+        t_first, z = timed(lambda: pk.hadamard_rotate(yd, sd), jax.block_until_ready)
+        t, _ = timed(lambda: pk.hadamard_rotate(yd, sd), jax.block_until_ready)
+        norm = np.float32(1.0 / math.sqrt(h))
+        for r in range(2):  # host reference on two rows: 20 numpy passes each
+            want = _fwht_np(y[r] * signs) * norm
+            require(
+                np.allclose(np.asarray(z[r]), want, rtol=1e-4, atol=1e-3),
+                f"hadamard_rotate [{rows}, {h}] row {r} differs from the "
+                "host butterfly",
+            )
+        back = pk.hadamard_rotate(z, sd, inverse=True)
+        require(
+            np.allclose(np.asarray(back), y, rtol=1e-4, atol=1e-3),
+            f"hadamard_rotate [{rows}, {h}]: inverse(forward(y)) != y",
+        )
+        out[f"hadamard_rotate[{rows},{h}]_first_s"] = round(t_first, 3)
+        out[f"hadamard_rotate[{rows},{h}]_s"] = round(t, 5)
+    return out
+
+
+def leg_rotq():
+    import dataclasses
+
+    import bench
+    from fedtpu.core import Federation
+
+    cfg = bench.headline_config()
+    cfg = dataclasses.replace(
+        cfg,
+        fed=dataclasses.replace(cfg.fed, compression="rotq", delta_layout="flat"),
+    )
+    fed = Federation(cfg, seed=0)
+    t_first, m0 = timed(fed.step, finished(fed))
+    t_steady, m1 = timed(fed.step, finished(fed))
+    losses = [float(m0.loss), float(m1.loss)]
+    require(int(fed.state.round_idx) == 2, "rotq: round_idx did not reach 2")
+    require_learning(losses, "rotq")
+    require_on_tpu(fed.state.params, "rotq global model")
+    return {
+        "rounds": 2,
+        "loss_first": round(losses[0], 4),
+        "loss_last": round(losses[1], 4),
+        "step_first_s": round(t_first, 3),
+        "step_s": round(t_steady, 4),
+    }
+
+
+def free_port():
+    with socket.socket() as s:
+        s.bind(("localhost", 0))
+        return s.getsockname()[1]
+
+
+def leg_grpc():
+    import bench
+    from fedtpu import native
+    from fedtpu.config import DataConfig, FedConfig, RoundConfig
+    from fedtpu.transport.federation import PrimaryServer, serve_client
+
+    cfg = RoundConfig(
+        model=bench.BENCH_MODEL,
+        num_classes=10,
+        data=DataConfig(
+            dataset="cifar10",
+            batch_size=bench.BATCH,
+            partition="iid",
+            num_examples=GRPC_CLIENTS * bench.STEPS_PER_ROUND * bench.BATCH,
+        ),
+        fed=FedConfig(
+            num_clients=GRPC_CLIENTS,
+            num_rounds=GRPC_ROUNDS,
+            compression="topk",
+            delta_layout="flat",
+            server_pipeline="stream",
+        ),
+    )
+    servers, agents, addrs = [], [], []
+    evals = []
+    try:
+        for i in range(GRPC_CLIENTS):
+            addr = f"localhost:{free_port()}"
+            server, agent = serve_client(addr, cfg, seed=i)
+            servers.append(server)
+            agents.append(agent)
+            addrs.append(addr)
+        primary = PrimaryServer(cfg, addrs)
+        require(primary.server_pipeline == "stream", "primary is not streaming")
+        t0 = time.perf_counter()
+        history = primary.run(
+            on_round=lambda r, rec: evals.append([a.last_eval for a in agents])
+        )
+        wall = time.perf_counter() - t0
+    finally:
+        for server in servers:
+            server.stop(0)
+    require(len(history) == GRPC_ROUNDS, f"{len(history)} gRPC rounds committed")
+    for rec in history:
+        require(
+            rec["participants"] == GRPC_CLIENTS and rec["pipeline"] == "stream",
+            f"gRPC round record {rec}",
+        )
+    for per_round in evals:
+        for loss, _ in per_round:
+            require(math.isfinite(loss), f"client eval losses {evals}")
+    require_on_tpu(primary.params, "gRPC primary's global model")
+    return {
+        "rounds": len(history),
+        "participants": [rec["participants"] for rec in history],
+        "host_codec": native.codec_name(),
+        "bytes_up_by_codec": history[-1]["bytes_up_by_codec"],
+        "eval_loss_first": round(evals[0][0][0], 4),
+        "eval_loss_last": round(evals[-1][0][0], 4),
+        "round_first_s": history[0]["t_round_s"],
+        "round_last_s": history[-1]["t_round_s"],
+        "t_h2d_last_s": history[-1]["t_h2d_s"],
+        "t_post_barrier_last_s": history[-1]["t_post_barrier_s"],
+        "wall_s": round(wall, 2),
+    }
+
+
+def leg_mesh(n_dev):
+    import jax
+    import numpy as np
+
+    import bench
+    from fedtpu.core import Federation
+    from fedtpu.parallel import client_mesh
+
+    cfg = bench.headline_config()
+    n = cfg.fed.num_clients
+    require(n % n_dev == 0, f"{n} clients do not divide over {n_dev} devices")
+    fed = Federation(cfg, seed=0, mesh=client_mesh(n_dev, cfg.mesh_axis))
+    fused = lambda: fed.run_on_device(ROUNDS_FUSED)
+    t_first, m0 = timed(fused, finished(fed))
+    t_steady, m1 = timed(fused, finished(fed))
+    losses = [float(x) for m in (m0, m1) for x in np.asarray(m.loss)]
+    require(int(fed.state.round_idx) == 2 * ROUNDS_FUSED, "mesh: round_idx")
+    require_learning(losses, "mesh")
+
+    def require_sharded(tree, what):
+        for leaf in jax.tree.leaves(tree):
+            shards = leaf.addressable_shards
+            require(
+                len({s.device for s in shards}) == n_dev
+                and all(s.data.shape[0] == n // n_dev for s in shards),
+                f"{what}: {[(str(s.device), s.data.shape) for s in shards]}",
+            )
+
+    state = fed.state
+    require_sharded(state.opt_state, "momentum")
+    require_sharded(
+        (state.client_rng, state.last_client_loss), "per-client state"
+    )
+    require_sharded(fed._ensure_device_data(), "presharded dataset")
+    for leaf in jax.tree.leaves((state.params, state.batch_stats)):
+        require(
+            leaf.sharding.is_fully_replicated
+            and len(leaf.addressable_shards) == n_dev,
+            f"global model leaf {leaf.shape} is not replicated",
+        )
+    records, lines, wall = run_cli(["--mesh", "auto"])
+    require(
+        any(f"clients axis sharded over {n_dev} devices" in line for line in lines),
+        "the CLI under --mesh auto did not shard the clients axis",
+    )
+    return {
+        "devices": n_dev,
+        "clients_per_device": n // n_dev,
+        "sharded": ["momentum", "per-client state", "presharded dataset"],
+        "replicated": ["params", "batch_stats"],
+        "loss_first": round(losses[0], 4),
+        "loss_last": round(losses[-1], 4),
+        "fused10_first_s": round(t_first, 3),
+        "fused10_s": round(t_steady, 4),
+        "cli_mesh_auto_wall_s": round(wall, 2),
+        "cli_loss_last": round(records[-1]["loss"], 4),
+    }
+
+
+def main():
+    t_start = time.perf_counter()
+    # Before the CLI leg's own basicConfig (a no-op once handlers exist):
+    # every leg logs at INFO, and run_cli can read the CLI's log lines.
+    logging.basicConfig(
+        level=logging.INFO, format="%(asctime)s %(name)s %(message)s"
+    )
+    device = preflight()
+    stats = CompileStats()
+    legs = [
+        ("engine", leg_engine),
+        ("cli", leg_cli),
+        ("kernels", leg_kernels),
+        ("rotq", leg_rotq),
+        ("grpc", leg_grpc),
+    ]
+    if device["count"] > 1:
+        legs.append(("mesh", lambda: leg_mesh(device["count"])))
+    for name, leg in legs:
+        t0 = time.perf_counter()
+        info = leg()
+        info = {"leg_wall_s": round(time.perf_counter() - t0, 2), **stats.take(), **info}
+        print(f"leg {name}: {json.dumps(info)}", flush=True)
+    if device["count"] == 1:
+        print("mesh: 1 device, leg not applicable")
+    print(f"chip_smoke: every leg passed in {time.perf_counter() - t_start:.1f}s")
+    print(json.dumps({"ok": True, "device": device}))
+
+
+if __name__ == "__main__":
+    main()

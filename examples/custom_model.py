@@ -33,8 +33,7 @@ class TinyNet(nn.Module):
 
 def main():
     if "--tpu" not in sys.argv:
-        # CPU by default: a wedged remote TPU backend would otherwise hang
-        # this demo at the first device query.
+        # CPU by default: the demo is seconds-scale and must run anywhere.
         import jax
 
         jax.config.update("jax_platforms", "cpu")

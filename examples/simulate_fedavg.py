@@ -24,8 +24,8 @@ def main():
         "--platform",
         default=None,
         choices=["cpu", "tpu"],
-        help="pin the jax platform (--smoke implies cpu); without a pin a "
-        "wedged remote TPU backend can hang the process",
+        help="pin the jax platform (--smoke implies cpu); without a pin the "
+        "run uses whatever backend jax initialises",
     )
     args = p.parse_args()
     if args.platform or args.smoke:

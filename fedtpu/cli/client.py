@@ -83,11 +83,10 @@ def main(argv=None) -> int:
         "probing a silent departure forever",
     )
     args = p.parse_args(argv)
-    apply_platform_flag(args)
-
     logging.basicConfig(
         level=logging.INFO, format="%(asctime)s %(name)s %(message)s"
     )
+    apply_platform_flag(args)
     cfg = build_config(args, num_clients=args.world)
     server, agent = serve_client(
         args.address, cfg, seed=args.seed, compress=compress_enabled(args),

@@ -30,10 +30,11 @@ def add_platform_flag(p: argparse.ArgumentParser) -> None:
         "--platform",
         default=None,
         choices=["cpu", "tpu", "cuda"],
-        help="pin the jax platform. Setting JAX_PLATFORMS in the environment "
-        "is NOT always equivalent: a registered TPU plugin can ignore it "
-        "(and a wedged remote TPU backend then hangs the process); this flag "
-        "uses jax.config.update, which wins.",
+        help="pin the jax platform (jax.config.update, which wins over "
+        "JAX_PLATFORMS in the environment). Default: whatever backend jax "
+        "initialises — the first log line names it. 'tpu' is fatal when "
+        "no chip can be acquired; a chip belongs to ONE process, so on a "
+        "one-chip host every other role runs with 'cpu'",
     )
     p.add_argument(
         "--fake-devices",
@@ -46,17 +47,25 @@ def add_platform_flag(p: argparse.ArgumentParser) -> None:
 
 
 def apply_platform_flag(args) -> None:
-    """Apply --platform/--fake-devices. Must run before any jax device query;
-    safe because fedtpu modules import jax lazily enough that the backend is
-    uninitialised until the first model/data build."""
-    if getattr(args, "fake_devices", None):
-        from fedtpu.utils.platform import force_host_device_count
+    """Apply --platform/--fake-devices, then initialise the backend: place
+    the compile cache (before anything compiles) and log which device this
+    process actually got. Must run before any other jax device query; safe
+    because fedtpu modules import jax lazily enough that the backend is
+    uninitialised until here. Raises when the pinned platform is absent."""
+    import jax
 
+    from fedtpu.utils.platform import (
+        enable_compile_cache,
+        force_host_device_count,
+        log_devices,
+    )
+
+    if getattr(args, "fake_devices", None):
         force_host_device_count(args.fake_devices)
     if getattr(args, "platform", None):
-        import jax
-
         jax.config.update("jax_platforms", args.platform)
+    enable_compile_cache()
+    log_devices()
 
 
 def add_model_flags(p: argparse.ArgumentParser) -> None:

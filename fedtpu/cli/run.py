@@ -112,11 +112,10 @@ def main(argv=None) -> int:
     p.add_argument("--progress", action="store_true",
                    help="per-round progress bar (headless-safe)")
     args = p.parse_args(argv)
-    apply_platform_flag(args)
-
     logging.basicConfig(
         level=logging.INFO, format="%(asctime)s %(name)s %(message)s"
     )
+    apply_platform_flag(args)
     cfg = build_config(
         args,
         # --cohort is the device-buffer size in population mode; it IS

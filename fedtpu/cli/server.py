@@ -128,11 +128,10 @@ def main(argv=None) -> int:
         "wait indefinitely (reference behavior, src/server.py:132-135)",
     )
     args = p.parse_args(argv)
-    apply_platform_flag(args)
-
     logging.basicConfig(
         level=logging.INFO, format="%(asctime)s %(name)s %(message)s"
     )
+    apply_platform_flag(args)
     clients = [c.strip() for c in args.clients.split(",") if c.strip()]
     cfg = build_config(args, num_clients=len(clients))
     compress = compress_enabled(args)

@@ -49,11 +49,10 @@ def main(argv=None) -> int:
         "data parallelism — the reference's DataParallel, src/main.py:79-81)",
     )
     args = p.parse_args(argv)
-    apply_platform_flag(args)
-
     logging.basicConfig(
         level=logging.INFO, format="%(asctime)s %(name)s %(message)s"
     )
+    apply_platform_flag(args)
     cfg = build_config(args, num_clients=1)
     mesh = None
     if args.mesh == "auto":

@@ -18,8 +18,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
-from fedtpu.utils.platform import shard_map
 from fedtpu import models as model_zoo
 from fedtpu.config import RoundConfig
 from fedtpu.core import optim
@@ -28,6 +28,7 @@ from fedtpu.core.client import batch_eval_arrays, make_eval_fn
 from fedtpu.data import dataset_info, load
 from fedtpu.transport import wire
 from fedtpu.utils.metrics import MetricsLogger
+from fedtpu.utils.platform import enable_compile_cache
 
 
 class SoloTrainer:
@@ -69,6 +70,7 @@ class SoloTrainer:
                 f"batch_size={cfg.data.batch_size} not divisible by "
                 f"mesh size {mesh.devices.size}"
             )
+        enable_compile_cache()
         self.model = model_zoo.create(
             cfg.model, num_classes=cfg.num_classes, remat=cfg.remat
         )

@@ -45,8 +45,8 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
-from fedtpu.utils.platform import shard_map
 from fedtpu.config import RoundConfig
 from fedtpu.core.round import (
     FederatedState,
@@ -339,10 +339,9 @@ def make_multi_round_step(
 
     The reference pays a full host round-trip per round — thread fan-out,
     blocking RPCs, checkpoint files (``src/server.py:120-153``). The jitted
-    single-round step already collapses that to one dispatch per round, but
-    on a remote/tunneled device even dispatch+sync latency dominates small
-    rounds. Scanning the round body keeps the WHOLE multi-round run on
-    device: per-round batches are still gathered fresh inside each scan
+    single-round step already collapses that to one dispatch per round;
+    scanning the round body keeps the WHOLE multi-round run on device, with
+    no dispatch or sync between rounds: per-round batches are still gathered fresh inside each scan
     iteration (``round_take_indices`` folds ``round_idx`` into the shuffle
     key, so round r's batches are identical to the sequential path's), and
     per-round metrics come back stacked ``[num_rounds, ...]``.

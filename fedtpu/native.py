@@ -1,26 +1,36 @@
 """ctypes bindings for the native host codec (``native/codec.cpp``).
 
-Loads ``native/libfedtpu_native.so`` if present (``make -C native`` builds
-it; :func:`ensure_built` does so programmatically). Every entry point has a
-numpy fallback, so the package works without a toolchain — the native path
-just makes the DCN-edge sparsification O(n) single-pass instead of
-numpy-temporary-heavy.
+The library is built on first use from the TRACKED source, under a file
+name that carries a hash of that source and the compiler flags
+(``native/libfedtpu_native-<hash>.so``, git-ignored): a binary left in the
+tree by another checkout, another flag set or another machine's
+``-march=native`` build is simply never opened. The flags are portable
+(no ``-march=native``), so a built tree can be copied between machines.
 
-No pybind11 in this environment, hence plain-C ABI + ctypes (allowed per the
-environment constraints).
+Every entry point has a numpy fallback, taken — with one WARNING — only
+when the build fails (no compiler): :func:`codec_name` says which of the
+two ran. The native path makes the DCN-edge sparsification O(n)
+single-pass instead of numpy-temporary-heavy.
+
+No pybind11 in this environment, hence plain-C ABI + ctypes.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 from typing import Optional, Tuple
 
 import numpy as np
 
+log = logging.getLogger("fedtpu.native")
+
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libfedtpu_native.so")
+_SOURCE = os.path.join(_NATIVE_DIR, "codec.cpp")
+_CXX = ("g++", "-O3", "-std=c++17", "-fPIC", "-shared")
 
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
@@ -50,39 +60,59 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     return lib
 
 
+def _lib_path() -> str:
+    digest = hashlib.sha256()
+    with open(_SOURCE, "rb") as f:
+        digest.update(f.read())
+    digest.update(" ".join(_CXX).encode())
+    return os.path.join(
+        _NATIVE_DIR, f"libfedtpu_native-{digest.hexdigest()[:16]}.so"
+    )
+
+
+def _build(path: str) -> None:
+    """Compile ``codec.cpp`` to ``path``. Written under a per-process temp
+    name and renamed, so processes racing on first use (four clients
+    starting together) each see either no library or a whole one."""
+    tmp = f"{path}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(
+            [*_CXX, "-o", tmp, _SOURCE],
+            check=True, capture_output=True, text=True, timeout=120,
+        )
+        os.replace(tmp, path)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+
+
 def load() -> Optional[ctypes.CDLL]:
-    """The native library, or None if unbuilt/unloadable (numpy fallback)."""
+    """The native library for THIS source tree (built now if its
+    hash-named file is absent), or None after a failed build."""
     global _lib, _load_attempted
     if _lib is None and not _load_attempted:
         _load_attempted = True
-        if os.path.exists(_LIB_PATH):
-            try:
-                _lib = _bind(ctypes.CDLL(_LIB_PATH))
-            except OSError:
-                _lib = None
+        path = _lib_path()
+        try:
+            if not os.path.exists(path):
+                _build(path)
+            _lib = _bind(ctypes.CDLL(path))
+        except (OSError, subprocess.SubprocessError) as e:
+            detail = getattr(e, "stderr", None) or e
+            log.warning(
+                "native codec unavailable, host codec is numpy: %s", detail
+            )
     return _lib
-
-
-def ensure_built() -> bool:
-    """Build the native library if missing; True if it is now loadable."""
-    global _load_attempted
-    if load() is not None:
-        return True
-    try:
-        subprocess.run(
-            ["make", "-C", _NATIVE_DIR],
-            check=True,
-            capture_output=True,
-            timeout=120,
-        )
-    except Exception:
-        return False
-    _load_attempted = False
-    return load() is not None
 
 
 def available() -> bool:
     return load() is not None
+
+
+def codec_name() -> str:
+    """Which host codec this process runs: ``native:<library file>`` or
+    ``numpy``."""
+    return f"native:{os.path.basename(_lib_path())}" if available() else "numpy"
 
 
 def _as_f32(x: np.ndarray) -> np.ndarray:

@@ -4,7 +4,7 @@
 ``--metrics`` path: same call shape (``log(step, **fields)``), same field
 coercion, same JSONL-append-and-flush behavior — plus a pinned
 ``schema_version`` on every record so downstream consumers
-(``tools/jsontail.py``, ``tools/metrics_report.py``, the watcher) can detect
+(``tools/jsontail.py``, ``tools/metrics_report.py``) can detect
 drift instead of silently misreading a renamed field.
 
 Schema history:

@@ -49,9 +49,9 @@ log = logging.getLogger("fedtpu.obs.profile")
 # ------------------------------------------------------------- peak tables
 # Public per-chip peak figures by PJRT device_kind substring (matched on
 # the lowercase space/hyphen-stripped form): (bf16 FLOPs/s, HBM bytes/s).
-# Single source of truth — bench.py and tools/bench_profile_tpu.py resolve
-# through here.
-PEAK_TABLE: Tuple[Tuple[Tuple[str, ...], float, Optional[float]], ...] = (
+# The ONLY peak table — bench.py, chip_smoke.py and the tools resolve
+# through device_peaks(). v5e row: Google Cloud documentation, "TPU v5e".
+PEAK_TABLE: Tuple[Tuple[Tuple[str, ...], float, float], ...] = (
     (("v6e", "v6lite", "trillium"), 918e12, 1640e9),
     (("v5p",), 459e12, 2765e9),
     (("v5e", "v5lite"), 197e12, 819e9),
@@ -60,36 +60,37 @@ PEAK_TABLE: Tuple[Tuple[Tuple[str, ...], float, Optional[float]], ...] = (
     (("v2",), 45e12, 700e9),
 )
 
-# Operator overrides for platforms the table cannot know (CPU dev boxes,
-# new chips): utilisation ratios against a wrong peak are worse than none.
+# Stand-in peaks for a backend that has none (a CPU dev box exercising the
+# MFU gauges in tests). Never consulted on a TPU backend.
 PEAK_FLOPS_ENV = "FEDTPU_PEAK_FLOPS"
 PEAK_HBM_ENV = "FEDTPU_PEAK_HBM_BYTES"
 
 
 def device_peaks(device_kind: str) -> Tuple[Optional[float], Optional[float]]:
-    """``(peak_flops_per_s, peak_hbm_bytes_per_s)`` for a PJRT device kind;
-    ``(None, None)`` when unknown (CPU, future chips). The ``FEDTPU_PEAK_*``
-    env overrides win over the table — the only way to get meaningful MFU
-    on hardware the table doesn't cover."""
-    peak_f = peak_b = None
+    """``(peak_flops_per_s, peak_hbm_bytes_per_s)`` for a PJRT device kind.
+
+    A kind in :data:`PEAK_TABLE` gets the table's row, always. An unknown
+    kind on a TPU backend RAISES — utilisation against a guessed peak, or
+    MFU silently dropped, is how a run on the wrong device goes unnoticed;
+    add the chip to the table. Off TPU an unknown kind (``"cpu"``) yields
+    the ``FEDTPU_PEAK_*`` stand-ins, else ``(None, None)``."""
     kind = (device_kind or "").lower().replace(" ", "").replace("-", "")
     for aliases, f, b in PEAK_TABLE:
         if any(a in kind for a in aliases):
-            peak_f, peak_b = f, b
-            break
+            return f, b
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise ValueError(
+            f"device kind {device_kind!r} is not in "
+            "fedtpu.obs.profile.PEAK_TABLE; add its published peaks there"
+        )
     env_f = os.environ.get(PEAK_FLOPS_ENV)
     env_b = os.environ.get(PEAK_HBM_ENV)
-    if env_f:
-        try:
-            peak_f = float(env_f)
-        except ValueError:
-            pass
-    if env_b:
-        try:
-            peak_b = float(env_b)
-        except ValueError:
-            pass
-    return peak_f, peak_b
+    return (
+        float(env_f) if env_f else None,
+        float(env_b) if env_b else None,
+    )
 
 
 # -------------------------------------------------------- analytic FLOPs
@@ -365,14 +366,8 @@ def analytic_bytes(fn: Callable, *args, **kwargs) -> float:
 
 def xla_cost(compiled) -> Dict[str, float]:
     """``{"flops": ..., "bytes": ...}`` from a compiled executable's
-    ``cost_analysis()`` (normalising the list-wrapped form some PJRT
-    versions return); zeros when unavailable."""
-    try:
-        analysis = compiled.cost_analysis()
-    except Exception:
-        return {"flops": 0.0, "bytes": 0.0}
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else {}
+    ``cost_analysis()``."""
+    analysis = compiled.cost_analysis()
     return {
         "flops": float(analysis.get("flops", 0.0)),
         "bytes": float(analysis.get("bytes accessed", 0.0)),
@@ -559,7 +554,7 @@ class RoundProfiler:
                 tel.gauge(
                     "fedtpu_mfu_ratio",
                     "model FLOPs utilization of the last dispatch vs "
-                    "per-chip peak (device_peaks table or FEDTPU_PEAK_FLOPS)",
+                    "per-chip peak (fedtpu.obs.profile.PEAK_TABLE)",
                 ).set(mfu)
         self._last = out
         return out
@@ -654,8 +649,6 @@ class CompileWatcher:
         self._installed = False
         self._lock = threading.Lock()
 
-    # The listener survives uninstall() in jax versions without an
-    # unregister API — the _installed gate keeps it inert.
     def _listener(self, event: str, duration: float, **kwargs) -> None:
         if not self._installed or _COMPILE_EVENT_SUBSTR not in event:
             return
@@ -715,14 +708,9 @@ class CompileWatcher:
         self._installed = False
         if CompileWatcher._active is self:
             CompileWatcher._active = None
-        try:  # best-effort: the public API grew unregister late
-            from jax._src import monitoring as _m
+        from jax import monitoring
 
-            _m._unregister_event_duration_listener_by_callback(
-                self._listener
-            )
-        except Exception:
-            pass  # inert via the _installed gate
+        monitoring.unregister_event_duration_listener(self._listener)
 
     def mark_steady(self) -> None:
         with self._lock:

@@ -1,39 +1,39 @@
 """Pallas TPU kernels for the update-compression hot path.
 
-MEASURED VERDICT (round 4, real v5e chip — `artifacts/PALLAS_TPU_RUN.json`):
-XLA's automatic fusion **matches or beats** both kernels at MobileNet scale
-(`threshold_with_feedback`: Mosaic 0.155 ms vs XLA 0.101 ms;
-`quantdequant_int8`: 71.8 vs 71.1 ms; outputs bitwise-equal both ways). The
-kernels stay in the tree as the repo's documented Pallas on-ramp and as a
-pinned-fusion fallback should a future surrounding program defeat XLA's
-fusion heuristics — NOT as a performance claim. They are correct, tested,
-and AOT-compile for v5e; the plain-XLA path is the default.
+What runs where. On a TPU backend ``threshold_with_feedback`` and
+``quantdequant_int8`` lower through Mosaic — that is the default there, and
+``chip_smoke.py`` compiles and runs both on the chip at the shapes the
+codecs hand them (the flat ``[clients, P]`` row and the smallest per-leaf
+``[clients, 10]``), bitwise against the plain-jnp bodies below. Off TPU the
+default is those plain-jnp bodies (XLA fuses the same chain; the Pallas
+interpreter costs ~1000x on CPU). ``hadamard_rotate`` is plain jnp on every
+backend: Mosaic refuses the butterfly's sub-lane reshapes
+("infer-vector-layout: unsupported shape cast", ``vector<8xhxf32> ->
+vector<8x(h/2)x2x1xf32>``, jax 0.9.0 / libtpu 0.0.34), so there is no
+``pallas_call`` around it.
+
+Whether the two kernels beat XLA's own fusion of the same chain has not
+been measured on the current tree (the round-4 record,
+``artifacts/PALLAS_TPU_RUN.json``, had them level); the codec cell of the
+benchmark decides whether they stay (ROADMAP Design 3).
 
 The compression pipeline (threshold mask, residual split, quantize — see
 :mod:`fedtpu.ops.compression`) is a chain of elementwise ops over every
 parameter of every client: at 64 clients x ~3.2M params (MobileNet, reference
 ``src/models/mobilenet.py``) that is ~800 MB of traffic per round if each op
-round-trips HBM. XLA fuses most of the chain already; the Pallas kernels below
-pin the fusion explicitly — one read of the combined delta+residual, one write
-of (compressed, new_residual) — so the compression path stays
-bandwidth-minimal regardless of what the surrounding program does to XLA's
-fusion decisions.
+round-trips HBM. The kernels pin the fusion explicitly — one read of the
+combined delta+residual, one write of (compressed, new_residual).
 
 Tiling obeys Mosaic's (8, 128) f32 tile rule: blocks are 8 client rows by a
 lane-aligned column slice (~1 MB per operand per grid step — small enough
 that the 4 double-buffered operands of the threshold kernel stay inside the
-16 MB VMEM scoped limit, verified by deviceless AOT compilation for a v5e
-target via ``tools/compile_pallas_tpu.py``). Per-row scalars (thresholds /
-scales) ride as a ``[rows, 1]`` column so their block shape satisfies the
-same rule.
+16 MB VMEM scoped limit). Per-row scalars (thresholds / scales) ride as a
+``[rows, 1]`` column so their block shape satisfies the same rule.
 
-Mode selection: on TPU the kernels lower through Mosaic. Off-TPU the DEFAULT
-is a plain-jnp equivalent (XLA fuses the same chain; Pallas interpret mode
-costs ~1000x on CPU and is pure overhead in production paths like the
-cpu-scale parity bench). Pass ``interpret=True`` to force the interpreted
-``pallas_call`` — the CPU test suite does this to exercise the actual kernel
-bodies — or ``interpret=False`` to force Mosaic (the deviceless AOT compile
-check, ``tools/compile_pallas_tpu.py``).
+The ``interpret`` argument: ``None`` (every production call site) decides by
+backend as above; ``False`` forces Mosaic — compiling FOR a TPU from a CPU
+host, ``tools/compile_pallas_tpu.py``; a true value runs the interpreted
+``pallas_call`` and is for the CPU test suite only.
 """
 
 from __future__ import annotations
@@ -54,31 +54,12 @@ _BLOCK_ROWS = 8
 assert _BLOCK_COLS % 128 == 0, "column blocks must stay lane-aligned"
 
 
-# Process-wide default for the mode decision, settable because "what
-# platform will this trace target?" is not knowable from inside a kernel
-# wrapper during deviceless AOT lowering (default_backend() is cpu even when
-# compiling FOR a TPU topology). Set BEFORE the first traced call — the
-# wrappers are jitted and cache their trace.
-_INTERPRET_DEFAULT: Optional[bool] = None
-
-
-def set_interpret_default(value: Optional[bool]) -> None:
-    global _INTERPRET_DEFAULT
-    _INTERPRET_DEFAULT = value
-
-
-def _mode(override: Optional[bool]) -> str:
+def _mode(interpret: Optional[bool]) -> str:
     """'mosaic' (pallas, compiled) | 'interpret' (pallas, interpreted) |
     'xla' (plain-jnp equivalent, off-TPU default)."""
-    if override is True:
-        return "interpret"
-    if override is False:
-        return "mosaic"
-    if _INTERPRET_DEFAULT is True:
-        return "interpret"
-    if _INTERPRET_DEFAULT is False:
-        return "mosaic"
-    return "mosaic" if jax.default_backend() == "tpu" else "xla"
+    if interpret is None:
+        return "mosaic" if jax.default_backend() == "tpu" else "xla"
+    return "interpret" if interpret else "mosaic"
 
 
 def _blocks(rows: int, cols: int):
@@ -104,6 +85,13 @@ def _threshold_kernel(y_ref, t_ref, out_ref, new_e_ref):
     new_e_ref[...] = y - out
 
 
+def threshold_with_feedback_jnp(y: jnp.ndarray, thresh: jnp.ndarray):
+    """Plain-jnp body of :func:`threshold_with_feedback`: the off-TPU path
+    and the reference the chip smoke compares the Mosaic kernel against."""
+    out = jnp.where(jnp.abs(y) >= thresh[:, None], y, jnp.zeros_like(y))
+    return out, y - out
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def threshold_with_feedback(
     y: jnp.ndarray, thresh: jnp.ndarray, interpret: Optional[bool] = None
@@ -117,8 +105,7 @@ def threshold_with_feedback(
     rows, cols = y.shape
     mode = _mode(interpret)
     if mode == "xla":
-        out = jnp.where(jnp.abs(y) >= thresh[:, None], y, jnp.zeros_like(y))
-        return out, y - out
+        return threshold_with_feedback_jnp(y, thresh)
     rb, cb = _blocks(rows, cols)
     grid = (pl.cdiv(rows, rb), pl.cdiv(cols, cb))
     return pl.pallas_call(
@@ -162,25 +149,9 @@ def _fwht_body(x: jnp.ndarray) -> jnp.ndarray:
     return x
 
 
-def _hadamard_kernel(x_ref, out_ref):
-    """One row-block of the full-width FWHT butterfly.
-
-    Unlike the elementwise kernels above, the transform MIXES every column
-    of a row, so the grid tiles rows only and each step reads the whole
-    ``[rb, h]`` row block — which bounds the Mosaic-compilable ``h`` by
-    VMEM (~16 MB / (2 operands x rb x 4 B) ≈ 256K f32 columns at rb=8).
-    Beyond that the plain-XLA path below is the production default anyway
-    (same measured-verdict story as the other kernels in this file).
-    """
-    out_ref[...] = _fwht_body(x_ref[...])
-
-
-@functools.partial(jax.jit, static_argnames=("inverse", "interpret"))
+@functools.partial(jax.jit, static_argnames=("inverse",))
 def hadamard_rotate(
-    y: jnp.ndarray,
-    signs: jnp.ndarray,
-    inverse: bool = False,
-    interpret: Optional[bool] = None,
+    y: jnp.ndarray, signs: jnp.ndarray, inverse: bool = False
 ) -> jnp.ndarray:
     """Seeded structured random rotation ``R = (1/sqrt(h)) * H * D``.
 
@@ -191,30 +162,18 @@ def hadamard_rotate(
     the client, quantizes, and inverse-rotates on the server — both ends
     regenerate ``signs`` from the shared record seed.
 
-    Parity: the interpreted pallas_call body is pinned against this
-    function's own plain-jnp (lax) branch by ``tests/test_compression.py``.
+    Plain jnp on every backend (see the module docstring): XLA:TPU compiles
+    the butterfly at the 2^20-column row of the zoo's flat layouts with
+    ~6x the operand in temporaries.
     """
-    rows, h = y.shape
+    h = y.shape[1]
     if h & (h - 1):
         raise ValueError(f"hadamard_rotate needs a power-of-two width, got {h}")
     y = y.astype(jnp.float32)
     signs = signs.astype(jnp.float32)
-    norm = jnp.float32(1.0 / math.sqrt(h))
     if not inverse:
         y = y * signs[None, :]
-    mode = _mode(interpret)
-    if mode == "xla":
-        out = _fwht_body(y) * norm
-    else:
-        rb = rows if rows <= _BLOCK_ROWS else _BLOCK_ROWS
-        out = pl.pallas_call(
-            _hadamard_kernel,
-            grid=(pl.cdiv(rows, rb),),
-            in_specs=[pl.BlockSpec((rb, h), lambda r: (r, 0))],
-            out_specs=pl.BlockSpec((rb, h), lambda r: (r, 0)),
-            out_shape=jax.ShapeDtypeStruct(y.shape, jnp.float32),
-            interpret=mode == "interpret",
-        )(y) * norm
+    out = _fwht_body(y) * jnp.float32(1.0 / math.sqrt(h))
     if inverse:
         out = out * signs[None, :]
     return out
@@ -227,6 +186,14 @@ def _quantdequant_kernel(x_ref, s_ref, out_ref):
     safe = jnp.where(s > 0, s, jnp.ones_like(s))
     q = jnp.clip(jnp.round(x_ref[...] / safe), -127.0, 127.0)
     out_ref[...] = q * safe
+
+
+def quantdequant_int8_jnp(x: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
+    """Plain-jnp body of :func:`quantdequant_int8` (off-TPU path; the chip
+    smoke's reference for the Mosaic kernel)."""
+    s = scale[:, None]
+    safe = jnp.where(s > 0, s, jnp.ones_like(s))
+    return jnp.clip(jnp.round(x / safe), -127.0, 127.0) * safe
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -243,9 +210,7 @@ def quantdequant_int8(
     rows, cols = x.shape
     mode = _mode(interpret)
     if mode == "xla":
-        s = scale[:, None]
-        safe = jnp.where(s > 0, s, jnp.ones_like(s))
-        return jnp.clip(jnp.round(x / safe), -127.0, 127.0) * safe
+        return quantdequant_int8_jnp(x, scale)
     rb, cb = _blocks(rows, cols)
     grid = (pl.cdiv(rows, rb), pl.cdiv(cols, cb))
     return pl.pallas_call(

@@ -13,9 +13,9 @@ from typing import Callable, Tuple
 
 import flax.linen as nn
 import jax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from fedtpu.utils.platform import shard_map
 from fedtpu.config import RoundConfig
 from fedtpu.core.round import (
     FederatedState,

@@ -78,6 +78,7 @@ from fedtpu.transport.service import (
     probe,
     trace_context_of,
 )
+from fedtpu.utils.platform import enable_compile_cache
 
 log = logging.getLogger("fedtpu.federation")
 
@@ -148,6 +149,7 @@ class LocalTrainer:
                 f"cfg.num_classes={cfg.num_classes} but dataset "
                 f"'{cfg.data.dataset}' has {n_classes} classes"
             )
+        enable_compile_cache()
         self.model = model_zoo.create(cfg.model, num_classes=cfg.num_classes)
         self.images, self.labels = load(
             cfg.data.dataset, "train", seed=cfg.data.seed, num=cfg.data.num_examples
@@ -800,6 +802,7 @@ class PrimaryServer:
         # CompileWatcher and hands it over so /statusz can surface compile
         # counts + steady-state recompile warnings.
         self.compile_watcher = None
+        enable_compile_cache()
         self.model = model_zoo.create(cfg.model, num_classes=cfg.num_classes)
         shape = dataset_info(cfg.data.dataset)[0]
         variables = self.model.init(

@@ -6,7 +6,8 @@
 // top-k / int8 payloads; the selection and packing below are the host-side
 // hot loops (the on-device path uses Pallas kernels, fedtpu/ops/pallas_kernels.py).
 //
-// Build: make -C native   (g++ -O3 -shared -fPIC)
+// Build: on first use by fedtpu/native.py (g++ -O3 -shared -fPIC), under a
+// file name hashed from this source and the flags.
 // ABI: plain C, loaded via ctypes (no pybind11 in this environment).
 
 #include <algorithm>

@@ -250,22 +250,27 @@ def test_pallas_blocks_are_mosaic_legal():
 
 
 # ----------------------------------------------------- sketch codecs (flat)
-def test_hadamard_rotate_interpret_matches_lax(rng):
-    """Interpreted pallas butterfly vs the plain-lax branch: identical up
-    to float-associativity, for a forward and an inverse rotation. This is
-    the parity pin the docstring promises — the Mosaic-compiled body runs
-    the same program on TPU."""
+def test_hadamard_rotate_matches_host_fwht(rng):
+    """The device rotation vs the wire codec's numpy butterfly
+    (``transport.sparse._fwht_np`` — what the gRPC edge decodes with):
+    identical up to float-associativity, forward and inverse. The chip
+    smoke makes the same comparison on the TPU at the 2^20-column row."""
+    from fedtpu.transport.sparse import _fwht_np
+
     for rows, h in [(1, 8), (4, 64), (9, 256)]:
-        y = jnp.asarray(rng.normal(size=(rows, h)).astype(np.float32))
-        signs = jnp.asarray(
-            (rng.integers(0, 2, size=h).astype(np.float32)) * 2 - 1
-        )
-        for inverse in (False, True):
-            ref = pk.hadamard_rotate(y, signs, inverse=inverse)
-            got = pk.hadamard_rotate(y, signs, inverse=inverse,
-                                     interpret=True)
+        y = rng.normal(size=(rows, h)).astype(np.float32)
+        signs = rng.integers(0, 2, size=h).astype(np.float32) * 2 - 1
+        norm = np.float32(1.0 / np.sqrt(h))
+        fwht = lambda m: np.stack([_fwht_np(row) for row in m])  # 1-D twin
+        for inverse, ref in (
+            (False, fwht(y * signs) * norm),
+            (True, fwht(y) * norm * signs),
+        ):
+            got = pk.hadamard_rotate(
+                jnp.asarray(y), jnp.asarray(signs), inverse=inverse
+            )
             np.testing.assert_allclose(
-                np.asarray(got), np.asarray(ref), rtol=1e-5, atol=1e-5
+                np.asarray(got), ref, rtol=1e-5, atol=1e-5
             )
 
 

@@ -14,17 +14,6 @@ import socket
 import subprocess
 import sys
 
-import pytest
-
-# Capability marker, not a bug marker: some jaxlib CPU builds (observed:
-# 0.4.37 in this container) implement jax.distributed bring-up but NOT
-# cross-process computations on the CPU backend — every program spanning the
-# two processes dies with this exact runtime error regardless of what fedtpu
-# does. Skipping on it keeps the tier-1 dots honest where the capability is
-# absent while the test still runs in full wherever multiprocess CPU
-# collectives exist.
-_NO_MULTIPROC_CPU = "Multiprocess computations aren't implemented on the CPU"
-
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SCRIPT = os.path.join(_REPO, "examples", "multihost_cpu.py")
 
@@ -83,12 +72,6 @@ def _run_and_check(markers, agree_keys, extra=()):
         outs = _launch(_free_port(), extra=extra)
         if all(rc == 0 for rc, _, _ in outs) or attempt == 1:
             break
-    if any(_NO_MULTIPROC_CPU in err for _, _, err in outs):
-        pytest.skip(
-            "jaxlib CPU backend in this environment cannot run cross-process "
-            "computations (XlaRuntimeError: Multiprocess computations aren't "
-            "implemented on the CPU backend)"
-        )
     for rc, out, err in outs:
         assert rc == 0, f"child failed (rc={rc}):\n{out}\n{err}"
         for marker in markers:

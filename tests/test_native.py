@@ -1,8 +1,9 @@
 """Native host codec (native/codec.cpp via ctypes) vs numpy oracles.
 
 Every binding is exercised against its pure-numpy fallback on the same
-inputs; if the toolchain is unavailable the fallback is what runs and the
-oracle comparison is still meaningful (self-consistency).
+inputs; the library builds itself on first use (``native.load``), and if
+the toolchain is unavailable the fallback is what runs and the oracle
+comparison is still meaningful (self-consistency).
 """
 
 import numpy as np
@@ -11,9 +12,15 @@ import pytest
 from fedtpu import native
 
 
-@pytest.fixture(scope="module", autouse=True)
-def built():
-    native.ensure_built()
+def test_library_name_is_tied_to_source_and_flags(monkeypatch):
+    """The loader only ever opens the file named by a hash of the tracked
+    source + flags, so a binary from another build is never picked up."""
+    base = native._lib_path()
+    assert "libfedtpu_native-" in base
+    if native.available():
+        assert native.codec_name() == "native:" + base.rsplit("/", 1)[1]
+    monkeypatch.setattr(native, "_CXX", native._CXX + ("-march=native",))
+    assert native._lib_path() != base
 
 
 def test_kth_magnitude_matches_partition(rng):

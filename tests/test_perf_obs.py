@@ -37,22 +37,33 @@ import trace_merge  # noqa: E402
 
 
 # ------------------------------------------------------------ peaks/roofline
-def test_device_peaks_table_and_env_override(monkeypatch):
+def test_device_peaks_table_and_cpu_stand_ins(monkeypatch):
     monkeypatch.delenv("FEDTPU_PEAK_FLOPS", raising=False)
     monkeypatch.delenv("FEDTPU_PEAK_HBM_BYTES", raising=False)
     assert device_peaks("TPU v5 lite") == (197e12, 819e9)
+    assert device_peaks("TPU v5e") == (197e12, 819e9)
     assert device_peaks("TPU v4") == (275e12, 1228e9)
     assert device_peaks("TPU v6e")[0] == 918e12
     assert device_peaks("cpu") == (None, None)
     assert device_peaks("") == (None, None)
-    # Env overrides are the only path to MFU on uncovered hardware.
+    # The env stand-ins serve a backend with no peaks of its own ...
     monkeypatch.setenv("FEDTPU_PEAK_FLOPS", "1e12")
     monkeypatch.setenv("FEDTPU_PEAK_HBM_BYTES", "5e10")
     assert device_peaks("cpu") == (1e12, 5e10)
-    # ... and win over the table.
-    assert device_peaks("TPU v4") == (1e12, 5e10)
-    monkeypatch.setenv("FEDTPU_PEAK_FLOPS", "not-a-number")
-    assert device_peaks("TPU v4")[0] == 275e12
+    # ... and never displace a table row.
+    assert device_peaks("TPU v4") == (275e12, 1228e9)
+
+
+def test_device_peaks_unknown_kind_raises_on_tpu(monkeypatch):
+    """On a TPU backend a device the table does not know is an error, not
+    a silently dropped MFU — and no env stand-in rescues it."""
+    import jax
+
+    monkeypatch.setenv("FEDTPU_PEAK_FLOPS", "1e12")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="PEAK_TABLE"):
+        device_peaks("TPU v9 hypothetical")
+    assert device_peaks("TPU v5 lite") == (197e12, 819e9)
 
 
 def test_roofline_classification():
@@ -88,8 +99,6 @@ def test_analytic_flops_agrees_with_xla_on_matmul():
     got = analytic_flops(f, a, b)
     assert got == expect
     an = jax.jit(f).lower(a, b).compile().cost_analysis()
-    if isinstance(an, (list, tuple)):
-        an = an[0] if an else {}
     xla = float(an.get("flops", 0.0))
     if xla:  # cost analysis availability varies by backend
         assert got == pytest.approx(xla, rel=0.05)
@@ -254,6 +263,23 @@ def test_engine_round_records_and_statusz_carry_mfu(monkeypatch):
     snap = fed.status_snapshot()
     assert snap["perf"]["mfu"] > 0
     assert snap["perf"]["flops_per_round"] > 0
+
+    # The wall handed to the profiler ends when the DEVICE has finished, not
+    # at the (asynchronous) enqueue — the first run on a v5e reported an
+    # MFU of 6.27 from enqueue walls. An unarmed engine stays asynchronous.
+    import jax
+
+    waits = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(
+        jax, "block_until_ready", lambda x: (waits.append(1), real(x))[1]
+    )
+    fed.run_on_device(2)
+    assert waits
+    fed.profiler = None
+    waits.clear()
+    fed.run_on_device(2)
+    assert not waits
 
 
 # -------------------------------------------------------- latency summary

@@ -242,9 +242,8 @@ def roofline_stamp(profile_path: str) -> dict:
     Accepts the ``--mfu-profile`` schema (``{"configs": [...]}`` where each
     row has ``flops_per_round``/``bytes_per_round``/``device_kind`` and
     usually ``rounds_per_sec``) or a flat dict with the same per-row keys.
-    Peaks resolve through ``fedtpu.obs.profile.device_peaks`` (honouring
-    the ``FEDTPU_PEAK_*`` env overrides); utilization is filled when the
-    row carries an achieved rate. Imports fedtpu lazily — see module
+    Peaks resolve through ``fedtpu.obs.profile.device_peaks`` (the one
+    table); utilization is filled when the row carries an achieved rate. Imports fedtpu lazily — see module
     docstring."""
     import os
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))

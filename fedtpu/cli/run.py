@@ -165,7 +165,8 @@ def main(argv=None) -> int:
         fed.compile_watcher = compile_w
     mfu_mode = resolve_mfu_mode(args)
     if mfu_mode != "off" and hasattr(fed, "enable_mfu_accounting"):
-        fed.enable_mfu_accounting(xla_check=mfu_mode == "xla")
+        profiler = fed.enable_mfu_accounting(xla_check=mfu_mode == "xla")
+        logging.info("mfu cost model: %s", profiler.cost.as_dict())
     capture = make_capture_window(args, role="engine", telemetry=fed.telemetry)
     ckpt, start_round, state = _restore_from(
         args, like=fed.state, telemetry=fed.telemetry, flight=flight,

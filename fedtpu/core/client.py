@@ -88,8 +88,9 @@ def make_local_update(
         if use_augment:
             from fedtpu.data.augment import augment_batch
 
-            aug_rng, rng = jax.random.split(rng)
-            x = augment_batch(aug_rng, x, crop=cfg.data.augment_crop)
+            with jax.named_scope("fed.data"):
+                aug_rng, rng = jax.random.split(rng)
+                x = augment_batch(aug_rng, x, crop=cfg.data.augment_crop)
         # True mixed precision: master params stay f32 in FederatedState;
         # casting them (not just x) at use keeps the WHOLE forward in the
         # compute dtype — flax layers otherwise promote bf16 activations
@@ -122,6 +123,7 @@ def make_local_update(
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
+    @jax.named_scope("fed.local_step")
     def _run_scan(
         global_params, global_stats, opt_state, step_elems, get_xy,
         steps, step_mask, rng, round_idx, anchor=None,
@@ -137,10 +139,12 @@ def make_local_update(
         def one_step(carry, batch):
             params, stats, ostate = carry
             elem, live, step_rng = batch
-            x, y = get_xy(elem)
-            (loss, (new_stats, ce, acc)), grads = grad_fn(
-                params, stats, anchor, x, y, step_rng
-            )
+            with jax.named_scope("fed.data"):
+                x, y = get_xy(elem)
+            with jax.named_scope("fed.local_step.fwd_bwd"):
+                (loss, (new_stats, ce, acc)), grads = grad_fn(
+                    params, stats, anchor, x, y, step_rng
+                )
             if cfg.debug_per_batch:
                 # Reference parity (src/utils.py:51-92): per-batch loss/acc
                 # lines mid-epoch. A host callback per batch — debugging
@@ -148,20 +152,26 @@ def make_local_update(
                 jax.debug.print(
                     "  batch: loss {l:.4f} acc {a:.4f}", l=ce, a=acc
                 )
-            new_params, new_ostate = optim.apply(params, grads, ostate, lr, cfg.opt)
-            # Masked steps (padding of ragged shards / dead clients) change
-            # nothing — the reference equivalent is the client simply not
-            # having that batch.
-            live_f = live.astype(jnp.float32)
-            params = jax.tree.map(
-                lambda new, old: jnp.where(live, new, old), new_params, params
-            )
-            stats = jax.tree.map(
-                lambda new, old: jnp.where(live, new, old), new_stats, stats
-            )
-            ostate = jax.tree.map(
-                lambda new, old: jnp.where(live, new, old), new_ostate, ostate
-            )
+            with jax.named_scope("fed.local_step.optimizer"):
+                new_params, new_ostate = optim.apply(
+                    params, grads, ostate, lr, cfg.opt
+                )
+                # Masked steps (padding of ragged shards / dead clients)
+                # change nothing — the reference equivalent is the client
+                # simply not having that batch.
+                live_f = live.astype(jnp.float32)
+                params = jax.tree.map(
+                    lambda new, old: jnp.where(live, new, old),
+                    new_params, params,
+                )
+                stats = jax.tree.map(
+                    lambda new, old: jnp.where(live, new, old),
+                    new_stats, stats,
+                )
+                ostate = jax.tree.map(
+                    lambda new, old: jnp.where(live, new, old),
+                    new_ostate, ostate,
+                )
             return (params, stats, ostate), (ce * live_f, acc * live_f, live_f)
 
         step_rngs = jax.random.split(rng, steps)
@@ -335,8 +345,9 @@ def make_local_update_mega(
         if use_augment:
             from fedtpu.data.augment import augment_batch
 
-            aug_rng, rng = jax.random.split(rng)
-            x = augment_batch(aug_rng, x, crop=cfg.data.augment_crop)
+            with jax.named_scope("fed.data"):
+                aug_rng, rng = jax.random.split(rng)
+                x = augment_batch(aug_rng, x, crop=cfg.data.augment_crop)
         if compute_dtype != jnp.float32:
             cast = jax.tree.map(lambda p: p.astype(compute_dtype), params)
         else:
@@ -365,6 +376,7 @@ def make_local_update_mega(
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
+    @jax.named_scope("fed.local_step")
     def _run_scan(
         global_params, global_stats, opt_state, step_elems, get_xy,
         steps, member_mask, rng, round_idx, anchor=None,
@@ -375,29 +387,35 @@ def make_local_update_mega(
         def one_step(carry, batch):
             params, stats, ostate = carry
             elem, live_m, step_rng = batch  # live_m: [k]
-            x, y = get_xy(elem)
-            live_f = live_m.astype(jnp.float32)
-            exw = jnp.broadcast_to(
-                live_f[:, None], (k, x.shape[0] // k)
-            ).reshape(-1)
-            (loss, (new_stats, ce_m, acc_m)), grads = grad_fn(
-                params, stats, anchor, x, y, exw, step_rng
-            )
-            new_params, new_ostate = optim.apply(
-                params, grads, ostate, lr, cfg.opt
-            )
-            # The group steps iff ANY member is live; all-masked steps are
-            # no-ops exactly like the per-client path.
-            live = live_m.any()
-            params = jax.tree.map(
-                lambda new, old: jnp.where(live, new, old), new_params, params
-            )
-            stats = jax.tree.map(
-                lambda new, old: jnp.where(live, new, old), new_stats, stats
-            )
-            ostate = jax.tree.map(
-                lambda new, old: jnp.where(live, new, old), new_ostate, ostate
-            )
+            with jax.named_scope("fed.data"):
+                x, y = get_xy(elem)
+            with jax.named_scope("fed.local_step.fwd_bwd"):
+                live_f = live_m.astype(jnp.float32)
+                exw = jnp.broadcast_to(
+                    live_f[:, None], (k, x.shape[0] // k)
+                ).reshape(-1)
+                (loss, (new_stats, ce_m, acc_m)), grads = grad_fn(
+                    params, stats, anchor, x, y, exw, step_rng
+                )
+            with jax.named_scope("fed.local_step.optimizer"):
+                new_params, new_ostate = optim.apply(
+                    params, grads, ostate, lr, cfg.opt
+                )
+                # The group steps iff ANY member is live; all-masked steps
+                # are no-ops exactly like the per-client path.
+                live = live_m.any()
+                params = jax.tree.map(
+                    lambda new, old: jnp.where(live, new, old),
+                    new_params, params,
+                )
+                stats = jax.tree.map(
+                    lambda new, old: jnp.where(live, new, old),
+                    new_stats, stats,
+                )
+                ostate = jax.tree.map(
+                    lambda new, old: jnp.where(live, new, old),
+                    new_ostate, ostate,
+                )
             return (params, stats, ostate), (ce_m * live_f, acc_m * live_f, live_f)
 
         step_rngs = jax.random.split(rng, steps)

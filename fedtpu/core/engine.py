@@ -274,12 +274,13 @@ class Federation:
         self._shuffle = shuffle
         self._img_shape = img_shape
         self._multi_steps = {}  # num_rounds -> compiled scan program
-        # Host-side telemetry (fedtpu.obs): spans wrap the per-round
-        # DISPATCH walls (device compute is async; use profile_rounds /
-        # the trace-mode jax bridge for on-device time), counters track
-        # rounds completed. Swappable post-construction — the jitted
-        # programs never close over it (bench.py --telemetry-microbench
-        # retimes one engine under all three modes).
+        # Host-side telemetry (fedtpu.obs): spans wrap what the HOST does
+        # in a round (fed.plan, fed.enqueue; device compute is async) and
+        # reach a --profile-rounds capture in every mode but "off", on the
+        # device operations' own clock; counters track rounds completed.
+        # Swappable post-construction — the jitted programs never close
+        # over it (bench.py --telemetry-microbench retimes one engine under
+        # all three modes).
         self.telemetry = Telemetry(cfg.fed.telemetry, role="engine")
         # Live status feed (fedtpu.obs.http: /statusz via --obs-port):
         # round/phase updates are one locked dict merge each — cheap enough
@@ -565,7 +566,7 @@ class Federation:
         r = self._round_number()
         self.status.update(round=r, phase="round")
         t0 = time.perf_counter()
-        with tel.span("round", round=r):
+        with tel.span("fed.round", step_num=r):
             metrics = self._step_impl(batch)
         self._observe(t0, metrics)
         self.status.update(round=r + 1, phase="idle")
@@ -588,31 +589,37 @@ class Federation:
             )
 
     def _step_impl(self, batch: Optional[RoundBatch] = None) -> RoundMetrics:
+        tel = self.telemetry
         r = self._round_number()
         if batch is not None:
             if self.mesh is not None:
                 from fedtpu.parallel.sharded import shard_batch
 
-                batch = shard_batch(batch, self.mesh, self.cfg.mesh_axis)
-            self._state, metrics = self._round_step(self._state, batch)
+                with tel.span("fed.plan"):
+                    batch = shard_batch(batch, self.mesh, self.cfg.mesh_axis)
+            with tel.span("fed.enqueue"):
+                self._state, metrics = self._round_step(self._state, batch)
             self._round_host = r + 1
             return metrics
-        d_images, d_labels, d_idx, d_mask = self._ensure_device_data()
-        extra = (
-            (jnp.asarray(self._attack_seats),)
-            if self._attack_seats is not None else ()
-        )
-        self._state, metrics = self._data_step(
-            self._state,
-            d_images,
-            d_labels,
-            d_idx,
-            d_mask,
-            self.weights,
-            self._placed(self._alive_for_round(r), sharded=True),
-            self._data_key,
-            *extra,
-        )
+        with tel.span("fed.plan"):
+            d_images, d_labels, d_idx, d_mask = self._ensure_device_data()
+            extra = (
+                (jnp.asarray(self._attack_seats),)
+                if self._attack_seats is not None else ()
+            )
+            alive = self._placed(self._alive_for_round(r), sharded=True)
+        with tel.span("fed.enqueue"):
+            self._state, metrics = self._data_step(
+                self._state,
+                d_images,
+                d_labels,
+                d_idx,
+                d_mask,
+                self.weights,
+                alive,
+                self._data_key,
+                *extra,
+            )
         self._round_host = r + 1
         return metrics
 
@@ -659,33 +666,38 @@ class Federation:
         self.status.update(round=r, phase="fused_rounds",
                            fused_block=num_rounds)
         t0 = time.perf_counter()
-        with tel.span("fused_rounds", round=r, num_rounds=num_rounds):
-            alive = np.stack(
-                [self._alive_for_round(r + i) for i in range(num_rounds)]
-            )
-            d_images, d_labels, d_idx, d_mask = self._ensure_device_data()
-            if self.mesh is None:
-                alive_dev = jnp.asarray(alive)
-            else:
-                from fedtpu.parallel.sharded import _put
-                from jax.sharding import PartitionSpec as P
+        with tel.span("fed.fused_rounds", round=r, num_rounds=num_rounds):
+            with tel.span("fed.plan"):
+                alive = np.stack(
+                    [self._alive_for_round(r + i) for i in range(num_rounds)]
+                )
+                d_images, d_labels, d_idx, d_mask = self._ensure_device_data()
+                if self.mesh is None:
+                    alive_dev = jnp.asarray(alive)
+                else:
+                    from fedtpu.parallel.sharded import _put
+                    from jax.sharding import PartitionSpec as P
 
-                alive_dev = _put(alive, self.mesh, P(None, self.cfg.mesh_axis))
-            extra = (
-                (jnp.asarray(self._attack_seats),)
-                if self._attack_seats is not None else ()
-            )
-            self._state, metrics = self._multi_step(num_rounds)(
-                self._state,
-                d_images,
-                d_labels,
-                d_idx,
-                d_mask,
-                self.weights,
-                alive_dev,
-                self._data_key,
-                *extra,
-            )
+                    alive_dev = _put(
+                        alive, self.mesh, P(None, self.cfg.mesh_axis)
+                    )
+                extra = (
+                    (jnp.asarray(self._attack_seats),)
+                    if self._attack_seats is not None else ()
+                )
+                multi_step = self._multi_step(num_rounds)
+            with tel.span("fed.enqueue"):
+                self._state, metrics = multi_step(
+                    self._state,
+                    d_images,
+                    d_labels,
+                    d_idx,
+                    d_mask,
+                    self.weights,
+                    alive_dev,
+                    self._data_key,
+                    *extra,
+                )
         self._observe(t0, metrics, rounds=num_rounds)
         self._round_host = r + num_rounds
         self.status.update(round=r + num_rounds, phase="idle")

@@ -193,6 +193,7 @@ def init_state(
     )
 
 
+@jax.named_scope("fed.aggregate")
 def _robust_over_clients(
     stacked: Pytree,
     alive_w: jnp.ndarray,
@@ -227,13 +228,15 @@ def _robust_over_clients(
         )[0]
     total = jnp.sum(alive_w)
     if axis_name is not None:
-        total = jax.lax.psum(total, axis_name)
+        with jax.named_scope("fed.aggregate.psum"):
+            total = jax.lax.psum(total, axis_name)
     alive_any = total > 0
 
     def leaf(x):
         if axis_name is not None:
-            x = jax.lax.all_gather(x, axis_name, axis=0, tiled=True)
-            w = jax.lax.all_gather(alive_w, axis_name, axis=0, tiled=True)
+            with jax.named_scope("fed.aggregate.all_gather"):
+                x = jax.lax.all_gather(x, axis_name, axis=0, tiled=True)
+                w = jax.lax.all_gather(alive_w, axis_name, axis=0, tiled=True)
         else:
             w = alive_w
         mask = (w > 0).reshape((-1,) + (1,) * (x.ndim - 1))
@@ -265,6 +268,7 @@ def _robust_over_clients(
 _KRUM_BIG = 1e30  # large-finite "infinity": keeps argmin/sums NaN-free
 
 
+@jax.named_scope("fed.aggregate")
 def _krum_over_clients(
     stacked: Pytree,
     alive_w: jnp.ndarray,
@@ -291,8 +295,9 @@ def _krum_over_clients(
     )
     w = alive_w
     if axis_name is not None:
-        X = jax.lax.all_gather(X, axis_name, axis=0, tiled=True)
-        w = jax.lax.all_gather(w, axis_name, axis=0, tiled=True)
+        with jax.named_scope("fed.aggregate.all_gather"):
+            X = jax.lax.all_gather(X, axis_name, axis=0, tiled=True)
+            w = jax.lax.all_gather(w, axis_name, axis=0, tiled=True)
     n = X.shape[0]
     alive = w > 0
     sq = jnp.sum(X * X, axis=1)
@@ -327,6 +332,7 @@ def _krum_over_clients(
     return jax.tree_util.tree_unflatten(treedef, out_leaves)
 
 
+@jax.named_scope("fed.aggregate")
 def _dp_clip(stacked: Pytree, clip_norm: float) -> Pytree:
     """Scale each client's delta so its GLOBAL L2 norm (across all leaves)
     is at most ``clip_norm`` (DP-FedAvg per-client sensitivity bound). Each
@@ -350,6 +356,7 @@ def _dp_clip(stacked: Pytree, clip_norm: float) -> Pytree:
     )
 
 
+@jax.named_scope("fed.aggregate")
 def _dp_noise(
     tree: Pytree, std: jnp.ndarray, round_idx: jnp.ndarray, seed: int
 ) -> Pytree:
@@ -366,6 +373,7 @@ def _dp_noise(
     return jax.tree_util.tree_unflatten(treedef, noised)
 
 
+@jax.named_scope("fed.aggregate")
 def flat_weighted_mean(rows: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
     """Weighted mean over a ``[clients, P]`` flat-row buffer — the streaming
     server pipeline's post-barrier combine (one fused reduce over rows that
@@ -385,6 +393,7 @@ def flat_weighted_mean(rows: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(rows * w, axis=0) / total.astype(rows.dtype)
 
 
+@jax.named_scope("fed.aggregate")
 def _mean_over_clients(stacked: Pytree, weights: jnp.ndarray, axis_name):
     """Masked weighted mean over the clients axis.
 
@@ -397,14 +406,16 @@ def _mean_over_clients(stacked: Pytree, weights: jnp.ndarray, axis_name):
     """
     total = jnp.sum(weights)
     if axis_name is not None:
-        total = jax.lax.psum(total, axis_name)
+        with jax.named_scope("fed.aggregate.psum"):
+            total = jax.lax.psum(total, axis_name)
     safe = jnp.where(total > 0, total, 1.0)
 
     def leaf_mean(x):
         w = weights.reshape((-1,) + (1,) * (x.ndim - 1)).astype(x.dtype)
         s = jnp.sum(x * w, axis=0)
         if axis_name is not None:
-            s = jax.lax.psum(s, axis_name)
+            with jax.named_scope("fed.aggregate.psum"):
+                s = jax.lax.psum(s, axis_name)
         return s / safe.astype(x.dtype)
 
     mean = jax.tree.map(leaf_mean, stacked)
@@ -433,6 +444,7 @@ def _megabatch_wrap(mega_v, k: int, stream) -> Callable[..., ClientOutput]:
     def group(x):
         return x.reshape((x.shape[0] // k, k) + x.shape[1:])
 
+    @jax.named_scope("fed.local_step")
     def wrapped(params, stats, opt_state, *rest):
         if stream:
             images, labels, takes, step_mask, rngs, round_idx = rest
@@ -692,12 +704,14 @@ def make_round_step(
         labels: Optional[jnp.ndarray] = None,
     ) -> Tuple[FederatedState, RoundMetrics]:
         n = batch.alive.shape[0]
-        rngs = jax.vmap(jax.random.fold_in)(
-            state.client_rng, jnp.broadcast_to(state.round_idx, (n,))
-        )
-        # Dead clients also get their steps masked out: they do no local work,
-        # mirroring a crashed reference client that never receives StartTrain.
-        step_mask = batch.step_mask & batch.alive[:, None]
+        with jax.named_scope("fed.local_step"):
+            rngs = jax.vmap(jax.random.fold_in)(
+                state.client_rng, jnp.broadcast_to(state.round_idx, (n,))
+            )
+            # Dead clients also get their steps masked out: they do no local
+            # work, mirroring a crashed reference client that never receives
+            # StartTrain.
+            step_mask = batch.step_mask & batch.alive[:, None]
         if stream:
             out: ClientOutput = vmapped(
                 state.params,
@@ -722,84 +736,95 @@ def make_round_step(
                 state.round_idx,
             )
 
-        if cfg.fed.weighted:
-            agg_w = batch.weights * batch.alive.astype(batch.weights.dtype)
-        else:
-            # Uniform over *active* clients — the reference averages uniformly
-            # (src/server.py:163-171) but (buggily) includes dead clients'
-            # stale files; we deliberately fix that, see SURVEY §"known bugs".
-            agg_w = batch.alive.astype(jnp.float32)
+        with jax.named_scope("fed.pack" if flat_mode else "fed.aggregate"):
+            if cfg.fed.weighted:
+                agg_w = batch.weights * batch.alive.astype(batch.weights.dtype)
+            else:
+                # Uniform over *active* clients — the reference averages
+                # uniformly (src/server.py:163-171) but (buggily) includes dead
+                # clients' stale files; we deliberately fix that, see SURVEY
+                # §"known bugs".
+                agg_w = batch.alive.astype(jnp.float32)
 
-        # Aggregate deltas rather than raw params: required for compression
-        # and numerically identical to averaging params when uncompressed.
-        deltas = jax.tree.map(
-            lambda c, g: c - g[None], out.params, state.params
-        )
-        if flat_mode:
-            # Pack ONCE per round into the lane-aligned [clients, P] buffer
-            # (fedtpu.ops.flat): compression, error feedback, DP clipping and
-            # the aggregation below each become one op over the whole model.
-            # A jnp array is itself a pytree, so every downstream combine
-            # (mean/median/trimmed_mean/krum, _dp_clip) applies unchanged;
-            # per-coordinate math is untouched, which is what keeps
-            # compression='none' and 'int8' bit-identical across layouts.
-            from fedtpu.ops import flat as flat_ops
-
-            flat_layout = flat_ops.make_layout(state.params, pow2=flat_pow2)
-            deltas = flat_ops.pack_stacked(flat_layout, deltas)
-        # Model-level adversaries (fedtpu.sim.adversary): malicious seats
-        # replace their honest delta with the attacked one BEFORE the codec
-        # — the attacker follows the protocol, only its update is hostile.
-        # Decisions (round window, per-round fire probability, colluding
-        # draws) are pure functions of (plan seed, round_idx) via jax.random
-        # — deterministic, so attack runs replay bit-identically from seed.
-        atk_fire = None
-        if attack_plan is not None and not isinstance(
-            batch.attack_seats, tuple
-        ):
-            from fedtpu.sim.adversary import attack_fire_mask
-
-            atk_fire = attack_fire_mask(
-                attack_plan, batch.attack_seats, state.round_idx, n
+            # Aggregate deltas rather than raw params: required for
+            # compression and numerically identical to averaging params when
+            # uncompressed.
+            deltas = jax.tree.map(
+                lambda c, g: c - g[None], out.params, state.params
             )
-            coef = jnp.where(
-                atk_fire, jnp.float32(attack_plan.coef), jnp.float32(1.0)
-            )
+            if flat_mode:
+                # Pack ONCE per round into the lane-aligned [clients, P]
+                # buffer (fedtpu.ops.flat): compression, error feedback, DP
+                # clipping and the aggregation below each become one op over
+                # the whole model. A jnp array is itself a pytree, so every
+                # downstream combine (mean/median/trimmed_mean/krum, _dp_clip)
+                # applies unchanged; per-coordinate math is untouched, which is
+                # what keeps compression='none' and 'int8' bit-identical across
+                # layouts.
+                from fedtpu.ops import flat as flat_ops
 
-            def poison(x):
-                c = coef.reshape((-1,) + (1,) * (x.ndim - 1))
-                return (x.astype(jnp.float32) * c).astype(x.dtype)
-
-            if attack_plan.coef != 1.0:
-                deltas = jax.tree.map(poison, deltas)
-            if attack_plan.kind == "noise":
-                nkey = jax.random.fold_in(
-                    jax.random.PRNGKey(attack_plan.seed ^ 0x4015E5),
-                    state.round_idx,
+                flat_layout = flat_ops.make_layout(
+                    state.params, pow2=flat_pow2
                 )
-                leaves, treedef = jax.tree_util.tree_flatten(deltas)
-                keys = jax.random.split(nkey, max(len(leaves), 1))
+                deltas = flat_ops.pack_stacked(flat_layout, deltas)
+            # Model-level adversaries (fedtpu.sim.adversary): malicious
+            # seats replace their honest delta with the attacked one BEFORE
+            # the codec — the attacker follows the protocol, only its update
+            # is hostile. Decisions (round window, per-round fire probability,
+            # colluding draws) are pure functions of (plan seed, round_idx)
+            # via jax.random — deterministic, so attack runs replay
+            # bit-identically from seed.
+            atk_fire = None
+            if attack_plan is not None and not isinstance(
+                batch.attack_seats, tuple
+            ):
+                from fedtpu.sim.adversary import attack_fire_mask
 
-                def noisy(x, k):
-                    # Colluding mode: ONE shared noise vector for the whole
-                    # malicious set (a consistent fake cluster — the attack
-                    # that defeats distance-based selection); otherwise
-                    # independent per-seat draws.
-                    shape = x.shape[1:] if attack_plan.collude else x.shape
-                    nz = (
-                        jax.random.normal(k, shape, jnp.float32)
-                        * attack_plan.std
-                    )
-                    nz = jnp.broadcast_to(nz, x.shape)
-                    m = atk_fire.reshape((-1,) + (1,) * (x.ndim - 1))
-                    return jnp.where(
-                        m, (x.astype(jnp.float32) + nz).astype(x.dtype), x
-                    )
-
-                deltas = jax.tree_util.tree_unflatten(
-                    treedef,
-                    [noisy(x, k) for x, k in zip(leaves, keys)],
+                atk_fire = attack_fire_mask(
+                    attack_plan, batch.attack_seats, state.round_idx, n
                 )
+                coef = jnp.where(
+                    atk_fire, jnp.float32(attack_plan.coef), jnp.float32(1.0)
+                )
+
+                def poison(x):
+                    c = coef.reshape((-1,) + (1,) * (x.ndim - 1))
+                    return (x.astype(jnp.float32) * c).astype(x.dtype)
+
+                if attack_plan.coef != 1.0:
+                    deltas = jax.tree.map(poison, deltas)
+                if attack_plan.kind == "noise":
+                    nkey = jax.random.fold_in(
+                        jax.random.PRNGKey(attack_plan.seed ^ 0x4015E5),
+                        state.round_idx,
+                    )
+                    leaves, treedef = jax.tree_util.tree_flatten(deltas)
+                    keys = jax.random.split(nkey, max(len(leaves), 1))
+
+                    def noisy(x, k):
+                        # Colluding mode: ONE shared noise vector for the
+                        # whole malicious set (a consistent fake cluster — the
+                        # attack that defeats distance-based selection);
+                        # otherwise independent per-seat draws.
+                        shape = (
+                            x.shape[1:] if attack_plan.collude else x.shape
+                        )
+                        nz = (
+                            jax.random.normal(k, shape, jnp.float32)
+                            * attack_plan.std
+                        )
+                        nz = jnp.broadcast_to(nz, x.shape)
+                        m = atk_fire.reshape((-1,) + (1,) * (x.ndim - 1))
+                        return jnp.where(
+                            m,
+                            (x.astype(jnp.float32) + nz).astype(x.dtype),
+                            x,
+                        )
+
+                    deltas = jax.tree_util.tree_unflatten(
+                        treedef,
+                        [noisy(x, k) for x, k in zip(leaves, keys)],
+                    )
         comp_state = state.comp_state
         if compressor is not None:
             if flat_mode:
@@ -819,124 +844,138 @@ def make_round_step(
             # drained either — keep the old residual so the correction is
             # carried until they actually contribute.
             if jax.tree_util.tree_leaves(comp_state):
-                keep = agg_w > 0
-                comp_state = jax.tree.map(
-                    lambda new, old: jnp.where(
-                        keep.reshape((-1,) + (1,) * (new.ndim - 1)), new, old
-                    ),
-                    new_comp,
-                    comp_state,
-                )
+                with jax.named_scope("fed.codec.feedback"):
+                    keep = agg_w > 0
+                    comp_state = jax.tree.map(
+                        lambda new, old: jnp.where(
+                            keep.reshape((-1,) + (1,) * (new.ndim - 1)),
+                            new, old,
+                        ),
+                        new_comp,
+                        comp_state,
+                    )
             else:
                 comp_state = new_comp
-        # BN stats deltas combine with the same rule as params (reference
-        # averages the full state_dict, src/server.py:163-171); computed
-        # here because krum must select ONE client jointly for both trees.
-        stats_delta = jax.tree.map(
-            lambda c, g: c - g[None], out.batch_stats, state.batch_stats
-        )
-        if atk_fire is not None and attack_plan.coef != 1.0:
-            # The attacker poisons its WHOLE submission coherently (krum
-            # selects params + stats jointly, so a clean stats tree would
-            # leak the honest update).
-            stats_delta = jax.tree.map(poison, stats_delta)
-        # Fused screening: one stats pass over the flat rows; rejected rows
-        # leave the combine through the same zero-weight mask dead clients
-        # use, so the weighted mean / robust aggregators are untouched
-        # bit-cleanly for the survivors.
-        screened = jnp.zeros((n,), bool)
-        if screen is not None:
-            from fedtpu.ops import flat as screen_flat_ops
+        with jax.named_scope("fed.aggregate"):
+            # BN stats deltas combine with the same rule as params
+            # (reference averages the full state_dict, src/server.py:163-171);
+            # computed here because krum must select ONE client jointly for
+            # both trees.
+            stats_delta = jax.tree.map(
+                lambda c, g: c - g[None], out.batch_stats, state.batch_stats
+            )
+            if atk_fire is not None and attack_plan.coef != 1.0:
+                # The attacker poisons its WHOLE submission coherently
+                # (krum selects params + stats jointly, so a clean stats tree
+                # would leak the honest update).
+                stats_delta = jax.tree.map(poison, stats_delta)
+            # Fused screening: one stats pass over the flat rows; rejected
+            # rows leave the combine through the same zero-weight mask dead
+            # clients use, so the weighted mean / robust aggregators are
+            # untouched bit-cleanly for the survivors.
+            screened = jnp.zeros((n,), bool)
+            if screen is not None:
+                from fedtpu.ops import flat as screen_flat_ops
 
-            rows = (
-                deltas if flat_mode
-                else screen_flat_ops.pack_stacked(
-                    screen_flat_ops.make_layout(state.params), deltas
+                rows = (
+                    deltas if flat_mode
+                    else screen_flat_ops.pack_stacked(
+                        screen_flat_ops.make_layout(state.params), deltas
+                    )
                 )
-            )
-            keep, _ = screen_flat_ops.screen_rows(
-                rows, agg_w, screen.norm_max, screen.zmax, screen.cos_min
-            )
-            screened = (agg_w > 0) & ~keep
-            agg_w = agg_w * keep.astype(agg_w.dtype)
-        if cfg.fed.dp_clip_norm > 0:
-            deltas = _dp_clip(deltas, cfg.fed.dp_clip_norm)
-        if cfg.fed.aggregator == "krum":
-            joint = _krum_over_clients(
-                {"p": deltas, "s": stats_delta}, agg_w, axis_name,
-                cfg.fed.trim_fraction,
-            )
-            mean_delta, mean_stats_delta = joint["p"], joint["s"]
-        else:
-            if cfg.fed.aggregator == "mean":
-                combine = lambda t: _mean_over_clients(t, agg_w, axis_name)[0]
-            else:  # median | trimmed_mean — validated at build time
-                combine = lambda t: _robust_over_clients(
-                    t, agg_w, axis_name, cfg.fed.aggregator,
+                keep, _ = screen_flat_ops.screen_rows(
+                    rows, agg_w, screen.norm_max, screen.zmax,
+                    screen.cos_min,
+                )
+                screened = (agg_w > 0) & ~keep
+                agg_w = agg_w * keep.astype(agg_w.dtype)
+            if cfg.fed.dp_clip_norm > 0:
+                deltas = _dp_clip(deltas, cfg.fed.dp_clip_norm)
+            if cfg.fed.aggregator == "krum":
+                joint = _krum_over_clients(
+                    {"p": deltas, "s": stats_delta}, agg_w, axis_name,
                     cfg.fed.trim_fraction,
                 )
-            mean_delta = combine(deltas)
-            mean_stats_delta = combine(stats_delta)
+                mean_delta, mean_stats_delta = joint["p"], joint["s"]
+            else:
+                if cfg.fed.aggregator == "mean":
+                    combine = lambda t: _mean_over_clients(
+                        t, agg_w, axis_name
+                    )[0]
+                else:  # median | trimmed_mean — validated at build time
+                    combine = lambda t: _robust_over_clients(
+                        t, agg_w, axis_name, cfg.fed.aggregator,
+                        cfg.fed.trim_fraction,
+                    )
+                mean_delta = combine(deltas)
+                mean_stats_delta = combine(stats_delta)
         if flat_mode:
             # Unpack ONCE, on the aggregated [P] row (not per client) —
             # BEFORE DP noise so the per-leaf noise draw is identical to the
             # per-leaf layout's.
             mean_delta = flat_ops.unpack(flat_layout, mean_delta)
         if cfg.fed.dp_clip_norm > 0 and cfg.fed.dp_noise_multiplier > 0:
-            n_participants = jnp.sum((agg_w > 0).astype(jnp.float32))
-            if axis_name is not None:
-                n_participants = jax.lax.psum(n_participants, axis_name)
-            std = (
-                cfg.fed.dp_clip_norm
-                * cfg.fed.dp_noise_multiplier
-                / jnp.maximum(n_participants, 1.0)
-            )
-            mean_delta = _dp_noise(
-                mean_delta, std, state.round_idx,
-                seed=cfg.data.seed ^ 0x5F5E5F,
-            )
+            with jax.named_scope("fed.aggregate"):
+                n_participants = jnp.sum((agg_w > 0).astype(jnp.float32))
+                if axis_name is not None:
+                    with jax.named_scope("fed.aggregate.psum"):
+                        n_participants = jax.lax.psum(
+                            n_participants, axis_name
+                        )
+                std = (
+                    cfg.fed.dp_clip_norm
+                    * cfg.fed.dp_noise_multiplier
+                    / jnp.maximum(n_participants, 1.0)
+                )
+                mean_delta = _dp_noise(
+                    mean_delta, std, state.round_idx,
+                    seed=cfg.data.seed ^ 0x5F5E5F,
+                )
         new_params, new_server_opt = server_opt_lib.apply(
             server_opt, state.params, mean_delta, state.server_opt_state
         )
-        new_stats = trees.tree_add(state.batch_stats, mean_stats_delta)
+        with jax.named_scope("fed.server_step"):
+            new_stats = trees.tree_add(state.batch_stats, mean_stats_delta)
 
-        alive_f = batch.alive.astype(jnp.float32)
-        loss_sum = jnp.sum(out.loss * alive_f)
-        acc_sum = jnp.sum(out.accuracy * alive_f)
-        n_alive = jnp.sum(alive_f)
-        if axis_name is not None:
-            loss_sum = jax.lax.psum(loss_sum, axis_name)
-            acc_sum = jax.lax.psum(acc_sum, axis_name)
-            n_alive = jax.lax.psum(n_alive, axis_name)
-        n_active = jnp.maximum(n_alive, 1.0)
-        metrics = RoundMetrics(
-            loss=loss_sum / n_active,
-            accuracy=acc_sum / n_active,
-            num_active=n_alive,
-            update_norm=trees.tree_norm(mean_delta),
-            per_client_loss=out.loss * alive_f,
-            screened=screened,
-        )
-        new_state = FederatedState(
-            params=new_params,
-            batch_stats=new_stats,
-            opt_state=out.opt_state,
-            client_rng=state.client_rng,
-            round_idx=state.round_idx + 1,
-            comp_state=comp_state,
-            server_opt_state=new_server_opt,
-            # Observe only clients that actually TRAINED this round: an
-            # alive client with an empty shard runs zero steps and its
-            # out.loss is a masked artifact (0.0) — recording it would hand
-            # loss-proportional sampling a stale zero that starves the
-            # client forever. Never-trained clients keep NaN and draw at
-            # the optimistic prior instead (fedtpu.sim.sampling).
-            last_client_loss=jnp.where(
-                step_mask.any(axis=1),
-                out.loss.astype(jnp.float32),
-                state.last_client_loss,
-            ),
-        )
+        with jax.named_scope("fed.metrics"):
+            alive_f = batch.alive.astype(jnp.float32)
+            loss_sum = jnp.sum(out.loss * alive_f)
+            acc_sum = jnp.sum(out.accuracy * alive_f)
+            n_alive = jnp.sum(alive_f)
+            if axis_name is not None:
+                with jax.named_scope("fed.aggregate.psum"):
+                    loss_sum = jax.lax.psum(loss_sum, axis_name)
+                    acc_sum = jax.lax.psum(acc_sum, axis_name)
+                    n_alive = jax.lax.psum(n_alive, axis_name)
+            n_active = jnp.maximum(n_alive, 1.0)
+            metrics = RoundMetrics(
+                loss=loss_sum / n_active,
+                accuracy=acc_sum / n_active,
+                num_active=n_alive,
+                update_norm=trees.tree_norm(mean_delta),
+                per_client_loss=out.loss * alive_f,
+                screened=screened,
+            )
+            new_state = FederatedState(
+                params=new_params,
+                batch_stats=new_stats,
+                opt_state=out.opt_state,
+                client_rng=state.client_rng,
+                round_idx=state.round_idx + 1,
+                comp_state=comp_state,
+                server_opt_state=new_server_opt,
+                # Observe only clients that actually TRAINED this round:
+                # an alive client with an empty shard runs zero steps and its
+                # out.loss is a masked artifact (0.0) — recording it would
+                # hand loss-proportional sampling a stale zero that starves
+                # the client forever. Never-trained clients keep NaN and draw
+                # at the optimistic prior instead (fedtpu.sim.sampling).
+                last_client_loss=jnp.where(
+                    step_mask.any(axis=1),
+                    out.loss.astype(jnp.float32),
+                    state.last_client_loss,
+                ),
+            )
         return new_state, metrics
 
     return round_step

@@ -52,6 +52,7 @@ def init(fed: FedConfig, params: Pytree) -> Pytree:
     return () if opt is None else opt.init(params)
 
 
+@jax.named_scope("fed.server_step")
 def apply(
     opt: Optional[optax.GradientTransformation],
     params: Pytree,

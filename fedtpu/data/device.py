@@ -216,28 +216,34 @@ def make_data_round_step(
         n = idx.shape[0]
         atk = () if attack_seats is None else attack_seats
         rng = None
-        if shuffle:
-            rng = jax.random.fold_in(data_key, state.round_idx)
-            if axis_name is not None:
-                # Decorrelate shuffles across mesh shards (the body sees only
-                # its local client rows; without this every device would draw
-                # the same per-row permutation pattern).
-                rng = jax.random.fold_in(rng, jax.lax.axis_index(axis_name))
-        take = round_take_indices(idx, mask, need, rng)
-        has_data = mask.any(axis=1)
-        step_mask = jnp.broadcast_to(has_data[:, None], (n, steps))
+        with jax.named_scope("fed.data"):
+            if shuffle:
+                rng = jax.random.fold_in(data_key, state.round_idx)
+                if axis_name is not None:
+                    # Decorrelate shuffles across mesh shards (the body sees
+                    # only its local client rows; without this every device
+                    # would draw the same per-row permutation pattern).
+                    rng = jax.random.fold_in(
+                        rng, jax.lax.axis_index(axis_name)
+                    )
+            take = round_take_indices(idx, mask, need, rng)
+            has_data = mask.any(axis=1)
+            step_mask = jnp.broadcast_to(has_data[:, None], (n, steps))
+            if stream:
+                takes = take.reshape((n, steps, batch_size))
         if stream:
-            takes = take.reshape((n, steps, batch_size))
             batch = RoundBatch(
                 x=takes, y=takes, step_mask=step_mask, weights=weights,
                 alive=alive, attack_seats=atk,
             )
             return base(state, batch, images, labels)
-        # Dataset may be stored flat ([N, H*W*C] — the TPU-friendly layout,
-        # reshaped back via image_shape) or as images (shape from the array).
-        tail = shape if images.ndim == 2 else tuple(images.shape[1:])
-        x = images[take].reshape((n, steps, batch_size) + tail)
-        y = labels[take].reshape((n, steps, batch_size))
+        with jax.named_scope("fed.data"):
+            # Dataset may be stored flat ([N, H*W*C] — the TPU-friendly
+            # layout, reshaped back via image_shape) or as images (shape
+            # from the array).
+            tail = shape if images.ndim == 2 else tuple(images.shape[1:])
+            x = images[take].reshape((n, steps, batch_size) + tail)
+            y = labels[take].reshape((n, steps, batch_size))
         batch = RoundBatch(
             x=x, y=y, step_mask=step_mask, weights=weights, alive=alive,
             attack_seats=atk,
@@ -257,15 +263,17 @@ def make_data_round_step(
     ) -> Tuple[FederatedState, RoundMetrics]:
         n = mask.shape[0]
         atk = () if attack_seats is None else attack_seats
-        rng = (
-            jax.random.fold_in(data_key, state.round_idx) if shuffle else None
-        )
-        off, shard_len = _round_offset(labels, shuffle, rng)
-        has_data = mask.any(axis=1)
-        step_mask = jnp.broadcast_to(has_data[:, None], (n, steps))
-        x, y = presharded_window(
-            images, labels, off, steps, batch_size, shape, stream=stream
-        )
+        with jax.named_scope("fed.data"):
+            rng = (
+                jax.random.fold_in(data_key, state.round_idx)
+                if shuffle else None
+            )
+            off, shard_len = _round_offset(labels, shuffle, rng)
+            has_data = mask.any(axis=1)
+            step_mask = jnp.broadcast_to(has_data[:, None], (n, steps))
+            x, y = presharded_window(
+                images, labels, off, steps, batch_size, shape, stream=stream
+            )
         batch = RoundBatch(
             x=x, y=y, step_mask=step_mask, weights=weights, alive=alive,
             attack_seats=atk,

@@ -26,12 +26,13 @@ accounting becomes *continuous*:
   ``tools/trace_merge.py`` can align device ops onto the host-span
   timeline.
 
-Shared scalar conventions (same as bench.py): FLOPs/bytes are PER ROUND
-from the SINGLE-round program — XLA cost analysis counts a ``lax.scan``
-body once regardless of trip count, so the fused multi-round program
-reports the same flops as one round. ``analytic_flops`` deliberately
-follows the same scan-once convention so the cross-check compares like
-with like.
+Shared scalar conventions: FLOPs/bytes are PER ROUND from the SINGLE-round
+program. XLA's cost analysis counts a ``lax.scan`` body ONCE regardless of
+trip count, so on a round of ``steps`` local steps it reads ``steps`` times
+too low (smallcnn, 6 steps: 6.6 x, PERF.md). ``analytic_flops`` multiplies
+every scan body by its length, and is what the MFU gauge is priced with;
+the ``analytic_vs_xla`` stamp then reads about ``steps`` wherever XLA
+counted once.
 """
 
 from __future__ import annotations
@@ -147,10 +148,12 @@ def _count_jaxpr(jaxpr) -> float:
                 (_count_jaxpr(b.jaxpr) for b in branches), default=0.0
             )
         else:
-            # scan/while bodies counted ONCE (the module's convention);
-            # everything else recursed structurally.
+            # A scan runs its body `length` times; a while's trip count is
+            # not in the jaxpr and counts once. Everything else recursed
+            # structurally.
+            trips = eqn.params["length"] if name == "scan" else 1
             for sub in _subjaxprs(eqn.params):
-                flops += _count_jaxpr(sub)
+                flops += trips * _count_jaxpr(sub)
     return flops
 
 
@@ -158,9 +161,8 @@ def analytic_flops(fn: Callable, *args, **kwargs) -> float:
     """Analytic FLOP count of ``fn(*args)``: 2 FLOPs per matmul/conv MAC,
     read off the traced jaxpr's shapes. Elementwise/reduction ops are
     excluded (MXU work dominates every zoo model by orders of magnitude);
-    ``lax.scan``/``while`` bodies are counted once — the same convention as
-    XLA's ``cost_analysis`` (see module docstring), so the two are directly
-    comparable."""
+    a ``lax.scan`` body counts ``length`` times (XLA's ``cost_analysis``
+    counts it once, see module docstring), a ``while`` body once."""
     import jax
 
     closed = jax.make_jaxpr(fn)(*args, **kwargs)
@@ -227,8 +229,8 @@ def _bytes_jaxpr(jaxpr) -> float:
     before. Layout eqns alias their output to their operand, so a
     pure-layout group charges nothing and a broadcast feeding another
     group charges its (small) operand, not the phantom broadcast bytes.
-    scan/while bodies counted ONCE (the module's convention —
-    comparable with XLA ``cost_analysis``); cond takes the worst branch.
+    A scan body counts ``length`` times (as :func:`_count_jaxpr`), a while
+    body once; cond takes the worst branch.
     """
     eqns = jaxpr.eqns
     total = 0.0
@@ -245,8 +247,11 @@ def _bytes_jaxpr(jaxpr) -> float:
         if subs:
             # The container eqn's own full-array operands are NOT added on
             # top: the body's boundary tensors carry the traffic.
+            trips = (
+                eqn.params["length"] if eqn.primitive.name == "scan" else 1
+            )
             for sub in subs:
-                total += _bytes_jaxpr(sub)
+                total += trips * _bytes_jaxpr(sub)
             opaque.add(i)
 
     producer: Dict[Any, int] = {}
@@ -340,8 +345,8 @@ def _bytes_jaxpr(jaxpr) -> float:
 
 def analytic_bytes(fn: Callable, *args, **kwargs) -> float:
     """Analytic HBM-traffic model of ``fn(*args)``: fusion-group boundary
-    bytes at the JAXPR avals' stated dtypes, scan/while bodies counted
-    once, shape/layout primitives free (see :func:`_bytes_jaxpr`).
+    bytes at the JAXPR avals' stated dtypes, scan bodies times their
+    length, shape/layout primitives free (see :func:`_bytes_jaxpr`).
 
     This is deliberately BACKEND-INDEPENDENT — read off the traced jaxpr,
     never the lowered HLO — because it exists to predict the TPU HBM
@@ -417,10 +422,11 @@ def roofline(
 # ------------------------------------------------------------- cost model
 class CostModel:
     """Per-round FLOP/byte figures for one round program, carrying both the
-    analytic count and the XLA cost-analysis one plus their agreement
-    ratio. ``flops`` prefers XLA (it sees the post-optimisation HLO);
-    analytic is the cross-check and the fallback when AOT compilation is
-    unavailable (e.g. shard_map paths on some backends)."""
+    analytic count and the XLA cost-analysis one plus their ratio.
+    ``flops`` prefers the analytic walk: it multiplies the local steps'
+    scan in, which XLA's count leaves out (module docstring), so a gauge
+    priced with XLA's reads low by the number of local steps. XLA's is the
+    cross-check, and the fallback where the walk fails."""
 
     def __init__(
         self,
@@ -433,10 +439,10 @@ class CostModel:
         self.xla_bytes = xla_bytes or None
         self.analytic = analytic or None
         self.analytic_bytes = analytic_bytes or None
-        self.flops = self.xla_flops or self.analytic
+        self.flops = self.analytic or self.xla_flops
         self.source = (
-            "xla" if self.xla_flops else
-            ("analytic" if self.analytic else None)
+            "analytic" if self.analytic else
+            ("xla" if self.xla_flops else None)
         )
         self.agreement = (
             round(self.analytic / self.xla_flops, 4)
@@ -843,15 +849,3 @@ class CaptureWindow:
             log.warning("profiler capture stop failed: %s", e)
         else:
             log.info("profiler capture window closed: %s", self.trace_dir)
-
-
-def find_device_trace(trace_dir: str) -> Optional[str]:
-    """Locate the newest ``*.trace.json.gz`` a ``jax.profiler.trace``
-    session wrote under ``trace_dir`` (layout:
-    ``plugins/profile/<run>/<host>.trace.json.gz``); None if absent."""
-    hits: List[str] = []
-    for dirpath, _dirs, files in os.walk(trace_dir):
-        for f in files:
-            if f.endswith(".trace.json.gz") or f.endswith(".trace.json"):
-                hits.append(os.path.join(dirpath, f))
-    return max(hits, key=os.path.getmtime) if hits else None

@@ -7,11 +7,14 @@
   round records stays (it is part of the round() API, and its thread-safe
   counters are a correctness fix, not telemetry).
 - ``"basic"`` (default) — the metrics registry is live (counters, gauges,
-  histograms; exportable as Prometheus text), no spans. Measured overhead:
+  histograms; exportable as Prometheus text). ``span()`` returns the bare
+  ``jax.profiler`` annotation: nothing is recorded in memory, and the span
+  reaches a profiler session (``--profile-rounds``) whenever one is
+  listening, at the cost of a flag test when none is. Measured overhead:
   well under 1% of round wall time (``bench.py --telemetry-microbench``,
   artifacts/TELEMETRY_MICROBENCH.json).
-- ``"trace"`` — basic plus the span tracer (Chrome-trace export, jax
-  TraceAnnotation bridge). Spans cost ~a microsecond each; fine for
+- ``"trace"`` — basic plus the span tracer (in-memory spans, Chrome-trace
+  export, the same annotation). Spans cost ~a microsecond each; fine for
   diagnosis runs, off the default path.
 
 Each engine/server owns ONE Telemetry instance (its registry is that
@@ -31,7 +34,7 @@ from fedtpu.obs.registry import (
     Histogram,
     MetricsRegistry,
 )
-from fedtpu.obs.trace import NULL_SPAN, SpanTracer
+from fedtpu.obs.trace import NULL_SPAN, SpanTracer, profiler_span
 
 TELEMETRY_MODES = ("off", "basic", "trace")
 
@@ -86,7 +89,6 @@ class Telemetry:
 
     def __init__(self, mode: str = "basic",
                  registry: Optional[MetricsRegistry] = None,
-                 bridge_jax: Optional[bool] = None,
                  role: Optional[str] = None):
         self.mode = validate_telemetry_mode(mode)
         # Process/component identity for multi-process trace stitching and
@@ -100,18 +102,13 @@ class Telemetry:
         # ``telemetry.registry`` to the FT modules is unconditional); the
         # off gate lives in the instrument getters below.
         self.registry = registry if registry is not None else MetricsRegistry()
-        # Bridge framework spans to jax.profiler.TraceAnnotation by default
-        # whenever we trace at all — TraceAnnotation is a no-op-cheap
-        # TraceMe outside an active profiler session.
-        if bridge_jax is None:
-            bridge_jax = self.tracing
-        self.tracer = SpanTracer(bridge_jax=bridge_jax) if self.tracing else None
+        self.tracer = SpanTracer() if self.tracing else None
 
     # ------------------------------------------------------------- spans
     def span(self, name: str, parent=None, **args):
-        if self.tracer is None:
-            return NULL_SPAN
-        return self.tracer.span(name, parent=parent, **args)
+        if self.tracer is not None:
+            return self.tracer.span(name, parent=parent, **args)
+        return profiler_span(name, args) if self.enabled else NULL_SPAN
 
     def trace_events(self):
         return self.tracer.events() if self.tracer is not None else []

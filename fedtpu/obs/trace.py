@@ -14,13 +14,14 @@ Parentage defaults to the innermost open span **on the same thread**
 (a thread-local stack); cross-thread children pass ``parent=`` explicitly
 (:meth:`SpanTracer.span` / :meth:`SpanTracer.current_id`).
 
-The jax bridge: with ``bridge_jax=True`` every span also enters a
-``jax.profiler.TraceAnnotation`` of the same name, so when a jax profiler
-session is active (``fedtpu.utils.progress.profile_rounds`` /
-``--profile-dir``) XLA device activity nests under the framework spans in
-the XProf timeline. TraceAnnotation is a no-op-cheap TraceMe when no
-session is active; the import is lazy and failure-tolerant so the tracer
-itself never drags in a backend.
+The profiler's clock: every span also enters a
+``jax.profiler.TraceAnnotation`` of the same name (:func:`profiler_span`),
+so a jax profiler session (``--profile-rounds``) records the framework's
+spans in the same ``.xplane.pb``, on the same clock, as the device's
+operations — what ``tools/gap_analyze.py`` attributes idle gaps to. Whether
+a session is listening is the annotation's own flag test in C++ (about
+0.4 us a span when none is); the import is lazy so that this module never
+drags in a backend.
 """
 
 from __future__ import annotations
@@ -34,8 +35,8 @@ from typing import Any, Dict, List, Optional
 
 
 class _NullSpan:
-    """Shared no-op span: what ``Telemetry.span`` returns below ``trace``
-    mode. ``id`` is None so ``parent=span.id`` chains stay valid."""
+    """Shared no-op span: what ``Telemetry.span`` returns in ``off`` mode.
+    ``id`` is None so ``parent=span.id`` chains stay valid."""
 
     __slots__ = ()
     id = None
@@ -48,6 +49,20 @@ class _NullSpan:
 
 
 NULL_SPAN = _NullSpan()
+
+
+def profiler_span(name: str, args: Dict[str, Any]):
+    """The span as the jax profiler records it, and nothing else: a
+    ``TraceAnnotation`` (a ``StepTraceAnnotation`` when ``args`` carries
+    ``step_num``, so that a viewer groups device work by round). What
+    ``Telemetry.span`` returns in ``basic`` mode; ``id`` is None like
+    :data:`NULL_SPAN`'s, since only a recorded span has one."""
+    from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+    kind = StepTraceAnnotation if "step_num" in args else TraceAnnotation
+    span = kind(name, **args)
+    span.id = None
+    return span
 
 
 class _Span:
@@ -70,22 +85,14 @@ class _Span:
             self.parent = tr.current_id()
         stack = tr._stack()
         stack.append(self.id)
-        if tr._annotation is not None:
-            try:
-                self._ann = tr._annotation(self.name)
-                self._ann.__enter__()
-            except Exception:
-                self._ann = None
+        self._ann = profiler_span(self.name, self.args)
+        self._ann.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.monotonic()
-        if self._ann is not None:
-            try:
-                self._ann.__exit__(*exc)
-            except Exception:
-                pass
+        self._ann.__exit__(*exc)
         tr = self._tracer
         stack = tr._stack()
         if stack and stack[-1] == self.id:
@@ -108,7 +115,7 @@ class _Span:
 class SpanTracer:
     """Collects spans; thread-safe; export via :func:`write_chrome_trace`."""
 
-    def __init__(self, bridge_jax: bool = False):
+    def __init__(self):
         self._events: List[dict] = []
         self._lock = threading.Lock()
         self._t0 = time.monotonic()
@@ -126,14 +133,6 @@ class SpanTracer:
         # called with the finished Chrome event OUTSIDE the tracer lock.
         # Must never raise into the traced code path.
         self.sink = None
-        self._annotation = None
-        if bridge_jax:
-            try:
-                from jax.profiler import TraceAnnotation
-
-                self._annotation = TraceAnnotation
-            except Exception:
-                self._annotation = None
 
     def _stack(self) -> List[int]:
         stack = getattr(self._local, "stack", None)

@@ -122,6 +122,7 @@ def _make_apply(
     """Lift a per-leaf ``(delta, residual) -> (compressed, new_residual)``
     codec to pytrees, handling the no-error-feedback case (empty state)."""
 
+    @jax.named_scope("fed.codec")
     def apply(deltas: Pytree, state: Pytree) -> Tuple[Pytree, Pytree]:
         if error_feedback:
             pairs = jax.tree.map(lambda d, e: _CodecPair(*leaf(d, e)), deltas, state)
@@ -159,6 +160,7 @@ def _lift_flat(
     codec, unpack. Standalone-caller convenience — the round step packs its
     own buffer and calls ``apply_flat`` directly."""
 
+    @jax.named_scope("fed.codec")
     def apply(deltas: Pytree, state: Pytree) -> Tuple[Pytree, Pytree]:
         lay = flat_ops.make_layout_stacked(deltas, pow2=pow2)
         out, new_state = apply_flat(
@@ -177,15 +179,18 @@ def _make_topk_flat(fraction: float, error_feedback: bool) -> Compressor:
     coordinates instead of quantised leaf-by-leaf (the documented semantic
     difference between layouts; see docs/FLAT_DELTA.md)."""
 
+    @jax.named_scope("fed.codec")
     def apply_flat(y, state, lay):
         if error_feedback:
-            y = y + state
-        kth = flat_ops.topk_threshold(y, fraction, lay.total)
-        if kth is None:  # keep-all budget: nothing dropped, residual zero
-            return y, (jnp.zeros_like(y) if error_feedback else state)
-        if not error_feedback:
-            return jnp.where(jnp.abs(y) >= kth[:, None], y, 0.0), state
-        return pk.threshold_with_feedback(y, kth)
+            with jax.named_scope("fed.codec.feedback"):
+                y = y + state
+        with jax.named_scope("fed.codec.select"):
+            kth = flat_ops.topk_threshold(y, fraction, lay.total)
+            if kth is None:  # keep-all budget: nothing dropped, residual zero
+                return y, (jnp.zeros_like(y) if error_feedback else state)
+            if not error_feedback:
+                return jnp.where(jnp.abs(y) >= kth[:, None], y, 0.0), state
+            return pk.threshold_with_feedback(y, kth)
 
     return Compressor(
         init=_make_flat_init(error_feedback),
@@ -201,15 +206,19 @@ def _make_int8_flat(error_feedback: bool) -> Compressor:
     the per-leaf codec exactly (max is order-independent), so this path is
     bit-identical to ``layout='per_leaf'`` — pinned by the parity tests."""
 
+    @jax.named_scope("fed.codec")
     def apply_flat(y, state, lay):
         if error_feedback:
-            y = y + state
-        scale = flat_ops.int8_scales(y, lay)
-        safe = jnp.where(scale > 0, scale, jnp.ones_like(scale))
-        out = jnp.clip(jnp.round(y / safe), -127.0, 127.0) * safe
+            with jax.named_scope("fed.codec.feedback"):
+                y = y + state
+        with jax.named_scope("fed.codec.quantize"):
+            scale = flat_ops.int8_scales(y, lay)
+            safe = jnp.where(scale > 0, scale, jnp.ones_like(scale))
+            out = jnp.clip(jnp.round(y / safe), -127.0, 127.0) * safe
         if not error_feedback:
             return out, state
-        return out, y - out
+        with jax.named_scope("fed.codec.feedback"):
+            return out, y - out
 
     return Compressor(
         init=_make_flat_init(error_feedback),
@@ -256,9 +265,11 @@ def _make_rotq_flat(bits: int, error_feedback: bool) -> Compressor:
         )
     levels = float(2**bits - 1)
 
+    @jax.named_scope("fed.codec")
     def apply_flat(y, state, lay, round_idx=0):
         if error_feedback:
-            y = y + state
+            with jax.named_scope("fed.codec.feedback"):
+                y = y + state
         h = lay.padded
         if h & (h - 1):
             raise ValueError(
@@ -269,12 +280,14 @@ def _make_rotq_flat(bits: int, error_feedback: bool) -> Compressor:
         k_sign, k_unif = jax.random.split(key)
         signs = jax.random.rademacher(k_sign, (h,), jnp.float32)
         z = pk.hadamard_rotate(y, signs)
-        lo = jnp.min(z, axis=1, keepdims=True)
-        scale = (jnp.max(z, axis=1, keepdims=True) - lo) / levels
-        safe = jnp.where(scale > 0, scale, jnp.ones_like(scale))
-        u = jax.random.uniform(k_unif, z.shape, jnp.float32)
-        q = jnp.clip(jnp.floor((z - lo) / safe + u), 0.0, levels)
-        out = pk.hadamard_rotate(lo + q * safe, signs, inverse=True)
+        with jax.named_scope("fed.codec.quantize"):
+            lo = jnp.min(z, axis=1, keepdims=True)
+            scale = (jnp.max(z, axis=1, keepdims=True) - lo) / levels
+            safe = jnp.where(scale > 0, scale, jnp.ones_like(scale))
+            u = jax.random.uniform(k_unif, z.shape, jnp.float32)
+            q = jnp.clip(jnp.floor((z - lo) / safe + u), 0.0, levels)
+            dequantized = lo + q * safe
+        out = pk.hadamard_rotate(dequantized, signs, inverse=True)
         if lay.pad:
             out = jnp.concatenate(
                 [out[:, : lay.total], jnp.zeros_like(out[:, lay.total :])],
@@ -282,7 +295,8 @@ def _make_rotq_flat(bits: int, error_feedback: bool) -> Compressor:
             )
         if not error_feedback:
             return out, state
-        return out, y - out
+        with jax.named_scope("fed.codec.feedback"):
+            return out, y - out
 
     return Compressor(
         init=_make_flat_init(error_feedback, pow2=True),
@@ -308,18 +322,24 @@ def _make_randk_flat(fraction: float, error_feedback: bool) -> Compressor:
     (total/k - 1)-amplified kept mass into the residual and diverge.
     """
 
+    @jax.named_scope("fed.codec")
     def apply_flat(y, state, lay, round_idx=0):
         if error_feedback:
-            y = y + state
+            with jax.named_scope("fed.codec.feedback"):
+                y = y + state
         k = max(1, int(math.ceil(fraction * lay.total)))
         if k >= lay.total:  # keep-all budget
             return y, (jnp.zeros_like(y) if error_feedback else state)
-        key = jax.random.fold_in(jax.random.PRNGKey(_RANDK_SEED), round_idx)
-        idx = jax.random.choice(key, lay.total, (k,), replace=False)
-        mask = jnp.zeros((lay.padded,), jnp.float32).at[idx].set(1.0)
-        kept = y * mask[None, :]
+        with jax.named_scope("fed.codec.select"):
+            key = jax.random.fold_in(
+                jax.random.PRNGKey(_RANDK_SEED), round_idx
+            )
+            idx = jax.random.choice(key, lay.total, (k,), replace=False)
+            mask = jnp.zeros((lay.padded,), jnp.float32).at[idx].set(1.0)
+            kept = y * mask[None, :]
         if error_feedback:
-            return kept, y - kept
+            with jax.named_scope("fed.codec.feedback"):
+                return kept, y - kept
         return kept * jnp.float32(lay.total / k), state
 
     return Compressor(
@@ -374,20 +394,23 @@ def make_topk(
         shape = d.shape
         y = _flatten_leaf(d)
         if e is not None:
-            y = y + e.reshape(y.shape)
+            with jax.named_scope("fed.codec.feedback"):
+                y = y + e.reshape(y.shape)
         size = y.shape[1]
         k = max(1, int(math.ceil(fraction * size)))
         if k >= size:
             return y.reshape(shape).astype(d.dtype), jnp.zeros(shape, jnp.float32)
-        # k-th largest magnitude per client row is the keep threshold.
-        kth = jax.lax.top_k(jnp.abs(y), k)[0][:, -1]
-        if e is None:
-            # No residual output wanted: a plain masked select, which XLA
-            # fuses; the two-output kernel would force a dead full-size write.
-            out = jnp.where(jnp.abs(y) >= kth[:, None], y, 0.0)
-            return out.reshape(shape).astype(d.dtype), None
-        out, new_e = pk.threshold_with_feedback(y, kth)
-        return out.reshape(shape).astype(d.dtype), new_e.reshape(shape)
+        with jax.named_scope("fed.codec.select"):
+            # k-th largest magnitude per client row is the keep threshold.
+            kth = jax.lax.top_k(jnp.abs(y), k)[0][:, -1]
+            if e is None:
+                # No residual output wanted: a plain masked select, which
+                # XLA fuses; the two-output kernel would force a dead
+                # full-size write.
+                out = jnp.where(jnp.abs(y) >= kth[:, None], y, 0.0)
+                return out.reshape(shape).astype(d.dtype), None
+            out, new_e = pk.threshold_with_feedback(y, kth)
+            return out.reshape(shape).astype(d.dtype), new_e.reshape(shape)
 
     return Compressor(init=_make_init(error_feedback), apply=_make_apply(leaf, error_feedback))
 
@@ -415,10 +438,13 @@ def make_int8(
         shape = d.shape
         y = _flatten_leaf(d)
         if e is not None:
-            y = y + e.reshape(y.shape)
-        scale = jnp.max(jnp.abs(y), axis=1) / 127.0
-        out = pk.quantdequant_int8(y, scale)
-        new_e = None if e is None else (y - out).reshape(shape)
+            with jax.named_scope("fed.codec.feedback"):
+                y = y + e.reshape(y.shape)
+        with jax.named_scope("fed.codec.quantize"):
+            scale = jnp.max(jnp.abs(y), axis=1) / 127.0
+            out = pk.quantdequant_int8(y, scale)
+        with jax.named_scope("fed.codec.feedback"):
+            new_e = None if e is None else (y - out).reshape(shape)
         return out.reshape(shape).astype(d.dtype), new_e
 
     return Compressor(init=_make_init(error_feedback), apply=_make_apply(leaf, error_feedback))

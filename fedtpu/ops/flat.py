@@ -140,6 +140,7 @@ def segment_ids(layout: FlatLayout) -> np.ndarray:
 
 
 # ------------------------------------------------------------------ packing
+@jax.named_scope("fed.pack")
 def pack_stacked(layout: FlatLayout, stacked: Pytree) -> jnp.ndarray:
     """``[clients, ...]`` pytree -> ``[clients, padded]`` f32 buffer.
 
@@ -158,6 +159,7 @@ def pack_stacked(layout: FlatLayout, stacked: Pytree) -> jnp.ndarray:
     return flat
 
 
+@jax.named_scope("fed.unpack")
 def unpack_stacked(layout: FlatLayout, flat: jnp.ndarray) -> Pytree:
     """Inverse of :func:`pack_stacked`: ``[clients, padded]`` -> stacked
     pytree (original leaf dtypes restored, padding dropped)."""
@@ -171,6 +173,7 @@ def unpack_stacked(layout: FlatLayout, flat: jnp.ndarray) -> Pytree:
     return jax.tree_util.tree_unflatten(layout.treedef, leaves)
 
 
+@jax.named_scope("fed.pack")
 def pack(layout: FlatLayout, tree: Pytree) -> jnp.ndarray:
     """Single (unstacked) pytree -> ``[padded]`` f32 row."""
     leaves = jax.tree_util.tree_leaves(tree)
@@ -204,6 +207,7 @@ def pack_row_host(
     return out
 
 
+@jax.named_scope("fed.unpack")
 def unpack(layout: FlatLayout, flat: jnp.ndarray) -> Pytree:
     """``[padded]`` row -> pytree (original dtypes, padding dropped)."""
     leaves = [

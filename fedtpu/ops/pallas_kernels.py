@@ -150,6 +150,7 @@ def _fwht_body(x: jnp.ndarray) -> jnp.ndarray:
 
 
 @functools.partial(jax.jit, static_argnames=("inverse",))
+@jax.named_scope("fed.codec.rotate")
 def hadamard_rotate(
     y: jnp.ndarray, signs: jnp.ndarray, inverse: bool = False
 ) -> jnp.ndarray:

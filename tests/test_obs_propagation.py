@@ -391,14 +391,47 @@ def test_every_emitted_span_name_is_documented():
     assert span_check.check() == []
 
 
-def test_span_check_catches_drift(tmp_path):
+def test_trace_vocabulary_is_pinned():
+    """The scopes of the round program and the engine's spans are what a
+    capture is reduced by: all emitted, prefixed and documented."""
+    scopes = span_check.emitted_scope_names()
+    assert {"fed.data", "fed.local_step", "fed.local_step.fwd_bwd",
+            "fed.local_step.optimizer", "fed.pack", "fed.unpack",
+            "fed.codec", "fed.codec.rotate", "fed.codec.quantize",
+            "fed.codec.feedback", "fed.codec.select", "fed.aggregate",
+            "fed.aggregate.psum", "fed.server_step",
+            "fed.metrics"} <= set(scopes)
+    spans = span_check.emitted_span_names()
+    assert {"fed.round", "fed.plan", "fed.enqueue",
+            "fed.fused_rounds"} <= set(spans)
+    assert not set(span_check.HARNESS_SPANS) & (set(scopes) | set(spans))
+    assert span_check.check_trace_vocabulary() == []
+
+
+@pytest.mark.parametrize("source, documented, expect", [
+    ('tel.span("fed.brand_new")', "`round`", ["no entry"]),
+    ('tel.span("brand_new_span")', "`brand_new_span`",
+     ["neither starts with 'fed.'"]),
+    ('with jax.named_scope("fed.new_layer"):', "`fed.data`", ["no entry"]),
+    ('@jax.named_scope("layer")', "`layer`", ["does not start with"]),
+    ('with TraceAnnotation("fed.hand_made"):', "`fed.plan`", ["no entry"]),
+    ('with jax.profiler.StepTraceAnnotation("sync", step_num=r):', "`sync`",
+     ["does not start with", "harness's own"]),
+    ('tel.span("dispatch")', "`dispatch`",
+     ["neither starts with", "harness's own"]),
+    ('tel.span("fed.ok")\nwith jax.named_scope("fed.ok.child"): pass',
+     "`fed.ok` `fed.ok.child`", []),
+])
+def test_span_check_catches_drift(tmp_path, source, documented, expect):
     pkg = tmp_path / "pkg"
     pkg.mkdir()
-    (pkg / "mod.py").write_text('tel.span("brand_new_span")\n')
+    (pkg / "mod.py").write_text(source + "\n")
     doc = tmp_path / "OBS.md"
-    doc.write_text("documented: `round` only\n")
+    doc.write_text(f"documented: {documented} only\n")
     problems = span_check.check(str(pkg), str(doc))
-    assert len(problems) == 1 and "brand_new_span" in problems[0]
+    assert len(problems) == len(expect), problems
+    for problem, text in zip(problems, expect):
+        assert text in problem
 
 
 # ----------------------------------------- crash-proofed exit exporters

@@ -236,8 +236,10 @@ def test_engine_step_emits_round_span_and_counter():
     fed.step()
     fed.run_on_device(3)
     names = [e["name"] for e in fed.telemetry.trace_events()]
-    assert names.count("round") == 1
-    assert names.count("fused_rounds") == 1
+    assert names.count("fed.round") == 1
+    assert names.count("fed.fused_rounds") == 1
+    # Each dispatch splits into what the host does: plan, then enqueue.
+    assert names.count("fed.plan") == 2 and names.count("fed.enqueue") == 2
     snap = fed.telemetry.registry.snapshot()
     assert snap["fedtpu_rounds_completed_total"][0]["value"] == 4
 

@@ -196,7 +196,7 @@ def test_round_profiler_gauges_and_record_fields(monkeypatch):
     assert out["achieved_flops_per_s"] is None and out["mfu"] is None
     assert prof.record_fields() == {}
     prof.set_cost_model(
-        CostModel(xla_flops=1e10, xla_bytes=1e9, analytic=1.01e10)
+        CostModel(xla_flops=1.01e10, xla_bytes=1e9, analytic=1e10)
     )
     out = prof.observe_round(0.5, rounds=5)
     assert out["step_time_s"] == pytest.approx(0.1)
@@ -212,7 +212,7 @@ def test_round_profiler_gauges_and_record_fields(monkeypatch):
     assert parsed["fedtpu_achieved_flops_per_sec"][""] == pytest.approx(1e11)
     snap = prof.snapshot()
     assert snap["mfu"] == pytest.approx(0.05)
-    assert snap["flops_source"] == "xla"
+    assert snap["flops_source"] == "analytic"
     # Roofline keys merge flat into the /statusz perf block: intensity
     # 10 FLOP/B vs ridge 20 -> bandwidth-bound; per-chip achieved 5e10
     # against a 5e11 ceiling at that intensity.
@@ -220,16 +220,31 @@ def test_round_profiler_gauges_and_record_fields(monkeypatch):
     assert snap["roofline_utilization"] == pytest.approx(0.1)
 
 
-def test_cost_model_prefers_xla_and_reports_agreement():
-    cm = CostModel(xla_flops=1e10, xla_bytes=1e9, analytic=1.02e10)
-    assert cm.flops == 1e10 and cm.source == "xla"
-    assert cm.agreement == pytest.approx(1.02)
+def test_cost_model_prefers_analytic_and_reports_agreement():
+    """XLA counts a scan body once, the analytic walk times its length:
+    the gauge is priced with the walk, XLA's count is the cross-check."""
+    cm = CostModel(xla_flops=1e10, xla_bytes=1e9, analytic=2.04e10)
+    assert cm.flops == 2.04e10 and cm.source == "analytic"
+    assert cm.agreement == pytest.approx(2.04)
     d = cm.as_dict()
-    assert d["flops_source"] == "xla"
-    assert d["analytic_vs_xla"] == pytest.approx(1.02)
-    cm = CostModel(xla_flops=None, xla_bytes=None, analytic=5e9)
-    assert cm.flops == 5e9 and cm.source == "analytic"
+    assert d["flops_source"] == "analytic"
+    assert d["analytic_vs_xla"] == pytest.approx(2.04)
+    cm = CostModel(xla_flops=5e9, xla_bytes=None, analytic=None)
+    assert cm.flops == 5e9 and cm.source == "xla"
     assert cm.agreement is None
+
+
+def test_analytic_flops_multiplies_scan_lengths():
+    import jax
+    import jax.numpy as jnp
+
+    a = jnp.ones((8, 16), jnp.float32)
+    b = jnp.ones((16, 16), jnp.float32)
+
+    def steps(a, b):
+        return jax.lax.scan(lambda c, _: (c @ b, None), a, None, length=6)[0]
+
+    assert analytic_flops(steps, a, b) == 6 * 2 * 8 * 16 * 16
 
 
 def test_engine_round_records_and_statusz_carry_mfu(monkeypatch):
@@ -362,8 +377,8 @@ def test_profile_meta_sidecar_roundtrip(tmp_path):
 
 # ------------------------------------------- trace_merge device ingestion
 def _tpu_device_doc(wall_start=None):
-    """Synthetic jax.profiler-shaped Chrome doc: TPU lanes are processes
-    whose name carries '/device:TPU:N'."""
+    """Synthetic load_device_trace-shaped Chrome doc: device lanes are
+    processes whose name carries '/device:'."""
     events = [
         {"ph": "M", "name": "process_name", "pid": 10,
          "args": {"name": "/device:TPU:0 (fake)"}},
@@ -383,17 +398,13 @@ def _tpu_device_doc(wall_start=None):
 
 
 def _cpu_device_doc():
-    """CPU-backend shape: no /device: process, XLA ops live on threads
-    named tf_XLA..."""
+    """CPU-backend shape: the capture reader hands the XLA executor's
+    operations over as the stand-in process /device:CPU:0."""
     events = [
-        {"ph": "M", "name": "thread_name", "pid": 20, "tid": 7,
-         "args": {"name": "tf_XLA_CPU_worker"}},
-        {"ph": "M", "name": "thread_name", "pid": 20, "tid": 8,
-         "args": {"name": "main"}},
-        {"ph": "X", "pid": 20, "tid": 7, "name": "convolution",
-         "ts": 10.0, "dur": 5.0},
-        {"ph": "X", "pid": 20, "tid": 8, "name": "python", "ts": 0.0,
-         "dur": 100.0},
+        {"ph": "M", "name": "process_name", "pid": 0,
+         "args": {"name": gap_analyze.CPU_PLANE}},
+        {"ph": "X", "pid": 0, "tid": 0, "name": "convolution",
+         "ts": 10.0, "dur": 5.0, "args": {"scope": ""}},
     ]
     return {"traceEvents": events, "metadata": {"role": "engine"}}
 
@@ -418,7 +429,7 @@ def test_extract_device_lanes_tpu_and_cpu_shapes():
     lanes = trace_merge.extract_device_lanes(_cpu_device_doc())
     assert len(lanes) == 1
     name, evs = lanes[0]
-    assert name == "XLA:CPU"
+    assert name == gap_analyze.CPU_PLANE
     assert [e["name"] for e in evs] == ["convolution"]
     # No device-looking content at all -> no lanes, no crash.
     assert trace_merge.extract_device_lanes(
@@ -498,22 +509,22 @@ def test_gap_analyze_ranks_gaps_and_attributes_to_deepest_span():
     assert rows["h2d"] == pytest.approx(600.0)
     assert rows["round"] == pytest.approx(400.0)
     assert top["attribution"][0]["span"] == "h2d"  # charged-most first
-    assert top["unattributed_us"] == pytest.approx(0.0)
+    assert gap_analyze.CALLER not in rows  # the spans cover the whole gap
     # Aggregate table mirrors the per-gap charges (small gap -> round too).
     by_phase = {r["span"]: r["us"] for r in report["by_phase"]}
     assert by_phase["h2d"] == pytest.approx(600.0)
     assert by_phase["round"] == pytest.approx(450.0)
 
 
-def test_gap_analyze_reports_unattributed_idle():
+def test_gap_analyze_charges_uncovered_idle_to_the_caller():
     doc = _merged_doc_with_gaps()
     # Shrink the round span so [900, 1100) of the big gap is uncovered.
     doc["traceEvents"][0]["dur"] = 900.0
     report = gap_analyze.analyze(doc, min_gap_us=10.0)
-    top = report["gaps"][0]
-    assert top["unattributed_us"] == pytest.approx(200.0)
+    rows = {r["span"]: r["us"] for r in report["gaps"][0]["attribution"]}
+    assert rows[gap_analyze.CALLER] == pytest.approx(200.0)
     by_phase = {r["span"]: r["us"] for r in report["by_phase"]}
-    assert by_phase["(unattributed)"] == pytest.approx(250.0)
+    assert by_phase[gap_analyze.CALLER] == pytest.approx(250.0)
 
 
 def test_gap_analyze_tolerates_timeline_without_device_ops():
@@ -560,19 +571,23 @@ def test_gap_analyze_roofline_stamp(tmp_path):
     assert frow["roofline_utilization"] is None
 
 
-def test_gap_report_committed_artifact_contract():
-    """The committed GAP_REPORT.json came from a real --profile-rounds
-    densenet CPU capture piped through trace_merge --device-trace."""
-    path = os.path.join(REPO, "artifacts", "GAP_REPORT.json")
-    assert os.path.exists(path), "artifacts/GAP_REPORT.json missing"
+def test_gap_report_contract_on_the_recorded_tpu_capture():
+    """The report's schema, on a capture a TPU v5e really wrote (three
+    rounds of sim192_rotq4's program, tests/data/): what the committed
+    densenet CPU GAP_REPORT.json used to stand for."""
+    path = os.path.join(REPO, "tests", "data", "capture_v5e_sim192_rotq4.json")
     with open(path) as fh:
-        report = json.load(fh)
+        events = json.load(fh)["events"]
+    report = gap_analyze.reduce_events(events)
     assert report["schema_version"] == gap_analyze.SCHEMA_VERSION
-    assert report["device_lanes"] >= 1
-    assert report["device_ops"] > 0
-    assert 0.0 <= report["idle_fraction"] <= 1.0
+    assert report["chips"] == ["/device:TPU:0"] and report["device_lanes"] == 1
+    assert report["device_ops"] == 1683
+    assert 0.0 < report["idle_fraction"] < 0.01
+    assert report["n_gaps"] == 2  # between the three rounds
     for gap in report["gaps"]:
         assert gap["dur_us"] >= report["min_gap_us"]
+        assert sum(r["us"] for r in gap["attribution"]) == pytest.approx(
+            gap["dur_us"], abs=0.01)
 
 
 # ------------------------------------------------------- metric-name drift

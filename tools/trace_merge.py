@@ -21,12 +21,15 @@ merges any number of those files into a single Chrome trace where:
   ``client_train`` span's parent chain walks through the coordinator's
   ``client_rpc`` span up to its ``round`` span;
 - ``--device-trace DIR`` ingests a ``jax.profiler`` capture (the CLIs'
-  ``--profile-rounds``, fedtpu.obs.profile.CaptureWindow): XLA device-op
+  ``--profile-rounds``, fedtpu.obs.profile.CaptureWindow) through the one
+  reader of captures, ``gap_analyze.load_capture``: XLA device-op
   executions land on extra ``device:*`` lanes — one per chip (TPU) or one
-  for the XLA CPU executor threads — wall-clock aligned with the host
-  spans via the capture's ``profile_meta.json`` sidecar, every event
-  tagged ``cat="device"`` so ``tools/gap_analyze.py`` can separate device
-  busy time from host phases.
+  for the XLA CPU executor — wall-clock aligned with the host spans via
+  the capture's ``profile_meta.json`` sidecar, every event tagged
+  ``cat="device"`` and carrying its ``args.scope`` so
+  ``tools/gap_analyze.py`` can separate device busy time from host phases
+  and split it by layer. For ONE process, skip the merge:
+  ``python tools/gap_analyze.py DIR`` reads the capture's own spans.
 
 Import-free of fedtpu (stdlib only), like the other ``tools/`` readers.
 
@@ -43,7 +46,6 @@ non-zero otherwise (the CI assertion, see tests/test_obs_propagation.py).
 from __future__ import annotations
 
 import argparse
-import gzip
 import json
 import os
 import sys
@@ -69,100 +71,56 @@ def _qualify(role: str, span_id) -> str:
 PROFILE_META = "profile_meta.json"  # fedtpu.obs.profile sidecar name
 
 
-def find_device_trace(trace_dir: str) -> Optional[str]:
-    """Newest ``*.trace.json[.gz]`` under a ``jax.profiler`` output dir
-    (layout: ``plugins/profile/<run>/<host>.trace.json.gz``)."""
-    hits = []
-    for dirpath, _dirs, files in os.walk(trace_dir):
-        for f in files:
-            if f.endswith(".trace.json.gz") or f.endswith(".trace.json"):
-                hits.append(os.path.join(dirpath, f))
-    return max(hits, key=os.path.getmtime) if hits else None
+def load_device_trace(capture_dir: str) -> dict:
+    """A ``jax.profiler`` capture directory as a Chrome-trace doc of device
+    lanes: the operations ``gap_analyze.load_capture`` reads from its
+    ``*.trace.json.gz`` (one ``/device:...`` process per chip, each event with
+    its ``args.scope``), on the capture's own clock, which starts when the
+    capture opens, plus ``metadata.wall_start``/``role`` from the
+    ``profile_meta.json`` sidecar (its wall clock is stamped at that same
+    moment) for the merge's alignment. The capture's own host spans are
+    not copied: the merge takes those from the per-process dumps."""
+    import gap_analyze
 
-
-def _find_sidecar(start_dir: str) -> Optional[dict]:
-    """Walk up from the trace file's dir looking for the capture sidecar
-    (the file sits 2-3 levels below the dir the sidecar was written to)."""
-    d = os.path.abspath(start_dir)
-    for _ in range(4):
-        p = os.path.join(d, PROFILE_META)
-        if os.path.exists(p):
-            try:
-                with open(p) as fh:
-                    return json.load(fh)
-            except (OSError, ValueError):
-                return None
-        parent = os.path.dirname(d)
-        if parent == d:
-            break
-        d = parent
-    return None
-
-
-def load_device_trace(path: str) -> dict:
-    """Load a ``jax.profiler`` Chrome trace (dir or file, .gz or plain)
-    plus its ``profile_meta.json`` sidecar. Returns the trace doc with
-    ``metadata.wall_start``/``role`` filled from the sidecar when found
-    (profiler timestamps are relative to the capture open, which is when
-    the sidecar stamps its wall clock)."""
-    if os.path.isdir(path):
-        hit = find_device_trace(path)
-        if hit is None:
-            raise FileNotFoundError(
-                f"no *.trace.json[.gz] under {path} (is this a "
-                "--profile-rounds / jax.profiler output dir?)"
-            )
-        path = hit
-    opener = gzip.open if path.endswith(".gz") else open
-    with opener(path, "rt") as fh:
-        doc = json.load(fh)
-    if isinstance(doc, list):
-        doc = {"traceEvents": doc}
-    doc.setdefault("metadata", {})
-    sidecar = _find_sidecar(os.path.dirname(os.path.abspath(path)))
-    if sidecar:
-        doc["metadata"].setdefault("wall_start", sidecar.get("wall_start"))
-        doc["metadata"].setdefault(
-            "role", sidecar.get("role") or "device"
-        )
+    ops = [e for e in gap_analyze.load_capture(capture_dir)
+           if e["plane"].startswith("/device:")]
+    planes = sorted({e["plane"] for e in ops})
+    events = [
+        {"ph": "M", "name": "process_name", "pid": i,
+         "args": {"name": plane}}
+        for i, plane in enumerate(planes)
+    ]
+    events += [
+        {"ph": "X", "pid": planes.index(e["plane"]), "tid": 0,
+         "name": e["name"], "ts": e["start_ns"] / 1e3,
+         "dur": e["dur_ns"] / 1e3, "args": {"scope": e["scope"]}}
+        for e in ops
+    ]
+    doc = {"traceEvents": events, "metadata": {}}
+    try:
+        with open(os.path.join(capture_dir, PROFILE_META)) as fh:
+            sidecar = json.load(fh)
+    except (OSError, ValueError):
+        return doc  # a bare jax.profiler capture: the merge lists it unaligned
+    doc["metadata"]["wall_start"] = sidecar.get("wall_start")
+    doc["metadata"]["role"] = sidecar.get("role") or "device"
     return doc
 
 
 def extract_device_lanes(doc: dict) -> List[Tuple[str, List[dict]]]:
-    """``[(lane_name, X-events)]`` for the device work in a profiler trace.
-
-    TPU/GPU captures name their op lanes ``/device:TPU:0`` etc. in
-    ``process_name`` metadata — one merged lane per chip. CPU captures
-    have no device process; there the XLA executor's op executions run on
-    host threads named ``tf_XLA...``, so when no ``/device:`` lane exists
-    those threads become one synthetic ``XLA:CPU`` lane (real HLO op
-    names, same idle-gap semantics)."""
-    pid_name: Dict[object, str] = {}
-    thread_name: Dict[Tuple[object, object], str] = {}
-    for e in doc.get("traceEvents", []):
-        if e.get("ph") != "M":
-            continue
-        if e.get("name") == "process_name":
-            pid_name[e.get("pid")] = str(e.get("args", {}).get("name", ""))
-        elif e.get("name") == "thread_name":
-            thread_name[(e.get("pid"), e.get("tid"))] = str(
-                e.get("args", {}).get("name", "")
-            )
-    device_pids = {
-        pid for pid, name in pid_name.items() if "/device:" in name
+    """``[(lane_name, X-events)]`` of the ``/device:`` processes of a
+    :func:`load_device_trace` doc: one lane per chip (a CPU capture's XLA
+    executor operations arrive as ``/device:CPU:0``)."""
+    pid_name = {
+        e.get("pid"): str(e.get("args", {}).get("name", ""))
+        for e in doc.get("traceEvents", [])
+        if e.get("ph") == "M" and e.get("name") == "process_name"
     }
     lanes: Dict[str, List[dict]] = {}
-    if device_pids:
-        for e in doc.get("traceEvents", []):
-            if e.get("ph") == "X" and e.get("pid") in device_pids:
-                lanes.setdefault(pid_name[e["pid"]], []).append(e)
-    else:
-        for e in doc.get("traceEvents", []):
-            if e.get("ph") != "X":
-                continue
-            tname = thread_name.get((e.get("pid"), e.get("tid")), "")
-            if tname.startswith("tf_XLA"):
-                lanes.setdefault("XLA:CPU", []).append(e)
+    for e in doc.get("traceEvents", []):
+        name = pid_name.get(e.get("pid"), "")
+        if e.get("ph") == "X" and "/device:" in name:
+            lanes.setdefault(name, []).append(e)
     return sorted(lanes.items())
 
 
@@ -328,9 +286,8 @@ def main(argv=None) -> int:
     p.add_argument("-o", "--out", required=True, help="merged trace path")
     p.add_argument(
         "--device-trace", action="append", default=[], metavar="DIR",
-        help="ingest a jax.profiler capture (--profile-rounds output dir "
-        "or a *.trace.json[.gz] file) as wall-clock-aligned device lanes; "
-        "repeatable",
+        help="ingest a jax.profiler capture (--profile-rounds output dir) "
+        "as wall-clock-aligned device lanes; repeatable",
     )
     p.add_argument("--check", action="store_true",
                    help="fail unless every client_train span roots in a "

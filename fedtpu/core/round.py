@@ -561,7 +561,7 @@ def make_round_step(
     flat_mode = cfg.fed.delta_layout == "flat"
     # Seeded codecs (rotq/randk) take the round index as their per-round
     # seed, and rotq needs the power-of-two row padding for the Hadamard
-    # butterfly — both are static properties of the compressor, resolved
+    # rotation — both are static properties of the compressor, resolved
     # once here so the traced body stays branch-free.
     flat_pow2 = compressor is not None and getattr(
         compressor, "pad_pow2", False

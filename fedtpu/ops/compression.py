@@ -69,7 +69,7 @@ class Compressor(NamedTuple):
     internally), so standalone callers need not care about the layout.
 
     ``pad_pow2`` marks codecs whose flat row must be padded to a power of
-    two (the Hadamard butterfly of ``rotq``): the round step and the
+    two (the Hadamard rotation of ``rotq``): the round step and the
     residual initialiser build their layouts with
     ``make_layout(..., pow2=True)`` when it is set. Seeded codecs
     (``rotq``/``randk``) additionally accept a ``round_idx`` keyword on

@@ -87,7 +87,7 @@ def _padded(total: int, lane: int, pow2: bool = False) -> int:
     lane_padded = max(lane, int(math.ceil(max(total, 1) / lane)) * lane)
     if not pow2:
         return lane_padded
-    # Power-of-two padding (rotated-sketch codecs): the Hadamard butterfly
+    # Power-of-two padding (rotated-sketch codecs): the Hadamard rotation
     # needs the row length to be 2^m. Every pow2 >= LANE is lane-aligned,
     # so the Mosaic tiling rule still holds.
     return next_pow2(lane_padded)

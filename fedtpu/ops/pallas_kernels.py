@@ -6,11 +6,14 @@ What runs where. On a TPU backend ``threshold_with_feedback`` and
 codecs hand them (the flat ``[clients, P]`` row and the smallest per-leaf
 ``[clients, 10]``), bitwise against the plain-jnp bodies below. Off TPU the
 default is those plain-jnp bodies (XLA fuses the same chain; the Pallas
-interpreter costs ~1000x on CPU). ``hadamard_rotate`` is plain jnp on every
-backend: Mosaic refuses the butterfly's sub-lane reshapes
-("infer-vector-layout: unsupported shape cast", ``vector<8xhxf32> ->
-vector<8x(h/2)x2x1xf32>``, jax 0.9.0 / libtpu 0.0.34), so there is no
-``pallas_call`` around it.
+interpreter costs ~1000x on CPU). ``hadamard_rotate`` is plain
+``lax.dot_general`` on every backend: the Walsh-Hadamard matrix factors as
+a Kronecker product of small Hadamard matrices, so the rotation is one f32
+matrix product per factor (three at the 2^20-column row), which XLA hands
+to the MXU. There is no ``pallas_call`` around it: a kernel that keeps a
+row in VMEM across the products (one pass instead of three) is the
+follow-up if the rotation is still the codec's largest scope (ROADMAP
+Speed 1).
 
 Whether the two kernels beat XLA's own fusion of the same chain has not
 been measured on the current tree (the round-4 record,
@@ -44,6 +47,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 # Max column-block size in elements: 32K f32 x 8 rows = 1 MB per operand per
@@ -127,26 +131,92 @@ def threshold_with_feedback(
     )(y, thresh.reshape(rows, 1))
 
 
-def _fwht_body(x: jnp.ndarray) -> jnp.ndarray:
-    """Unnormalized fast Walsh–Hadamard transform over the last axis.
+# Widest Kronecker factor of the rotation: the v5e's MXU tile and the lane
+# width of a vector register. An f32 register holds _SUBLANES rows of it.
+_MAX_FACTOR = 128
+_SUBLANES = 8
 
-    Iterative stride-doubling butterfly: at step ``s`` the row is viewed as
-    ``[pairs, 2, s]`` blocks and each (a, b) pair maps to (a+b, a-b) —
-    log2(h) passes, each a reshape plus one add/sub, which XLA fuses into a
-    handful of elementwise programs. ``h`` must be a power of two (the
-    ``pow2=True`` flat layout guarantees it). H is symmetric and
-    ``H @ H == h * I``, so the same body normalized by ``1/sqrt(h)`` is its
-    own inverse — the property the rotq codec's decode side relies on.
+
+def _hadamard_factors(h: int) -> tuple:
+    """Kronecker factor widths of ``H_h``, major first: a function of ``h``
+    alone. Every factor but the major one is ``_MAX_FACTOR``; the major one
+    takes the remainder (``2^20 -> (64, 128, 128)``, ``2^13 -> (64, 128)``,
+    ``64 -> (64,)``)."""
+    factors = []
+    while h > _MAX_FACTOR:
+        factors.append(_MAX_FACTOR)
+        h //= _MAX_FACTOR
+    factors.append(h)
+    return tuple(reversed(factors))
+
+
+@functools.lru_cache(maxsize=None)
+def _sylvester(n: int) -> np.ndarray:
+    """The Sylvester-ordered Walsh-Hadamard matrix ``H_n`` (entries +-1,
+    symmetric, ``H_n @ H_n == n * I``) as a host constant."""
+    hmat = np.ones((1, 1), np.float32)
+    while hmat.shape[0] < n:
+        hmat = np.block([[hmat, hmat], [hmat, -hmat]])
+    return hmat
+
+
+def _dot_f32(lhs, rhs, dimension_numbers):
+    """An f32 product that stays f32 on a TPU: ``Precision.HIGHEST`` is six
+    bf16 passes on the MXU. The DEFAULT there is ONE bf16 pass, which would
+    truncate every coordinate to 8 bits of mantissa — a different codec,
+    and one the benchmark's check would not catch.
+    ``tests/test_compression.py`` walks the traced rotation so that it
+    cannot ship."""
+    return jax.lax.dot_general(
+        lhs,
+        rhs,
+        dimension_numbers,
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _fwht_products(x: jnp.ndarray) -> jnp.ndarray:
+    """Unnormalized Walsh-Hadamard transform over the last axis, as one
+    matrix product per Kronecker factor.
+
+    ``H_(a*b*c) = H_a (x) H_b (x) H_c``: with the row viewed as
+    ``[a, b, c]`` (``c`` minor) the transform is a right-multiplication of
+    the minor axis by ``H_c`` and a left-multiplication of each other axis
+    by its factor (``H_b @ X[r, a]``, ``H_a @ X[r]``): MXU-shaped products,
+    none with a trailing dimension below ``_MAX_FACTOR`` once the row has
+    one. ``h`` must be a power of two (the ``pow2=True`` flat layout
+    guarantees it). H is symmetric and ``H @ H == h * I``, so the same body
+    normalized by ``1/sqrt(h)`` is its own inverse — the property the rotq
+    codec's decode side relies on.
+
+    Rows are taken ``_SUBLANES`` at a time, ``[groups, tiles, g, lanes]``:
+    that is how the TPU's (8, 128) tiling already lays a ``[rows, h]`` f32
+    buffer out, so the first product reads the flat buffer in place and
+    every later one keeps whole registers as its trailing dimensions.
+    Viewed as ``[rows * a * b, c]`` instead, XLA copies the whole buffer
+    into that layout before the first product and again between products
+    (PERF.md, PR 26). The transposes are of the view only: XLA:TPU folds
+    each into a product's output layout, and one copy is left at the end.
     """
     rows, h = x.shape
-    step = 1
-    while step < h:
-        x = x.reshape(rows, h // (2 * step), 2, step)
-        a = x[:, :, 0, :]
-        b = x[:, :, 1, :]
-        x = jnp.stack([a + b, a - b], axis=2).reshape(rows, h)
-        step *= 2
-    return x
+    factors = _hadamard_factors(h)
+    lanes = factors[-1]
+    g = math.gcd(rows, _SUBLANES)
+    groups, tiles = rows // g, h // lanes
+    x = x.reshape(groups, g, tiles, lanes).transpose(0, 2, 1, 3)
+    x = _dot_f32(x, jnp.asarray(_sylvester(lanes)), (((3,), (0,)), ((), ())))
+    minor = 1  # tiles spanned by the factors already transformed
+    for f in reversed(factors[:-1]):
+        major = tiles // (f * minor)
+        # [f, groups, major, minor, g, lanes] -> [groups, major, f, ...]
+        x = _dot_f32(
+            jnp.asarray(_sylvester(f)),
+            x.reshape(groups, major, f, minor, g, lanes),
+            (((1,), (2,)), ((), ())),
+        ).transpose(1, 2, 0, 3, 4, 5)
+        minor *= f
+    return x.reshape(groups, tiles, g, lanes).transpose(0, 2, 1, 3).reshape(rows, h)
 
 
 @functools.partial(jax.jit, static_argnames=("inverse",))
@@ -163,9 +233,13 @@ def hadamard_rotate(
     the client, quantizes, and inverse-rotates on the server — both ends
     regenerate ``signs`` from the shared record seed.
 
-    Plain jnp on every backend (see the module docstring): XLA:TPU compiles
-    the butterfly at the 2^20-column row of the zoo's flat layouts with
-    ~6x the operand in temporaries.
+    One algorithm on every backend and for every width (see the module
+    docstring): Kronecker-factored f32 matrix products, three passes over
+    a 2^20-column row where a stride-doubling butterfly makes twenty. The
+    summation order differs from the host butterflies'
+    (``transport.sparse._fwht_np``, the benchmark's reference), so results
+    agree with them to float rounding (1e-5 of the normalised values), not
+    bit for bit.
     """
     h = y.shape[1]
     if h & (h - 1):
@@ -174,7 +248,7 @@ def hadamard_rotate(
     signs = signs.astype(jnp.float32)
     if not inverse:
         y = y * signs[None, :]
-    out = _fwht_body(y) * jnp.float32(1.0 / math.sqrt(h))
+    out = _fwht_products(y) * jnp.float32(1.0 / math.sqrt(h))
     if inverse:
         out = out * signs[None, :]
     return out

@@ -376,10 +376,11 @@ def _next_pow2(n: int) -> int:
 def _fwht_np(x: np.ndarray) -> np.ndarray:
     """Unnormalized fast Walsh-Hadamard transform of a 1-D f32 vector.
 
-    Same stride-doubling butterfly as the engine kernel
-    (:func:`fedtpu.ops.pallas_kernels.hadamard_rotate`), in numpy for the
-    wire hot path (the decode side runs on the serving thread, no jax
-    dispatch). ``x.size`` must be a power of two.
+    A stride-doubling butterfly in numpy for the wire hot path (the decode
+    side runs on the serving thread, no jax dispatch). The engine's
+    :func:`fedtpu.ops.pallas_kernels.hadamard_rotate` computes the same
+    transform as matrix products and is tested against this one.
+    ``x.size`` must be a power of two.
     """
     h = x.size
     y = np.array(x, np.float32, copy=True)

@@ -250,28 +250,91 @@ def test_pallas_blocks_are_mosaic_legal():
 
 
 # ----------------------------------------------------- sketch codecs (flat)
-def test_hadamard_rotate_matches_host_fwht(rng):
+# Every way the factor rule can split a width: one factor below / at the
+# MXU tile, two factors with a small / large / full major one, three factors
+# (the cell's 2^20 row, one row here: 6.7e8 FLOP as products).
+@pytest.mark.parametrize("inverse", [False, True], ids=["forward", "inverse"])
+@pytest.mark.parametrize(
+    "rows,h",
+    [(1, 2), (1, 8), (4, 64), (3, 128), (9, 256), (2, 2**13), (2, 2**14), (1, 2**20)],
+)
+def test_hadamard_rotate_matches_host_fwht(rows, h, inverse, rng):
     """The device rotation vs the wire codec's numpy butterfly
     (``transport.sparse._fwht_np`` — what the gRPC edge decodes with):
-    identical up to float-associativity, forward and inverse. The chip
-    smoke makes the same comparison on the TPU at the 2^20-column row."""
+    identical up to float-associativity (the device sums each factor's
+    terms in one matrix product, the host pairwise), forward and inverse.
+    The chip smoke makes the same comparison on the TPU at the 2^20-column
+    row."""
     from fedtpu.transport.sparse import _fwht_np
 
-    for rows, h in [(1, 8), (4, 64), (9, 256)]:
-        y = rng.normal(size=(rows, h)).astype(np.float32)
-        signs = rng.integers(0, 2, size=h).astype(np.float32) * 2 - 1
-        norm = np.float32(1.0 / np.sqrt(h))
-        fwht = lambda m: np.stack([_fwht_np(row) for row in m])  # 1-D twin
-        for inverse, ref in (
-            (False, fwht(y * signs) * norm),
-            (True, fwht(y) * norm * signs),
-        ):
-            got = pk.hadamard_rotate(
-                jnp.asarray(y), jnp.asarray(signs), inverse=inverse
-            )
-            np.testing.assert_allclose(
-                np.asarray(got), ref, rtol=1e-5, atol=1e-5
-            )
+    y = rng.normal(size=(rows, h)).astype(np.float32)
+    signs = rng.integers(0, 2, size=h).astype(np.float32) * 2 - 1
+    norm = np.float32(1.0 / np.sqrt(h))
+    fwht = lambda m: np.stack([_fwht_np(row) for row in m])  # 1-D twin
+    ref = fwht(y) * norm * signs if inverse else fwht(y * signs) * norm
+    got = pk.hadamard_rotate(jnp.asarray(y), jnp.asarray(signs), inverse=inverse)
+    np.testing.assert_allclose(np.asarray(got), ref, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "h,factors",
+    [
+        (1, (1,)), (2, (2,)), (64, (64,)), (128, (128,)), (256, (2, 128)),
+        (2**13, (64, 128)), (2**14, (128, 128)), (2**20, (64, 128, 128)),
+        (2**21, (128, 128, 128)), (2**22, (2, 128, 128, 128)),
+    ],
+)
+def test_hadamard_factor_rule(h, factors):
+    """The factor widths are a function of the width alone: at most 128 a
+    factor, every factor but the major one exactly 128, the remainder in
+    the major one."""
+    assert pk._hadamard_factors(h) == factors
+    assert int(np.prod(factors)) == h
+
+
+def _jaxpr_eqns(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs (pjit, custom calls) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _jaxpr_eqns(sub)
+
+
+@pytest.mark.parametrize("inverse", [False, True], ids=["forward", "inverse"])
+@pytest.mark.parametrize("rows,h", [(8, 64), (8, 2**13), (192, 2**20)])
+def test_hadamard_rotate_products_cannot_lose_precision(rows, h, inverse):
+    """Nothing runs: the traced program is walked. A TPU's default for an
+    f32 ``dot`` is one bf16 pass (8 bits of mantissa), which the benchmark's
+    check would not catch, so every product must say HIGHEST on both
+    operands or take bf16 operands (an exact split) into an f32 result.
+    One product per Kronecker factor, and the butterfly's sub-lane shapes
+    are gone, not moved: no intermediate is narrower than a vector
+    register's 128 lanes once the row is."""
+    jaxpr = jax.make_jaxpr(
+        lambda y, s: pk.hadamard_rotate(y, s, inverse=inverse)
+    )(
+        jax.ShapeDtypeStruct((rows, h), jnp.float32),
+        jax.ShapeDtypeStruct((h,), jnp.float32),
+    )
+    eqns = list(_jaxpr_eqns(jaxpr.jaxpr))
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == len(pk._hadamard_factors(h))
+    for e in dots:
+        operands = {str(v.aval.dtype) for v in e.invars}
+        assert str(e.outvars[0].aval.dtype) == "float32"
+        if operands == {"bfloat16"}:
+            assert e.params["preferred_element_type"] == jnp.float32
+        else:
+            assert operands == {"float32"}
+            assert e.params["precision"] == (
+                jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST
+            ), e.params["precision"]
+    if h >= 128:
+        for e in eqns:
+            for v in e.outvars:
+                shape = v.aval.shape
+                if len(shape) >= 2:
+                    assert shape[-1] >= 128, (e.primitive.name, shape)
 
 
 def test_hadamard_rotation_pair_is_identity(rng):

@@ -1,0 +1,60 @@
+"""Deviceless compiles for a described TPU v5e: what the chip's compiler
+makes of the hot kernels at their real widths, at no chip time.
+
+One file, one process: only one process at a time may load the TPU's
+library, so the topology is described inside a fixture (never at import)
+and every compile happens in the test's own process.
+"""
+
+import math
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from fedtpu.ops import pallas_kernels as pk
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("inverse", [False, True], ids=["forward", "inverse"])
+def test_rotation_compiles_to_three_f32_products_and_one_copy(one_chip, inverse):
+    """``hadamard_rotate`` at the benchmark cell's ``[192, 2^20]`` row: one
+    MXU convolution per Kronecker factor, each at HIGHEST precision in the
+    OPTIMIZED module (nothing downgraded it), and at most one layout copy
+    of the whole buffer (the 8-rows-at-a-time view is what keeps XLA from
+    copying it on the way in and between products)."""
+    rows, h = 192, 2**20
+    compiled = pk.hadamard_rotate.lower(
+        jax.ShapeDtypeStruct((rows, h), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((h,), jnp.float32, sharding=one_chip),
+        inverse=inverse,
+    ).compile()
+    text = compiled.as_text()
+    products = re.findall(r" convolution\([^\n]*", text)
+    assert len(products) == 3, products
+    for line in products:
+        assert "operand_precision={highest,highest}" in line, line
+    entry = text[text.index("ENTRY"):]
+    whole = rows * h
+    moves = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = f32\[([0-9,]+)\]\S* (copy|reshape|transpose)\(", line)
+        if m and math.prod(map(int, m.group(1).split(","))) == whole:
+            moves.append(line.strip()[:120])
+    assert len(moves) <= 1, moves
+    # one output buffer a product, not the butterfly's ~6x in temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2.1 * whole * 4

@@ -13,25 +13,28 @@ import jax
 import numpy as np
 
 
-def follow_reference(model, cfg, traffic, initial, images, labels, shards,
-                     devices, seed, quant=None, bits=None):
+def follow_reference(cell, seed, inputs, devices, quant=None, bits=None):
     """The plain reference's first rounds from the seeded weights on the
-    cell's rows: ``({"losses", "first", "last"}, codec)``. ``quant`` and
-    ``bits`` are the lower-precision controls' (``reference/lowprec.py``)."""
+    cell's rows (``inputs`` as ``seeded_inputs`` gives them):
+    ``({"losses", "first", "last"}, codec)``. ``quant`` and ``bits`` are the
+    lower-precision controls' (``reference/lowprec.py``)."""
     from benchmark import seeded
     from benchmark.reference import fedavg, layers, rotq
 
+    cfg, traffic = cell.config, cell.traffic
+    examples, targets, shards, initial = inputs
+
     def feed(c):
         rows = seeded.client_rows(shards[0][c], traffic["steps"], cfg["batch_size"])
-        return images[rows], labels[rows]
+        return examples[rows], targets[rows]
 
     codec = None
     if traffic.get("codec"):
         codec = rotq.RotQ(initial["params"], bits or traffic["codec"]["bits"], seed)
     ref = fedavg.Reference(
-        model.make_forward(cfg), initial["params"], initial["stats"],
-        cfg["optimizer"], feed, shards[1].sum(axis=1), devices, codec,
-        quant or layers.ident)
+        cell.reference.make_forward(cfg), cell.task.loss, initial["params"],
+        initial["stats"], cfg["optimizer"], feed, shards[1].sum(axis=1),
+        devices, codec, quant or layers.ident)
     out = {"losses": []}
     for r in range(traffic["check_rounds"]):
         loss, _, _, extra = ref.round()
@@ -43,16 +46,15 @@ def follow_reference(model, cfg, traffic, initial, images, labels, shards,
 
 
 def seeded_inputs(cell, seed):
-    """What a run draws from the seed for a cell: ``(images, labels, shards,
-    initial)`` with ``initial = {"params", "stats"}`` as host trees."""
+    """What a run draws from the seed for a cell: ``(examples, targets,
+    shards, initial)``, the first two the task's (host arrays, first axis the
+    examples), ``initial = {"params", "stats"}`` as host trees."""
     from benchmark import seeded
 
-    cfg = cell.config
-    images, labels = seeded.make_data(
-        seed, cfg["num_examples"], cfg["image_shape"], cfg["num_classes"])
-    shards = seeded.make_shards(seed, cfg["num_examples"], cell.traffic["clients"])
-    params, stats = seeded.make_weights(seed, *cell.reference.spec(cfg))
-    return images, labels, shards, {
+    examples, targets = cell.task.make_data(seed, cell.config)
+    shards = seeded.make_shards(seed, len(examples), cell.traffic["clients"])
+    params, stats = seeded.make_weights(seed, *cell.reference.spec(cell.config))
+    return examples, targets, shards, {
         "params": jax.tree.map(np.asarray, params), "stats": stats}
 
 
@@ -74,10 +76,8 @@ def first_rounds(fed, n, one_round, after_first=lambda: None):
 def against_reference(cell, seed, inputs, devices, program):
     """The numbers of ``program`` against the plain reference's rounds, and
     the reference's readings (the controls are compared with them too)."""
-    images, labels, shards, initial = inputs
-    reference, codec = follow_reference(
-        cell.reference, cell.config, cell.traffic, initial, images, labels,
-        shards, devices, seed)
+    initial = inputs[3]
+    reference, codec = follow_reference(cell, seed, inputs, devices)
     if codec is not None and "mean_residual_row" in program["first"]:
         program["first"]["mean_residual"] = codec.unpack(
             program["first"].pop("mean_residual_row"), initial["params"])

@@ -43,11 +43,11 @@ def readings(manifest_path, workload, seeds, n_controls, program=True,
     traffic = cell.traffic
     rows = []
     for i, seed in enumerate(seeds):
-        inputs = images, labels, shards, initial = check.seeded_inputs(cell, seed)
+        inputs = check.seeded_inputs(cell, seed)
+        initial = inputs[3]
         row = {"seed": seed}
         if program:
-            fed = sut.build(cell.config, traffic, images, labels, shards,
-                            initial["params"], initial["stats"], cell.chips)
+            fed = sut.build(cell, inputs)
             prog = check.first_rounds(
                 fed, traffic["check_rounds"],
                 lambda: float(np.asarray(sut.step(fed).loss)))
@@ -55,8 +55,7 @@ def readings(manifest_path, workload, seeds, n_controls, program=True,
             sut.release()
             row["sound"], reference = check.against_reference(
                 cell, seed, inputs, devices, prog)
-        args = (cell.reference, cell.config, traffic, initial, images, labels,
-                shards, devices, seed)
+        args = (cell, seed, inputs, devices)
         if not program:
             reference, _ = check.follow_reference(*args)
         if i < n_controls:

@@ -28,14 +28,14 @@ def tree_map(f, *trees):
     return jax.tree.map(f, *trees)
 
 
-def make_local_epoch(forward, opt, quant=ident):
+def make_local_epoch(forward, loss, opt, quant=ident):
+    """One client's local steps as a jitted function. ``loss(logits, targets)
+    -> scalar`` is the task's (``tasks/<task>.py``)."""
     lr, mu, wd = opt["learning_rate"], opt["momentum"], opt["weight_decay"]
 
     def loss_fn(params, stats, x, y):
         logits, new_stats = forward(params, stats, x, quant)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        ce = -jnp.take_along_axis(logp, y[:, None], axis=1).mean()
-        return ce, new_stats
+        return loss(logits, y), new_stats
 
     grad = jax.value_and_grad(loss_fn, has_aux=True)
 
@@ -66,14 +66,14 @@ def make_local_epoch(forward, opt, quant=ident):
 class Reference:
     """The reference federation's state and one method, ``round()``.
 
-    ``feed(client) -> (xs [steps, batch, H, W, C] f32, ys [steps, batch])``
-    is the cell's feed, the same rows every round (``seeded.client_batches``).
+    ``feed(client) -> (xs [steps, batch, ...], ys [steps, batch, ...])`` is
+    the cell's feed, the same rows every round (``seeded.client_rows``).
     """
 
-    def __init__(self, forward, params, stats, opt, feed, weights, devices,
-                 codec=None, quant=ident):
+    def __init__(self, forward, loss, params, stats, opt, feed, weights,
+                 devices, codec=None, quant=ident):
         self.devices = list(devices)
-        self.epoch = make_local_epoch(forward, opt, quant)
+        self.epoch = make_local_epoch(forward, loss, opt, quant)
         self.params = tree_map(np.asarray, params)
         self.stats = tree_map(np.asarray, stats)
         self.weights = np.asarray(weights, np.float64)

@@ -5,11 +5,12 @@
         --seconds S --trace 0|1
 
 A cell is an entry of ``BENCHMARK.json``'s ``workloads``: a configuration
-(``configs/<config>.json``, plain reference ``reference/<model>.py``, FLOP
-function ``flops/<config>.py``) under a traffic mix (``traffic/<traffic>.json``)
-with its check limits (``limits/<cell>.json``). A per-layer metric is read by
-``layer_metrics/<name>.py``. Nothing here names a cell, a configuration or a
-metric: a later PR adds files and manifest entries.
+(``configs/<config>.json``, its task ``tasks/<task>.py``, plain reference
+``reference/<model>.py``, FLOP function ``flops/<config>.py``) under a traffic
+mix (``traffic/<traffic>.json``) with its check limits (``limits/<cell>.json``).
+A per-layer metric is read by ``layer_metrics/<name>.py``. Nothing here names
+a cell, a configuration, a task, a kind of data, a metric or a scope of the
+program: a later PR adds files and manifest entries.
 
 Order of a run: set-up (data and weights from the seed, the engine, its first
 rounds, which compile, warm up and are kept for the check), the measured
@@ -55,6 +56,8 @@ class Cell:
     """Everything the manifest and its files say about one workload."""
 
     def __init__(self, manifest_path, workload):
+        from benchmark import tasks
+
         self.manifest = load_json(manifest_path)
         rows = [w for w in self.manifest["workloads"] if w["name"] == workload]
         if not rows:
@@ -64,15 +67,25 @@ class Cell:
         base = os.path.dirname(os.path.abspath(manifest_path))
         conf = [c for c in self.manifest["configs"] if c["name"] == self.row["config"]][0]
         self.config = load_json(os.path.join(base, conf["file"]))
-        data_dir = os.path.dirname(os.path.dirname(os.path.join(base, conf["file"])))
+        self.data_dir = os.path.dirname(os.path.dirname(os.path.join(base, conf["file"])))
         self.traffic = load_json(os.path.join(
-            data_dir, "traffic", self.row["traffic"] + ".json"))
+            self.data_dir, "traffic", self.row["traffic"] + ".json"))
         self.limits = load_json(os.path.join(
-            data_dir, "limits", workload + ".json"))["numbers"]
-        self.flops = load_py(os.path.join(
-            HERE, "flops", self.config.get("flops", self.row["config"]) + ".py"))
-        self.reference = load_py(os.path.join(
-            HERE, "reference", self.config["model"] + ".py"))
+            self.data_dir, "limits", workload + ".json"))["numbers"]
+        self.flops = self.code("flops", self.config.get("flops", self.row["config"]))
+        self.reference = self.code("reference", self.config["model"])
+        self.task = self.code("tasks", self.config.get("task", tasks.DEFAULT))
+
+    def code(self, kind, name):
+        """A code file of the cell (``flops/``, ``reference/``, ``tasks/``):
+        beside its traffic/ and limits/ first, so that a manifest elsewhere
+        (the tests' tiny one) can bring its own, else the benchmark's."""
+        for root in (self.data_dir, HERE):
+            path = os.path.join(root, kind, name + ".py")
+            if os.path.isfile(path):
+                return load_py(path)
+        raise SystemExit(f"no {kind}/{name}.py beside {self.name}'s files "
+                         f"or under {HERE}")
 
     def reports(self, metric):
         return "workloads" not in metric or self.name in metric["workloads"]
@@ -173,12 +186,10 @@ def run(manifest_path, workload, seed, seconds, trace, need_tpu=True,
 
     from benchmark import check, sut, trace_reduce
 
-    cfg, traffic = cell.config, cell.traffic
-    inputs = images, labels, shards, initial = check.seeded_inputs(cell, seed)
+    inputs = check.seeded_inputs(cell, seed)
     stamps["data"] = time.perf_counter()
 
-    fed = sut.build(cfg, traffic, images, labels, shards, initial["params"],
-                    initial["stats"], cell.chips)
+    fed = sut.build(cell, inputs)
     stamps["build"] = time.perf_counter()
 
     def one_round(spans=False):
@@ -196,7 +207,7 @@ def run(manifest_path, workload, seed, seconds, trace, need_tpu=True,
         return time.perf_counter() - t0, loss
 
     # The first rounds: they compile, warm up and are what `correct` compares.
-    n_check = traffic["check_rounds"]
+    n_check = cell.traffic["check_rounds"]
     program = check.first_rounds(
         fed, n_check, lambda: one_round()[1],
         lambda: stamps.__setitem__("first_round", time.perf_counter()))
@@ -209,7 +220,7 @@ def run(manifest_path, workload, seed, seconds, trace, need_tpu=True,
     setup_s = setup_end - stamps["start"]
 
     # ------------------------------------------------------------ the window
-    round_s, losses, traced = [], [], None
+    round_s, losses, traced, traced_rounds = [], [], None, 0
     with tempfile.TemporaryDirectory() as trace_dir:
         tracing = bool(trace)
         if tracing:
@@ -225,7 +236,7 @@ def run(manifest_path, workload, seed, seconds, trace, need_tpu=True,
             if tracing and len(round_s) >= 2 and (
                     time.perf_counter() - w0 >= min(TRACE_SECONDS, seconds)):
                 jax.profiler.stop_trace()
-                tracing = False
+                tracing, traced_rounds = False, len(round_s)
                 traced = trace_reduce.reduce_trace(
                     trace_reduce.load_xplane(trace_dir))
                 # Writing the trace out is not the program's time: the rest
@@ -268,14 +279,16 @@ def run(manifest_path, workload, seed, seconds, trace, need_tpu=True,
     ctx = {
         "cell": cell, "chips": cell.chips, "trace": traced, "rate": rate,
         "peaks": peaks, "memory_peak_bytes": peak_bytes,
-        "window_s": elapsed, "rounds": len(round_s),
+        "window_s": elapsed, "rounds": len(round_s), "traced_rounds": traced_rounds,
         "compile_setup_s": sum(e[2] for e in compiles.between(0, setup_end)),
         "compiles_in_window": len(in_window),
     }
     metrics = {}
     if trace:
-        if traced is None:
-            say("benchmark: the trace holds no device operation or no span")
+        refusal = ("the trace holds no device operation or no span"
+                   if traced is None else trace_reduce.stale_scopes(traced))
+        if refusal:
+            say("benchmark: " + refusal)
             raise SystemExit(3)
         for m in cell.metrics("per_layer"):
             value = load_py(os.path.join(

@@ -1,7 +1,6 @@
-"""Everything a run draws from ``--seed``: the data, the clients' shards, the
-weights. Made on the device in one jitted call each, in float32 values that
-bfloat16 holds exactly for the images, so the program (which stores them in
-its compute type) and the float32 reference see the same pixels.
+"""What a run draws from ``--seed`` whatever the task: the key streams, the
+clients' shards, the weights (one jitted call on the device). The data is the
+task's (``tasks/<task>.py::make_data``, stream 1 of ``key_of``).
 """
 
 from __future__ import annotations
@@ -17,22 +16,6 @@ def key_of(seed: int, stream: int):
     """A key for any whole-number seed (the driver's pass 2**31)."""
     k = jax.random.PRNGKey(seed % 1000003)
     return jax.random.fold_in(jax.random.fold_in(k, seed // 1000003), stream)
-
-
-def make_data(seed, n, image_shape, num_classes):
-    """Class prototypes plus unit noise: ``(images [n, H, W, C] f32, labels [n])``
-    as host arrays (the program takes its dataset from the host)."""
-
-    @jax.jit
-    def gen(key):
-        k_proto, k_lab, k_noise = jax.random.split(key, 3)
-        proto = jax.random.normal(k_proto, (num_classes,) + tuple(image_shape))
-        labels = jax.random.randint(k_lab, (n,), 0, num_classes)
-        x = 0.25 * (proto[labels] + jax.random.normal(k_noise, (n,) + tuple(image_shape)))
-        return x.astype(jnp.bfloat16).astype(jnp.float32), labels.astype(jnp.int32)
-
-    images, labels = gen(key_of(seed, 1))
-    return np.asarray(images), np.asarray(labels)
 
 
 def make_shards(seed, n, clients):
@@ -54,9 +37,13 @@ def client_rows(idx_row, steps, batch):
 
 
 def make_weights(seed, param_spec, stats_spec):
-    """He-normal kernels (fan-in), a head a tenth of that so the first loss
-    sits near ln(classes), zero biases, unit BatchNorm. Nested dicts."""
-    unknown = {k for _, _, k in param_spec} - {"he", "head", "zeros", "ones"}
+    """A reference's parameter list ``[(path, shape, kind)]`` as nested dicts.
+    Kinds: ``he`` (normal, fan-in ``prod(shape[:-1])``), ``head`` (a tenth of
+    that, so the first loss sits near ln(classes)), ``zeros``, ``ones``, or a
+    number: the standard deviation of a normal (an embedding, a stacked
+    ``[experts, in, out]`` leaf, whatever fan-in the shape does not show)."""
+    unknown = {k for _, _, k in param_spec
+               if not _is_std(k) and k not in ("he", "head", "zeros", "ones")}
     if unknown:
         raise ValueError(f"unknown initialiser kinds in the parameter list: {unknown}")
 
@@ -64,9 +51,10 @@ def make_weights(seed, param_spec, stats_spec):
     def gen(key):
         out = []
         for i, (_, shape, kind) in enumerate(param_spec):
-            if kind in ("he", "head"):
-                fan_in = math.prod(shape[:-1])
-                std = math.sqrt(2.0 / fan_in) * (0.1 if kind == "head" else 1.0)
+            if _is_std(kind) or kind in ("he", "head"):
+                std = kind if _is_std(kind) else (
+                    math.sqrt(2.0 / math.prod(shape[:-1]))
+                    * (0.1 if kind == "head" else 1.0))
                 out.append(std * jax.random.normal(jax.random.fold_in(key, i), shape))
             else:
                 out.append(jnp.full(shape, 1.0 if kind == "ones" else 0.0))
@@ -79,6 +67,10 @@ def make_weights(seed, param_spec, stats_spec):
         for path, shape, kind in stats_spec
     )
     return params, stats
+
+
+def _is_std(kind):
+    return isinstance(kind, (int, float)) and not isinstance(kind, bool)
 
 
 def _nest(items):

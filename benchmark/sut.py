@@ -10,54 +10,74 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def round_config(config, traffic):
-    """The program's own configuration object for a cell's files."""
+def round_config(config, traffic, task):
+    """The program's own configuration object for a cell's files: what every
+    cell states (model, optimizer, batch, clients, codec), then the task's
+    fields (``program_fields``: the kind of data), then the optional
+    ``"program": {"round"|"opt"|"data"|"fed": {...}}`` of the configuration
+    file and of the traffic file, by dataclass field name. A field the
+    program gains later is stated in a file, not here."""
+    import dataclasses
+
     from fedtpu.config import DataConfig, FedConfig, OptimizerConfig, RoundConfig
 
     opt, codec = config["optimizer"], traffic.get("codec") or {}
-    return RoundConfig(
-        model=config["model"],
-        num_classes=config["num_classes"],
-        image_size=tuple(config["image_shape"]),
-        opt=OptimizerConfig(
+    fields = {
+        "round": dict(
+            model=config["model"], steps_per_round=traffic["steps"],
+            dtype=config["activation_dtype"], remat=config["remat"]),
+        "opt": dict(
             learning_rate=opt["learning_rate"], momentum=opt["momentum"],
-            weight_decay=opt["weight_decay"], schedule="constant",
-        ),
-        data=DataConfig(
-            dataset=config["dataset"], batch_size=config["batch_size"],
-            # The benchmark hands in its own IID shards; "round_robin" is the
-            # program's name for iterating a shard unshuffled from its head.
-            partition="round_robin", augment=config["augment"],
-            device_layout=config["device_layout"],
-        ),
-        fed=FedConfig(
+            weight_decay=opt["weight_decay"], schedule="constant"),
+        # The benchmark hands in its own IID shards; "round_robin" is the
+        # program's name for iterating a shard unshuffled from its head.
+        "data": dict(batch_size=config["batch_size"], partition="round_robin"),
+        "fed": dict(
             num_clients=traffic["clients"], weighted=True,
             delta_layout=traffic["delta_layout"],
             compression=codec.get("name", "none"),
             rotq_bits=codec.get("bits", 4),
-            error_feedback=codec.get("error_feedback", True),
-        ),
-        steps_per_round=traffic["steps"],
-        dtype=config["activation_dtype"],
-        remat=config["remat"],
-    )
+            error_feedback=codec.get("error_feedback", True)),
+    }
+    classes = {"round": RoundConfig, "opt": OptimizerConfig, "data": DataConfig,
+               "fed": FedConfig}
+    overlays = (("the task", task.program_fields(config)),
+                ("the configuration file", config.get("program", {})),
+                ("the traffic file", traffic.get("program", {})))
+    for where, overlay in overlays:
+        for group, values in overlay.items():
+            if group not in classes:
+                raise ValueError(f"{where} states program fields under {group!r}; "
+                                 f"the groups are {sorted(classes)}")
+            known = {f.name for f in dataclasses.fields(classes[group])} - set(classes)
+            for name in values:
+                if name not in known:
+                    raise ValueError(
+                        f"{where} states {group}.{name}, and the program's "
+                        f"{classes[group].__name__} has no field {name!r}")
+            fields[group].update(values)
+    return RoundConfig(
+        opt=OptimizerConfig(**fields["opt"]), data=DataConfig(**fields["data"]),
+        fed=FedConfig(**fields["fed"]), **fields["round"])
 
 
-def build(config, traffic, images, labels, shards, params, stats, chips):
+def build(cell, inputs):
+    """The engine for a cell on what ``check.seeded_inputs`` drew for it."""
     from fedtpu.core import Federation
 
-    cfg = round_config(config, traffic)
+    examples, targets, shards, initial = inputs
+    cfg = round_config(cell.config, cell.traffic, cell.task)
     mesh = None
-    if traffic["mesh"]:
+    if cell.traffic["mesh"]:
         from fedtpu.parallel import client_mesh
 
-        mesh = client_mesh(chips, cfg.mesh_axis)
-    fed = Federation(cfg, seed=0, data=(images, labels), mesh=mesh,
+        mesh = client_mesh(cell.chips, cfg.mesh_axis)
+    fed = Federation(cfg, seed=0, data=(examples, targets), mesh=mesh,
                      assignment=shards)
     state = fed.state
     fed.state = state._replace(
-        params=_like(state.params, params),
-        batch_stats=_like(state.batch_stats, stats),
+        params=_like(state.params, initial["params"]),
+        batch_stats=_like(state.batch_stats, initial["stats"]),
     )
     return fed
 

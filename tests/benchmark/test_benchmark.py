@@ -1,13 +1,18 @@
 """The benchmark's own tests: the manifest, the refusal to run without a
-TPU, the FLOP functions, the trace reduction on a recorded TPU trace, the
-plain reference against ``Federation`` on the CPU, the lower-precision
-control, and a run whose timed path is broken underneath.
+TPU, the FLOP functions, the trace reduction on recorded TPU traces, what the
+harness hands the program at equal seeds (digests recorded at the parent of
+PR 27), a task of another kind of data through the reference path, the plain
+reference against ``Federation`` on the CPU, the lower-precision control, and
+a run whose timed path is broken underneath.
 
 Everything here runs on the CPU at a tiny size (``tiny/``: the real smallcnn,
 4 clients, 2 steps of 32); times and rates come only from the chip.
 """
 
+import dataclasses
+import hashlib
 import json
+import math
 import os
 import re
 import shutil
@@ -89,7 +94,8 @@ def test_manifest_files_exist(manifest):
         for rel in (f"benchmark/traffic/{w['traffic']}.json",
                     f"benchmark/limits/{w['name']}.json",
                     f"benchmark/flops/{w['config']}.py",
-                    f"benchmark/reference/{cfg['model']}.py"):
+                    f"benchmark/reference/{cfg['model']}.py",
+                    f"benchmark/tasks/{cfg.get('task', 'image_classification')}.py"):
             assert os.path.isfile(os.path.join(ROOT, rel)), rel
     for m in manifest["per_layer"]:
         assert os.path.isfile(os.path.join(
@@ -215,6 +221,102 @@ def test_reduction_of_a_recorded_tpu_trace(fixture):
     assert len(out["device_ops"]) == 10
 
 
+def test_busy_by_scope_on_a_recorded_capture_agrees_with_gap_analyze():
+    """Three rounds of sim192_rotq4's round program on a v5e (PR 25), with the
+    shares ``tools/gap_analyze.py`` itself reduced them to (recorded in the
+    fixture): every scope to 0.1 point, the idle time by innermost span."""
+    from benchmark import trace_reduce as tr
+
+    with open(os.path.join(HERE, "capture_v5e_sim192_rotq4.json")) as fh:
+        recorded = json.load(fh)
+    events, expect = recorded["events"], recorded["expect"]
+    ops = [e for e in events if e["plane"].startswith("/device:")]
+    lo = min(e["start_ns"] for e in ops)
+    hi = max(e["start_ns"] + e["dur_ns"] for e in ops)
+    # The capture was taken outside the harness: one harness span over it.
+    events = events + [{"plane": "/host:CPU", "line": "t", "name": "sync",
+                        "start_ns": lo, "dur_ns": hi - lo}]
+    out = tr.reduce_trace(events)
+    assert out["window_s"] * 1e6 == pytest.approx(expect["window_us"], abs=1e-2)
+    assert out["busy_s"] * 1e6 == pytest.approx(expect["device_busy_us"], abs=1e-2)
+    assert sum(out["busy_by_scope"].values()) == pytest.approx(out["busy_s"], rel=1e-9)
+    assert set(out["busy_by_scope"]) == set(expect["scope_share"])
+    for scope, share in expect["scope_share"].items():
+        assert out["busy_by_scope"][scope] / out["busy_s"] == pytest.approx(
+            share, abs=1e-3), scope
+    assert expect["scope_share"]["fed.codec.rotate"] > 0.7  # PR 25's butterfly
+    assert tr.scope_share(out, "fed.codec") == pytest.approx(100 * sum(
+        v for k, v in expect["scope_share"].items() if k.startswith("fed.codec")),
+        abs=1e-3)
+    assert tr.scope_share(out, "fed.nothing") is None
+    assert tr.stale_scopes(out) is None  # _unscoped_ 2.4 %
+    idle = {k: v * 1e6 for k, v in out["idle_by_span"].items()}
+    for span, us in expect["idle_us_by_span"].items():
+        assert idle["sync" if span == "caller" else span] == pytest.approx(us, abs=1e-2)
+    assert out["collective_exposed_s"] == 0.0 and out["collective_share"] == 0.0
+
+
+def test_nested_spans_give_a_gap_to_the_deepest_and_collectives_their_exposed_part():
+    from benchmark import trace_reduce as tr
+
+    dev = "/device:TPU:0"
+    ev = lambda plane, name, a, d, **kw: {
+        "plane": plane, "line": "t", "name": name, "start_ns": a, "dur_ns": d, **kw}
+    events = [
+        ev("/host:CPU", "dispatch", 0, 400),
+        ev("/host:CPU", "fed.round", 50, 300),       # inside dispatch
+        ev("/host:CPU", "fed.plan", 60, 100),        # inside fed.round
+        ev("/host:CPU", "fed.enqueue", 160, 150),    # inside fed.round
+        ev("/host:CPU", "sync", 400, 600),
+        ev(dev, "fusion.1", 200, 300, scope="fed.local_step.fwd_bwd"),
+        ev(dev, "while.2", 500, 300, scope="fed.local_step"),
+        ev(dev, "fusion.3", 550, 100, scope="fed.codec.rotate"),  # the loop's body
+        ev(dev, "all-reduce.4", 800, 100, scope="fed.aggregate.psum"),
+        ev(dev, "copy.5", 850, 100),                 # hides half the all-reduce
+    ]
+    out = tr.reduce_trace(events)
+    assert out["window_s"] == pytest.approx(1000e-9) and out["busy_s"] == pytest.approx(750e-9)
+    # device idle [0, 200) and [950, 1000)
+    assert {k: round(v * 1e9) for k, v in out["idle_by_span"].items()} == {
+        "dispatch": 50, "fed.round": 10, "fed.plan": 100, "fed.enqueue": 40,
+        "sync": 50, "_no_span_": 0}
+    assert {k: round(v * 1e9) for k, v in out["busy_by_scope"].items()} == {
+        "fed.local_step.fwd_bwd": 300, "fed.local_step": 200,
+        "fed.codec.rotate": 100, "fed.aggregate.psum": 50, "_unscoped_": 100}
+    assert out["collective_share"] == pytest.approx(100 / 750)
+    assert out["collective_exposed_s"] == pytest.approx(50e-9)
+    assert tr.scope_share(out, "fed.local_step") == pytest.approx(100 * 500 / 750)
+    assert "13.3 %" in tr.stale_scopes(out)  # 100 of 750 without a scope
+    assert tr.scope_of("jit(round)/vmap(fed.local_step)/while/body/"
+                       "transpose(jvp(fed.local_step.fwd_bwd))/conv") == "fed.local_step.fwd_bwd"
+    assert tr.scope_of("jit(round)/copy") == "" and tr.scope_of(None) == ""
+
+
+def test_scopes_are_read_from_the_trace_viewer_json(tmp_path):
+    import gzip
+
+    from benchmark import trace_reduce as tr
+
+    meta = lambda pid, tid, kind, name: {
+        "ph": "M", "pid": pid, "tid": tid, "name": kind, "args": {"name": name}}
+    op = lambda pid, tid, name, tf_op: {
+        "ph": "X", "pid": pid, "tid": tid, "name": name, "ts": 1.0, "dur": 2.0,
+        "args": {"tf_op": tf_op} if tf_op else {}}
+    doc = {"traceEvents": [
+        meta(3, 0, "process_name", "/device:TPU:0"), meta(3, 1, "thread_name", "XLA Ops"),
+        meta(3, 2, "thread_name", "Steps"), meta(9, 0, "process_name", "/host:CPU"),
+        op(3, 1, "fusion.7", "jit(r)/fed.codec/fed.codec.rotate/dot_general"),
+        op(3, 1, "copy.1", ""), op(3, 2, "step 0", "jit(r)/fed.data/x"),
+        op(9, 5, "fusion.7", "jit(r)/fed.pack/x"),
+    ]}
+    path = tmp_path / "host.trace.json.gz"
+    with gzip.open(path, "wt") as fh:
+        json.dump(doc, fh)
+    assert tr.load_scopes(str(path)) == {
+        ("/device:TPU:0", "fusion.7"): "fed.codec.rotate",
+        ("/device:TPU:0", "copy.1"): ""}
+
+
 def test_layer_metric_readers_return_nothing_without_something_to_read(manifest):
     from benchmark import run
 
@@ -226,18 +328,41 @@ def test_layer_metric_readers_return_nothing_without_something_to_read(manifest)
         ROOT, "benchmark", "layer_metrics", m["name"] + ".py")).read(ctx)
         for m in manifest["per_layer"]}
     assert got["device.idle_share"] is None and got["mesh.collective_share"] is None
+    for name in ("local_step.device_share", "aggregate.device_share",
+                 "codec.device_share", "codec.rotate_roofline",
+                 "engine.enqueue_gap_share", "mesh.collective_exposed_share"):
+        assert name in got and got[name] is None, name
     assert got["engine.host_gap_share"] is None and got["device.peak_hbm_gb"] is None
     assert got["entry.compile_s"] == 1.5 and got["entry.compiles_in_window"] == 0.0
     # 6 x 6,128,896 FLOP x 5e5 samples/s over 197 TFLOP/s
     assert got["local_step.mfu"] == pytest.approx(100 * 36_773_376 * 5e5 / 197e12)
     ctx["trace"] = {"window_s": 2.0, "busy_s": 1.5, "collective_share": 0.25,
-                    "idle_by_span": {"sync": 0.3, "_no_span_": 0.2}}
+                    "collective_exposed_s": 0.03,
+                    "idle_by_span": {"sync": 0.3, "_no_span_": 0.2},
+                    "busy_by_scope": {"fed.local_step": 0.1, "fed.local_step.fwd_bwd": 0.8,
+                                      "fed.aggregate.psum": 0.015, "_unscoped_": 0.585}}
     ctx["chips"] = 4
     read = lambda n: run.load_py(os.path.join(
         ROOT, "benchmark", "layer_metrics", n + ".py")).read(ctx)
     assert read("device.idle_share") == pytest.approx(25.0)
     assert read("engine.host_gap_share") == pytest.approx(15.0)
     assert read("mesh.collective_share") == pytest.approx(25.0)
+    assert read("mesh.collective_exposed_share") == pytest.approx(2.0)
+    assert read("local_step.device_share") == pytest.approx(60.0)
+    assert read("aggregate.device_share") == pytest.approx(1.0)
+    # no codec scope, no engine span in this trace: nothing, never 0
+    assert read("codec.device_share") is None and read("codec.rotate_roofline") is None
+    assert read("engine.enqueue_gap_share") is None
+    ctx["trace"]["idle_by_span"]["fed.enqueue"] = 0.001
+    assert read("engine.enqueue_gap_share") == pytest.approx(0.05)
+    # two rotations of [192, 2^20] f32 a round, read once and written once,
+    # at 819 GB/s: 3.933 ms a round; 20 ms under the scope in each of 3 rounds
+    ctx["trace"]["busy_by_scope"]["fed.codec.rotate"] = 0.060
+    ctx.update(traced_rounds=3, peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+               cell=_cell("smallcnn_cifar10.sim192_rotq4"))
+    assert read("codec.rotate_roofline") == pytest.approx(
+        100 * 3 * (2 * 2 * 192 * 2**20 * 4 / 819e9) / 0.060)
+    assert read("codec.device_share") == pytest.approx(4.0)
 
 
 # ------------------------------------------------------------- seeds and numbers
@@ -245,9 +370,11 @@ def test_any_seed_gives_the_same_inputs_again():
     from benchmark import seeded
 
     big = 2**31 + 12345
-    a = seeded.make_data(big, 64, (8, 8, 3), 10)
-    b = seeded.make_data(big, 64, (8, 8, 3), 10)
-    c = seeded.make_data(big + 1, 64, (8, 8, 3), 10)
+    task = _cell("smallcnn_cifar10.sim192").task
+    cfg = {"num_examples": 64, "image_shape": [8, 8, 3], "num_classes": 10}
+    a = task.make_data(big, cfg)
+    b = task.make_data(big, cfg)
+    c = task.make_data(big + 1, cfg)
     assert np.array_equal(a[0], b[0]) and np.array_equal(a[1], b[1])
     assert not np.array_equal(a[0], c[0])
     assert np.array_equal(a[0], a[0].astype("bfloat16").astype(np.float32))
@@ -255,6 +382,272 @@ def test_any_seed_gives_the_same_inputs_again():
     assert sorted(idx.ravel()) == list(range(64)) and mask.all()
     rows = seeded.client_rows(idx[0], steps=3, batch=8)  # 24 rows from 16: cycles
     assert rows.shape == (3, 8) and np.array_equal(rows[2], idx[0][:8])
+
+
+# ------------------- what the program is handed, against the parent of PR 27
+def _digest(a):
+    a = np.ascontiguousarray(np.asarray(a))
+    h = hashlib.sha256(f"{a.dtype}{a.shape}".encode())
+    h.update(a.tobytes())
+    return h.hexdigest()[:16]
+
+
+def _leaf_digests(tree, prefix=()):
+    if isinstance(tree, dict):
+        out = {}
+        for k in sorted(tree):
+            out.update(_leaf_digests(tree[k], prefix + (k,)))
+        return out
+    return {"/".join(prefix): _digest(tree)}
+
+
+CELLS = ["resnet18_cifar100.sim64", "smallcnn_cifar10.sim192",
+         "smallcnn_cifar10.sim192_rotq4", "resnet18_cifar100.mesh4_sim256"]
+
+
+@pytest.mark.parametrize("seed", [1, 2147485101])
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_the_program_is_handed_the_parents_arrays(cell_name, seed):
+    """Examples, targets, shards and every weight leaf that
+    ``check.seeded_inputs`` draws equal, bit for bit, what the harness drew
+    before tasks existed (``parent_digests.json``: commit 447791e, this
+    backend, ``num_examples`` 1024: the generator is one code path at any
+    size, and the chip runs of PR 27 hold the full size)."""
+    from benchmark import check
+
+    with open(os.path.join(HERE, "parent_digests.json")) as fh:
+        recorded = json.load(fh)
+    cell = _cell(cell_name)
+    cell.config = dict(cell.config, num_examples=recorded["num_examples"])
+    examples, targets, shards, initial = check.seeded_inputs(cell, seed)
+    assert {
+        "inputs": _digest(examples), "targets": _digest(targets),
+        "shards": [_digest(shards[0]), _digest(shards[1])],
+        "params": _leaf_digests(initial["params"]),
+        "stats": _leaf_digests(initial["stats"]),
+    } == recorded["cells"][cell_name][str(seed)]
+
+
+def _parent_round_config(cell_name):
+    """The configuration object the parent's ``sut.round_config`` built for
+    each cell, written out."""
+    from fedtpu.config import DataConfig, FedConfig, OptimizerConfig, RoundConfig
+
+    opt = OptimizerConfig(learning_rate=0.1, momentum=0.9, weight_decay=0.0005,
+                          schedule="constant")
+    resnet = dict(model="resnet18", num_classes=100, image_size=(32, 32, 3), opt=opt,
+                  data=DataConfig(dataset="cifar100", batch_size=128,
+                                  partition="round_robin", augment=False,
+                                  device_layout="gather"),
+                  steps_per_round=6, dtype="bfloat16", remat=True)
+    small = dict(model="smallcnn", num_classes=10, image_size=(32, 32, 3), opt=opt,
+                 data=DataConfig(dataset="cifar10", batch_size=128,
+                                 partition="round_robin", augment=False,
+                                 device_layout="presharded"),
+                 steps_per_round=2, dtype="bfloat16", remat=False)
+    plain = dict(weighted=True, delta_layout="per_leaf", compression="none",
+                 rotq_bits=4, error_feedback=True)
+    return {
+        "resnet18_cifar100.sim64": RoundConfig(
+            fed=FedConfig(num_clients=64, **plain), **resnet),
+        "smallcnn_cifar10.sim192": RoundConfig(
+            fed=FedConfig(num_clients=192, **plain), **small),
+        "smallcnn_cifar10.sim192_rotq4": RoundConfig(
+            fed=FedConfig(num_clients=192, weighted=True, delta_layout="flat",
+                          compression="rotq", rotq_bits=4, error_feedback=True), **small),
+        "resnet18_cifar100.mesh4_sim256": RoundConfig(
+            fed=FedConfig(num_clients=256, **plain), **resnet),
+    }[cell_name]
+
+
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_round_config_is_the_parents_object_field_for_field(cell_name):
+    from benchmark import sut
+
+    cell = _cell(cell_name)
+    got = sut.round_config(cell.config, cell.traffic, cell.task)
+    want = _parent_round_config(cell_name)
+    assert dataclasses.asdict(got) == dataclasses.asdict(want)
+    assert got == want
+
+
+def test_a_program_overlay_sets_a_real_field():
+    """``"program"`` in a configuration file or a traffic file states fields
+    of the program's configuration by dataclass field name, the traffic file
+    last: a field the program gains later needs no edit to ``sut.py``."""
+    from benchmark import sut
+
+    cell = _cell("smallcnn_cifar10.sim192")
+    base = sut.round_config(cell.config, cell.traffic, cell.task)
+    assert base.opt.nesterov is False and base.fed.server_optimizer == "none"
+    config = dict(cell.config, program={"opt": {"nesterov": True},
+                                        "fed": {"server_optimizer": "adam"}})
+    traffic = dict(cell.traffic, program={"fed": {"server_optimizer": "momentum"},
+                                          "round": {"debug_per_batch": True}})
+    got = sut.round_config(config, traffic, cell.task)
+    assert got.opt.nesterov is True and got.fed.server_optimizer == "momentum"
+    assert got.debug_per_batch is True
+    assert dataclasses.replace(
+        got, debug_per_batch=False, opt=base.opt, fed=base.fed) == base
+
+
+@pytest.mark.parametrize("overlay,names", [
+    ({"opt": {"nesterovv": True}}, ("opt.nesterovv", "OptimizerConfig")),
+    ({"round": {"fed": {}}}, ("round.fed", "RoundConfig")),
+    ({"optimizer": {"nesterov": True}}, ("'optimizer'",)),
+])
+def test_an_unknown_program_field_is_an_error_that_names_it(overlay, names):
+    from benchmark import sut
+
+    cell = _cell("smallcnn_cifar10.sim192")
+    with pytest.raises(ValueError) as err:
+        sut.round_config(dict(cell.config, program=overlay), cell.traffic, cell.task)
+    assert all(n in str(err.value) for n in names), str(err.value)
+
+
+def test_a_numeric_initialiser_is_a_standard_deviation():
+    from benchmark import seeded
+
+    spec = [(("embed", "table"), (512, 64), 0.5), (("experts", "w"), (4, 64, 64), 0.02),
+            (("head", "kernel"), (64, 8), "head"), (("head", "bias"), (8,), "zeros")]
+    params, stats = seeded.make_weights(3, spec, [])
+    assert float(np.std(params["embed"]["table"])) == pytest.approx(0.5, rel=0.02)
+    assert float(np.std(params["experts"]["w"])) == pytest.approx(0.02, rel=0.02)
+    assert float(np.std(params["head"]["kernel"])) == pytest.approx(
+        0.1 * math.sqrt(2 / 64), rel=0.1)
+    assert not np.any(params["head"]["bias"]) and stats == {}
+    with pytest.raises(ValueError, match="glorot"):
+        seeded.make_weights(3, [(("a",), (2, 2), "glorot")], [])
+    with pytest.raises(ValueError, match="True"):
+        seeded.make_weights(3, [(("a",), (2, 2), True)], [])
+
+
+# -------------------- a task of another kind of data, by files alone
+TOY_TASK = '''
+"""Next-token prediction on rows of a seeded affine walk over the vocabulary:
+ids [n, T], targets the ids shifted by one, mean cross-entropy over positions.
+A sample is one row of T tokens."""
+import jax, jax.numpy as jnp, numpy as np
+
+
+def make_data(seed, cfg):
+    from benchmark import seeded
+    n, t, v = cfg["num_examples"], cfg["seq_len"], cfg["vocab"]
+    start = np.asarray(jax.random.randint(seeded.key_of(seed, 1), (n,), 0, v))
+    walk = [start]
+    for _ in range(t):
+        walk.append((5 * walk[-1] + 7) % v)
+    ids = np.stack(walk, axis=1).astype(np.int32)
+    return ids[:, :-1], ids[:, 1:]
+
+
+def loss(logits, targets):
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+    return -jnp.take_along_axis(logp, targets[..., None], axis=-1).mean()
+
+
+def program_fields(cfg):
+    return {"round": {"num_classes": cfg["vocab"]}, "data": {"dataset": "synthetic"}}
+'''
+
+TOY_REFERENCE = '''
+"""A bigram model: an embedding table and a head."""
+from benchmark.reference.layers import dense, ident
+
+
+def spec(cfg):
+    v, w = cfg["vocab"], cfg["width"]
+    return [(("embed", "table"), (v, w), 1.0),
+            (("head", "kernel"), (w, v), "head"),
+            (("head", "bias"), (v,), "zeros")], []
+
+
+def make_forward(cfg):
+    def forward(params, stats, x, quant=ident):
+        return dense(params["embed"]["table"][x], params["head"], quant), stats
+    return forward
+'''
+
+
+@pytest.fixture(scope="module")
+def toy_cell(tmp_path_factory):
+    """A configuration of another task, added by files alone: a manifest and,
+    beside its traffic/ and limits/, a task, a reference, a FLOP function."""
+    from benchmark import run
+
+    root = tmp_path_factory.mktemp("toy")
+    files = {
+        "manifest.json": json.dumps({
+            "configs": [{"name": "bigram_toy", "file": "toy/configs/bigram_toy.json"}],
+            "workloads": [{"name": "bigram_toy.sim4", "config": "bigram_toy",
+                           "traffic": "sim4", "chips": 1}]}),
+        "toy/configs/bigram_toy.json": json.dumps({
+            "model": "bigram", "task": "next_token", "vocab": 64, "width": 32,
+            "seq_len": 16, "num_examples": 256, "batch_size": 16,
+            "optimizer": {"name": "sgd", "learning_rate": 0.3, "momentum": 0.9,
+                          "weight_decay": 0.0}}),
+        "toy/traffic/sim4.json": json.dumps({
+            "clients": 4, "steps": 2, "mesh": False, "delta_layout": "per_leaf",
+            "codec": None, "check_rounds": 3}),
+        "toy/limits/bigram_toy.sim4.json": json.dumps({"numbers": {}}),
+        "toy/tasks/next_token.py": TOY_TASK,
+        "toy/reference/bigram.py": TOY_REFERENCE,
+        "toy/flops/bigram_toy.py":
+            "def train_flops_per_sample(cfg):\n"
+            "    return 6 * cfg['seq_len'] * cfg['width'] * cfg['vocab']\n",
+    }
+    for rel, text in files.items():
+        os.makedirs(os.path.dirname(root / rel), exist_ok=True)
+        (root / rel).write_text(text)
+    return run.Cell(str(root / "manifest.json"), "bigram_toy.sim4")
+
+
+def test_a_token_task_goes_through_the_reference_path(toy_cell):
+    import jax
+
+    from benchmark import check
+
+    examples, targets, shards, initial = inputs = check.seeded_inputs(toy_cell, 11)
+    assert examples.shape == targets.shape == (256, 16) and examples.dtype == np.int32
+    assert np.array_equal(examples[:, 1:], targets[:, :-1])
+    assert toy_cell.samples_per_round == 4 * 2 * 16
+    assert toy_cell.flops.train_flops_per_sample(toy_cell.config) == 6 * 16 * 32 * 64
+    reference, codec = check.follow_reference(toy_cell, 11, inputs, jax.devices()[:1])
+    losses = reference["losses"]
+    assert codec is None and len(losses) == 3
+    assert losses[0] == pytest.approx(math.log(64), rel=0.02)
+    assert losses[-1] < losses[0] - 0.5
+    moved = check.sub(reference["last"]["params"], initial["params"])
+    assert all(float(np.abs(l).max()) > 0 for l in jax.tree.leaves(moved))
+    # and the numbers the check compares are defined for such a tree
+    nums = check.numbers(initial, reference, reference)
+    assert nums == {"loss_gap": 0.0, "update1_gap": 0.0, "update1_diff": 0.0,
+                    "change_gap": 0.0}
+
+
+def test_a_token_task_draws_from_the_seed(toy_cell):
+    from benchmark import check
+
+    a, b = check.seeded_inputs(toy_cell, 11), check.seeded_inputs(toy_cell, 11)
+    c = check.seeded_inputs(toy_cell, 2**31 + 12)
+    for x, y in zip(a[:3], b[:3]):
+        assert all(np.array_equal(p, q) for p, q in zip(np.atleast_1d(x), np.atleast_1d(y)))
+    assert np.array_equal(a[3]["params"]["embed"]["table"], b[3]["params"]["embed"]["table"])
+    assert not np.array_equal(a[0], c[0])
+    assert not np.array_equal(a[3]["params"]["embed"]["table"],
+                              c[3]["params"]["embed"]["table"])
+    assert float(np.std(a[3]["params"]["embed"]["table"])) == pytest.approx(1.0, rel=0.05)
+
+
+def test_the_toy_task_states_its_program_fields(toy_cell):
+    """The token task's fields reach the program's configuration through the
+    same overlay (the program has no token path yet: nothing is built)."""
+    from benchmark import sut
+
+    toy_cell.config.update(remat=False, activation_dtype="float32")
+    cfg = sut.round_config(toy_cell.config, toy_cell.traffic, toy_cell.task)
+    assert cfg.num_classes == 64 and cfg.data.dataset == "synthetic"
+    assert cfg.model == "bigram" and cfg.fed.num_clients == 4 and cfg.steps_per_round == 2
 
 
 def test_worst_leaf_gap_and_percentile():
@@ -370,7 +763,7 @@ def test_resnet18_reference_forward_equals_the_programs():
     cell = _cell("resnet18_cifar100.sim64")
     cfg = dict(cell.config, image_shape=[8, 8, 3])
     params, stats = seeded.make_weights(5, *cell.reference.spec(cfg))
-    x = seeded.make_data(5, 4, (8, 8, 3), 100)[0]
+    x = cell.task.make_data(5, dict(cfg, num_examples=4))[0]
     ours, our_stats = cell.reference.make_forward(cfg)(params, stats, x)
     model = models.create("resnet18", num_classes=100, remat=True)
     variables = model.init(jax.random.PRNGKey(0), x[:1], train=False)

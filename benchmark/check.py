@@ -34,7 +34,8 @@ def follow_reference(cell, seed, inputs, devices, quant=None, bits=None):
     ref = fedavg.Reference(
         cell.reference.make_forward(cfg), cell.task.loss, initial["params"],
         initial["stats"], cfg["optimizer"], feed, shards[1].sum(axis=1),
-        devices, codec, quant or layers.ident)
+        devices, codec, quant or layers.ident,
+        cfg.get("reference_block_rows"), getattr(cell.task, "loss_parts", None))
     out = {"losses": []}
     for r in range(traffic["check_rounds"]):
         loss, _, _, extra = ref.round()
@@ -52,7 +53,8 @@ def seeded_inputs(cell, seed):
     from benchmark import seeded
 
     examples, targets = cell.task.make_data(seed, cell.config)
-    shards = seeded.make_shards(seed, len(examples), cell.traffic["clients"])
+    shards = seeded.make_shards(seed, len(examples), cell.traffic["clients"],
+                                cell.traffic.get("shards", "iid"))
     params, stats = seeded.make_weights(seed, *cell.reference.spec(cell.config))
     return examples, targets, shards, {
         "params": jax.tree.map(np.asarray, params), "stats": stats}
@@ -84,9 +86,32 @@ def against_reference(cell, seed, inputs, devices, program):
     return numbers(initial, program, reference), reference
 
 
+def _f64(leaf):
+    return np.asarray(leaf, np.float64)
+
+
+class _Lazy:
+    """A float64 leaf of a difference or a sum of trees, worked out when it
+    is read and kept by no one: the check holds one leaf in float64 at a
+    time, not whole trees."""
+
+    def __init__(self, op, x, y):
+        self.op, self.x, self.y = op, x, y
+
+    def __array__(self, dtype=None, copy=None):
+        return self.op(_f64(self.x), _f64(self.y))
+
+
+def sub(a, b):
+    return jax.tree.map(lambda x, y: _Lazy(np.subtract, x, y), a, b)
+
+
+def add(a, b):
+    return jax.tree.map(lambda x, y: _Lazy(np.add, x, y), a, b)
+
+
 def _leaf_norms(tree):
-    return np.array([float(np.linalg.norm(np.asarray(l, np.float64)))
-                     for l in jax.tree.leaves(tree)])
+    return np.array([float(np.linalg.norm(_f64(l))) for l in jax.tree.leaves(tree)])
 
 
 def worst_leaf_gap(program, reference):
@@ -106,19 +131,13 @@ def worst_leaf_gap(program, reference):
 def rel_diff(program, reference):
     """Norm of the difference over the reference's norm, whole tree: what
     rounding in a lower precision moves most, and steadily."""
-    d = sum(float(np.sum((np.asarray(p, np.float64) - np.asarray(r, np.float64)) ** 2))
-            for p, r in zip(jax.tree.leaves(program), jax.tree.leaves(reference)))
-    n = sum(float(np.sum(np.asarray(r, np.float64) ** 2))
-            for r in jax.tree.leaves(reference))
+    d, n = [], []
+    for p, r in zip(jax.tree.leaves(program), jax.tree.leaves(reference)):
+        r = _f64(r)
+        d.append(float(np.sum((_f64(p) - r) ** 2)))
+        n.append(float(np.sum(r ** 2)))
+    d, n = sum(d), sum(n)
     return float(np.sqrt(d / n)) if n > 0 else float("inf")
-
-
-def sub(a, b):
-    return jax.tree.map(lambda x, y: np.asarray(x, np.float64) - np.asarray(y, np.float64), a, b)
-
-
-def add(a, b):
-    return jax.tree.map(lambda x, y: np.asarray(x, np.float64) + np.asarray(y, np.float64), a, b)
 
 
 def numbers(initial, program, reference):

@@ -13,9 +13,20 @@ client at a time, one jitted local epoch, no vmap, no kernels, nothing from
 the program under test. Clients are dealt round-robin onto the devices it is
 given so that a four-chip cell's reference takes no longer than a one-chip
 cell's.
+
+What a device holds does not grow with the clients. Between turns: the global
+model and the running weighted sum of the changes. In a turn, besides: the
+client's momentum (on the host between its turns; none at all where the
+momentum is 0), the weights the epoch steps (which end as the change) and a
+step's gradient: five copies of the parameters at most, four without
+momentum, and the activations of one step, or of one block of its rows where
+the configuration states ``reference_block_rows`` (the blocks' running sum is
+then up to one copy more).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -28,24 +39,54 @@ def tree_map(f, *trees):
     return jax.tree.map(f, *trees)
 
 
-def make_local_epoch(forward, loss, opt, quant=ident):
-    """One client's local steps as a jitted function. ``loss(logits, targets)
-    -> scalar`` is the task's (``tasks/<task>.py``)."""
+def make_local_epoch(forward, loss, opt, quant=ident, block_rows=None,
+                     loss_parts=None):
+    """One client's local steps as a jitted function, ``epoch(params, stats,
+    mom, xs, ys) -> (change, stats' change, mom, mean loss)``; ``mom`` is
+    donated, and is ``None`` in and out where the momentum is 0 (``0*m + g``
+    is ``g``). ``loss(logits, targets) -> scalar`` is the task's
+    (``tasks/<task>.py``).
+
+    With ``block_rows`` a step's gradient is the sum of the gradients of
+    blocks of that many rows, so that only one block's activations are alive:
+    what the whole batch gives, up to the order of float32 sums. The blocks
+    weigh alike, the loss being a mean over rows, unless the task says
+    otherwise with ``loss_parts(logits, targets) -> (sum, count)``: the step's
+    loss is then the summed sums over the summed counts."""
     lr, mu, wd = opt["learning_rate"], opt["momentum"], opt["weight_decay"]
+    parts = loss_parts or (lambda logits, y: (loss(logits, y), 1.0))
 
     def loss_fn(params, stats, x, y):
         logits, new_stats = forward(params, stats, x, quant)
         return loss(logits, y), new_stats
 
+    def parts_fn(params, stats, x, y):
+        return parts(forward(params, stats, x, quant)[0], y)
+
     grad = jax.value_and_grad(loss_fn, has_aux=True)
+    parts_grad = jax.value_and_grad(parts_fn, has_aux=True)
+
+    def grad_in_blocks(params, stats, x, y):
+        def one_block(acc, block):
+            (total, count), g = parts_grad(params, stats, *block)
+            return tree_map(jnp.add, acc, (g, total, count)), None
+
+        blocks = tree_map(
+            lambda a: a.reshape((-1, block_rows) + a.shape[1:]), (x, y))
+        (g, total, count), _ = jax.lax.scan(
+            one_block, (tree_map(jnp.zeros_like, params),) + 2 * (jnp.zeros(()),),
+            blocks)
+        return (total / count, stats), tree_map(lambda g: g / count, g)
 
     def epoch(params, stats, mom, xs, ys):
         def one_step(carry, batch):
             params, stats, mom = carry
-            (ce, stats), g = grad(params, stats, *batch)
+            (ce, stats), g = (grad_in_blocks if block_rows else grad)(
+                params, stats, *batch)
             g = tree_map(lambda g, p: g + wd * p, g, params)
-            mom = tree_map(lambda m, g: mu * m + g, mom, g)
-            params = tree_map(lambda p, m: p - lr * m, params, mom)
+            if mu:
+                g = mom = tree_map(lambda m, g: mu * m + g, mom, g)
+            params = tree_map(lambda p, m: p - lr * m, params, g)
             return (params, stats, mom), ce
 
         # A scan, not an unrolled loop, so that a round of six steps compiles
@@ -60,7 +101,7 @@ def make_local_epoch(forward, loss, opt, quant=ident):
         with jax.default_matmul_precision("highest"):
             return epoch(*args)
 
-    return jax.jit(with_precision)
+    return jax.jit(with_precision, donate_argnums=2)
 
 
 class Reference:
@@ -71,16 +112,22 @@ class Reference:
     """
 
     def __init__(self, forward, loss, params, stats, opt, feed, weights,
-                 devices, codec=None, quant=ident):
+                 devices, codec=None, quant=ident, block_rows=None,
+                 loss_parts=None):
+        if block_rows and jax.tree.leaves(stats):
+            raise ValueError("reference_block_rows with batch statistics: a "
+                             "block's statistics are not the batch's")
         self.devices = list(devices)
-        self.epoch = make_local_epoch(forward, loss, opt, quant)
+        self.epoch = make_local_epoch(forward, loss, opt, quant, block_rows,
+                                      loss_parts)
+        self.momentum = opt["momentum"]
         self.params = tree_map(np.asarray, params)
         self.stats = tree_map(np.asarray, stats)
         self.weights = np.asarray(weights, np.float64)
         self.n = len(self.weights)
         self.feed = feed
         self.codec = codec
-        self.mom = [None] * self.n
+        self.mom = [None] * self.n  # host trees between the clients' turns
         self.residual = None  # [clients, padded] on device 0, codec cells
         self.round_idx = 0
 
@@ -89,33 +136,61 @@ class Reference:
         the updates as host trees and ``extra`` the codec's readings."""
         glob = [jax.device_put((self.params, self.stats), d) for d in self.devices]
         share = (self.weights / self.weights.sum()).astype(np.float32)
-        sums, rows, losses = [None] * len(self.devices), [], []
+        sums, rows, losses = [None] * len(self.devices), [], [None] * self.n
+        turn = [None] * len(self.devices)  # a device's turn in flight
+
+        def settle(k):
+            """Wait for device ``k``'s turn in flight: its change added to the
+            sum and let go, its loss, the client's momentum to the host (exact
+            both ways). One turn behind, so a device holds one client's
+            buffers and the others stay busy."""
+            if turn[k] is not None:
+                c, mom, loss = turn[k]
+                turn[k] = None
+                jax.block_until_ready(sums[k])
+                losses[c], self.mom[c] = float(loss), jax.device_get(mom)
+
         for c in range(self.n):
             k = c % len(self.devices)
+            settle(k)
             p, s = glob[k]
-            if self.mom[c] is None:
-                self.mom[c] = tree_map(jnp.zeros_like, p)
             xs, ys = jax.device_put(self.feed(c), self.devices[k])
-            d, sd, self.mom[c], loss = self.epoch(p, s, self.mom[c], xs, ys)
-            losses.append(loss)
-            # Weighted sums stay on the client's device; the host adds the
-            # devices' sums at the end, so nothing waits on a transfer.
-            sums[k] = _axpy(sums[k], (d, sd), share[c])
+            if not self.momentum:
+                mom = None
+            elif self.mom[c] is None:
+                mom = tree_map(jnp.zeros_like, p)
+            else:
+                mom = jax.device_put(self.mom[c], self.devices[k])
+            d, sd, mom, loss = self.epoch(p, s, mom, xs, ys)
+            for leaf in jax.tree.leaves(mom):
+                leaf.copy_to_host_async()  # on its way before ``settle`` asks
+            turn[k] = (c, mom, loss)
             if self.codec is not None:
                 rows.append(jax.device_put(self.codec.pack(d), self.devices[0]))
-        total = None
-        for part in sums:
-            if part is not None:
-                part = tree_map(lambda a: np.asarray(a, np.float64), part)
-                total = part if total is None else tree_map(np.add, total, part)
-        upd, supd = tree_map(lambda a: a.astype(np.float32), total)
+            # Weighted sums stay on the client's device, and the change goes
+            # into them (donated): the host adds the devices' sums at the end.
+            sums[k] = _axpy(sums[k], (d, sd), share[c])
+            # Let go now, not when the next turn binds the names again: the
+            # device would hold two clients' buffers meanwhile.
+            del d, sd, mom
+        for k in range(len(self.devices)):
+            settle(k)
+        # The devices' sums, added in float64 one leaf at a time.
+        flat = [jax.tree.flatten(part) for part in sums if part is not None]
+        total = []
+        for leaves in zip(*(leaves for leaves, _ in flat)):
+            leaf = np.asarray(leaves[0], np.float64)
+            for more in leaves[1:]:
+                leaf = np.add(leaf, np.asarray(more, np.float64))
+            total.append(leaf.astype(np.float32))
+        upd, supd = jax.tree.unflatten(flat[0][1], total)
         extra = {}
         if self.codec is not None:
             upd, extra = self._through_codec(jnp.stack(rows), share)
         self.params = tree_map(np.add, self.params, upd)
         self.stats = tree_map(np.add, self.stats, supd)
         self.round_idx += 1
-        return float(np.mean([float(l) for l in losses])), upd, supd, extra
+        return float(np.mean(losses)), upd, supd, extra
 
     def _through_codec(self, rows, share):
         if self.residual is None:
@@ -130,12 +205,12 @@ class Reference:
         return self.codec.unpack(mean(out), self.params), extra
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=0)
 def _scaled(tree, w):
     return tree_map(lambda t: w * t, tree)
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=0)
 def _plus_scaled(acc, tree, w):
     return tree_map(lambda a, t: a + w * t, acc, tree)
 

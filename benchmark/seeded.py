@@ -18,12 +18,21 @@ def key_of(seed: int, stream: int):
     return jax.random.fold_in(jax.random.fold_in(k, seed // 1000003), stream)
 
 
-def make_shards(seed, n, clients):
-    """An IID split: a seeded permutation cut into ``clients`` equal shards.
-    ``(idx [clients, L] int32, mask [clients, L] bool)``, L = n // clients."""
+def make_shards(seed, n, clients, how="iid"):
+    """``clients`` equal shards, ``(idx [clients, L] int32, mask [clients, L]
+    bool)``, L = n // clients, as the traffic file's ``"shards"`` says:
+    ``iid`` (absent: this), a seeded permutation cut into shards;
+    ``contiguous``, client ``c`` takes rows ``[c*L, (c+1)*L)`` in the task's
+    own order, so that a task's ``make_data`` lays each client's rows out in
+    turn and decides how the clients differ."""
     length = n // clients
-    perm = np.random.default_rng(seed).permutation(n)[: clients * length]
-    idx = perm.reshape(clients, length).astype(np.int32)
+    if how == "iid":
+        rows = np.random.default_rng(seed).permutation(n)[: clients * length]
+    elif how == "contiguous":
+        rows = np.arange(clients * length)
+    else:
+        raise ValueError(f'"shards": {how!r}; the kinds are "iid" and "contiguous"')
+    idx = rows.reshape(clients, length).astype(np.int32)
     return idx, np.ones_like(idx, bool)
 
 

@@ -7,6 +7,17 @@ program's configuration that state this kind of data. A configuration names
 its task (``"task"``; absent, this one); the harness asks the task and names
 no kind of data itself. A sample, in ``samples_per_s_per_chip``, is one row
 of ``inputs``: here one image.
+
+What a task gives: ``make_data(seed, cfg) -> (inputs, targets)``, host arrays
+whose first axis is the examples, in an order of the task's own (a traffic
+file with ``"shards": "contiguous"`` deals client ``c`` the ``c``-th run of
+rows, so the order decides how the clients differ); ``loss(logits, targets)
+-> scalar`` in float32, of a whole batch; ``program_fields(cfg)``; and, only
+where the loss is not a mean over rows that all weigh alike (positions masked
+at a document's end), ``loss_parts(logits, targets) -> (sum, count)`` with
+``loss == sum / count``: a configuration that states
+``"reference_block_rows"`` has the reference take a step's gradient block by
+block, and the blocks then weigh by their counts.
 """
 
 from __future__ import annotations

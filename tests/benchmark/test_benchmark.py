@@ -569,37 +569,59 @@ def make_forward(cfg):
 '''
 
 
-@pytest.fixture(scope="module")
-def toy_cell(tmp_path_factory):
+# A task whose loss is no mean over equal blocks of rows: positions whose
+# target is a multiple of 3 do not count (as positions past a document's end).
+TOY_TASK_WITH_PARTS = TOY_TASK + '''
+
+def loss_parts(logits, targets):
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    keep = targets % 3 != 0
+    return jnp.sum(jnp.where(keep, nll, 0.0)), jnp.sum(keep).astype(jnp.float32)
+
+
+def loss(logits, targets):  # in place of the mean over all positions above
+    total, count = loss_parts(logits, targets)
+    return total / count
+'''
+
+
+def make_toy_cell(root, config=(), traffic=(), task=TOY_TASK):
     """A configuration of another task, added by files alone: a manifest and,
-    beside its traffic/ and limits/, a task, a reference, a FLOP function."""
+    beside its traffic/ and limits/, a task, a reference, a FLOP function;
+    ``config`` and ``traffic`` are laid over the two files' keys."""
     from benchmark import run
 
-    root = tmp_path_factory.mktemp("toy")
     files = {
         "manifest.json": json.dumps({
             "configs": [{"name": "bigram_toy", "file": "toy/configs/bigram_toy.json"}],
             "workloads": [{"name": "bigram_toy.sim4", "config": "bigram_toy",
                            "traffic": "sim4", "chips": 1}]}),
-        "toy/configs/bigram_toy.json": json.dumps({
+        "toy/configs/bigram_toy.json": json.dumps(dict({
             "model": "bigram", "task": "next_token", "vocab": 64, "width": 32,
             "seq_len": 16, "num_examples": 256, "batch_size": 16,
             "optimizer": {"name": "sgd", "learning_rate": 0.3, "momentum": 0.9,
-                          "weight_decay": 0.0}}),
-        "toy/traffic/sim4.json": json.dumps({
+                          "weight_decay": 0.0}}, **dict(config))),
+        "toy/traffic/sim4.json": json.dumps(dict({
             "clients": 4, "steps": 2, "mesh": False, "delta_layout": "per_leaf",
-            "codec": None, "check_rounds": 3}),
+            "codec": None, "check_rounds": 3}, **dict(traffic))),
         "toy/limits/bigram_toy.sim4.json": json.dumps({"numbers": {}}),
-        "toy/tasks/next_token.py": TOY_TASK,
+        "toy/tasks/next_token.py": task,
         "toy/reference/bigram.py": TOY_REFERENCE,
         "toy/flops/bigram_toy.py":
             "def train_flops_per_sample(cfg):\n"
             "    return 6 * cfg['seq_len'] * cfg['width'] * cfg['vocab']\n",
     }
     for rel, text in files.items():
-        os.makedirs(os.path.dirname(root / rel), exist_ok=True)
-        (root / rel).write_text(text)
-    return run.Cell(str(root / "manifest.json"), "bigram_toy.sim4")
+        os.makedirs(os.path.dirname(os.path.join(root, rel)), exist_ok=True)
+        with open(os.path.join(root, rel), "w") as fh:
+            fh.write(text)
+    return run.Cell(os.path.join(root, "manifest.json"), "bigram_toy.sim4")
+
+
+@pytest.fixture(scope="module")
+def toy_cell(tmp_path_factory):
+    return make_toy_cell(str(tmp_path_factory.mktemp("toy")))
 
 
 def test_a_token_task_goes_through_the_reference_path(toy_cell):
@@ -648,6 +670,233 @@ def test_the_toy_task_states_its_program_fields(toy_cell):
     cfg = sut.round_config(toy_cell.config, toy_cell.traffic, toy_cell.task)
     assert cfg.num_classes == 64 and cfg.data.dataset == "synthetic"
     assert cfg.model == "bigram" and cfg.fed.num_clients == 4 and cfg.steps_per_round == 2
+
+
+# ------------- the reference at size: memory, blocks of rows, shards, leaves
+def _toy_reference(cell, inputs, feed_spy=lambda: None):
+    """``fedavg.Reference`` on a toy cell's inputs, as ``follow_reference``
+    builds it; ``feed_spy`` is called at the head of every client's turn."""
+    import jax
+
+    from benchmark import seeded
+    from benchmark.reference import fedavg
+
+    examples, targets, shards, initial = inputs
+    cfg, traffic = cell.config, cell.traffic
+
+    def feed(c):
+        feed_spy()
+        rows = seeded.client_rows(shards[0][c], traffic["steps"], cfg["batch_size"])
+        return examples[rows], targets[rows]
+
+    return fedavg.Reference(
+        cell.reference.make_forward(cfg), cell.task.loss, initial["params"],
+        initial["stats"], cfg["optimizer"], feed, shards[1].sum(axis=1),
+        jax.devices()[:1], block_rows=cfg.get("reference_block_rows"),
+        loss_parts=getattr(cell.task, "loss_parts", None))
+
+
+def _tree_bytes(tree):
+    import jax
+
+    return sum(np.asarray(l).nbytes for l in jax.tree.leaves(tree))
+
+
+def _device_bytes(host_trees):
+    """The bytes of the buffers behind JAX's live arrays. On the CPU an array
+    put from the host, or copied to it, shares the host's buffer: a buffer is
+    counted once, and one that ``host_trees`` view is the host's, as it would
+    be beside a chip, and is not counted."""
+    import gc
+
+    import jax
+
+    gc.collect()  # JAX lets go of what dropped host views held when this runs
+    host = {l.ctypes.data for l in jax.tree.leaves(host_trees)}
+    buffers = {a.unsafe_buffer_pointer(): a.nbytes for a in jax.live_arrays()}
+    return sum(n for at, n in buffers.items() if at not in host)
+
+
+@pytest.mark.parametrize("momentum,copies", [(0.9, 5), (0.0, 4)])
+def test_the_references_device_memory_does_not_grow_with_the_clients(
+        tmp_path, momentum, copies):
+    """Between the clients' turns a device holds the global model and the
+    running sum, at 2 clients as at 8; in a turn the epoch's own arguments,
+    outputs and scratch come on top: five copies of the parameters in all
+    with momentum, four without, and a step's activations."""
+    import jax
+
+    from benchmark import check
+
+    sizes = {"vocab": 512, "width": 256, "seq_len": 8, "batch_size": 4,
+             "num_examples": 64, "optimizer": {
+                 "name": "sgd", "learning_rate": 0.3, "momentum": momentum,
+                 "weight_decay": 0.0}}
+    between, ref = {}, None
+    for clients in (2, 8):
+        cell = make_toy_cell(str(tmp_path / str(clients)), sizes,
+                             {"clients": clients, "check_rounds": 2})
+        inputs = check.seeded_inputs(cell, 5)
+        del ref
+        base = _device_bytes([])
+        seen = []
+        ref = _toy_reference(cell, inputs, lambda: seen.append(
+            _device_bytes(ref.mom) - base))
+        ref.round()
+        ref.round()
+        between[clients] = max(seen)
+        assert len(seen) == 2 * clients
+        held = [m for m in ref.mom if m is not None]
+        assert len(held) == (clients if momentum else 0)
+        assert all(isinstance(l, np.ndarray) for l in jax.tree.leaves(held))
+    one = _tree_bytes(inputs[3]["params"])
+    assert between[2] == between[8] and 2 * one <= between[2] < 2.01 * one
+    xs, ys = ref.feed(0)
+    mom = jax.tree.map(np.zeros_like, ref.params) if momentum else None
+    mem = ref.epoch.lower(ref.params, ref.stats, mom, xs, ys).compile().memory_analysis()
+    epoch = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    # With the running sum. On top of the copies: the logits [4, 8, 512] and
+    # their gradient, 0.13 of a copy, and one table's gradient, 0.5, which the
+    # CPU's compiler keeps apart at momentum 0 (the chip's does not: PERF.md).
+    assert epoch + one <= (copies + 0.6) * one, (
+        {k: getattr(mem, k) for k in dir(mem) if k.endswith("in_bytes")}, one)
+
+
+@pytest.mark.parametrize("task", [TOY_TASK, TOY_TASK_WITH_PARTS],
+                         ids=["mean", "loss_parts"])
+def test_a_step_in_blocks_of_rows_gives_the_whole_batchs_update(tmp_path, task):
+    import jax
+
+    from benchmark import check
+
+    got = {}
+    for name, extra in (("whole", {}), ("blocks", {"reference_block_rows": 4})):
+        cell = make_toy_cell(str(tmp_path / name), extra, task=task)
+        inputs = check.seeded_inputs(cell, 13)
+        got[name], _ = check.follow_reference(cell, 13, inputs, jax.devices()[:1])
+    initial = inputs[3]["params"]
+    update = lambda run, at: check.sub(run[at]["params"], initial)
+    for at in ("first", "last"):
+        assert check.rel_diff(update(got["blocks"], at), update(got["whole"], at)) < 1e-6
+    assert got["blocks"]["losses"] == pytest.approx(got["whole"]["losses"], rel=1e-6)
+    if task is TOY_TASK_WITH_PARTS:
+        # the blocks of a step do not weigh alike: equal weights would be wrong
+        counts = (inputs[1][:16] % 3 != 0).reshape(4, -1).sum(axis=1)
+        assert len(set(counts)) > 1
+    cell.config["reference_block_rows"] = 5  # 16 rows do not cut into fives
+    with pytest.raises(TypeError, match="reshape"):
+        check.follow_reference(cell, 13, inputs, jax.devices()[:1])
+
+
+@pytest.mark.parametrize("how", ["contiguous", "iid"])
+def test_the_traffic_file_says_how_the_clients_shards_are_cut(how):
+    from benchmark import check, seeded
+
+    cell = _cell("smallcnn_cifar10.sim192")
+    cell.config = dict(cell.config, num_examples=1024)
+    cell.traffic = dict(cell.traffic, shards=how)
+    idx, mask = check.seeded_inputs(cell, 1)[2]
+    assert idx.shape == (192, 5) and idx.dtype == np.int32 and mask.all()
+    if how == "contiguous":
+        assert np.array_equal(idx, np.arange(960).reshape(192, 5))
+    else:
+        with open(os.path.join(HERE, "parent_digests.json")) as fh:
+            recorded = json.load(fh)["cells"][cell.name]["1"]
+        assert [_digest(idx), _digest(mask)] == recorded["shards"]
+        assert np.array_equal(idx, seeded.make_shards(1, 1024, 192)[0])
+    with pytest.raises(ValueError, match="dirichlet"):
+        seeded.make_shards(1, 1024, 192, "dirichlet")
+
+
+def _whole_tree_numbers(initial, program, reference):
+    """``check.numbers`` as the parent of PR 28 worked it out, on whole
+    float64 trees: the arithmetic the leaf-by-leaf one has to repeat."""
+    import jax
+
+    from benchmark import check
+
+    f64 = lambda x: np.asarray(x, np.float64)
+    sub = lambda a, b: jax.tree.map(lambda x, y: f64(x) - f64(y), a, b)
+    add = lambda a, b: jax.tree.map(lambda x, y: f64(x) + f64(y), a, b)
+
+    def rel_diff(p, r):
+        d = sum(float(np.sum((x - y) ** 2))
+                for x, y in zip(jax.tree.leaves(p), jax.tree.leaves(r)))
+        n = sum(float(np.sum(y ** 2)) for y in jax.tree.leaves(r))
+        return float(np.sqrt(d / n))
+
+    out = {}
+    pl, rl = np.array(program["losses"]), np.array(reference["losses"])
+    out["loss_gap"] = float(np.max(np.abs(pl - rl) / np.abs(rl)))
+    p_upd = sub(program["first"]["params"], initial["params"])
+    r_upd = sub(reference["first"]["params"], initial["params"])
+    if "mean_residual" in program["first"]:
+        p_upd = add(p_upd, program["first"]["mean_residual"])
+        r_upd = add(r_upd, reference["first"]["mean_residual"])
+        pn = f64(program["first"]["residual_norms"])
+        rn = f64(reference["first"]["residual_norms"])
+        out["codec_residual_gap"] = float(np.max(np.abs(pn - rn) / rn))
+    out["update1_gap"] = check.worst_leaf_gap(p_upd, r_upd)
+    out["update1_diff"] = rel_diff(p_upd, r_upd)
+    out["change_gap"] = check.worst_leaf_gap(
+        sub(program["last"]["params"], initial["params"]),
+        sub(reference["last"]["params"], initial["params"]))
+    return out
+
+
+@pytest.mark.parametrize("cell_name", ["smallcnn_tiny.sim4", "smallcnn_tiny.sim4_rotq4"])
+def test_the_numbers_leaf_by_leaf_are_the_whole_trees(cell_name):
+    """The fp8 control against the reference on a tiny cell: every number
+    the same, to the last bit, whichever way the float64 leaves are held."""
+    import jax
+
+    from benchmark import check, run
+    from benchmark.reference import lowprec
+
+    cell = run.Cell(TINY, cell_name)
+    inputs = check.seeded_inputs(cell, 21)
+    args = (cell, 21, inputs, jax.devices()[:1])
+    reference, _ = check.follow_reference(*args)
+    low, _ = check.follow_reference(*args, quant=lowprec.fp8)
+    nums = check.numbers(inputs[3], low, reference)
+    assert nums == _whole_tree_numbers(inputs[3], low, reference)
+    assert ("codec_residual_gap" in nums) == cell_name.endswith("rotq4")
+    assert 0 < nums["update1_gap"] < 1 and 0 < nums["update1_diff"] < 1
+
+
+def test_momentum_0_keeps_no_buffer_and_is_plain_sgd(tmp_path):
+    """A round of the reference at momentum 0 against a hand-written one:
+    every client from the global model, ``p -= lr * (g + wd * p)`` a step,
+    the mean of the changes added (the shards are equal)."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import check, seeded
+
+    opt = {"name": "sgd", "learning_rate": 0.3, "momentum": 0.0, "weight_decay": 0.01}
+    cell = make_toy_cell(str(tmp_path), {"optimizer": opt}, {"clients": 2})
+    examples, targets, shards, initial = inputs = check.seeded_inputs(cell, 17)
+    ref = _toy_reference(cell, inputs)
+    loss, upd, _, _ = ref.round()
+    assert ref.mom == [None, None]
+
+    forward = cell.reference.make_forward(cell.config)
+    mean_ce = lambda p, x, y: cell.task.loss(forward(p, {}, x)[0], y)
+    start = jax.tree.map(jnp.asarray, initial["params"])
+    changes, first_losses = [], []
+    with jax.default_matmul_precision("highest"):
+        for c in range(2):
+            p = start
+            for rows in seeded.client_rows(shards[0][c], 2, 16):
+                ce, g = jax.value_and_grad(mean_ce)(p, examples[rows], targets[rows])
+                p = jax.tree.map(lambda p, g: p - 0.3 * (g + 0.01 * p), p, g)
+                first_losses.append(float(ce))
+            changes.append(jax.tree.map(jnp.subtract, p, start))
+    want = jax.tree.map(lambda a, b: 0.5 * a + 0.5 * b, *changes)
+    assert check.rel_diff(upd, want) < 1e-6
+    assert loss == pytest.approx(np.mean(first_losses), rel=1e-6)
+    assert check.rel_diff(ref.params, jax.tree.map(jnp.add, start, want)) < 1e-6
 
 
 def test_worst_leaf_gap_and_percentile():

@@ -60,8 +60,7 @@ UNIT = "client-epochs/sec/chip"
 # masquerade as the headline.
 BENCH_MODEL = os.environ.get("FEDTPU_BENCH_MODEL", "smallcnn")
 MOMENTUM_DTYPE = os.environ.get("FEDTPU_MOMENTUM_DTYPE", "float32")
-COMPUTE_DTYPE = os.environ.get("FEDTPU_COMPUTE_DTYPE", "float32")
-MEGABATCH_CLIENTS = int(os.environ.get("FEDTPU_MEGABATCH_CLIENTS", "0") or 0)
+DTYPE = "bfloat16"  # RoundConfig.dtype of the headline: no variant changes it
 _TIMED_ROUNDS_ENV = os.environ.get("FEDTPU_BENCH_TIMED_ROUNDS", "")
 if _TIMED_ROUNDS_ENV:
     TIMED_ROUNDS = int(_TIMED_ROUNDS_ENV)
@@ -81,13 +80,9 @@ def headline_config():
             partition="iid",
             num_examples=NUM_CLIENTS * STEPS_PER_ROUND * BATCH,
         ),
-        fed=FedConfig(
-            num_clients=NUM_CLIENTS,
-            compute_dtype=COMPUTE_DTYPE,
-            megabatch_clients=MEGABATCH_CLIENTS,
-        ),
+        fed=FedConfig(num_clients=NUM_CLIENTS),
         steps_per_round=STEPS_PER_ROUND,
-        dtype="bfloat16",
+        dtype=DTYPE,
     )
 
 
@@ -197,16 +192,13 @@ def _apply_variant_labels(result):
     if (
         BENCH_MODEL != "smallcnn"
         or MOMENTUM_DTYPE != "float32"
-        or COMPUTE_DTYPE != "float32"
-        or MEGABATCH_CLIENTS
         or _TIMED_ROUNDS_ENV
     ):
         result["metric"] = METRIC + "_variant"
         result.pop("vs_baseline", None)
         result["variant"] = {
             "model": BENCH_MODEL, "momentum_dtype": MOMENTUM_DTYPE,
-            "compute_dtype": COMPUTE_DTYPE,
-            "megabatch_clients": MEGABATCH_CLIENTS,
+            "dtype": DTYPE,
         }
         if _TIMED_ROUNDS_ENV:
             # Deeper fusion changes the dispatch-amortisation denominator,
@@ -2028,171 +2020,6 @@ def _mfu_microbench():
     return result
 
 
-def _mixed_precision_microbench():
-    """``--mixed-precision-microbench``: the fast-path levers, A/B'd off-chip.
-
-    Three modes of the SAME round program — ``f32`` (parity),
-    ``bf16_mixed`` (``FedConfig.compute_dtype='bfloat16_mixed'``) and
-    ``bf16_megabatch`` (bf16 plus ``megabatch_clients``) — measured two
-    ways:
-
-    - **analytic** (the headline ``value``): per-round FLOPs and
-      bytes-accessed from XLA cost analysis of the AOT-compiled fused round
-      program at the PROFILE shape (densenet_cifar, batch 128, 6 steps —
-      the config behind ``artifacts/MFU_PROFILE_r04*.json``; client count
-      reduced for CPU compile tractability, stamped in the artifact), plus
-      roofline placement against the headline chip's peaks
-      (``fedtpu.obs.profile.device_peaks``). ``value`` is the
-      f32→bf16+megabatch bytes_per_round drop — the ISSUE-13 acceptance
-      gate is ≥1.8x.
-    - **walls**: host wall-clock A/B at a seconds-scale config, mode order
-      rotated per rep, medians + the f32-mode noise floor. CPU walls are
-      an honesty check that the modes RUN, not a TPU speedup predictor —
-      CPUs emulate bf16; the on-chip effect of these modes is not
-      measured on the current tree.
-
-    Env knobs (shrunk by tests/test_bench.py): FEDTPU_MP_MODEL / _CLIENTS /
-    _MEGABATCH / _COST_BATCH / _COST_STEPS / _BATCH / _ROUNDS / _REPS /
-    _PLACEMENT_DEVICE. Run via ``python bench.py
-    --mixed-precision-microbench``; prints one JSON line and writes
-    ``artifacts/MIXED_PRECISION_MICROBENCH.json``.
-    """
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    import numpy as np
-
-    from fedtpu.config import DataConfig, FedConfig, RoundConfig
-    from fedtpu.core.engine import Federation
-    from fedtpu.obs.profile import device_peaks, engine_cost_model, roofline
-
-    model_name = os.environ.get("FEDTPU_MP_MODEL", "densenet_cifar")
-    clients = int(os.environ.get("FEDTPU_MP_CLIENTS", "8"))
-    mega = int(os.environ.get("FEDTPU_MP_MEGABATCH", "0")) or clients
-    cost_batch = int(os.environ.get("FEDTPU_MP_COST_BATCH", "128"))
-    cost_steps = int(os.environ.get("FEDTPU_MP_COST_STEPS", "6"))
-    batch = int(os.environ.get("FEDTPU_MP_BATCH", "8"))
-    rounds = int(os.environ.get("FEDTPU_MP_ROUNDS", "2"))
-    reps = int(os.environ.get("FEDTPU_MP_REPS", "3"))
-    # Roofline placement chip: the headline bench fleet (v5e; the committed
-    # MFU_PROFILE_r04 ridge point 240 flops/byte comes from its peaks).
-    placement = os.environ.get("FEDTPU_MP_PLACEMENT_DEVICE", "v5e")
-
-    modes = (
-        ("f32", "float32", 0),
-        ("bf16_mixed", "bfloat16_mixed", 0),
-        ("bf16_megabatch", "bfloat16_mixed", mega),
-    )
-
-    def make_cfg(compute_dtype, megabatch, batch_size, steps):
-        return RoundConfig(
-            model=model_name,
-            num_classes=10,
-            data=DataConfig(
-                dataset="cifar10", batch_size=batch_size, partition="iid",
-                num_examples=clients * steps * batch_size,
-            ),
-            fed=FedConfig(
-                num_clients=clients, telemetry="off",
-                compute_dtype=compute_dtype, megabatch_clients=megabatch,
-            ),
-            steps_per_round=steps,
-        )
-
-    peak_f, peak_b = device_peaks(placement)
-    analytic = {}
-    for name, cd, mb in modes:
-        fed = Federation(make_cfg(cd, mb, cost_batch, cost_steps), seed=0)
-        # bytes_per_round is the backend-independent jaxpr aval model
-        # (obs.profile.analytic_bytes): the CPU backend's cost_analysis
-        # bytes describe bf16 EMULATION (f32 upconverts), inverting the
-        # dtype lever this artifact exists to predict. The CPU-XLA figure
-        # rides along as the audit trail.
-        cost = engine_cost_model(fed, xla_check=True)
-        analytic[name] = {
-            "flops_per_round": cost.flops,
-            "bytes_per_round": cost.analytic_bytes,
-            "xla_bytes_cpu": cost.xla_bytes,
-            "flops_source": cost.source,
-            **roofline(cost.flops, cost.analytic_bytes, peak_f, peak_b),
-        }
-        del fed
-
-    b_f32 = analytic["f32"]["bytes_per_round"]
-    b_fast = analytic["bf16_megabatch"]["bytes_per_round"]
-    bytes_drop = round(b_f32 / b_fast, 3) if b_f32 and b_fast else None
-    b_bf16 = analytic["bf16_mixed"]["bytes_per_round"]
-
-    feds = {
-        name: Federation(make_cfg(cd, mb, batch, 1), seed=0)
-        for name, cd, mb in modes
-    }
-
-    def run_block(fed):
-        m = fed.run_on_device(rounds)
-        np.asarray(m.loss)  # sync: fetch a program output
-
-    for fed in feds.values():
-        run_block(fed)  # compile + warmup
-    order = tuple(feds)
-    trials = {name: [] for name in order}
-    for rep in range(reps):
-        # Rotate mode order per rep so machine-wide drift cannot read as a
-        # mode delta (see _telemetry_microbench for the measured rationale).
-        for name in order if rep % 2 == 0 else order[::-1]:
-            t0 = time.perf_counter()
-            run_block(feds[name])
-            trials[name].append((time.perf_counter() - t0) / rounds)
-    med = {name: sorted(ts)[len(ts) // 2] for name, ts in trials.items()}
-    noise_floor_pct = (
-        (max(trials["f32"]) - min(trials["f32"])) / med["f32"] * 100.0
-    )
-
-    result = {
-        "metric": "mixed_precision_bytes_drop",
-        "unit": "x reduction in analytic bytes_per_round, f32 -> "
-                "bf16_mixed+megabatch",
-        "value": bytes_drop,
-        "gate_x": 1.8,
-        "passes_gate": bool(bytes_drop and bytes_drop >= 1.8),
-        "analytic": analytic,
-        "bytes_drop_bf16_only": (
-            round(b_f32 / b_bf16, 3) if b_f32 and b_bf16 else None
-        ),
-        "flops_ratio_fast_vs_f32": (
-            round(
-                analytic["bf16_megabatch"]["flops_per_round"]
-                / analytic["f32"]["flops_per_round"], 3,
-            )
-            if analytic["f32"]["flops_per_round"]
-            and analytic["bf16_megabatch"]["flops_per_round"] else None
-        ),
-        "analytic_config": {
-            "model": model_name, "num_clients": clients,
-            "batch": cost_batch, "steps_per_round": cost_steps,
-            "megabatch_clients": mega, "placement_device": placement,
-            "peak_flops": peak_f, "peak_hbm_bytes_per_s": peak_b,
-        },
-        "walls": {
-            "round_ms": {n: round(t * 1e3, 3) for n, t in med.items()},
-            "noise_floor_pct": round(noise_floor_pct, 3),
-            "config": {"batch": batch, "rounds_per_trial": rounds,
-                       "reps": reps},
-            "note": "CPU walls prove the modes run; bf16 is emulated on "
-                    "CPU, so TPU speedups come from the live artifacts",
-        },
-        "backend": os.environ.get("JAX_PLATFORMS", "default"),
-    }
-    os.makedirs(ARTIFACTS_DIR, exist_ok=True)
-    path = os.path.join(ARTIFACTS_DIR, "MIXED_PRECISION_MICROBENCH.json")
-    tmp = path + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(result, f, indent=2)
-    os.replace(tmp, path)
-    return result
-
-
 def _fanin_microbench():
     """``fanin_microbench``: does the hierarchical root's per-round work
     scale with AGGREGATORS or with CLIENTS?
@@ -2496,9 +2323,6 @@ def main():
         return
     if "--mfu-microbench" in sys.argv:
         print(json.dumps(_mfu_microbench()))
-        return
-    if "--mixed-precision-microbench" in sys.argv:
-        print(json.dumps(_mixed_precision_microbench()))
         return
     if "--fanin-microbench" in sys.argv:
         print(json.dumps(_fanin_microbench()))

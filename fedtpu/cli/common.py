@@ -290,7 +290,16 @@ def add_fed_flags(p: argparse.ArgumentParser) -> None:
         "jax.profiler.TraceAnnotation under --profile-dir",
     )
     add_screening_flags(p)
-    add_perf_flags(p)
+    p.add_argument(
+        "--compute-dtype",
+        dest="dtype",
+        default="float32",
+        choices=["float32", "bfloat16"],
+        help="dtype the local step computes in (RoundConfig.dtype): "
+        "float32 = full-precision parity (default); bfloat16 = bf16 "
+        "params/activations/dataset on device over an f32 master copy — "
+        "aggregation, FedOpt, screening and checkpoints keep f32 semantics",
+    )
     p.add_argument(
         "--debug-per-batch",
         action="store_true",
@@ -298,64 +307,6 @@ def add_fed_flags(p: argparse.ArgumentParser) -> None:
         "(the reference's mid-epoch console lines, src/utils.py:51-92). "
         "Host callback per batch — debugging only, ruins throughput",
     )
-
-
-def add_perf_flags(p: argparse.ArgumentParser) -> None:
-    """The perf fast-path bundle (docs/PERF_ANALYSIS.md §Roofline). The
-    individual flags default to None so --perf-preset can fill whichever
-    ones the user did not set explicitly — an explicit flag always wins
-    over the preset."""
-    p.add_argument(
-        "--compute-dtype",
-        default=None,
-        choices=["float32", "bfloat16_mixed"],
-        help="device compute dtype for local training: float32 = "
-        "full-precision parity (default); bfloat16_mixed = bf16 params/"
-        "activations/dataset on device with an f32 master copy — "
-        "aggregation, FedOpt, screening and checkpoints keep f32 "
-        "semantics (measured 2.4x on-chip, "
-        "artifacts/BENCH_LIVE_r04_bf16.json)",
-    )
-    p.add_argument(
-        "--megabatch-clients",
-        default=None,
-        type=int,
-        metavar="K",
-        help="fold K simulated clients into one [K*batch, F] MXU pass "
-        "inside the vmapped round body (must divide the client count; "
-        "0 = off). K=1 is bit-identical to the per-client path "
-        "(test-pinned); K>1 shares BN batch stats, rng stream and "
-        "optimizer trajectory per group (documented approximation) to "
-        "raise arithmetic intensity for the small-model zoo",
-    )
-    p.add_argument(
-        "--perf-preset",
-        default=None,
-        choices=["parity", "fast"],
-        help="bundle of perf knobs: parity = float32 + no megabatching "
-        "(the bit-parity contract vs the reference); fast = "
-        "bfloat16_mixed + the largest of 8/4/2 that divides the client "
-        "count. Explicit --compute-dtype/--megabatch-clients always win "
-        "over the preset (see docs/PERF_ANALYSIS.md §Roofline)",
-    )
-
-
-def resolve_perf_preset(args, num_clients: int):
-    """Resolve --perf-preset + explicit flags to concrete
-    (compute_dtype, megabatch_clients) FedConfig values."""
-    preset = getattr(args, "perf_preset", None)
-    compute = getattr(args, "compute_dtype", None)
-    mega = getattr(args, "megabatch_clients", None)
-    if preset == "fast":
-        if compute is None:
-            compute = "bfloat16_mixed"
-        if mega is None:
-            mega = next(
-                (k for k in (8, 4, 2) if num_clients % k == 0), 0
-            )
-    # "parity" (and no preset) leave the dataclass defaults in charge:
-    # float32 + megabatching off.
-    return (compute or "float32", 0 if mega is None else mega)
 
 
 def add_screening_flags(p: argparse.ArgumentParser) -> None:
@@ -942,7 +893,6 @@ def build_config(args, num_clients: int, steps_per_round: int = 8) -> RoundConfi
     if compression is None:
         compression = "topk" if compress else "none"
     shape, n_classes = dataset_info(args.dataset)
-    compute_dtype, megabatch = resolve_perf_preset(args, num_clients)
     return RoundConfig(
         model=args.model,
         num_classes=n_classes,
@@ -991,13 +941,12 @@ def build_config(args, num_clients: int, steps_per_round: int = 8) -> RoundConfi
             ),
             telemetry=getattr(args, "telemetry", "basic"),
             tier_fanout=getattr(args, "tier_fanout", 0),
-            compute_dtype=compute_dtype,
-            megabatch_clients=megabatch,
             sim=sim_config(args),
             screen=screen_config(args),
             **robustness_config(args),
         ),
         steps_per_round=steps_per_round,
+        dtype=getattr(args, "dtype", "float32"),
         debug_per_batch=getattr(args, "debug_per_batch", False),
     )
 

@@ -477,37 +477,6 @@ class FedConfig:
     # escalation. Unlike the robust aggregators this composes with
     # server_pipeline='stream' and with aggregator='mean'.
     screen: ScreenConfig = dataclasses.field(default_factory=ScreenConfig)
-    # Device compute dtype for the local-training fast path
-    # (docs/PERF_ANALYSIS.md §Roofline).
-    #   "float32": full-precision parity (the seed default). The legacy
-    #     RoundConfig.dtype knob keeps selecting the activation dtype for
-    #     callers that set it directly (the bench has always run bf16
-    #     activations through it).
-    #   "bfloat16_mixed": params, activations and the device-resident
-    #     dataset live in bf16 through the (fused) local step — master-copy
-    #     mixed precision: FederatedState.params stays f32 and the bf16
-    #     cast happens at use inside the jitted step, so gradients, the
-    #     [clients, P] flat aggregation surface, FedOpt moments, screening
-    #     statistics and checkpoints all keep f32 semantics (test-pinned).
-    #     Measured lever: bf16 residency alone was worth 2.4x on-chip
-    #     (artifacts/BENCH_LIVE_r04_bf16.json).
-    compute_dtype: str = "float32"  # float32 | bfloat16_mixed
-    # Fold k simulated clients into ONE [k*batch, features] MXU pass inside
-    # the vmapped round body (fedtpu.core.round): a group of k clients
-    # shares one parameter trajectory per round (sound because every client
-    # starts each round at the same global params), per-example weights
-    # keep masked/dead members exact, and per-member metrics + deltas are
-    # broadcast back onto the [clients] axis so screening, compression and
-    # aggregation are untouched. Raises arithmetic intensity for the
-    # small-model zoo: k skinny matmuls become one wide one (the
-    # bandwidth-bound diagnosis in artifacts/MFU_PROFILE_r04*.json).
-    # 0 = off (the per-client path, the parity default); k >= 1 engages the
-    # megabatched body (k=1 is the debug setting, test-pinned bit-identical
-    # to the per-client path); k must divide num_clients. k > 1 is a
-    # documented approximation: members share BN batch statistics over the
-    # k*batch examples, one augment/dropout rng stream and one optimizer
-    # trajectory per group.
-    megabatch_clients: int = 0
     # Hierarchical multi-tier aggregation (docs/ARCHITECTURE.md
     # §Multi-tier): 0 (default) = flat one-tier federation. N >= 1 turns
     # the distributed server into a two-tier ROOT whose roster entries are
@@ -565,38 +534,6 @@ def validate_tier_config(fed: FedConfig, face: str) -> None:
         )
 
 
-def resolve_compute_dtype(cfg: "RoundConfig") -> str:
-    """Resolve the effective activation/param compute dtype for the local
-    step, as a dtype name ("float32" | "bfloat16").
-
-    ``FedConfig.compute_dtype`` is the user-facing switch:
-    ``"bfloat16_mixed"`` resolves to bf16 compute over the f32 master
-    state; ``"float32"`` defers to the legacy ``RoundConfig.dtype`` knob so
-    callers that set it directly (bench variants) keep working unchanged.
-    """
-    if cfg.fed.compute_dtype not in ("float32", "bfloat16_mixed"):
-        raise ValueError(
-            f"unknown compute_dtype {cfg.fed.compute_dtype!r}; "
-            "have float32 | bfloat16_mixed"
-        )
-    if cfg.fed.compute_dtype == "bfloat16_mixed":
-        return "bfloat16"
-    return cfg.dtype
-
-
-def validate_megabatch(fed: FedConfig) -> None:
-    """Raise on inconsistent megabatch settings (cheap, before build work)."""
-    k = fed.megabatch_clients
-    if k < 0:
-        raise ValueError(f"megabatch_clients must be >= 0, got {k}")
-    if k and fed.num_clients % k:
-        raise ValueError(
-            f"megabatch_clients={k} must divide num_clients="
-            f"{fed.num_clients}: the group regrouping is a static reshape "
-            "of the [clients] axis"
-        )
-
-
 def resolve_server_pipeline(fed: FedConfig) -> str:
     """Resolve ``FedConfig.server_pipeline`` to ``"barrier"`` or
     ``"stream"``, naming WHY a combination cannot stream.
@@ -650,7 +587,17 @@ class RoundConfig:
     # Steps of local SGD per round per client; with static shapes this is the
     # padded maximum — shorter shards are masked (see fedtpu.core.client).
     steps_per_round: int = 8
-    dtype: str = "float32"  # compute dtype for activations; params stay f32
+    # The dtype the local step COMPUTES in. "float32": full-precision parity
+    # (the seed default). "bfloat16": master-copy mixed precision —
+    # FederatedState.params stays f32 and the local step casts params and
+    # inputs to bf16 at use, so the whole forward/backward runs in bf16
+    # while gradients, the [clients, P] flat aggregation surface, FedOpt
+    # moments, screening statistics and checkpoints keep f32 semantics
+    # (test-pinned); the engine's device-resident images are stored bf16
+    # too (Federation._store_dtype). Every benchmark cell runs "bfloat16"
+    # (benchmark/sut.py), so the ledger's numbers are this path's. What the
+    # momentum buffers are STORED in is OptimizerConfig.momentum_dtype.
+    dtype: str = "float32"  # float32 | bfloat16
     mesh_axis: str = "clients"
     # Per-block rematerialisation for models that support it (resnet*):
     # trades recompute FLOPs for HBM so big vmapped-client configs fit one

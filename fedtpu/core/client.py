@@ -17,7 +17,7 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from fedtpu.config import RoundConfig, resolve_compute_dtype
+from fedtpu.config import RoundConfig
 from fedtpu.core import optim
 from fedtpu.ops.losses import softmax_ce_int_labels
 from fedtpu.utils import trees
@@ -72,7 +72,7 @@ def make_local_update(
     if stream is True:
         stream = "gather"
     mu = cfg.fed.fedprox_mu if cfg.fed.algorithm == "fedprox" else 0.0
-    compute_dtype = jnp.dtype(resolve_compute_dtype(cfg))
+    dtype = jnp.dtype(cfg.dtype)
     # Random crop + flip for CIFAR-style training, fused into the jitted step
     # (the reference augments on the host via torchvision, src/main.py:37-42).
     use_augment = cfg.data.augment and cfg.data.dataset in ("cifar10", "cifar100")
@@ -84,7 +84,7 @@ def make_local_update(
         # bit-identical to augment-then-cast while halving the augment
         # pipeline's HBM traffic — the largest elementwise fusions in the
         # round-4 on-chip trace (artifacts/MFU_PROFILE_r04_fastcrop.json).
-        x = x.astype(compute_dtype)
+        x = x.astype(dtype)
         if use_augment:
             from fedtpu.data.augment import augment_batch
 
@@ -97,8 +97,8 @@ def make_local_update(
         # back to f32 against f32 kernels, silently doubling activation HBM
         # and halving MXU rate. Gradients flow through the cast and come out
         # f32. BN running stats stay f32 (they are outputs in train mode).
-        if compute_dtype != jnp.float32:
-            cast = jax.tree.map(lambda p: p.astype(compute_dtype), params)
+        if dtype != jnp.float32:
+            cast = jax.tree.map(lambda p: p.astype(dtype), params)
         else:
             cast = params
         variables = {"params": cast, "batch_stats": batch_stats}
@@ -190,7 +190,7 @@ def make_local_update(
             num_steps=jnp.sum(lives),
         )
 
-    if stream == "presharded":
+    if stream:
         shape = tuple(image_shape or cfg.image_size)
         batch_size = cfg.data.batch_size
 
@@ -206,52 +206,34 @@ def make_local_update(
             round_idx: jnp.ndarray,
             anchor: Pytree = None,
         ) -> ClientOutput:
-            # images/labels are THIS client's [2L, ...] presharded rows;
-            # each scan step slices its [batch]-sized window at the step's
-            # offset — one contiguous DMA, no gather.
-            f_tail = tuple(images.shape[1:])
+            if stream == "presharded":
+                # images/labels are THIS client's [2L, ...] presharded rows;
+                # each scan step slices its [batch]-sized window at the
+                # step's offset — one contiguous DMA, no gather.
+                f_tail = tuple(images.shape[1:])
 
-            def get_xy(o):
-                x = jax.lax.dynamic_slice(
-                    images, (o,) + (0,) * len(f_tail),
-                    (batch_size,) + f_tail,
-                )
-                if x.ndim == 2:
-                    x = x.reshape((batch_size,) + shape)
-                y = jax.lax.dynamic_slice(labels, (o,), (batch_size,))
-                return x, y
+                def get_xy(o):
+                    x = jax.lax.dynamic_slice(
+                        images, (o,) + (0,) * len(f_tail),
+                        (batch_size,) + f_tail,
+                    )
+                    if x.ndim == 2:
+                        x = x.reshape((batch_size,) + shape)
+                    y = jax.lax.dynamic_slice(labels, (o,), (batch_size,))
+                    return x, y
 
-            return _run_scan(
-                global_params, global_stats, opt_state,
-                takes, get_xy,
-                takes.shape[0], step_mask, rng, round_idx, anchor,
-            )
-
-    elif stream:
-        shape = tuple(image_shape or cfg.image_size)
-
-        def local_update(
-            global_params: Pytree,
-            global_stats: Pytree,
-            opt_state: optim.SGDState,
-            images: jnp.ndarray,
-            labels: jnp.ndarray,
-            takes: jnp.ndarray,
-            step_mask: jnp.ndarray,
-            rng: jax.Array,
-            round_idx: jnp.ndarray,
-            anchor: Pytree = None,
-        ) -> ClientOutput:
-            # Each scan step gathers only its own [batch]-sized slice from
-            # the device-resident dataset — nothing [steps, batch, ...]-sized
-            # ever exists. The dataset may arrive FLATTENED ([N, H*W*C]):
-            # NHWC image tensors pad ~4x under TPU tiled layouts, flat rows
-            # tile exactly; the per-batch reshape after the gather is free.
-            def get_xy(t):
-                x = images[t]
-                if x.ndim == 2:
-                    x = x.reshape((t.shape[0],) + shape)
-                return x, labels[t]
+            else:
+                # Each scan step gathers only its own [batch]-sized slice
+                # from the device-resident dataset — nothing
+                # [steps, batch, ...]-sized ever exists. The dataset may
+                # arrive FLATTENED ([N, H*W*C]): NHWC image tensors pad ~4x
+                # under TPU tiled layouts, flat rows tile exactly; the
+                # per-batch reshape after the gather is free.
+                def get_xy(t):
+                    x = images[t]
+                    if x.ndim == 2:
+                        x = x.reshape((t.shape[0],) + shape)
+                    return x, labels[t]
 
             return _run_scan(
                 global_params, global_stats, opt_state,
@@ -276,256 +258,6 @@ def make_local_update(
                 global_params, global_stats, opt_state,
                 (xs, ys), lambda e: e,
                 xs.shape[0], step_mask, rng, round_idx, anchor,
-            )
-
-    return local_update
-
-
-def make_local_update_mega(
-    apply_fn: Callable,
-    cfg: RoundConfig,
-    k: int,
-    stream: bool = False,
-    image_shape: Optional[Tuple[int, ...]] = None,
-) -> Callable:
-    """Build the GROUP-of-k local-epoch function (``megabatch_clients=k``).
-
-    Same contract as :func:`make_local_update` but over a group of k
-    clients whose per-step batches are concatenated into ONE
-    ``[k*batch, ...]`` forward/backward — k skinny matmuls become one wide
-    MXU pass, the arithmetic-intensity lever for the small-model zoo
-    (every committed roofline profile is bandwidth-bound; see
-    docs/PERF_ANALYSIS.md §Roofline). The group shares one parameter
-    trajectory per round, which is sound because every client starts each
-    round at the same global params; per-example weights keep masked/dead
-    members exact.
-
-    Signatures (designed to vmap over the GROUP axis in
-    :mod:`fedtpu.core.round`):
-
-        presharded: (gp, gs, opt, images [k, 2L, ...], labels [k, 2L],
-                     takes [k, steps], member_mask [k, steps], rng,
-                     round_idx)
-        gather:     (gp, gs, opt, images [N, ...], labels [N],
-                     takes [k, steps, batch], member_mask [k, steps], rng,
-                     round_idx)
-        non-stream: (gp, gs, opt, xs [k, steps, batch, ...],
-                     ys [k, steps, batch], member_mask [k, steps], rng,
-                     round_idx)
-
-    returning a :class:`ClientOutput` whose params/stats/opt_state are the
-    GROUP trajectory and whose loss/accuracy/num_steps are per-member
-    ``[k]`` vectors (the round layer broadcasts the trajectory back onto
-    the clients axis).
-
-    Parity contract (test-pinned): at ``k=1`` every array this function
-    produces is bit-identical to :func:`make_local_update` — the masked
-    per-example loss ``sum(per * w) / max(sum(w), 1)`` reduces over the
-    same values in the same order as ``per.mean()`` (w is exactly 1.0,
-    multiplying by 1.0 and dividing by the same f32 count preserve bits,
-    and the VJP divides the same cotangent by the same count).
-
-    ``k > 1`` approximations (documented, not silent): members share BN
-    batch statistics over the ``k*batch`` examples, one augment/dropout
-    rng stream (member 0's key), and one optimizer trajectory seeded from
-    the mean of the members' buffers; per-member loss/accuracy are
-    measured on the member's examples under the GROUP model.
-    """
-    if stream is True:
-        stream = "gather"
-    mu = cfg.fed.fedprox_mu if cfg.fed.algorithm == "fedprox" else 0.0
-    compute_dtype = jnp.dtype(resolve_compute_dtype(cfg))
-    use_augment = cfg.data.augment and cfg.data.dataset in ("cifar10", "cifar100")
-
-    def loss_fn(params, batch_stats, global_params, x, y, exw, rng):
-        # exw: [k*batch] per-example weight (1.0 where the example's member
-        # is live this step). Same cast-before-augment rationale as the
-        # per-client loss_fn.
-        x = x.astype(compute_dtype)
-        if use_augment:
-            from fedtpu.data.augment import augment_batch
-
-            with jax.named_scope("fed.data"):
-                aug_rng, rng = jax.random.split(rng)
-                x = augment_batch(aug_rng, x, crop=cfg.data.augment_crop)
-        if compute_dtype != jnp.float32:
-            cast = jax.tree.map(lambda p: p.astype(compute_dtype), params)
-        else:
-            cast = params
-        variables = {"params": cast, "batch_stats": batch_stats}
-        logits, updated = apply_fn(
-            variables,
-            x,
-            train=True,
-            mutable=["batch_stats"],
-            rngs={"dropout": rng},
-        )
-        logits = logits.astype(jnp.float32)
-        per = softmax_ce_int_labels(logits, y)  # [k*batch]
-        loss = jnp.sum(per * exw) / jnp.maximum(jnp.sum(exw), 1.0)
-        if mu > 0.0:
-            loss = loss + 0.5 * mu * trees.tree_sq_norm(
-                trees.tree_sub(params, global_params)
-            )
-        correct = (jnp.argmax(logits, -1) == y).astype(jnp.float32)
-        # Per-member metrics: the member's own examples under the group
-        # model (unmasked — dead members' entries are zeroed by the caller).
-        ce_m = per.reshape(k, -1).mean(axis=1)  # [k]
-        acc_m = correct.reshape(k, -1).mean(axis=1)
-        return loss, (updated.get("batch_stats", batch_stats), ce_m, acc_m)
-
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
-
-    @jax.named_scope("fed.local_step")
-    def _run_scan(
-        global_params, global_stats, opt_state, step_elems, get_xy,
-        steps, member_mask, rng, round_idx, anchor=None,
-    ) -> ClientOutput:
-        anchor = global_params if anchor is None else anchor
-        lr = cfg.opt.lr_at(round_idx)
-
-        def one_step(carry, batch):
-            params, stats, ostate = carry
-            elem, live_m, step_rng = batch  # live_m: [k]
-            with jax.named_scope("fed.data"):
-                x, y = get_xy(elem)
-            with jax.named_scope("fed.local_step.fwd_bwd"):
-                live_f = live_m.astype(jnp.float32)
-                exw = jnp.broadcast_to(
-                    live_f[:, None], (k, x.shape[0] // k)
-                ).reshape(-1)
-                (loss, (new_stats, ce_m, acc_m)), grads = grad_fn(
-                    params, stats, anchor, x, y, exw, step_rng
-                )
-            with jax.named_scope("fed.local_step.optimizer"):
-                new_params, new_ostate = optim.apply(
-                    params, grads, ostate, lr, cfg.opt
-                )
-                # The group steps iff ANY member is live; all-masked steps
-                # are no-ops exactly like the per-client path.
-                live = live_m.any()
-                params = jax.tree.map(
-                    lambda new, old: jnp.where(live, new, old),
-                    new_params, params,
-                )
-                stats = jax.tree.map(
-                    lambda new, old: jnp.where(live, new, old),
-                    new_stats, stats,
-                )
-                ostate = jax.tree.map(
-                    lambda new, old: jnp.where(live, new, old),
-                    new_ostate, ostate,
-                )
-            return (params, stats, ostate), (ce_m * live_f, acc_m * live_f, live_f)
-
-        step_rngs = jax.random.split(rng, steps)
-        (params, stats, ostate), (ces, accs, lives) = jax.lax.scan(
-            one_step,
-            (global_params, global_stats, opt_state),
-            (step_elems, jnp.swapaxes(member_mask, 0, 1), step_rngs),
-        )
-        # ces/accs/lives: [steps, k] -> per-member round means.
-        n = jnp.maximum(jnp.sum(lives, axis=0), 1.0)
-        return ClientOutput(
-            params=params,
-            batch_stats=stats,
-            opt_state=ostate,
-            loss=jnp.sum(ces, axis=0) / n,
-            accuracy=jnp.sum(accs, axis=0) / n,
-            num_steps=jnp.sum(lives, axis=0),
-        )
-
-    if stream == "presharded":
-        shape = tuple(image_shape or cfg.image_size)
-        batch_size = cfg.data.batch_size
-
-        def local_update(
-            global_params: Pytree,
-            global_stats: Pytree,
-            opt_state: optim.SGDState,
-            images: jnp.ndarray,
-            labels: jnp.ndarray,
-            takes: jnp.ndarray,
-            member_mask: jnp.ndarray,
-            rng: jax.Array,
-            round_idx: jnp.ndarray,
-            anchor: Pytree = None,
-        ) -> ClientOutput:
-            # images/labels: the k members' [2L, ...] presharded rows
-            # stacked [k, 2L, ...]; per step, slice each member's [batch]
-            # window and concatenate along the example axis.
-            f_tail = tuple(images.shape[2:])
-
-            def slice_one(img, lab, o):
-                x = jax.lax.dynamic_slice(
-                    img, (o,) + (0,) * len(f_tail), (batch_size,) + f_tail
-                )
-                y = jax.lax.dynamic_slice(lab, (o,), (batch_size,))
-                return x, y
-
-            def get_xy(o):  # o: [k] per-member offsets
-                xs, ys = jax.vmap(slice_one)(images, labels, o)
-                x = xs.reshape((k * batch_size,) + f_tail)
-                if x.ndim == 2:
-                    x = x.reshape((k * batch_size,) + shape)
-                return x, ys.reshape(k * batch_size)
-
-            return _run_scan(
-                global_params, global_stats, opt_state,
-                jnp.swapaxes(takes, 0, 1), get_xy,
-                takes.shape[1], member_mask, rng, round_idx, anchor,
-            )
-
-    elif stream:
-        shape = tuple(image_shape or cfg.image_size)
-
-        def local_update(
-            global_params: Pytree,
-            global_stats: Pytree,
-            opt_state: optim.SGDState,
-            images: jnp.ndarray,
-            labels: jnp.ndarray,
-            takes: jnp.ndarray,
-            member_mask: jnp.ndarray,
-            rng: jax.Array,
-            round_idx: jnp.ndarray,
-            anchor: Pytree = None,
-        ) -> ClientOutput:
-            def get_xy(t):  # t: [k, batch] indices into the flat dataset
-                flat_t = t.reshape(-1)
-                x = images[flat_t]
-                if x.ndim == 2:
-                    x = x.reshape((flat_t.shape[0],) + shape)
-                return x, labels[flat_t]
-
-            return _run_scan(
-                global_params, global_stats, opt_state,
-                jnp.swapaxes(takes, 0, 1), get_xy,
-                takes.shape[1], member_mask, rng, round_idx, anchor,
-            )
-
-    else:
-
-        def local_update(
-            global_params: Pytree,
-            global_stats: Pytree,
-            opt_state: optim.SGDState,
-            xs: jnp.ndarray,
-            ys: jnp.ndarray,
-            member_mask: jnp.ndarray,
-            rng: jax.Array,
-            round_idx: jnp.ndarray,
-            anchor: Pytree = None,
-        ) -> ClientOutput:
-            # xs: [k, steps, batch, ...] -> scanned [k, batch, ...] slabs.
-            def get_xy(e):
-                x, y = e
-                return x.reshape((-1,) + x.shape[2:]), y.reshape(-1)
-
-            return _run_scan(
-                global_params, global_stats, opt_state,
-                (jnp.swapaxes(xs, 0, 1), jnp.swapaxes(ys, 0, 1)), get_xy,
-                xs.shape[1], member_mask, rng, round_idx, anchor,
             )
 
     return local_update

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from fedtpu import models as model_zoo
-from fedtpu.config import RoundConfig, resolve_compute_dtype, validate_megabatch
+from fedtpu.config import RoundConfig
 from fedtpu.core.round import (
     FederatedState,
     RoundBatch,
@@ -102,13 +102,9 @@ class Federation:
                 f"sim.malicious_fraction must be in [0, 1), got "
                 f"{cfg.fed.sim.malicious_fraction}"
             )
-        resolve_compute_dtype(cfg)  # raises on an unknown compute_dtype
-        validate_megabatch(cfg.fed)
-        if mesh is not None and cfg.fed.megabatch_clients:
-            raise NotImplementedError(
-                "megabatch_clients does not compose with a mesh yet: the "
-                "group regrouping is a reshape across the shard_map client "
-                "axis. Run megabatched rounds single-chip."
+        if cfg.dtype not in ("float32", "bfloat16"):
+            raise ValueError(
+                f"unknown dtype {cfg.dtype!r}; have float32 | bfloat16"
             )
         validate_telemetry_mode(cfg.fed.telemetry)
         shape, n_classes = dataset_info(cfg.data.dataset)
@@ -356,7 +352,7 @@ class Federation:
         footprint and every per-round slice/gather's bandwidth."""
         import ml_dtypes
 
-        dt = jnp.dtype(resolve_compute_dtype(self.cfg))
+        dt = jnp.dtype(self.cfg.dtype)
         return np.dtype(ml_dtypes.bfloat16) if dt == jnp.bfloat16 else np.float32
 
     def _ensure_device_data(self):

@@ -27,15 +27,10 @@ import jax.numpy as jnp
 from fedtpu.config import (
     RoundConfig,
     screening_enabled,
-    validate_megabatch,
     validate_screen_config,
 )
 from fedtpu.core import optim
-from fedtpu.core.client import (
-    ClientOutput,
-    make_local_update,
-    make_local_update_mega,
-)
+from fedtpu.core.client import ClientOutput, make_local_update
 from fedtpu.utils import trees
 
 Pytree = Any
@@ -425,100 +420,6 @@ def _mean_over_clients(stacked: Pytree, weights: jnp.ndarray, axis_name):
     return jax.tree.map(lambda m: m * alive_any.astype(m.dtype), mean), safe
 
 
-def _megabatch_wrap(mega_v, k: int, stream) -> Callable[..., ClientOutput]:
-    """Adapt the group-vmapped megabatch local update to the per-client
-    ``vmapped`` call signature, so the rest of the round step (deltas,
-    screening, compression, aggregation, metrics) is untouched.
-
-    [clients]-axis inputs are regrouped ``[C] -> [G, k]`` (contiguous in
-    client order: clients ``0..k-1`` form group 0), the group body runs
-    once per group, and group outputs are broadcast back ``[G] -> [C]``.
-    Members that never trained this round (all steps masked: dead client
-    or empty shard) keep exactly what the per-client path would give them —
-    params/stats fall back to the GLOBAL values (delta exactly 0) and
-    opt_state falls back to the member's own pre-round buffers. At k=1
-    every reshape/broadcast here is an identity and the wrapped output is
-    bit-identical to the per-client path (test-pinned).
-    """
-
-    def group(x):
-        return x.reshape((x.shape[0] // k, k) + x.shape[1:])
-
-    @jax.named_scope("fed.local_step")
-    def wrapped(params, stats, opt_state, *rest):
-        if stream:
-            images, labels, takes, step_mask, rngs, round_idx = rest
-        else:
-            xs, ys, step_mask, rngs, round_idx = rest
-        n = step_mask.shape[0]
-        g = n // k
-        # The group optimizer trajectory starts from the mean of its
-        # members' buffers (f32 accumulate; a size-1 mean is exact, so k=1
-        # parity holds even for bf16-stored momentum).
-        def opt_mean(x):
-            return jnp.mean(
-                group(x).astype(jnp.float32), axis=1
-            ).astype(x.dtype)
-
-        opt_g = jax.tree.map(opt_mean, opt_state)
-        member_mask = group(step_mask)  # [G, k, steps]
-        rng_g = group(rngs)[:, 0]  # member 0's key per group
-        if stream == "presharded":
-            out = mega_v(
-                params, stats, opt_g, group(images), group(labels),
-                group(takes), member_mask, rng_g, round_idx,
-            )
-        elif stream:
-            out = mega_v(
-                params, stats, opt_g, images, labels,
-                group(takes), member_mask, rng_g, round_idx,
-            )
-        else:
-            out = mega_v(
-                params, stats, opt_g, group(xs), group(ys),
-                member_mask, rng_g, round_idx,
-            )
-        trained = step_mask.any(axis=1)  # [C]
-
-        def bcast(xg):
-            return jnp.broadcast_to(
-                xg[:, None], (g, k) + xg.shape[1:]
-            ).reshape((n,) + xg.shape[1:])
-
-        def member_where(new, old):
-            m = trained.reshape((n,) + (1,) * (new.ndim - 1))
-            return jnp.where(m, new, old)
-
-        params_c = jax.tree.map(
-            lambda xg, glob: member_where(
-                bcast(xg), jnp.broadcast_to(glob[None], (n,) + glob.shape)
-            ),
-            out.params, params,
-        )
-        stats_c = jax.tree.map(
-            lambda xg, glob: member_where(
-                bcast(xg), jnp.broadcast_to(glob[None], (n,) + glob.shape)
-            ),
-            out.batch_stats, stats,
-        )
-        opt_c = jax.tree.map(
-            lambda xg, old: member_where(bcast(xg), old),
-            out.opt_state, opt_state,
-        )
-        # Per-member metrics come out [G, k] — dead members are already
-        # zeroed by the per-example masking, no fallback needed.
-        return ClientOutput(
-            params=params_c,
-            batch_stats=stats_c,
-            opt_state=opt_c,
-            loss=out.loss.reshape(n),
-            accuracy=out.accuracy.reshape(n),
-            num_steps=out.num_steps.reshape(n),
-        )
-
-    return wrapped
-
-
 def make_round_step(
     model: nn.Module,
     cfg: RoundConfig,
@@ -665,37 +566,6 @@ def make_round_step(
             local_update,
             in_axes=(None, None, 0, 0, 0, 0, 0, None),
         )
-
-    mb = cfg.fed.megabatch_clients
-    if mb:
-        validate_megabatch(cfg.fed)
-        if axis_name is not None:
-            raise NotImplementedError(
-                "megabatch_clients does not compose with a mesh yet: the "
-                "group regrouping is a reshape across the shard_map client "
-                "axis. Run megabatched rounds single-chip (the configs it "
-                "targets — the small-model zoo — fit one chip)."
-            )
-        if cfg.debug_per_batch:
-            raise ValueError(
-                "debug_per_batch prints per-CLIENT batch lines; the "
-                "megabatched body trains groups, so the lines would be "
-                "misleading. Disable one of the two."
-            )
-        mega = make_local_update_mega(
-            model.apply, cfg, mb, stream=stream, image_shape=image_shape
-        )
-        if stream == "presharded":
-            mega_v = jax.vmap(
-                mega, in_axes=(None, None, 0, 0, 0, 0, 0, 0, None)
-            )
-        elif stream:
-            mega_v = jax.vmap(
-                mega, in_axes=(None, None, 0, None, None, 0, 0, 0, None)
-            )
-        else:
-            mega_v = jax.vmap(mega, in_axes=(None, None, 0, 0, 0, 0, 0, None))
-        vmapped = _megabatch_wrap(mega_v, mb, stream)
 
     def round_step(
         state: FederatedState,

@@ -32,7 +32,7 @@ class BatchNorm(nn.BatchNorm):
     flax's ``nn.BatchNorm`` upcasts the WHOLE activation to f32 for the
     statistics reduction and keeps every activation-sized elementwise op
     (``x - mean``, ``y * mul``, ``y + bias``) in f32, casting only the
-    final output back — under ``compute_dtype=bfloat16_mixed`` that made
+    final output back — under ``RoundConfig.dtype="bfloat16"`` that made
     BN intermediates ~73% of the analytic per-round bytes on the BN-dense
     zoo (DenseNet/ResNet), erasing the residency lever this knob exists
     for. Here the statistics stay in f32 (stability; running stats remain

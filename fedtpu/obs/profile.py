@@ -360,7 +360,7 @@ def analytic_bytes(fn: Callable, *args, **kwargs) -> float:
     biasing the count AGAINST exactly the dtype lever being measured.
     Greedy elementwise grouping is still a model, not a compiler:
     absolute numbers are approximate; mode-over-mode RATIOS (f32 vs
-    bf16_mixed, per-client vs megabatched) are the supported use.
+    bf16) are the supported use.
     On-chip, prefer the XLA figure (:func:`xla_cost`), which is measured
     from the optimised HLO."""
     import jax

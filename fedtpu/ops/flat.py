@@ -31,7 +31,7 @@ Dtype invariant: the packed buffer is ALWAYS float32 — the concat
 primitives (:func:`fedtpu.utils.trees.tree_concat_rows` /
 ``tree_concat_flat``) cast every leaf on entry, and :func:`unpack` /
 :func:`unpack_stacked` restore original leaf dtypes from the layout table.
-Under ``compute_dtype=bfloat16_mixed`` deltas are taken against the f32
+Under ``RoundConfig.dtype="bfloat16"`` deltas are taken against the f32
 master params, so aggregation, FedOpt, screening statistics and checkpoint
 wire bytes are bit-identical in layout to a pure-f32 run (pinned by
 ``tests/test_mixed_precision.py``).

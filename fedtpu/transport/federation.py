@@ -3559,9 +3559,14 @@ class BackupServer(TrainerServicer):
     def _stop_acting(self, wait: float = 120.0) -> None:
         if self._acting_stop is not None:
             self._acting_stop.set()
-        if self._promote_thread is not None:
-            self._promote_thread.join(timeout=wait)
-            if not self._promote_thread.is_alive():
+        # Read the thread once: FetchModel (a gRPC worker), the next
+        # promotion (the watchdog thread) and a caller's shutdown can all be
+        # in here at once, and one of them clears the field while another
+        # is still joining.
+        thread = self._promote_thread
+        if thread is not None:
+            thread.join(timeout=wait)
+            if not thread.is_alive() and self._promote_thread is thread:
                 self._promote_thread = None
 
     def start(self, address: str):

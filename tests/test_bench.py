@@ -754,89 +754,19 @@ def test_mfu_microbench_contract(bench, monkeypatch, tmp_path):
         assert json_mod.load(fh) == result
 
 
-# --------------------------------------------- mixed-precision fast path
+# ------------------------------------------------------ variant labels
 def test_variant_labels_cover_perf_knobs(bench, monkeypatch):
-    """FEDTPU_COMPUTE_DTYPE / FEDTPU_MEGABATCH_CLIENTS runs must be
-    self-distinguishing like every other experiment knob: suffixed metric,
-    no vs_baseline, knob values recorded in the variant block."""
+    """A variant run must be self-distinguishing: suffixed metric, no
+    vs_baseline, knob values recorded in the variant block — and the block
+    says the dtype the headline config really computes in."""
     base = {"metric": bench.METRIC, "value": 1.0, "vs_baseline": 0.005}
-    monkeypatch.setattr(bench, "COMPUTE_DTYPE", "bfloat16_mixed")
-    monkeypatch.setattr(bench, "MEGABATCH_CLIENTS", 8)
+    assert bench._apply_variant_labels(dict(base)) == base
+    monkeypatch.setattr(bench, "MOMENTUM_DTYPE", "bfloat16")
     result = bench._apply_variant_labels(dict(base))
     assert result["metric"] == bench.METRIC + "_variant"
     assert "vs_baseline" not in result
-    assert result["variant"]["compute_dtype"] == "bfloat16_mixed"
-    assert result["variant"]["megabatch_clients"] == 8
-
-
-def test_mixed_precision_microbench_contract(bench, monkeypatch, tmp_path):
-    """--mixed-precision-microbench at a seconds-scale mlp config: schema,
-    artifact emission, and the analytic invariants (value = f32/fast byte
-    ratio; bf16 alone already cuts analytic bytes; walls present for all
-    three modes). The >=1.8x densenet-scale gate itself is pinned by the
-    committed-artifact test below."""
-    import json as json_mod
-
-    art = tmp_path / "artifacts"
-    monkeypatch.setattr(bench, "ARTIFACTS_DIR", str(art))
-    monkeypatch.setenv("FEDTPU_MP_MODEL", "mlp")
-    monkeypatch.setenv("FEDTPU_MP_CLIENTS", "2")
-    monkeypatch.setenv("FEDTPU_MP_COST_BATCH", "8")
-    monkeypatch.setenv("FEDTPU_MP_COST_STEPS", "1")
-    monkeypatch.setenv("FEDTPU_MP_BATCH", "4")
-    monkeypatch.setenv("FEDTPU_MP_ROUNDS", "1")
-    monkeypatch.setenv("FEDTPU_MP_REPS", "1")
-    result = bench._mixed_precision_microbench()
-    assert result["metric"] == "mixed_precision_bytes_drop"
-    assert result["gate_x"] == 1.8
-    analytic = result["analytic"]
-    assert set(analytic) == {"f32", "bf16_mixed", "bf16_megabatch"}
-    for row in analytic.values():
-        assert row["flops_per_round"] > 0
-        assert row["bytes_per_round"] > 0
-        assert row["roofline_bound"] in ("compute", "bandwidth")
-    # The headline value is the f32 -> bf16+megabatch byte ratio...
-    assert result["value"] == pytest.approx(
-        analytic["f32"]["bytes_per_round"]
-        / analytic["bf16_megabatch"]["bytes_per_round"],
-        abs=1e-3,
-    )
-    # ...and bf16 residency ALONE must already cut analytic bytes (the
-    # backend-independent model sees the stated dtypes, not the CPU
-    # backend's f32 emulation, whose xla_bytes INVERT this signal). The
-    # magnitude is shape-dependent — at this tiny mlp config the f32
-    # master/opt traffic dominates — so the pin is direction, not size;
-    # the >=1.8x magnitude gate lives on the committed densenet artifact.
-    assert result["bytes_drop_bf16_only"] > 1.0
-    # No ordering pin between value and bytes_drop_bf16_only: megabatch's
-    # byte effect is shape-dependent (weight-sharing wins are negligible on
-    # this tiny mlp, while the mega path's masked-loss bookkeeping adds a
-    # little traffic); the densenet-shape gate below is where it must win.
-    assert result["value"] > 0
-    assert result["passes_gate"] == (result["value"] >= 1.8)
-    cfgrow = result["analytic_config"]
-    assert cfgrow["model"] == "mlp" and cfgrow["megabatch_clients"] == 2
-    walls = result["walls"]
-    assert set(walls["round_ms"]) == {"f32", "bf16_mixed", "bf16_megabatch"}
-    assert all(v > 0 for v in walls["round_ms"].values())
-    with open(art / "MIXED_PRECISION_MICROBENCH.json") as fh:
-        assert json_mod.load(fh) == result
-
-
-def test_mixed_precision_microbench_committed_gate():
-    """The committed densenet-scale artifact must pass the ISSUE gate:
-    analytic bytes_per_round drops >= 1.8x under bf16+megabatch on the
-    profile config, with roofline placement stamped."""
-    result = _committed_artifact("MIXED_PRECISION_MICROBENCH.json")
-    assert result["metric"] == "mixed_precision_bytes_drop"
-    assert result["analytic_config"]["model"] == "densenet_cifar"
-    assert result["passes_gate"] is True
-    assert result["value"] >= 1.8
-    fast = result["analytic"]["bf16_megabatch"]
-    assert fast["arith_intensity_flops_per_byte"] > (
-        result["analytic"]["f32"]["arith_intensity_flops_per_byte"]
-    )
-    assert fast["roofline_bound"] in ("compute", "bandwidth")
+    assert result["variant"]["momentum_dtype"] == "bfloat16"
+    assert result["variant"]["dtype"] == bench.headline_config().dtype
 
 
 # --------------------------------------------- hierarchical fan-in (PR 14)

@@ -1,18 +1,18 @@
-"""Mixed-precision device residency + client megabatching (perf fast path).
+"""The local step's compute dtype (``RoundConfig.dtype``).
 
-The two fast-path levers (``FedConfig.compute_dtype='bfloat16_mixed'``,
-``FedConfig.megabatch_clients=k``) are PERF knobs with a precisely scoped
-numerics contract, pinned here:
+``RoundConfig.dtype="bfloat16"`` is master-copy mixed precision with a
+precisely scoped numerics contract, pinned here:
 
-* ``megabatch_clients=1`` is BITWISE identical to the classic per-client
-  vmapped path — stepped and fused, gather and presharded layouts. The
-  masked-mean loss, group rng selection and wrapper reshapes are all exact
-  identities at k=1, so any bit of drift means the mega body diverged from
-  the reference body.
-* Under ``bfloat16_mixed`` the AGGREGATION SURFACE stays f32: server
-  params, optimizer state, the flat packed buffer and the checkpoint wire
-  bytes are identical in dtype/size to a float32 run. Only the on-device
-  compute/dataset residency changes.
+* The ONE local-update builder (``fedtpu.core.client.make_local_update``)
+  returns float32 params, stats and optimizer state against float32 masters
+  in every feed form and either dtype; ``num_steps`` counts the unmasked
+  steps; a client whose every step is masked returns the global parameters
+  bit for bit with its optimizer state untouched.
+* Under ``bfloat16`` the AGGREGATION SURFACE stays f32: server params,
+  optimizer state, the flat packed buffer and the checkpoint wire bytes are
+  identical in dtype/size to a float32 run. Only the on-device compute and
+  dataset residency change, and a round from the bf16-resident dataset
+  equals a round from f32 data cast at use.
 * ``augment_crop=False`` is flip-only with the SAME flip decisions as the
   crop path (shared rng split structure).
 * bf16-vs-f32 convergence stays within a documented tolerance on the easy
@@ -24,25 +24,22 @@ numerics contract, pinned here:
 import dataclasses
 import os
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from fedtpu.config import (
-    DataConfig,
-    FedConfig,
-    OptimizerConfig,
-    RoundConfig,
-    resolve_compute_dtype,
-    validate_megabatch,
-)
-from fedtpu.core import Federation
+from fedtpu.config import DataConfig, FedConfig, OptimizerConfig, RoundConfig
+from fedtpu.core import Federation, optim
+from fedtpu.core.client import make_local_update
 from fedtpu.data.augment import augment_batch
+from fedtpu.models.common import batch_norm
 
 
-def _cfg(layout="gather", compute="float32", mega=0, clients=4,
-         model="mlp", dataset="synthetic", augment=False, **kw):
+def _cfg(layout="gather", dtype="float32", clients=4,
+         model="mlp", dataset="synthetic", augment=False, partition="iid",
+         **kw):
     base = dict(
         model=model,
         num_classes=10,
@@ -50,17 +47,14 @@ def _cfg(layout="gather", compute="float32", mega=0, clients=4,
         data=DataConfig(
             dataset=dataset,
             batch_size=4,
-            partition="iid",
+            partition=partition,
             num_examples=32 * clients,
             augment=augment,
             device_layout=layout,
         ),
-        fed=FedConfig(
-            num_clients=clients,
-            compute_dtype=compute,
-            megabatch_clients=mega,
-        ),
+        fed=FedConfig(num_clients=clients),
         steps_per_round=2,
+        dtype=dtype,
     )
     base.update(kw)
     return RoundConfig(**base)
@@ -83,60 +77,90 @@ def _assert_bitwise(fa, fb):
     )
 
 
-# ------------------------------------------------------- megabatch parity
-@pytest.mark.parametrize("layout", ["gather", "presharded"])
-@pytest.mark.parametrize("fused", [False, True])
-def test_megabatch_k1_bitwise_identical(layout, fused):
-    """k=1 engages the FULL mega path (masked-mean loss, group wrapper,
-    broadcast/where recombination) against the classic path — the strongest
-    cheap correctness pin the k>1 modes inherit."""
-    fa = Federation(_cfg(layout=layout, mega=0), seed=0)
-    fb = Federation(_cfg(layout=layout, mega=1), seed=0)
-    if fused:
-        fa.run_on_device(2)
-        fb.run_on_device(2)
-    else:
-        for _ in range(2):
-            fa.step()
-            fb.step()
-    _assert_bitwise(fa, fb)
+# ------------------------------------------------- the local-update contract
+class _BNNet(nn.Module):
+    """Dense -> BatchNorm -> ReLU -> Dense: the smallest model that has
+    batch statistics for the local step to carry."""
+
+    @nn.compact
+    def __call__(self, x, train: bool = True):
+        x = nn.Dense(16)(x.reshape((x.shape[0], -1)))
+        x = nn.relu(batch_norm(train)(x))
+        return nn.Dense(10)(x)
 
 
-def test_megabatch_k1_bitwise_with_augment_bn_dropout():
-    """Same pin through the full stochastic client body: augmentation rng,
-    BN batch stats and dropout all flow through the mega body's single
-    [k*batch] pass. cifar-shaped so the conv stack and augment engage."""
-    kw = dict(model="smallcnn", dataset="cifar10", augment=True,
-              layout="presharded", clients=2)
-    fa = Federation(_cfg(mega=0, **kw), seed=0)
-    fb = Federation(_cfg(mega=1, **kw), seed=0)
-    fa.step()
-    fb.step()
-    _assert_bitwise(fa, fb)
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("feed", ["presharded", "gather", "materialised"])
+def test_local_update_contract(feed, dtype):
+    steps, batch, shape = 3, 4, (4, 4, 1)
+    cfg = RoundConfig(
+        model="mlp", num_classes=10, image_size=shape, dtype=dtype,
+        opt=OptimizerConfig(learning_rate=0.05, momentum=0.9),
+        data=DataConfig(dataset="synthetic", batch_size=batch, augment=False),
+        steps_per_round=steps,
+    )
+    model = _BNNet()
+    rng = np.random.default_rng(0)
+    images = rng.normal(size=(steps * batch, 16)).astype(np.float32)
+    labels = rng.integers(0, 10, size=(steps * batch,)).astype(np.int32)
+    variables = model.init(jax.random.PRNGKey(0), jnp.zeros((batch,) + shape))
+    params, stats = variables["params"], variables["batch_stats"]
+    # A non-zero momentum going in, so "untouched" is not "still zero".
+    opt_state = jax.tree.map(
+        lambda m: m + 0.5, optim.init(params, cfg.opt)
+    )
+    local_update = jax.jit(make_local_update(
+        model.apply, cfg, stream=False if feed == "materialised" else feed,
+        image_shape=shape,
+    ))
 
+    def run(step_mask):
+        head = (params, stats, opt_state)
+        tail = (jnp.asarray(step_mask), jax.random.PRNGKey(1), jnp.int32(0))
+        if feed == "presharded":
+            # This client's rows twice over ([2L, F]) and per-step offsets.
+            data = (jnp.asarray(np.concatenate([images, images])),
+                    jnp.asarray(np.concatenate([labels, labels])),
+                    jnp.arange(steps, dtype=jnp.int32) * batch)
+        elif feed == "gather":
+            data = (jnp.asarray(images), jnp.asarray(labels),
+                    jnp.arange(steps * batch, dtype=jnp.int32).reshape(
+                        steps, batch))
+        else:
+            data = (jnp.asarray(images.reshape((steps, batch) + shape)),
+                    jnp.asarray(labels.reshape(steps, batch)))
+        return local_update(*head, *data, *tail)
 
-def test_megabatch_k2_trains_and_keeps_per_client_metrics():
-    """k=2 is the documented-approximation regime: one group trajectory per
-    k clients. It must still learn and still report PER-CLIENT metrics at
-    the [num_clients] shape the sim/observability layers consume."""
-    fed = Federation(_cfg(mega=2, clients=4, steps_per_round=4), seed=0)
-    first = fed.run(num_rounds=1)
-    last = fed.run(num_rounds=5)
-    assert float(last.loss) < float(first.loss)
-    assert fed.state.last_client_loss.shape == (4,)
+    out = run([True, False, True])
+    for leaf in jax.tree.leaves((out.params, out.batch_stats, out.opt_state)):
+        assert leaf.dtype == jnp.float32
+    assert float(out.num_steps) == 2.0
+    assert np.isfinite(float(out.loss))
+    assert any(
+        not np.array_equal(np.asarray(a), np.asarray(b))
+        for a, b in zip(jax.tree.leaves(out.params), jax.tree.leaves(params))
+    )
+
+    idle = run([False, False, False])
+    assert float(idle.num_steps) == 0.0 and float(idle.loss) == 0.0
+    for got, want in zip(
+        jax.tree.leaves((idle.params, idle.batch_stats, idle.opt_state)),
+        jax.tree.leaves((params, stats, opt_state)),
+    ):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # --------------------------------------------------- bf16 f32 surface pin
 def test_bf16_mixed_keeps_aggregation_surface_f32(tmp_path):
-    """bfloat16_mixed changes device residency, never server semantics:
+    """dtype="bfloat16" changes device residency, never server semantics:
     master params/opt stay f32, the flat packed buffer stays f32, and a
     checkpoint of the bf16-mode state is byte-for-byte the SIZE of the f32
     mode's (the wire format must not notice the compute dtype)."""
     from fedtpu.checkpoint.checkpoint import save
     from fedtpu.ops import flat as flat_ops
 
-    f32 = Federation(_cfg(compute="float32"), seed=0)
-    b16 = Federation(_cfg(compute="bfloat16_mixed"), seed=0)
+    f32 = Federation(_cfg(dtype="float32"), seed=0)
+    b16 = Federation(_cfg(dtype="bfloat16"), seed=0)
     f32.step()
     b16.step()
 
@@ -167,18 +191,40 @@ def test_bf16_convergence_within_documented_tolerance():
     diverge — but both must LEARN, and the final losses must agree to 25%
     relative (measured headroom ~5x on this config)."""
     losses = {}
-    for compute in ("float32", "bfloat16_mixed"):
+    for dtype in ("float32", "bfloat16"):
         fed = Federation(
-            _cfg(compute=compute, clients=2, steps_per_round=4), seed=0
+            _cfg(dtype=dtype, clients=2, steps_per_round=4), seed=0
         )
         first = fed.run(num_rounds=1)
         last = fed.run(num_rounds=3)
         assert float(last.loss) < float(first.loss)
-        losses[compute] = float(last.loss)
+        losses[dtype] = float(last.loss)
     # 25% relative with a small absolute floor: the synthetic task drives
     # the loss to ~0, where a relative bound alone is ill-conditioned.
-    diff = abs(losses["bfloat16_mixed"] - losses["float32"])
+    diff = abs(losses["bfloat16"] - losses["float32"])
     assert diff < max(0.25 * losses["float32"], 0.05), losses
+
+
+@pytest.mark.parametrize("layout", ["presharded", "gather"])
+def test_device_store_dtype_follows_round_dtype(layout):
+    """The resident images are bf16 exactly when RoundConfig.dtype is, and a
+    round from the bf16 store equals a round from f32 data cast at use (the
+    materialised feed hands the local step f32 batches)."""
+    # round_robin: every feed iterates a shard unshuffled from its head.
+    kw = dict(layout=layout, model="smallcnn", dataset="cifar10", clients=2,
+              partition="round_robin")
+    f32 = Federation(_cfg(dtype="float32", **kw), seed=0)
+    assert f32._ensure_device_data()[0].dtype == jnp.float32
+    stored = Federation(_cfg(dtype="bfloat16", **kw), seed=0)
+    assert stored._ensure_device_data()[0].dtype == jnp.bfloat16
+    cast_at_use = Federation(_cfg(dtype="bfloat16", **kw), seed=0)
+    batch = cast_at_use.round_batch(0)
+    assert batch.x.dtype == jnp.float32
+    m_stored = stored.step()
+    m_cast = cast_at_use.step(batch)
+    for a, b in zip(_state_leaves(stored), _state_leaves(cast_at_use)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
+    assert float(m_stored.loss) == pytest.approx(float(m_cast.loss), abs=1e-6)
 
 
 # -------------------------------------------------------- crop toggle pin
@@ -218,58 +264,22 @@ def test_crop_flag_flows_from_data_config():
 
 
 # ------------------------------------------------------------- validation
-def test_megabatch_must_divide_cohort():
-    with pytest.raises(ValueError, match="divide"):
-        validate_megabatch(FedConfig(num_clients=4, megabatch_clients=3))
-    with pytest.raises(ValueError, match="divide"):
-        Federation(_cfg(mega=3, clients=4), seed=0)
-    with pytest.raises(ValueError, match=">= 0"):
-        validate_megabatch(FedConfig(num_clients=4, megabatch_clients=-1))
+@pytest.mark.parametrize("name", ["float16", "bf16", "bfloat16_mixed"])
+def test_unknown_compute_dtype_rejected_cheaply(name, monkeypatch):
+    """An unknown RoundConfig.dtype is refused at construction, with the two
+    names there are, before anything is built or compiled."""
+    import fedtpu.core.engine as engine
+
+    def never(*a, **k):
+        raise AssertionError("built a model for an invalid config")
+
+    monkeypatch.setattr(engine.model_zoo, "create", never)
+    with pytest.raises(ValueError, match="float32 \\| bfloat16"):
+        Federation(_cfg(dtype=name), seed=0)
 
 
-def test_unknown_compute_dtype_rejected_cheaply():
-    with pytest.raises(ValueError, match="compute_dtype"):
-        resolve_compute_dtype(_cfg(compute="float16"))
-    with pytest.raises(ValueError, match="compute_dtype"):
-        Federation(_cfg(compute="bf16"), seed=0)
-
-
-def test_megabatch_rejects_debug_per_batch():
-    with pytest.raises(ValueError, match="debug_per_batch"):
-        fed = Federation(_cfg(mega=2, debug_per_batch=True), seed=0)
-        fed.step()
-
-
-# --------------------------------------------------------- CLI perf knobs
-def test_perf_preset_resolution():
-    """--perf-preset fast fills only the knobs the user left unset; parity
-    and no-preset leave the dataclass defaults (f32, megabatching off) in
-    charge; an odd cohort degrades megabatching to off, not to a crash."""
-    import argparse
-
-    from fedtpu.cli.common import add_perf_flags, resolve_perf_preset
-
-    def parse(argv):
-        p = argparse.ArgumentParser()
-        add_perf_flags(p)
-        return p.parse_args(argv)
-
-    assert resolve_perf_preset(parse([]), 64) == ("float32", 0)
-    assert resolve_perf_preset(
-        parse(["--perf-preset", "parity"]), 64) == ("float32", 0)
-    assert resolve_perf_preset(
-        parse(["--perf-preset", "fast"]), 64) == ("bfloat16_mixed", 8)
-    assert resolve_perf_preset(
-        parse(["--perf-preset", "fast"]), 6) == ("bfloat16_mixed", 2)
-    assert resolve_perf_preset(
-        parse(["--perf-preset", "fast"]), 3) == ("bfloat16_mixed", 0)
-    # Explicit flags beat the preset.
-    assert resolve_perf_preset(
-        parse(["--perf-preset", "fast", "--compute-dtype", "float32",
-               "--megabatch-clients", "4"]), 64) == ("float32", 4)
-
-
-def test_build_config_threads_perf_knobs():
+# ------------------------------------------------------ the CLI's one flag
+def _parser():
     import argparse
 
     from fedtpu.cli import common
@@ -277,9 +287,31 @@ def test_build_config_threads_perf_knobs():
     p = argparse.ArgumentParser()
     common.add_model_flags(p)
     common.add_fed_flags(p)
-    args = p.parse_args(
-        ["--dataset", "synthetic", "--batch-size", "4",
-         "--num-examples", "64", "--perf-preset", "fast"])
+    return p
+
+
+_ARGV = ["--dataset", "synthetic", "--batch-size", "4", "--num-examples", "64"]
+
+
+def test_build_config_threads_perf_knobs():
+    """--compute-dtype writes RoundConfig.dtype; absent, float32."""
+    from fedtpu.cli import common
+
+    args = _parser().parse_args(_ARGV + ["--compute-dtype", "bfloat16"])
     cfg = common.build_config(args, num_clients=8, steps_per_round=2)
-    assert cfg.fed.compute_dtype == "bfloat16_mixed"
-    assert cfg.fed.megabatch_clients == 8
+    assert cfg.dtype == "bfloat16"
+    cfg = common.build_config(
+        _parser().parse_args(_ARGV), num_clients=8, steps_per_round=2)
+    assert cfg.dtype == "float32"
+
+
+@pytest.mark.parametrize("argv", [
+    ["--compute-dtype", "bfloat16_mixed"],
+    ["--perf-preset", "fast"],
+    ["--megabatch-clients", "2"],
+])
+def test_removed_perf_spellings_are_argparse_errors(argv, capsys):
+    with pytest.raises(SystemExit) as e:
+        _parser().parse_args(_ARGV + argv)
+    assert e.value.code == 2
+    capsys.readouterr()

@@ -182,6 +182,6 @@ def test_bench_variant_field(monkeypatch):
     result = bench_mod._measure()
     assert result["variant"] == {
         "model": "mlp", "momentum_dtype": "bfloat16",
-        "compute_dtype": "float32", "megabatch_clients": 0,
+        "dtype": "bfloat16",
     }
     assert result["value"] > 0

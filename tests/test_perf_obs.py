@@ -714,7 +714,7 @@ def test_perf_baseline_committed_artifact_contract():
         "calibration_us", "span_trace_us", "counter_inc_us", "gauge_set_us",
         "histogram_observe_us", "mfu_observe_us", "latency_summary_us",
         "round_record_us", "prometheus_render_us", "trace_merge_us",
-        "gap_analyze_us", "mixed_precision_cast_us", "megabatch_reshape_us",
+        "gap_analyze_us", "mixed_precision_cast_us",
         "partial_reduce_fold_us", "submit_partial_frame_us",
         "hadamard_rotate_us", "randk_gather_us",
     }

@@ -99,11 +99,14 @@ def test_window_rotates_and_wraps():
     )
 
 
-def test_round_robin_presharded_equals_gather_and_host():
-    """Unshuffled semantics are bit-identical across all three paths."""
-    fp = Federation(_cfg("presharded"), seed=0)
-    fg = Federation(_cfg("gather"), seed=0)
-    fh = Federation(_cfg("presharded"), seed=0)
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_round_robin_presharded_equals_gather_and_host(dtype):
+    """Unshuffled semantics are bit-identical across all three paths, in
+    either compute dtype (the benchmark's cells sit on both layouts in
+    bfloat16)."""
+    fp = Federation(_cfg("presharded", dtype=dtype), seed=0)
+    fg = Federation(_cfg("gather", dtype=dtype), seed=0)
+    fh = Federation(_cfg("presharded", dtype=dtype), seed=0)
     fp.step()
     fg.step()
     fh.step(fh.round_batch(0))
@@ -161,8 +164,9 @@ def test_stream_equals_materialised_window():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
 
-def test_fused_scan_equals_sequential_presharded():
-    cfg = _cfg(part="iid")
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_fused_scan_equals_sequential_presharded(dtype):
+    cfg = _cfg(part="iid", dtype=dtype)
     fa, fb = Federation(cfg, seed=0), Federation(cfg, seed=0)
     fa.run_on_device(3)
     for _ in range(3):

@@ -146,27 +146,16 @@ def _build_workloads() -> List[Tuple[str, Callable[[], None], int, object]]:
         "metadata": {"wall_start": 1000.0, "role": "engine"},
     }
 
-    # Mixed-precision host costs (PR: bf16 device residency + megabatch).
-    # These are the ONLY host-side steps the compute_dtype/megabatch knobs
-    # add outside the jitted round: the one-time f32 -> bf16 master-copy
-    # cast at device upload, and the [clients] -> [groups, k*batch] static
-    # regrouping reshape. Both must stay trivially cheap — a regression
-    # here means someone moved the cast/regroup out of XLA into a per-round
-    # host loop. numpy + ml_dtypes stand in for the jitted versions so the
-    # harness stays jax-free and seconds-scale.
+    # Mixed-precision host cost: the one-time f32 -> bf16 cast of the
+    # resident dataset at device upload (RoundConfig.dtype="bfloat16") is
+    # the ONLY host-side step the dtype adds outside the jitted round. It
+    # must stay trivially cheap — a regression here means someone moved the
+    # cast out of XLA into a per-round host loop. numpy + ml_dtypes stand in
+    # for the jitted version so the harness stays jax-free and seconds-scale.
     cast_src = np.ones((64, 4096), dtype=np.float32)
 
     def cast_one():
         cast_src.astype(ml_dtypes.bfloat16)
-
-    mega_src = np.ones((8, 32, 32, 32, 3), dtype=np.float32)  # [C,B,H,W,ch]
-
-    def megabatch_reshape_one():
-        # Group k=4 clients -> [G, k*B, H, W, ch]. The contiguous [clients]
-        # axis makes this a VIEW (sub-microsecond) — exactly the claim in
-        # validate_megabatch's error message; this metric pins that nobody
-        # replaces it with a gather/copy regroup.
-        np.ascontiguousarray(mega_src.reshape(2, 4 * 32, 32, 32, 3))
 
     # Hierarchical-aggregation host costs (PR: sub-aggregator tier). The
     # two per-round steps the tier adds OUTSIDE the jitted reduce: the
@@ -248,7 +237,6 @@ def _build_workloads() -> List[Tuple[str, Callable[[], None], int, object]]:
          50, None),
         ("gap_analyze_us", lambda: gap_analyze.analyze(doc), 20, None),
         ("mixed_precision_cast_us", cast_one, 200, None),
-        ("megabatch_reshape_us", megabatch_reshape_one, 5000, None),
         ("partial_reduce_fold_us", partial_reduce_fold_one, 500, None),
         ("submit_partial_frame_us", submit_partial_frame_one, 500, None),
         ("hadamard_rotate_us", hadamard_rotate_one, 200, None),

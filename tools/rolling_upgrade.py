@@ -199,16 +199,37 @@ def run_upgrade_drill(
                         join_addr, gens)
 
             backup_addr = f"localhost:{free_port()}"
+            record_acting = on_round("acting")
+
+            def on_acting_round(r, rec):
+                record_acting(r, rec)
+                if gens["acting"] >= acting_window:
+                    # Hold the acting primary at this round boundary until
+                    # gen 2's recovering ping demotes it: how many rounds
+                    # it commits while gen 2 starts up is the host's speed,
+                    # and past `rounds` there is no lineage left to hand
+                    # over.
+                    backup._acting_stop.wait(timeout=60)
+
             backup = BackupServer(
                 cfg, addrs, watchdog_timeout=watchdog_s,
-                on_acting_round=lambda r, rec: on_round("acting")(r, rec),
+                on_acting_round=on_acting_round,
             )
+            # The watchdog measures gen 1's silence on the DRILL's clock,
+            # which stands still until gen 1 has drained: on the wall clock
+            # a ping held up by a loaded host (1 Hz pings against a ~1 s
+            # window) promotes the backup under a primary that is still
+            # training, and the drill then exercises split-brain fencing
+            # instead of a handover.
+            silence = [0.0]
+            backup.machine.clock = lambda: silence[0]
             backup_srv = backup.start(backup_addr)
             note(f"gen 1: {upgrade_round} rounds, then drain")
             gen1 = PrimaryServer(cfg, addrs, backup_address=backup_addr)
             current = [gen1]
             gen1.run(num_rounds=upgrade_round, on_round=on_round("gen1"))
             # gen 1 stopped pinging -> the watchdog bridges the gap.
+            silence[0] += watchdog_s + 1e-3
             note("waiting for backup promotion + acting rounds")
             deadline = time.monotonic() + 60
             while time.monotonic() < deadline:

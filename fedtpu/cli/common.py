@@ -12,6 +12,7 @@ the reference hardcodes (model, dataset, rounds, client registry).
 from __future__ import annotations
 
 import argparse
+import json
 
 from fedtpu.config import (
     DataConfig,
@@ -77,9 +78,22 @@ def add_model_flags(p: argparse.ArgumentParser) -> None:
     p.add_argument(
         "--dataset",
         default="cifar10",
-        choices=["cifar10", "cifar100", "mnist", "synthetic"],
+        choices=["cifar10", "cifar100", "mnist", "synthetic", "tokens"],
+        help="'tokens': rows of token ids for a language model "
+        "(docs/OPERATIONS.md, language-model federations)",
+    )
+    p.add_argument(
+        "--model-args", default="{}", type=json.loads, metavar="JSON",
+        help="the model constructor's sizes as a JSON object "
+        "(RoundConfig.model_args), e.g. for joyai_llm_flash "
+        '\'{"num_hidden_layers": 3, "experts_held": [0, 8]}\'',
     )
     p.add_argument("--lr", default=0.1, type=float, help="learning rate")
+    p.add_argument(
+        "--momentum", default=0.9, type=float,
+        help="local SGD momentum (reference: 0.9). 0 with "
+        "--client-schedule sequential keeps no buffers",
+    )
     p.add_argument(
         "--schedule",
         default="constant",
@@ -192,6 +206,13 @@ def add_fed_flags(p: argparse.ArgumentParser) -> None:
     p.add_argument("--rounds", default=20, type=int,
                    help="federated rounds (reference hardcodes 20)")
     p.add_argument("--algorithm", default="fedavg", choices=["fedavg", "fedprox"])
+    p.add_argument(
+        "--client-schedule", default="vmap", choices=["vmap", "sequential"],
+        help="how the round program runs its clients: all at once under "
+        "vmap, or one after another with a running weighted sum "
+        "(FedConfig.client_schedule) — a model of which the chip holds one "
+        "local copy; mean aggregation only",
+    )
     p.add_argument("--fedprox-mu", default=0.01, type=float)
     p.add_argument(
         "--partition",
@@ -899,6 +920,7 @@ def build_config(args, num_clients: int, steps_per_round: int = 8) -> RoundConfi
         image_size=shape,
         opt=OptimizerConfig(
             learning_rate=args.lr,
+            momentum=getattr(args, "momentum", 0.9),
             schedule=getattr(args, "schedule", "constant"),
             momentum_dtype=getattr(args, "momentum_dtype", "float32"),
         ),
@@ -915,6 +937,7 @@ def build_config(args, num_clients: int, steps_per_round: int = 8) -> RoundConfi
             num_clients=num_clients,
             num_rounds=getattr(args, "rounds", 20),
             algorithm=getattr(args, "algorithm", "fedavg"),
+            client_schedule=getattr(args, "client_schedule", "vmap"),
             fedprox_mu=(
                 getattr(args, "fedprox_mu", 0.0)
                 if getattr(args, "algorithm", "fedavg") == "fedprox"
@@ -947,6 +970,7 @@ def build_config(args, num_clients: int, steps_per_round: int = 8) -> RoundConfi
         ),
         steps_per_round=steps_per_round,
         dtype=getattr(args, "dtype", "float32"),
+        model_args=getattr(args, "model_args", None) or (),
         debug_per_batch=getattr(args, "debug_per_batch", False),
     )
 

@@ -10,7 +10,7 @@ typed, hashable dataclasses so configs can be closed over by jitted functions.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Any, Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -408,6 +408,19 @@ class FedConfig:
     # weights by construction and tolerate ~trim_fraction adversaries.
     aggregator: str = "mean"  # mean | median | trimmed_mean | krum
     trim_fraction: float = 0.1
+    # HOW the round program runs its clients (fedtpu.core.round).
+    #   "vmap" (default): all at once under jax.vmap — every client's model
+    #     copy, gradient and activations live side by side, which is what
+    #     fills a chip with 64-192 small models.
+    #   "sequential": one after another in a lax.scan inside the same jitted
+    #     round; the carry is the running weighted sum of the clients'
+    #     changes, so the round holds ONE client's copies whatever the
+    #     number of clients — a model of which a chip holds a single local
+    #     copy. The rows are never stacked, so everything that needs all of
+    #     them at once is refused at construction: aggregator != 'mean',
+    #     update screening, DP clipping, delta compression, the flat delta
+    #     layout, the adversarial harness, a mesh.
+    client_schedule: str = "vmap"  # vmap | sequential
     # Differential privacy (DP-FedAvg, McMahan et al. 2018): clip each
     # client's delta to L2 norm dp_clip_norm (0 = off), then add Gaussian
     # noise with std = dp_clip_norm * dp_noise_multiplier / n_participants
@@ -598,6 +611,12 @@ class RoundConfig:
     # (benchmark/sut.py), so the ledger's numbers are this path's. What the
     # momentum buffers are STORED in is OptimizerConfig.momentum_dtype.
     dtype: str = "float32"  # float32 | bfloat16
+    # The model's sizes where its constructor takes any: keyword arguments
+    # of the registered constructor (fedtpu.models), as a mapping or a
+    # sequence of (name, value) pairs — a JSON object or a literal dict;
+    # kept as a sorted tuple of pairs so that the config stays hashable.
+    # () (default): the constructor's own defaults, as every CNN takes them.
+    model_args: Tuple[Tuple[str, Any], ...] = ()
     mesh_axis: str = "clients"
     # Per-block rematerialisation for models that support it (resnet*):
     # trades recompute FLOPs for HBM so big vmapped-client configs fit one
@@ -609,6 +628,17 @@ class RoundConfig:
     # each print is a host callback that serialises the device against the
     # host, so this is a debugging aid, never a benchmarking mode.
     debug_per_batch: bool = False
+
+    def __post_init__(self):
+        def frozen(v):
+            return tuple(frozen(x) for x in v) if isinstance(v, (list, tuple)) else v
+
+        args = self.model_args
+        pairs = args.items() if isinstance(args, dict) else args
+        object.__setattr__(
+            self, "model_args",
+            tuple(sorted((str(k), frozen(v)) for k, v in pairs)),
+        )
 
 
 DEFAULT_ROUND_CONFIG = RoundConfig()

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 
 from fedtpu.config import RoundConfig
 from fedtpu.core import optim
-from fedtpu.ops.losses import softmax_ce_int_labels
+from fedtpu.data.datasets import is_token_dataset
+from fedtpu.ops.losses import next_token_ce_parts, softmax_ce_int_labels
 from fedtpu.utils import trees
 
 Pytree = Any
@@ -32,6 +33,33 @@ class ClientOutput(NamedTuple):
     loss: jnp.ndarray    # mean masked loss over the round
     accuracy: jnp.ndarray
     num_steps: jnp.ndarray
+    # What a token model counted over the round's live steps, ``()`` for
+    # every other model: {"tokens", "moe_pairs_here"} summed,
+    # "moe_load_max_over_mean" the largest (docs/OBSERVABILITY.md).
+    counters: Any = ()
+
+
+def _token_loss(heads, extra_weight):
+    """A token model's training loss from its heads' ``(cross-entropy sum,
+    count, hits)``: the next-token head's mean over its valid positions plus
+    ``extra_weight`` times each further head's (a multi-token-prediction
+    module's) over its own. Returns ``(loss, accuracy, tokens)``, the last
+    two the next-token head's."""
+    total = 0.0
+    for depth, (ce_sum, count, _) in enumerate(heads):
+        total = total + (extra_weight if depth else 1.0) * ce_sum / jnp.maximum(
+            count, 1.0)
+    _, count, hits = heads[0]
+    return total, hits / jnp.maximum(count, 1.0), count
+
+
+def _over_steps(counted):
+    """A round's counters from the steps': sums, and the largest of a
+    ``*_max_*`` reading."""
+    if not counted:
+        return ()
+    return {k: (jnp.max(v) if "_max_" in k else jnp.sum(v))
+            for k, v in counted.items()}
 
 
 def make_local_update(
@@ -76,6 +104,77 @@ def make_local_update(
     # Random crop + flip for CIFAR-style training, fused into the jitted step
     # (the reference augments on the host via torchvision, src/main.py:37-42).
     use_augment = cfg.data.augment and cfg.data.dataset in ("cifar10", "cifar100")
+    # Token data ([batch, T] int32 ids, targets the next ids): the batch goes
+    # to the model as the task gives it, and the loss is over sequences.
+    tokens_task = is_token_dataset(cfg.data.dataset)
+    mtp_weight = dict(cfg.model_args).get("mtp_loss_weight", 0.3)
+    micro_rows = dict(cfg.model_args).get("micro_batch_rows", 0)
+
+    def to_compute_dtype(params):
+        if dtype == jnp.float32:
+            return params
+        return jax.tree.map(lambda p: p.astype(dtype), params)
+
+    def token_loss_of_cast(cast, batch_stats, x, y, rng, share=1.0):
+        """The token loss of parameters already in the compute dtype, times
+        ``share`` (a micro-batch's share of its batch)."""
+        heads, updated = apply_fn(
+            {"params": cast, "batch_stats": batch_stats}, x, train=True,
+            targets=y, mutable=["batch_stats", "counters"],
+            rngs={"dropout": rng},
+        )
+        ce, acc, tokens = _token_loss(heads, mtp_weight)
+        counters = dict(updated.get("counters", {}), tokens=tokens)
+        return share * ce, (
+            updated.get("batch_stats", batch_stats), ce, acc, counters)
+
+    def proximal(params, global_params):
+        return 0.5 * mu * trees.tree_sq_norm(trees.tree_sub(params, global_params))
+
+    def token_loss_fn(params, batch_stats, global_params, x, y, rng):
+        loss, aux = token_loss_of_cast(
+            to_compute_dtype(params), batch_stats, x, y, rng)
+        if mu > 0.0:
+            loss = loss + proximal(params, global_params)
+        return loss, aux
+
+    def token_sgd_in_micro_batches(params, batch_stats, global_params, x, y,
+                                   rng, lr):
+        """One step of plain SGD (momentum 0) on the token loss of the batch,
+        ``micro_rows`` rows through forward and backward at a time: the
+        parameters are cast once, a micro-batch weighs by its share of the
+        batch's next-token targets (the batch's own loss where every row has
+        as many, as packed rows do), and each micro-batch's gradient goes
+        straight into the float32 parameters, ``p - lr (wd p + prox) - lr g_1
+        - lr g_2 ...``: what the step over the whole batch gives, to float32
+        rounding, with the activations of ``micro_rows`` rows and no
+        gradient accumulator beside the model (PERF.md §6, PR 34: an
+        accumulator costs 2.5 copies of the parameters in the compiled round,
+        this 1). ``lr`` is 0 for a masked step, which then changes nothing.
+        Returns ``(params, (ce, accuracy, counters))``."""
+        targets = jnp.maximum(jnp.sum(y >= 0, dtype=jnp.float32), 1.0)
+        cut = lambda a: a.reshape((-1, micro_rows) + a.shape[1:])
+        cast = to_compute_dtype(params)
+        grad_of_cast = jax.value_and_grad(token_loss_of_cast, has_aux=True)
+        if cfg.opt.weight_decay or mu > 0.0:
+            params = jax.tree.map(
+                lambda p, g: p - lr * (cfg.opt.weight_decay * p + mu * (p - g)),
+                params, global_params)
+
+        def one(carry, xy):
+            params, ce, acc = carry
+            share = jnp.sum(xy[1] >= 0, dtype=jnp.float32) / targets
+            (_, (_, c, a, counted)), g = grad_of_cast(
+                cast, batch_stats, *xy, rng, share)
+            with jax.named_scope("fed.local_step.optimizer"):
+                params = jax.tree.map(
+                    lambda p, g: p - lr * g.astype(p.dtype), params, g)
+            return (params, ce + share * c, acc + share * a), counted
+
+        zero = jnp.zeros((), jnp.float32)
+        (params, ce, acc), counted = jax.lax.scan(
+            one, (params, zero, zero), (cut(x), cut(y)))
+        return params, (ce, acc, _over_steps(counted))
 
     def loss_fn(params, batch_stats, global_params, x, y, rng):
         # Cast to the compute dtype BEFORE augmentation: the crop/flip are
@@ -119,9 +218,20 @@ def make_local_update(
                 trees.tree_sub(params, global_params)
             )
         acc = jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32))
-        return loss, (updated.get("batch_stats", batch_stats), ce, acc)
+        return loss, (updated.get("batch_stats", batch_stats), ce, acc, ())
 
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+    grad_fn = jax.value_and_grad(
+        token_loss_fn if tokens_task else loss_fn, has_aux=True
+    )
+    in_micro_batches = tokens_task and 0 < micro_rows < cfg.data.batch_size
+    if in_micro_batches and (
+        cfg.data.batch_size % micro_rows or cfg.opt.momentum or cfg.opt.nesterov
+    ):
+        raise ValueError(
+            f"micro_batch_rows={micro_rows} needs a batch_size it divides "
+            f"({cfg.data.batch_size}) and plain SGD (momentum 0): each "
+            "micro-batch's gradient goes straight into the parameters"
+        )
 
     @jax.named_scope("fed.local_step")
     def _run_scan(
@@ -141,8 +251,18 @@ def make_local_update(
             elem, live, step_rng = batch
             with jax.named_scope("fed.data"):
                 x, y = get_xy(elem)
+            if in_micro_batches:
+                live_f = live.astype(jnp.float32)
+                with jax.named_scope("fed.local_step.fwd_bwd"):
+                    params, (ce, acc, counted) = token_sgd_in_micro_batches(
+                        params, stats, anchor, x, y, step_rng, lr * live_f
+                    )
+                counted = jax.tree.map(
+                    lambda c: c * live.astype(c.dtype), counted)
+                return (params, stats, ostate), (
+                    ce * live_f, acc * live_f, live_f, counted)
             with jax.named_scope("fed.local_step.fwd_bwd"):
-                (loss, (new_stats, ce, acc)), grads = grad_fn(
+                (loss, (new_stats, ce, acc, counted)), grads = grad_fn(
                     params, stats, anchor, x, y, step_rng
                 )
             if cfg.debug_per_batch:
@@ -172,10 +292,12 @@ def make_local_update(
                     lambda new, old: jnp.where(live, new, old),
                     new_ostate, ostate,
                 )
-            return (params, stats, ostate), (ce * live_f, acc * live_f, live_f)
+            counted = jax.tree.map(lambda c: c * live.astype(c.dtype), counted)
+            return (params, stats, ostate), (
+                ce * live_f, acc * live_f, live_f, counted)
 
         step_rngs = jax.random.split(rng, steps)
-        (params, stats, ostate), (ces, accs, lives) = jax.lax.scan(
+        (params, stats, ostate), (ces, accs, lives, counted) = jax.lax.scan(
             one_step,
             (global_params, global_stats, opt_state),
             (step_elems, step_mask, step_rngs),
@@ -188,6 +310,7 @@ def make_local_update(
             loss=jnp.sum(ces) / n,
             accuracy=jnp.sum(accs) / n,
             num_steps=jnp.sum(lives),
+            counters=_over_steps(counted),
         )
 
     if stream:
@@ -278,7 +401,9 @@ def batch_eval_arrays(images, labels, batch_size: int):
     xs = np.asarray(images[: nb * batch_size]).reshape(
         (nb, batch_size) + images.shape[1:]
     )
-    ys = np.asarray(labels[: nb * batch_size]).reshape((nb, batch_size))
+    ys = np.asarray(labels[: nb * batch_size]).reshape(
+        (nb, batch_size) + labels.shape[1:]
+    )
     return jnp.asarray(xs), jnp.asarray(ys)
 
 
@@ -286,20 +411,28 @@ def make_eval_fn(apply_fn: Callable, cfg: RoundConfig) -> Callable:
     """Batched evaluation of a model snapshot (parity: ``src/main.py:167-191``,
     the eval the reference runs on every client after each SendModel)."""
 
+    tokens_task = is_token_dataset(cfg.data.dataset)
+
     def eval_step(params, batch_stats, x, y):
         variables = {"params": params, "batch_stats": batch_stats}
         logits = apply_fn(variables, x, train=False, mutable=False)
+        if tokens_task:
+            # (token loss summed, hits, positions with a target)
+            ce_sum, count, hits = next_token_ce_parts(logits, y)
+            return ce_sum, hits, count
         ce = softmax_ce_int_labels(logits.astype(jnp.float32), y)
         correct = (jnp.argmax(logits, -1) == y).astype(jnp.float32)
-        return ce.sum(), correct.sum()
+        return ce.sum(), correct.sum(), jnp.float32(y.size)
 
     @jax.jit
     def evaluate(params, batch_stats, xs, ys):
-        """xs: [num_batches, batch, ...] — returns (mean_loss, accuracy)."""
-        losses, corrects = jax.lax.map(
+        """xs: [num_batches, batch, ...] — returns (mean_loss, accuracy);
+        for token data the next-token loss and accuracy over the positions
+        that have a target."""
+        losses, corrects, counts = jax.lax.map(
             lambda b: eval_step(params, batch_stats, b[0], b[1]), (xs, ys)
         )
-        n = ys.size
+        n = jnp.sum(counts)
         return jnp.sum(losses) / n, jnp.sum(corrects) / n
 
     return evaluate

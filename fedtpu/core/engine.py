@@ -31,7 +31,13 @@ from fedtpu.core.round import (
     make_round_step,
 )
 from fedtpu.core.client import make_eval_fn
-from fedtpu.data import data_source, dataset_info, load, partition
+from fedtpu.data import (
+    data_source,
+    dataset_info,
+    is_token_dataset,
+    load,
+    partition,
+)
 from fedtpu.obs import StatusBoard, Telemetry, validate_telemetry_mode
 from fedtpu.utils.metrics import MetricsLogger
 
@@ -108,7 +114,10 @@ class Federation:
             )
         validate_telemetry_mode(cfg.fed.telemetry)
         shape, n_classes = dataset_info(cfg.data.dataset)
-        if cfg.num_classes != n_classes:
+        # Token data: num_classes is the model's vocabulary (the rows it
+        # holds), and a caller's corpus draws its ids from it.
+        self._tokens = is_token_dataset(cfg.data.dataset)
+        if cfg.num_classes != n_classes and not self._tokens:
             raise ValueError(
                 f"cfg.num_classes={cfg.num_classes} but dataset "
                 f"'{cfg.data.dataset}' has {n_classes} classes — set "
@@ -130,7 +139,8 @@ class Federation:
         # short shards), matching the reference's epochs-per-StartTrain knob.
         self._steps = cfg.steps_per_round * max(1, cfg.fed.local_epochs)
         self.model = model_zoo.create(
-            cfg.model, num_classes=cfg.num_classes, remat=cfg.remat
+            cfg.model, num_classes=cfg.num_classes, remat=cfg.remat,
+            **dict(cfg.model_args),
         )
 
         if data is None:
@@ -205,13 +215,20 @@ class Federation:
                 else:
                     self._attack_seats = amask.astype(np.float32)
 
-        sample = jnp.zeros((1,) + tuple(images.shape[1:]), jnp.float32)
+        sample = jnp.zeros(
+            (1,) + tuple(images.shape[1:]),
+            jnp.int32 if self._tokens else jnp.float32,
+        )
         self.state: FederatedState = init_state(
             self.model, cfg, jax.random.PRNGKey(seed), sample, compressor
         )
         shuffle = cfg.data.partition != "round_robin"
         img_shape = tuple(images.shape[1:])
         layout = cfg.data.device_layout
+        if self._tokens:
+            # Rows are sequences of ids with a target a position; the
+            # presharded rows are float pixels with one label a row.
+            layout = "gather"
         if layout == "presharded":
             # Footprint guard: presharded costs clients * 2L floats of
             # labels-side rows where L is the padded MAX shard length, so a
@@ -380,9 +397,12 @@ class Federation:
             # Images live FLAT ([N, H*W*C]): NHWC tensors pad ~4x under TPU
             # tiled layouts, flat rows tile exactly — the per-batch reshape
             # after the gather is free.
-            flat = np.asarray(self.images, np.float32).reshape(
-                len(self.images), -1
-            ).astype(store)
+            if self._tokens:
+                flat = np.asarray(self.images, np.int32)  # ids, not pixels
+            else:
+                flat = np.asarray(self.images, np.float32).reshape(
+                    len(self.images), -1
+                ).astype(store)
             self._device_data = (
                 self._placed(flat, sharded=False),
                 self._placed(np.asarray(self.labels, np.int32), sharded=False),

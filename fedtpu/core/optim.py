@@ -42,10 +42,19 @@ def _momentum_dtype(cfg: Optional[OptimizerConfig]) -> jnp.dtype:
     return jnp.dtype(name)
 
 
-def init(params: Pytree, cfg: Optional[OptimizerConfig] = None) -> SGDState:
+def init(
+    params: Pytree, cfg: Optional[OptimizerConfig] = None, buffers: bool = True
+) -> SGDState:
     """Zero buffers in ``cfg.momentum_dtype`` (f32 when ``cfg`` is omitted —
-    the reference-parity default)."""
+    the reference-parity default). ``buffers=False`` keeps none: plain SGD
+    at momentum 0 needs no copy of the model (:func:`apply` then takes the
+    decayed gradient as the direction); the caller asks for it, because the
+    buffers' tree is part of every checkpoint's layout."""
     dtype = _momentum_dtype(cfg)
+    if not buffers:
+        if cfg is None or cfg.momentum != 0:
+            raise ValueError("no momentum buffers only at momentum 0")
+        return SGDState(momentum=())
     return SGDState(
         momentum=jax.tree.map(lambda p: jnp.zeros(p.shape, dtype), params)
     )
@@ -68,6 +77,9 @@ def apply(
     """
     store_dtype = _momentum_dtype(cfg)
     decayed = jax.tree.map(lambda g, p: g + cfg.weight_decay * p, grads, params)
+    if isinstance(state.momentum, tuple) and not state.momentum:
+        # init(buffers=False): momentum 0, the direction is the gradient.
+        return jax.tree.map(lambda p, d: p - lr * d, params, decayed), state
     new_buf = jax.tree.map(
         lambda b, g: cfg.momentum * b.astype(jnp.float32) + g,
         state.momentum, decayed,

@@ -121,6 +121,14 @@ class RoundMetrics(NamedTuple):
     # round (always all-False when screening is off). Sharded like
     # per_client_loss on a mesh.
     screened: jnp.ndarray = ()
+    # A token model's counters over the round's live clients and steps
+    # (``()`` for every other model; docs/OBSERVABILITY.md): target positions
+    # trained on; (token, expert) pairs the held experts computed, none
+    # dropped; the busiest held expert's load over the held experts' mean,
+    # the largest any expert layer of any step saw.
+    tokens: jnp.ndarray = ()
+    moe_pairs_here: jnp.ndarray = ()
+    moe_load_max_over_mean: jnp.ndarray = ()
 
 
 class RoundBatch(NamedTuple):
@@ -158,7 +166,17 @@ def init_state(
     :class:`fedtpu.ops.compression.Compressor`) seeds error-feedback
     residuals when given."""
     init_rng, client_rng = jax.random.split(rng)
-    variables = model.init(init_rng, sample_input, train=False)
+    if jnp.issubdtype(sample_input.dtype, jnp.integer):
+        # Token models: no leaf's shape depends on the sequence's length, so
+        # a few positions do (a forward over the whole sequence at half a
+        # billion parameters is a minute's compile); one jitted program; the
+        # training pass, which also builds a prediction module's leaves.
+        few = sample_input[:, :8]
+        variables = jax.jit(
+            lambda r, x: model.init(r, x, train=True, targets=x)
+        )(init_rng, few)
+    else:
+        variables = model.init(init_rng, sample_input, train=False)
     params = variables["params"]
     batch_stats = variables.get("batch_stats", {})
     if cfg.fed.dp_clip_norm > 0 and jax.tree_util.tree_leaves(batch_stats):
@@ -170,7 +188,13 @@ def init_state(
         )
     n = cfg.fed.num_clients
     # Per-client momentum buffers, stacked along a new leading axis.
-    single = optim.init(params, cfg.opt)
+    # Clients in sequence at momentum 0 keep no buffers: a model that takes
+    # this schedule is one of which a chip holds a single local copy.
+    single = optim.init(
+        params, cfg.opt,
+        buffers=not (cfg.fed.client_schedule == "sequential"
+                     and cfg.opt.momentum == 0),
+    )
     opt_state = jax.tree.map(
         lambda x: jnp.broadcast_to(x, (n,) + x.shape).copy(), single
     )
@@ -420,6 +444,23 @@ def _mean_over_clients(stacked: Pytree, weights: jnp.ndarray, axis_name):
     return jax.tree.map(lambda m: m * alive_any.astype(m.dtype), mean), safe
 
 
+def _round_counters(counters, axis_name):
+    """``RoundMetrics``' counter fields from the clients' stacked counters
+    (``ClientOutput.counters``; ``()`` for a model that counts nothing)."""
+    if not counters:
+        return {}
+    out = {}
+    for name, per_client in counters.items():
+        biggest = "_max_" in name
+        value = jnp.max(per_client) if biggest else jnp.sum(per_client)
+        if axis_name is not None:
+            with jax.named_scope("fed.aggregate.psum"):
+                value = (jax.lax.pmax if biggest else jax.lax.psum)(
+                    value, axis_name)
+        out[name] = value
+    return out
+
+
 def make_round_step(
     model: nn.Module,
     cfg: RoundConfig,
@@ -491,6 +532,37 @@ def make_round_step(
             f"unknown aggregator {cfg.fed.aggregator!r}; "
             "have mean | median | trimmed_mean | krum"
         )
+    if cfg.fed.client_schedule not in ("vmap", "sequential"):
+        raise ValueError(
+            f"unknown client_schedule {cfg.fed.client_schedule!r}; "
+            "have vmap | sequential"
+        )
+    sequential = cfg.fed.client_schedule == "sequential"
+    if sequential:
+        # The clients' rows never exist side by side: only a per-coordinate
+        # weighted sum can be kept as a running sum.
+        needs_rows = [
+            (cfg.fed.aggregator != "mean",
+             f"aggregator={cfg.fed.aggregator!r} is a statistic of all rows"),
+            (screening_enabled(cfg.fed.screen),
+             "update screening compares every row with the others"),
+            (cfg.fed.dp_clip_norm > 0,
+             "DP clipping is built on the stacked rows"),
+            (compressor is not None or cfg.fed.compression != "none",
+             f"compression={cfg.fed.compression!r}: the codecs and their "
+             "error-feedback state are built on the stacked rows"),
+            (flat_mode, "delta_layout='flat' packs every client's row"),
+            (cfg.fed.sim.malicious_fraction > 0,
+             "the adversarial harness rewrites stacked rows"),
+            (axis_name is not None, "a mesh shards the stacked clients axis"),
+        ]
+        for refused, why in needs_rows:
+            if refused:
+                raise ValueError(
+                    "FedConfig.client_schedule='sequential' keeps a running "
+                    f"weighted sum, not the clients' rows, and {why}; use "
+                    "client_schedule='vmap'"
+                )
     if cfg.fed.weighted:
         warn_weighted_robust(cfg.fed.aggregator)
     # Fused update screening (ScreenConfig; one stats pass over the flat
@@ -552,20 +624,43 @@ def make_round_step(
     if stream == "presharded":
         # images/labels are per-client rows — vmapped, unlike the shared
         # flat dataset of the gather form.
-        vmapped = jax.vmap(
-            local_update,
-            in_axes=(None, None, 0, 0, 0, 0, 0, 0, None),
-        )
+        in_axes = (None, None, 0, 0, 0, 0, 0, 0, None)
     elif stream:
-        vmapped = jax.vmap(
-            local_update,
-            in_axes=(None, None, 0, None, None, 0, 0, 0, None),
-        )
+        in_axes = (None, None, 0, None, None, 0, 0, 0, None)
     else:
-        vmapped = jax.vmap(
-            local_update,
-            in_axes=(None, None, 0, 0, 0, 0, 0, None),
+        in_axes = (None, None, 0, 0, 0, 0, 0, None)
+    vmapped = jax.vmap(local_update, in_axes=in_axes)
+
+    def clients_in_sequence(state, agg_w, args):
+        """``client_schedule='sequential'``: the clients' local updates one
+        after another (``args`` as the vmapped call takes them), each
+        client's weighted change added into one running sum as it ends.
+        Returns ``(out, mean_delta, mean_stats_delta)``, ``out`` a
+        :class:`ClientOutput` whose per-client readings are stacked and whose
+        ``params`` / ``batch_stats`` are ``None`` (no rows are kept)."""
+        total = jnp.sum(agg_w)
+        share = agg_w / jnp.where(total > 0, total, 1.0)
+        glob = (state.params, state.batch_stats)
+
+        def one_client(acc, mapped):
+            mapped, w = mapped
+            rest = iter(mapped)
+            out = local_update(*(
+                next(rest) if ax == 0 else a for a, ax in zip(args, in_axes)
+            ))
+            with jax.named_scope("fed.aggregate.running_sum"):
+                acc = jax.tree.map(
+                    lambda s, c, g: s + w.astype(s.dtype) * (c - g),
+                    acc, (out.params, out.batch_stats), glob,
+                )
+            return acc, out._replace(params=None, batch_stats=None)
+
+        (mean_delta, mean_stats_delta), out = jax.lax.scan(
+            one_client,
+            jax.tree.map(jnp.zeros_like, glob),
+            (tuple(a for a, ax in zip(args, in_axes) if ax == 0), share),
         )
+        return out, mean_delta, mean_stats_delta
 
     def round_step(
         state: FederatedState,
@@ -583,7 +678,7 @@ def make_round_step(
             # StartTrain.
             step_mask = batch.step_mask & batch.alive[:, None]
         if stream:
-            out: ClientOutput = vmapped(
+            args = (
                 state.params,
                 state.batch_stats,
                 state.opt_state,
@@ -595,7 +690,7 @@ def make_round_step(
                 state.round_idx,
             )
         else:
-            out = vmapped(
+            args = (
                 state.params,
                 state.batch_stats,
                 state.opt_state,
@@ -605,7 +700,28 @@ def make_round_step(
                 rngs,
                 state.round_idx,
             )
+        if sequential:
+            if cfg.fed.weighted:
+                agg_w = batch.weights * batch.alive.astype(batch.weights.dtype)
+            else:
+                agg_w = batch.alive.astype(jnp.float32)
+            out, mean_delta, mean_stats_delta = clients_in_sequence(
+                state, agg_w, args
+            )
+            comp_state, screened = state.comp_state, jnp.zeros((n,), bool)
+        else:
+            out: ClientOutput = vmapped(*args)
+            mean_delta, mean_stats_delta, comp_state, screened = (
+                aggregate_rows(state, batch, out, n)
+            )
+        return finish_round(
+            state, batch, out, step_mask, mean_delta, mean_stats_delta,
+            comp_state, screened,
+        )
 
+    def aggregate_rows(state, batch, out, n):
+        """The stacked clients' rows to the aggregated change: pack, attack
+        harness, codec, screening, DP, the combine."""
         with jax.named_scope("fed.pack" if flat_mode else "fed.aggregate"):
             if cfg.fed.weighted:
                 agg_w = batch.weights * batch.alive.astype(batch.weights.dtype)
@@ -801,6 +917,12 @@ def make_round_step(
                     mean_delta, std, state.round_idx,
                     seed=cfg.data.seed ^ 0x5F5E5F,
                 )
+        return mean_delta, mean_stats_delta, comp_state, screened
+
+    def finish_round(
+        state, batch, out, step_mask, mean_delta, mean_stats_delta,
+        comp_state, screened,
+    ):
         new_params, new_server_opt = server_opt_lib.apply(
             server_opt, state.params, mean_delta, state.server_opt_state
         )
@@ -825,6 +947,7 @@ def make_round_step(
                 update_norm=trees.tree_norm(mean_delta),
                 per_client_loss=out.loss * alive_f,
                 screened=screened,
+                **_round_counters(out.counters, axis_name),
             )
             new_state = FederatedState(
                 params=new_params,

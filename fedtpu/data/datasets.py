@@ -39,7 +39,7 @@ def _record_source(dataset: str, source: str, split: str) -> None:
     _SOURCE[(dataset, split)] = source
     # *_hard tasks and the plain "synthetic" name are synthetic BY DESIGN
     # (benchmark tasks), not a fallback for missing files — no warning.
-    deliberate = dataset == "synthetic" or dataset.endswith("_hard")
+    deliberate = dataset in ("synthetic", "tokens") or dataset.endswith("_hard")
     if source == "synthetic" and not deliberate and dataset not in _WARNED:
         _WARNED.add(dataset)
         warnings.warn(
@@ -255,6 +255,43 @@ def load_mnist(split: str = "train", seed: int = 0):
     return x, _read_idx(lbl).astype(np.int32)
 
 
+# Token data: rows of ``TOKENS_SEQ_LEN`` int32 ids for a language model, the
+# targets the ids moved left by one with -1 at a row's end (no target). The
+# kind the local step reads as sequences (``is_token_dataset``): no image
+# shape, no crop or flip, a next-token loss. A caller's own corpus
+# (``Federation(data=(ids, targets))``) has any length and vocabulary; the
+# built-in one is a seeded walk, learnable and small.
+TOKENS_SEQ_LEN, TOKENS_VOCAB, TOKENS_ROWS = 128, 256, 4096
+
+
+def next_token_targets(ids: np.ndarray) -> np.ndarray:
+    """``ids [n, T]`` -> targets ``[n, T]``: the next id, -1 at the end."""
+    ids = np.asarray(ids, np.int32)
+    return np.concatenate(
+        [ids[:, 1:], np.full((len(ids), 1), -1, np.int32)], axis=1)
+
+
+def load_tokens(split: str = "train", seed: int = 0):
+    """A seeded token corpus: each row walks the vocabulary by a random
+    affine step most of the time and jumps otherwise, so the next token is
+    predictable but not certain; ``TOKENS_ROWS`` rows. ALWAYS synthetic."""
+    _record_source("tokens", "synthetic", split)
+    n = TOKENS_ROWS
+    rng = np.random.default_rng(seed + 70 + (1_000_003 if split == "test" else 0))
+    ids = np.empty((n, TOKENS_SEQ_LEN), np.int32)
+    ids[:, 0] = rng.integers(0, TOKENS_VOCAB, n)
+    jump = rng.random((n, TOKENS_SEQ_LEN)) < 0.2
+    fresh = rng.integers(0, TOKENS_VOCAB, (n, TOKENS_SEQ_LEN))
+    for t in range(1, TOKENS_SEQ_LEN):
+        ids[:, t] = np.where(jump[:, t], fresh[:, t],
+                             (5 * ids[:, t - 1] + 7) % TOKENS_VOCAB)
+    return ids, next_token_targets(ids)
+
+
+def is_token_dataset(dataset: str) -> bool:
+    return dataset == "tokens"
+
+
 _LOADERS = {
     "cifar10": (load_cifar10, (32, 32, 3), 10),
     "cifar100": (load_cifar100, (32, 32, 3), 100),
@@ -262,6 +299,7 @@ _LOADERS = {
     "cifar100_hard": (load_cifar100_hard, (32, 32, 3), 100),
     "mnist": (load_mnist, (28, 28, 1), 10),
     "synthetic": (None, (32, 32, 3), 10),
+    "tokens": (load_tokens, (TOKENS_SEQ_LEN,), TOKENS_VOCAB),
 }
 
 

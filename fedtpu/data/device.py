@@ -243,7 +243,9 @@ def make_data_round_step(
             # from the array).
             tail = shape if images.ndim == 2 else tuple(images.shape[1:])
             x = images[take].reshape((n, steps, batch_size) + tail)
-            y = labels[take].reshape((n, steps, batch_size))
+            y = labels[take].reshape(
+                (n, steps, batch_size) + tuple(labels.shape[1:])
+            )
         batch = RoundBatch(
             x=x, y=y, step_mask=step_mask, weights=weights, alive=alive,
             attack_seats=atk,

@@ -46,6 +46,7 @@ from fedtpu.models.regnet import RegNetX_200MF, RegNetX_400MF, RegNetY_400MF
 from fedtpu.models.pnasnet import PNASNetA, PNASNetB
 from fedtpu.models.dla import DLA
 from fedtpu.models.dla_simple import SimpleDLA
+from fedtpu.models.joyai_llm_flash import JoyAILLMFlash
 
 __all__ = [
     "available",
@@ -91,4 +92,5 @@ __all__ = [
     "PNASNetB",
     "DLA",
     "SimpleDLA",
+    "JoyAILLMFlash",
 ]

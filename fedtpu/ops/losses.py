@@ -39,3 +39,31 @@ def softmax_ce_int_labels(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarr
     """
     onehot = jax.nn.one_hot(labels, logits.shape[-1], dtype=logits.dtype)
     return optax.softmax_cross_entropy(logits, onehot)
+
+
+def next_token_ce_parts(logits: jnp.ndarray, targets: jnp.ndarray):
+    """Next-token cross-entropy over a slice of the vocabulary, as ``(sum,
+    count, hits)``: ``logits [..., T, V]`` over the ``V`` rows held here,
+    ``targets [..., T]`` int ids in ``[0, V)`` or negative where a position
+    has no target (a packed row's end). Float32 log-softmax over the slice;
+    the mean loss is ``sum / count``, and a step taken in blocks adds the
+    sums and the counts. ``hits`` counts the positions whose largest logit is
+    the target."""
+    valid = targets >= 0
+    safe = jnp.where(valid, targets, 0)
+    nll = softmax_ce_int_labels(logits.astype(jnp.float32), safe)
+    hits = (jnp.argmax(logits, -1) == safe) & valid
+    return (
+        jnp.sum(jnp.where(valid, nll, 0.0)),
+        jnp.sum(valid, dtype=jnp.float32),
+        jnp.sum(hits, dtype=jnp.float32),
+    )
+
+
+def shift_targets(targets: jnp.ndarray, by: int) -> jnp.ndarray:
+    """The targets of a head that predicts ``by`` tokens further: the row
+    moved left, its end without targets."""
+    if by == 0:
+        return targets
+    pad = jnp.full(targets.shape[:-1] + (by,), -1, targets.dtype)
+    return jnp.concatenate([targets[..., by:], pad], axis=-1)

@@ -58,3 +58,35 @@ def test_rotation_compiles_to_three_f32_products_and_one_copy(one_chip, inverse)
     assert len(moves) <= 1, moves
     # one output buffer a product, not the butterfly's ~6x in temporaries
     assert compiled.memory_analysis().temp_size_in_bytes <= 2.1 * whole * 4
+
+
+def test_the_expert_layer_compiles_at_the_published_widths_with_its_scopes(one_chip):
+    """One row of 4,096 tokens through an expert layer that holds 8 of 256
+    experts of width 768 (``joyai_llm_flash.fl4_seq4k``'s micro-batch),
+    forward and backward: the grouped products are batched products over
+    blocks of 256 rows that carry the program's scope (``jax.lax.ragged_dot``
+    would compile to kernels named ``ragged-dot-none``, which a capture reads
+    as ``_unscoped_``), no product runs over every expert's copy of the
+    tokens, and the layer's temporaries stay under 2 GB."""
+    from fedtpu.models import joyai_llm_flash as m
+
+    layer = m.ExpertLayer(m.Sizes(experts_held=(0, 8), moe_chunk_pairs=4096), 1)
+    x = jax.ShapeDtypeStruct((1, 4096, 2048), jnp.bfloat16, sharding=one_chip)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 2048)))["params"])
+    params = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, jnp.bfloat16, sharding=one_chip), params)
+
+    def loss(params, x):
+        y, pairs, _ = layer.apply({"params": params}, x)
+        return jnp.sum(y.astype(jnp.float32)), pairs
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)).lower(
+        params, x).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    grouped = [l for l in text.splitlines() if " convolution(" in l
+               and "fed.local_step.fwd_bwd.moe.experts" in l]
+    assert len(grouped) >= 9 * 8  # 3 forward, 6 transposed products a chunk
+    assert not re.search(r"bf16\[8,4096,2048\]|bf16\[8,32768,2048\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9

@@ -360,6 +360,34 @@ def leg_kernels():
         out[f"hadamard_rotate[{rows},{h}]_first_s"] = round(t_first, 3)
         out[f"hadamard_rotate[{rows},{h}]_s"] = round(t, 5)
         out[f"hadamard_rotate[{rows},{h}]_max_abs_err"] = [err_fwd, err_back]
+    # The attention core's kernels against the plain body, bfloat16 at the
+    # language model's head sizes, three blocks long: output and gradients
+    # within bfloat16 rounding of the plain body's largest value.
+    from fedtpu.models import joyai_llm_flash as lm
+    from fedtpu.ops import attention_kernels as ak
+
+    t, heads, scale = 3 * ak.BLOCK, 4, 1.0 / math.sqrt(192)
+    shapes = [(t, heads, 128), (t, heads, 64), (t, heads, 128), (t, 64),
+              (t, heads, 128), (t, heads, 128)]
+    *ops, ct = (jnp.asarray(rng.normal(size=s), jnp.bfloat16) for s in shapes)
+    require(ak.takes(ops[0], ops[1], ops[4]), "the attention kernels do not engage")
+    both = [
+        jax.jit(lambda *a, f=f: (lambda o, vjp: (o,) + vjp(ct))(*jax.vjp(f, *a)))
+        for f in (lambda *a: ak.causal_attention(*a, scale),
+                  lambda *a: lm.causal_attention(*a, scale, ak.BLOCK))
+    ]
+    require("tpu_custom_call" in both[0].lower(*ops).as_text(),
+            "the attention core did not lower through Mosaic")
+    t_attn, got = timed(lambda: both[0](*ops), jax.block_until_ready)
+    errs = [
+        float(jnp.max(jnp.abs(g.astype(jnp.float32) - w.astype(jnp.float32)))
+              / jnp.max(jnp.abs(w.astype(jnp.float32))))
+        for g, w in zip(got, both[1](*ops))
+    ]
+    require(max(errs) <= 2e-2,
+            f"attention kernels differ from the plain body by {errs}")
+    out[f"attention_core[{t},{heads}]_first_s"] = round(t_attn, 3)
+    out[f"attention_core[{t},{heads}]_max_rel_err"] = errs
     return out
 
 

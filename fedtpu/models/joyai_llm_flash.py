@@ -11,9 +11,13 @@ writes them too:
   W_qb c_q``; ``[c_kv, k_rope] = W_kva x`` with ``k_rope`` one for all
   heads; ``[k_nope, v] = W_kvb RMSNorm(c_kv)``; interleaved RoPE on
   ``q_rope`` and ``k_rope``; causal softmax of ``q.k / sqrt(nope + rope)`` in
-  float32; ``W_o`` on the heads' ``P v``. The scores run in query blocks
-  against the keys up to the block's end, one sequence at a time, so no
-  ``[B, heads, T, T]`` tensor exists.
+  float32; ``W_o`` on the heads' ``P v``. One sequence at a time, by one of
+  two bodies of the same function (:func:`attention_core` chooses by the
+  backend and the shapes, and counts which): on a TPU, at a length the
+  kernels' blocks divide, the fused kernels of
+  :mod:`fedtpu.ops.attention_kernels` (a block's scores stay in VMEM,
+  forward and backward); everywhere else plain query blocks against the
+  keys up to the block's end, so no ``[B, heads, T, T]`` tensor exists.
 - Expert layer: ``s = sigmoid(W_r x)`` in float32 over ALL routed experts;
   chosen = top ``k`` of ``s + b``; ``g = scale * s[chosen] / sum(s[chosen])``;
   ``y = SwiGLU_shared(x) + sum over chosen e that are HELD of g_e
@@ -65,10 +69,15 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from fedtpu.models.registry import register
+from fedtpu.obs.registry import get_global_registry
+from fedtpu.ops import attention_kernels
 from fedtpu.ops.losses import next_token_ce_parts, shift_targets
 
 SCOPE = "fed.local_step.fwd_bwd."
-KEEP = "attention_core_out"  # [B, T, heads, v]: what a rematerialised block keeps
+# What a rematerialised block keeps of its attention core: the output
+# [T, heads, v] and, where the kernels run, the rows' log-sum-exp.
+KEEP = attention_kernels.KEPT
+CORES_TRACED = "fedtpu_attention_cores_traced_total"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -210,6 +219,22 @@ def causal_attention(q_nope, q_rope, k_nope, k_rope, v, scale, q_block):
     ], axis=0)
 
 
+def attention_core(q_nope, q_rope, k_nope, k_rope, v, scale, q_block):
+    """One sequence's causal attention by the body its shapes and the backend
+    call for: the fused kernels (:mod:`fedtpu.ops.attention_kernels`) or the
+    plain query blocks above, one function of the same operands. Counted in
+    the process's registry by the body taken, once a core traced."""
+    kernel = attention_kernels.takes(q_nope, q_rope, v)
+    get_global_registry().counter(
+        CORES_TRACED, "attention cores traced, by the body taken",
+        labels={"body": "kernel" if kernel else "plain"}).inc()
+    if kernel:
+        return attention_kernels.causal_attention(
+            q_nope, q_rope, k_nope, k_rope, v, scale)
+    return checkpoint_name(causal_attention(
+        q_nope, q_rope, k_nope, k_rope, v, scale, q_block), KEEP)
+
+
 class LatentAttention(nn.Module):
     sizes: Sizes
 
@@ -231,15 +256,16 @@ class LatentAttention(nn.Module):
         def one_sequence(args):
             q, kv, k_rope = args
             with jax.named_scope(SCOPE + "attention.core"):
-                return causal_attention(
+                return attention_core(
                     q[..., :nope], rope(q[..., nope:], c.rope_theta),
                     kv[..., :nope], rope(k_rope, c.rope_theta), kv[..., nope:],
                     1.0 / math.sqrt(nope + rp), c.attn_q_block,
                 )
 
-        # Kept through a block's rematerialisation (``KEEP``): the layer's
-        # backward pass then makes the scores once more, not twice.
-        o = checkpoint_name(jax.lax.map(one_sequence, (q, kv, k_rope)), KEEP)
+        # The core's output is kept through a block's rematerialisation
+        # (``KEEP``): the layer's backward pass then makes the scores once
+        # more, not twice.
+        o = jax.lax.map(one_sequence, (q, kv, k_rope))
         return Linear(x.shape[-1], name="o")(o.reshape(b, t, h * vd))
 
 

@@ -90,3 +90,53 @@ def test_the_expert_layer_compiles_at_the_published_widths_with_its_scopes(one_c
     assert len(grouped) >= 9 * 8  # 3 forward, 6 transposed products a chunk
     assert not re.search(r"bf16\[8,4096,2048\]|bf16\[8,32768,2048\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+def test_the_attention_layer_compiles_to_one_forward_and_one_backward_kernel(
+        one_chip, monkeypatch):
+    """One row of 4,096 tokens through latent attention at the published
+    widths (32 heads of 128 + 64 and 128), forward and backward under
+    ``nn.remat`` with the model's policy, as ``joyai_llm_flash.fl4_seq4k``
+    runs a layer. The backend here is the CPU, so the test itself says
+    "Mosaic" where the program asks. The core is TWO kernels, one forward (its
+    output and log-sum-exp are kept, so the rematerialised forward pass runs
+    none) and one backward; both carry the scope the benchmark reads
+    (``mla.core_roofline`` divides by the time under it: a kernel outside it
+    would read over 100 %); and no float32 tensor of heads x queries x keys
+    is left in the module (the plain body's ``f32[32,512,k]`` scores)."""
+    import flax.linen as nn
+
+    from fedtpu.models import joyai_llm_flash as m
+    from fedtpu.ops import attention_kernels as ak
+
+    monkeypatch.setattr(ak, "_mode", lambda interpret: "mosaic")
+    sizes = m.Sizes()
+    layer = nn.remat(
+        m.LatentAttention,
+        policy=jax.checkpoint_policies.save_only_these_names(m.KEEP))(sizes)
+    x = jax.ShapeDtypeStruct((1, 4096, 2048), jnp.bfloat16, sharding=one_chip)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 2048)))["params"])
+    params = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, jnp.bfloat16, sharding=one_chip), params)
+
+    def loss(params, x):
+        return jnp.sum(layer.apply({"params": params}, x).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile().as_text()
+    kernels = [l for l in text.splitlines()
+               if " custom-call(" in l and 'custom_call_target="tpu_custom_call"' in l]
+    assert sorted(re.search(r"%(latent_attention_core_\w+?)[.\d]* =", l).group(1)
+                  for l in kernels) == [
+        "latent_attention_core_bwd", "latent_attention_core_fwd"], kernels
+    for line in kernels:
+        assert ak.SCOPE in re.search(r'op_name="([^"]*)"', line).group(1), line
+    heads = sizes.num_attention_heads
+    scores = []
+    for dims in re.findall(r"f32\[([0-9,]+)\]", text):
+        dims = [int(d) for d in dims.split(",")]
+        if heads in dims:
+            dims.remove(heads)
+            if sum(d >= ak.BLOCK for d in dims) >= 2:
+                scores.append(dims)
+    assert not scores, scores[:5]

@@ -47,6 +47,7 @@ from fedtpu.models.pnasnet import PNASNetA, PNASNetB
 from fedtpu.models.dla import DLA
 from fedtpu.models.dla_simple import SimpleDLA
 from fedtpu.models.joyai_llm_flash import JoyAILLMFlash
+from fedtpu.models.qwen3_next import Qwen3Next
 
 __all__ = [
     "available",
@@ -93,4 +94,5 @@ __all__ = [
     "DLA",
     "SimpleDLA",
     "JoyAILLMFlash",
+    "Qwen3Next",
 ]

@@ -66,18 +66,13 @@ from typing import Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
+from fedtpu.models.lm_layers import (  # noqa: F401 (names the tests and tools reach through this module)
+    CORES_TRACED, KEEP, SCOPE, Linear, RMSNorm, SwiGLU, _expert_init, _rms,
+    _row_loss_parts, attention_core, causal_attention, held_range,
+    routed_experts, sizes_from_keywords)
 from fedtpu.models.registry import register
-from fedtpu.obs.registry import get_global_registry
-from fedtpu.ops import attention_kernels
-from fedtpu.ops.losses import next_token_ce_parts, shift_targets
-
-SCOPE = "fed.local_step.fwd_bwd."
-# What a rematerialised block keeps of its attention core: the output
-# [T, heads, v] and, where the kernels run, the rows' log-sum-exp.
-KEEP = attention_kernels.KEPT
-CORES_TRACED = "fedtpu_attention_cores_traced_total"
+from fedtpu.ops.losses import shift_targets
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,13 +113,7 @@ class Sizes:
 
     @property
     def held(self) -> Tuple[int, int]:
-        lo, hi = self.experts_held or (0, self.n_routed_experts)
-        if not 0 <= lo < hi <= self.n_routed_experts:
-            raise ValueError(
-                f"experts_held={self.experts_held} is no range of the "
-                f"{self.n_routed_experts} routed experts"
-            )
-        return int(lo), int(hi)
+        return held_range(self.experts_held, self.n_routed_experts)
 
 
 def correction_bias(layer: int, sizes: Sizes) -> jnp.ndarray:
@@ -133,46 +122,6 @@ def correction_bias(layer: int, sizes: Sizes) -> jnp.ndarray:
     return sizes.bias_std * jax.random.normal(
         key, (sizes.n_routed_experts,), jnp.float32
     )
-
-
-def _rms(x, scale, eps):
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (y * scale.astype(jnp.float32)).astype(x.dtype)
-
-
-class RMSNorm(nn.Module):
-    eps: float
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones_init(), (x.shape[-1],))
-        return _rms(x, scale, self.eps)
-
-
-class Linear(nn.Module):
-    """``x @ kernel``, no bias."""
-
-    features: int
-
-    @nn.compact
-    def __call__(self, x):
-        kernel = self.param(
-            "kernel",
-            nn.initializers.variance_scaling(1.0, "fan_in", "normal"),
-            (x.shape[-1], self.features),
-        )
-        return jnp.dot(x, kernel.astype(x.dtype))
-
-
-class SwiGLU(nn.Module):
-    width: int
-
-    @nn.compact
-    def __call__(self, x):
-        h = jax.nn.silu(Linear(self.width, name="gate")(x)) * Linear(
-            self.width, name="up")(x)
-        return Linear(x.shape[-1], name="down")(h)
 
 
 def rope(x, theta: float):
@@ -187,52 +136,6 @@ def rope(x, theta: float):
     a, b = xf[..., 0], xf[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
-
-
-@functools.partial(jax.checkpoint, static_argnums=(5, 6, 7))
-def _attend_block(q_nope, q_rope, k_nope, k_rope, v, lo, hi, scale):
-    """Queries ``[lo, hi)`` of a sequence ``[T, H, .]`` against the keys up to
-    ``hi`` (``k_rope [T, .]`` is every head's): float32 scores and softmax.
-    The whole sequence comes in and is cut here, so that what the backward
-    pass keeps of a block is the sequence itself and no copy of a prefix."""
-    q_nope, q_rope = q_nope[lo:hi], q_rope[lo:hi]
-    k_nope, k_rope, v = k_nope[:hi], k_rope[:hi], v[:hi]
-    s = jnp.einsum("qhd,khd->hqk", q_nope, k_nope,
-                   preferred_element_type=jnp.float32)
-    s = s + jnp.einsum("qhd,kd->hqk", q_rope, k_rope,
-                       preferred_element_type=jnp.float32)
-    seen = jnp.arange(hi)[None, :] <= (lo + jnp.arange(hi - lo))[:, None]
-    s = jnp.where(seen[None], s * scale, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), v)
-
-
-def causal_attention(q_nope, q_rope, k_nope, k_rope, v, scale, q_block):
-    """Causal attention of one sequence ``[T, H, .]`` in query blocks."""
-    t = q_nope.shape[0]
-    qb = min(q_block, t)
-    if t % qb:
-        raise ValueError(f"attn_q_block={q_block} does not divide T={t}")
-    return jnp.concatenate([
-        _attend_block(q_nope, q_rope, k_nope, k_rope, v, lo, lo + qb, scale)
-        for lo in range(0, t, qb)
-    ], axis=0)
-
-
-def attention_core(q_nope, q_rope, k_nope, k_rope, v, scale, q_block):
-    """One sequence's causal attention by the body its shapes and the backend
-    call for: the fused kernels (:mod:`fedtpu.ops.attention_kernels`) or the
-    plain query blocks above, one function of the same operands. Counted in
-    the process's registry by the body taken, once a core traced."""
-    kernel = attention_kernels.takes(q_nope, q_rope, v)
-    get_global_registry().counter(
-        CORES_TRACED, "attention cores traced, by the body taken",
-        labels={"body": "kernel" if kernel else "plain"}).inc()
-    if kernel:
-        return attention_kernels.causal_attention(
-            q_nope, q_rope, k_nope, k_rope, v, scale)
-    return checkpoint_name(causal_attention(
-        q_nope, q_rope, k_nope, k_rope, v, scale, q_block), KEEP)
 
 
 class LatentAttention(nn.Module):
@@ -269,11 +172,6 @@ class LatentAttention(nn.Module):
         return Linear(x.shape[-1], name="o")(o.reshape(b, t, h * vd))
 
 
-def _expert_init(key, shape, dtype=jnp.float32):
-    """Stacked ``[experts, in, out]`` leaves: normal over the fan-in."""
-    return jax.random.normal(key, shape, dtype) / math.sqrt(shape[1])
-
-
 class ExpertLayer(nn.Module):
     """Shared expert plus this chip's share of the routed experts. Returns
     ``(y, pairs, load)``: the pairs computed here and the busiest held
@@ -289,7 +187,6 @@ class ExpertLayer(nn.Module):
         held, k = hi - lo, c.num_experts_per_tok
         d, width = x.shape[-1], c.moe_intermediate_size
         xf = x.reshape(-1, d)
-        n = xf.shape[0]
         shared = SwiGLU(width * c.n_shared_experts, name="shared")(xf)
         router = self.param(
             "router", nn.initializers.variance_scaling(2.0, "fan_in", "normal"),
@@ -313,80 +210,10 @@ class ExpertLayer(nn.Module):
             gates_here = gates[:, lo:hi]  # [n, held], 0 where not chosen
             picked_here = picked[:, lo:hi]
 
-        with jax.named_scope(SCOPE + "moe.dispatch"):
-            # Pair p = token * held + expert. Sorted by expert (then token),
-            # the pairs on held experts first, the rest behind them.
-            key = jnp.where(picked_here, jnp.arange(held)[None, :], held)
-            order = jnp.argsort(key.reshape(-1), stable=True).astype(jnp.int32)
-            counts = jnp.sum(picked_here, axis=0, dtype=jnp.int32)  # [held]
-            ends = jnp.cumsum(counts)
-            starts, pairs = ends - counts, ends[-1]
-
-        chunk = min(c.moe_chunk_pairs, n * held)
-        n_chunks = -(-n * held // chunk)
-        order = jnp.pad(order, (0, n_chunks * chunk - n * held))
-        flat_gates = gates_here.reshape(-1)
-
-        block = min(c.moe_block_rows, chunk)
-        if chunk % block:
-            raise ValueError(
-                f"moe_block_rows={c.moe_block_rows} does not divide the chunk "
-                f"of {chunk} pairs")
-        n_blocks = chunk // block + held  # every expert may end in a part block
-
-        @jax.checkpoint
-        def one_chunk(base):
-            """Sorted pairs ``[base, base + chunk)`` through their experts:
-            ``(gated outputs [rows, d] float32, their tokens [rows])``. Each
-            expert's pairs are laid out from a block boundary on, so a block of
-            ``block`` rows has ONE expert and the grouped product is a batched
-            one over blocks; rows past an expert's last pair are zeros."""
-            with jax.named_scope(SCOPE + "moe.dispatch"):
-                sizes = jnp.clip(
-                    jnp.minimum(ends, base + chunk) - jnp.maximum(starts, base),
-                    0, None)  # each expert's pairs in this chunk
-                blocks = (sizes + block - 1) // block
-                last = jnp.cumsum(blocks)
-                expert = jnp.searchsorted(last, jnp.arange(n_blocks), side="right")
-                used = expert < held
-                expert = jnp.minimum(expert, held - 1)
-                within = ((jnp.arange(n_blocks) - (last - blocks)[expert]) * block
-                          )[:, None] + jnp.arange(block)[None, :]
-                live = (used[:, None] & (within < sizes[expert][:, None])).reshape(-1)
-                at = (jnp.cumsum(sizes) - sizes)[expert][:, None] + within
-                src = jax.lax.dynamic_slice(order, (base,), (chunk,))[
-                    jnp.where(live, at.reshape(-1), 0)]
-                token = src // held
-                rows = jnp.where(live[:, None], xf[token], 0).reshape(
-                    n_blocks, block, d)
-                pick = jax.nn.one_hot(expert, held, dtype=rows.dtype)
-                of_block = lambda w: jnp.einsum("be,eio->bio", pick, w.astype(rows.dtype))
-            with jax.named_scope(SCOPE + "moe.experts"):
-                hidden = jax.nn.silu(
-                    jnp.einsum("bri,bio->bro", rows, of_block(w_gate))
-                ) * jnp.einsum("bri,bio->bro", rows, of_block(w_up))
-                out = jnp.einsum("bri,bio->bro", hidden, of_block(w_down),
-                                 preferred_element_type=jnp.float32)
-            with jax.named_scope(SCOPE + "moe.combine"):
-                gate = jnp.where(live, flat_gates[src], 0.0)
-                return out.reshape(-1, d) * gate[:, None], token
-
-        def add_chunk(routed, base):
-            out, token = one_chunk(base)
-            with jax.named_scope(SCOPE + "moe.combine"):
-                return routed.at[token].add(out)
-
-        # Chunk by chunk while pairs are left: the first nearly always holds
-        # them all, the others are there so that nothing is ever dropped.
-        routed = jnp.zeros((n, d), jnp.float32)
-        for j in range(n_chunks):
-            routed = jax.lax.cond(
-                j * chunk < pairs, add_chunk, lambda routed, _: routed,
-                routed, jnp.int32(j * chunk))
-        with jax.named_scope(SCOPE + "moe.combine"):
-            y = (shared.astype(jnp.float32) + routed).astype(x.dtype)
-        load = jnp.max(counts) * held / jnp.maximum(pairs, 1)
-        return y.reshape(x.shape), pairs, load.astype(jnp.float32)
+        y, pairs, load = routed_experts(
+            xf, shared, gates_here, picked_here, w_gate, w_up, w_down, k,
+            c.moe_chunk_pairs, c.moe_block_rows)
+        return y.reshape(x.shape), pairs, load
 
 
 class Block(nn.Module):
@@ -409,17 +236,6 @@ class Block(nn.Module):
             with jax.named_scope(SCOPE + "moe"):
                 y, pairs, load = ExpertLayer(c, self.layer, name="moe")(x)
         return h + y, pairs, load
-
-
-@functools.partial(jax.checkpoint, static_argnums=(4,))
-def _row_loss_parts(h, targets, scale, kernel, eps):
-    """One row's final norm, head and cross-entropy, ``(sum, count, hits)``;
-    the row's float32 logits ``[T, vocab]`` are made again in the backward
-    pass, so that no step holds a whole batch of them."""
-    with jax.named_scope(SCOPE + "lm_loss"):
-        logits = jnp.dot(_rms(h, scale, eps), kernel.astype(h.dtype),
-                         preferred_element_type=jnp.float32)
-        return next_token_ce_parts(logits, targets)
 
 
 class JoyAILLMFlashModule(nn.Module):
@@ -494,13 +310,5 @@ def JoyAILLMFlash(num_classes: int = 129280, remat: bool = False,
                   **sizes) -> nn.Module:
     """``num_classes``: the vocabulary's rows held here; ``sizes``: any field
     of :class:`Sizes` (lists from a JSON file become tuples)."""
-    unknown = set(sizes) - {f.name for f in dataclasses.fields(Sizes)}
-    if unknown:
-        raise ValueError(
-            f"joyai_llm_flash has no size {sorted(unknown)}; the sizes are "
-            f"{[f.name for f in dataclasses.fields(Sizes)]}"
-        )
-    sizes = {k: tuple(v) if isinstance(v, (list, tuple)) else v
-             for k, v in sizes.items()}
-    return JoyAILLMFlashModule(
-        Sizes(vocab_size=num_classes, **sizes), remat=remat)
+    return JoyAILLMFlashModule(sizes_from_keywords(
+        Sizes, "joyai_llm_flash", num_classes, sizes), remat=remat)

@@ -1,0 +1,270 @@
+"""What the language models share (``joyai_llm_flash``, ``qwen3_next``): the
+norm, the plain layers, one sequence's causal softmax attention, the routed
+experts' path and a row's head-and-loss. Each model keeps its own scoring
+rule, its own projections and its own parameter names; what is here takes
+arrays and sizes, and names device time under ``fed.local_step.fwd_bwd.``.
+
+- :func:`attention_core`: causal attention of one sequence by the body its
+  shapes and the backend call for: the fused kernels of
+  :mod:`fedtpu.ops.attention_kernels` (as many key heads as query heads, a
+  separate rotary operand, a length their blocks divide, on a TPU) or the
+  plain query blocks of :func:`causal_attention`, one function of every
+  shape: a key head may serve a group of query heads (it is read by its
+  group, never copied), and the rotary operands may be absent.
+- :func:`routed_experts`: the (token, expert) pairs that fall on the HELD
+  experts, sorted by expert and multiplied group by group, a chunk of
+  ``chunk_pairs`` sorted pairs at a time: within a chunk each expert's pairs
+  start at a boundary of ``block_rows`` rows, so every block has one expert
+  and the grouped product is a batched product over blocks (not
+  ``jax.lax.ragged_dot``: the chip's compiler turns that into kernels named
+  ``ragged-dot-none``, which carry no scope of the program, and a capture
+  would read the experts' time as ``_unscoped_``). A chunk past the last pair
+  is skipped, so the work follows the load and no pair is ever dropped.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from fedtpu.obs.registry import get_global_registry
+from fedtpu.ops import attention_kernels
+from fedtpu.ops.losses import next_token_ce_parts
+
+SCOPE = "fed.local_step.fwd_bwd."
+# What a rematerialised block keeps of its attention core: the output
+# [T, heads, v] and, where the kernels run, the rows' log-sum-exp.
+KEEP = attention_kernels.KEPT
+CORES_TRACED = "fedtpu_attention_cores_traced_total"
+
+
+def _rms(x, scale, eps):
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+class RMSNorm(nn.Module):
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones_init(), (x.shape[-1],))
+        return _rms(x, scale, self.eps)
+
+
+class Linear(nn.Module):
+    """``x @ kernel``, no bias."""
+
+    features: int
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param(
+            "kernel",
+            nn.initializers.variance_scaling(1.0, "fan_in", "normal"),
+            (x.shape[-1], self.features),
+        )
+        return jnp.dot(x, kernel.astype(x.dtype))
+
+
+class SwiGLU(nn.Module):
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        h = jax.nn.silu(Linear(self.width, name="gate")(x)) * Linear(
+            self.width, name="up")(x)
+        return Linear(x.shape[-1], name="down")(h)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(5, 6, 7))
+def _attend_block(q_nope, q_rope, k_nope, k_rope, v, lo, hi, scale):
+    """Queries ``[lo, hi)`` of a sequence against the keys up to ``hi``:
+    float32 scores and softmax. ``q_nope [T, H, ..., d]``: whatever axes lie
+    between a key head and the width are the query heads that read it;
+    ``k_nope``, ``v [T, H, .]``. The rotary operands enter the scores as a
+    second product (``k_rope [T, .]`` is every head's) or are ``None``. The
+    whole sequence comes in and is cut here, so that what the backward pass
+    keeps of a block is the sequence itself and no copy of a prefix."""
+    cut = lambda a, lo: None if a is None else a[lo:hi]
+    q_nope, q_rope = cut(q_nope, lo), cut(q_rope, lo)
+    k_nope, k_rope, v = cut(k_nope, 0), cut(k_rope, 0), cut(v, 0)
+    s = jnp.einsum("qh...d,khd->h...qk", q_nope, k_nope,
+                   preferred_element_type=jnp.float32)
+    if q_rope is not None:
+        s = s + jnp.einsum("qh...d,kd->h...qk", q_rope, k_rope,
+                           preferred_element_type=jnp.float32)
+    seen = jnp.arange(hi)[None, :] <= (lo + jnp.arange(hi - lo))[:, None]
+    s = jnp.where(jnp.expand_dims(seen, tuple(range(s.ndim - 2))),
+                  s * scale, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("h...qk,khd->qh...d", p.astype(v.dtype), v)
+
+
+def causal_attention(q_nope, q_rope, k_nope, k_rope, v, scale, q_block):
+    """Causal attention of one sequence in query blocks (shapes as
+    :func:`_attend_block` takes them)."""
+    t = q_nope.shape[0]
+    qb = min(q_block, t)
+    if t % qb:
+        raise ValueError(f"attn_q_block={q_block} does not divide T={t}")
+    return jnp.concatenate([
+        _attend_block(q_nope, q_rope, k_nope, k_rope, v, lo, lo + qb, scale)
+        for lo in range(0, t, qb)
+    ], axis=0)
+
+
+def attention_core(q_nope, q_rope, k_nope, k_rope, v, scale, q_block):
+    """One sequence's causal attention by the body its shapes and the backend
+    call for: the fused kernels (:mod:`fedtpu.ops.attention_kernels`) or the
+    plain query blocks above, one function of the same operands. Counted in
+    the process's registry by the body taken, once a core traced."""
+    kernel = attention_kernels.takes(q_nope, q_rope, v)
+    get_global_registry().counter(
+        CORES_TRACED, "attention cores traced, by the body taken",
+        labels={"body": "kernel" if kernel else "plain"}).inc()
+    if kernel:
+        return attention_kernels.causal_attention(
+            q_nope, q_rope, k_nope, k_rope, v, scale)
+    return checkpoint_name(causal_attention(
+        q_nope, q_rope, k_nope, k_rope, v, scale, q_block), KEEP)
+
+
+def sizes_from_keywords(cls, model: str, num_classes: int, sizes: dict):
+    """``cls(vocab_size=num_classes, **sizes)`` for a model's ``Sizes``
+    dataclass, lists from a JSON file as tuples; a keyword that is no field is
+    refused with the fields' names."""
+    fields = [f.name for f in dataclasses.fields(cls)]
+    unknown = set(sizes) - set(fields)
+    if unknown:
+        raise ValueError(
+            f"{model} has no size {sorted(unknown)}; the sizes are {fields}")
+    return cls(vocab_size=num_classes, **{
+        k: tuple(v) if isinstance(v, (list, tuple)) else v
+        for k, v in sizes.items()})
+
+
+def held_range(experts_held, routed: int):
+    """``experts_held = (lo, hi)`` as a checked range of the ``routed``
+    experts a router scores; ``None``: all of them."""
+    lo, hi = experts_held or (0, routed)
+    if not 0 <= lo < hi <= routed:
+        raise ValueError(
+            f"experts_held={experts_held} is no range of the "
+            f"{routed} routed experts"
+        )
+    return int(lo), int(hi)
+
+
+def _expert_init(key, shape, dtype=jnp.float32):
+    """Stacked ``[experts, in, out]`` leaves: normal over the fan-in."""
+    return jax.random.normal(key, shape, dtype) / math.sqrt(shape[1])
+
+
+def routed_experts(xf, shared, gates_here, picked_here, w_gate, w_up, w_down,
+                   per_token, chunk_pairs, block_rows):
+    """An expert layer's sum: ``shared [n, d]`` (what every chip computes
+    alike, the model's own) plus the held experts' part (module docstring).
+    ``xf [n, d]`` tokens; ``gates_here``, ``picked_here [n, held]``: each
+    token's gate for each held expert (0 where not chosen) and whether it was
+    chosen; ``w_gate``, ``w_up [held, d, width]``, ``w_down [held, width,
+    d]``; ``per_token``: the most experts a token picks. Returns ``(y [n, d], pairs, load)``: the sum (added in float32), the
+    pairs computed here and the busiest held expert's load over the held
+    experts' mean load."""
+    n, d = xf.shape
+    held = gates_here.shape[1]
+    with jax.named_scope(SCOPE + "moe.dispatch"):
+        # Pair p = token * held + expert. Sorted by expert (then token),
+        # the pairs on held experts first, the rest behind them.
+        key = jnp.where(picked_here, jnp.arange(held)[None, :], held)
+        order = jnp.argsort(key.reshape(-1), stable=True).astype(jnp.int32)
+        counts = jnp.sum(picked_here, axis=0, dtype=jnp.int32)  # [held]
+        ends = jnp.cumsum(counts)
+        starts, pairs = ends - counts, ends[-1]
+
+    # No token has more than ``per_token`` pairs, and the pairs that exist
+    # come first: chunks for that many, not for every (token, held expert).
+    most = n * min(per_token, held)
+    chunk = min(chunk_pairs, most)
+    n_chunks = -(-most // chunk)
+    order = jnp.pad(order, (0, max(0, n_chunks * chunk - n * held)))
+    flat_gates = gates_here.reshape(-1)
+
+    block = min(block_rows, chunk)
+    if chunk % block:
+        raise ValueError(
+            f"moe_block_rows={block_rows} does not divide the chunk "
+            f"of {chunk} pairs")
+    n_blocks = chunk // block + held  # every expert may end in a part block
+
+    @jax.checkpoint
+    def one_chunk(base):
+        """Sorted pairs ``[base, base + chunk)`` through their experts:
+        ``(gated outputs [rows, d] float32, their tokens [rows])``. Each
+        expert's pairs are laid out from a block boundary on, so a block of
+        ``block`` rows has ONE expert and the grouped product is a batched
+        one over blocks; rows past an expert's last pair are zeros."""
+        with jax.named_scope(SCOPE + "moe.dispatch"):
+            sizes = jnp.clip(
+                jnp.minimum(ends, base + chunk) - jnp.maximum(starts, base),
+                0, None)  # each expert's pairs in this chunk
+            blocks = (sizes + block - 1) // block
+            last = jnp.cumsum(blocks)
+            expert = jnp.searchsorted(last, jnp.arange(n_blocks), side="right")
+            used = expert < held
+            expert = jnp.minimum(expert, held - 1)
+            within = ((jnp.arange(n_blocks) - (last - blocks)[expert]) * block
+                      )[:, None] + jnp.arange(block)[None, :]
+            live = (used[:, None] & (within < sizes[expert][:, None])).reshape(-1)
+            at = (jnp.cumsum(sizes) - sizes)[expert][:, None] + within
+            src = jax.lax.dynamic_slice(order, (base,), (chunk,))[
+                jnp.where(live, at.reshape(-1), 0)]
+            token = src // held
+            rows = jnp.where(live[:, None], xf[token], 0).reshape(
+                n_blocks, block, d)
+            pick = jax.nn.one_hot(expert, held, dtype=rows.dtype)
+            of_block = lambda w: jnp.einsum("be,eio->bio", pick, w.astype(rows.dtype))
+        with jax.named_scope(SCOPE + "moe.experts"):
+            hidden = jax.nn.silu(
+                jnp.einsum("bri,bio->bro", rows, of_block(w_gate))
+            ) * jnp.einsum("bri,bio->bro", rows, of_block(w_up))
+            out = jnp.einsum("bri,bio->bro", hidden, of_block(w_down),
+                             preferred_element_type=jnp.float32)
+        with jax.named_scope(SCOPE + "moe.combine"):
+            gate = jnp.where(live, flat_gates[src], 0.0)
+            return out.reshape(-1, d) * gate[:, None], token
+
+    def add_chunk(routed, base):
+        out, token = one_chunk(base)
+        with jax.named_scope(SCOPE + "moe.combine"):
+            return routed.at[token].add(out)
+
+    # Chunk by chunk while pairs are left: the first nearly always holds
+    # them all, the others are there so that nothing is ever dropped.
+    routed = jnp.zeros((n, d), jnp.float32)
+    for j in range(n_chunks):
+        routed = jax.lax.cond(
+            j * chunk < pairs, add_chunk, lambda routed, _: routed,
+            routed, jnp.int32(j * chunk))
+    with jax.named_scope(SCOPE + "moe.combine"):
+        y = (shared.astype(jnp.float32) + routed).astype(xf.dtype)
+    load = jnp.max(counts) * held / jnp.maximum(pairs, 1)
+    return y, pairs, load.astype(jnp.float32)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(4,))
+def _row_loss_parts(h, targets, scale, kernel, eps):
+    """One row's final norm, head and cross-entropy, ``(sum, count, hits)``;
+    the row's float32 logits ``[T, vocab]`` are made again in the backward
+    pass, so that no step holds a whole batch of them."""
+    with jax.named_scope(SCOPE + "lm_loss"):
+        logits = jnp.dot(_rms(h, scale, eps), kernel.astype(h.dtype),
+                         preferred_element_type=jnp.float32)
+        return next_token_ce_parts(logits, targets)

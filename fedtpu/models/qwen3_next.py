@@ -1,0 +1,443 @@
+"""Qwen3-Next — a language model whose layers are gated DeltaNet linear
+attention three times in four and gated softmax attention the fourth, each
+followed by an expert layer that holds a share of 512 softmax-routed experts
+(``huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct``, ``config.json``:
+``model_type: qwen3_next``, 80B-A3B; the family's published modelling code is
+``modeling_qwen3_next.py``).
+
+The layers, as the plain reference (``benchmark/reference/qwen3_next.py``)
+writes them too. ``Norm(x) = x * rsqrt(mean x^2 + eps) * (1 + w)``, ``w``
+initialised 0, is every norm but the gated one:
+
+- Block ``i``: ``h += Mixer_i(Norm(h))``; ``h += MoE(Norm(h))``. ``Mixer_i``
+  is the gated softmax layer where ``(i + 1) % full_attention_interval == 0``,
+  else gated DeltaNet.
+- Gated DeltaNet (``Hk`` key heads and ``Hv`` value heads of widths ``dk``,
+  ``dv``; key head ``j`` serves value heads ``j * Hv / Hk`` on): ``[q, k, v,
+  z] = W_qkvz x``, ``[b, a] = W_ba x``; a causal depthwise convolution of
+  width ``linear_conv_kernel_dim`` without bias over the channels of ``[q, k,
+  v]`` (``y_t = sum_i w_i x_{t - width + 1 + i}``), then SiLU; ``q, k`` divided
+  by ``sqrt(sum of squares + 1e-6)`` over their width, ``q`` scaled by
+  ``dk^-0.5``; ``beta_t = sigmoid(b_t)``, ``g_t = -exp(A_log) softplus(a_t +
+  dt_bias)`` in float32, one a value head. A value head's state ``S`` (``dk x
+  dv``, keys by values, ``S_0 = 0``): ``S'_t = exp(g_t) S_{t-1}``; ``u_t =
+  beta_t (v_t - S'_t^T k_t)``; ``S_t = S'_t + k_t u_t^T``; ``o_t = S_t^T
+  q_t``. Output: ``o_t * rsqrt(mean o_t^2 + eps) * w`` (a plain weight, ones)
+  ``* SiLU(z_t)``, then ``W_o``. The state runs across the document
+  boundaries of a packed row. Columns of ``W_qkvz`` are ``[q | k | v | z]``
+  and of ``W_ba`` ``[b | a]``, each part head by head (the published
+  projection groups its columns by key head: with seeded weights any fixed
+  layout is the same model).
+- The recurrence's training form (:func:`gated_delta_rule`) takes a chunk of
+  ``gdn_chunk`` tokens at a time. With ``G_i`` the chunk's running sum of
+  ``g`` and ``A_ij = beta_i exp(G_i - G_j) k_i.k_j`` for ``j < i``, the
+  chunk's ``u`` solve the unit lower-triangular system ``(I + A) U = beta (V -
+  exp(G) K S_0)`` (float32), so ``U = U~ - W S_0`` with ``U~`` and ``W`` from
+  one solve over every chunk at once; then ``O = exp(G) Q S_0 + (exp(G_i -
+  G_j) q_i.k_j)_{j <= i} U`` and ``S_C = exp(G_C) S_0 + (exp(G_C - G) K)^T
+  U``, a ``lax.scan`` over the chunks that is rematerialised, so the backward
+  pass holds one chunk-start state a chunk. Every exponent is a difference
+  ``G_i - G_j`` with ``j <= i``, never positive. A length the chunk does not
+  divide is padded with tokens of ``beta = 0``, ``g = 0``, which leave the
+  state as it is.
+- Gated softmax layer: ``[q, gate] = W_q x`` (a head's ``head_dim`` of ``q``,
+  then its ``head_dim`` of ``gate``), ``k = W_k x``, ``v = W_v x``; ``q, k <-
+  Norm(q), Norm(k)`` over ``head_dim``; rotary turns (rotate-half pairing:
+  dimension ``i`` with ``i + rot / 2``) on the first ``rot = head_dim *
+  partial_rotary_factor`` dimensions of each head; causal softmax of ``q.k /
+  sqrt(head_dim)`` in float32, ``num_attention_heads / num_key_value_heads``
+  query heads a key-value head, by :func:`fedtpu.models.lm_layers.attention_core`
+  (the body ``joyai_llm_flash`` runs off the chip; a key-value head is read by
+  its group, not copied); ``o * sigmoid(gate)``; ``W_o``.
+- Expert layer: ``p = softmax(W_r x)`` in float32 over ALL ``num_experts``;
+  chosen = the ``num_experts_per_tok`` largest; ``g = p[chosen] / sum
+  p[chosen]``; ``y = sigmoid(w_s . x) SwiGLU_shared(x) + sum over chosen e
+  that are HELD of g_e SwiGLU_e(x)``. ``experts_held = (lo, hi)`` says which
+  experts live here (all by default); what the absent ones would add is left
+  out and the partial sum goes on. The routed path is
+  :func:`fedtpu.models.lm_layers.routed_experts`, ``joyai_llm_flash``'s too.
+- Embedding, final ``Norm``, head, next-token cross-entropy over the
+  vocabulary's rows held here. No prediction module: the config has no key
+  for one.
+
+In training the module takes the targets and returns ``((cross-entropy sum,
+count, hits),)``, the final norm, head and loss worked out a row at a time;
+in evaluation the next-token logits. Every size is a keyword of the
+constructor (``RoundConfig.model_args``); the defaults are the published ones.
+``num_classes`` is the vocabulary's rows held here.
+
+Device time is named under ``fed.local_step.fwd_bwd.``: ``embed``,
+``linear_attention`` (``.proj``, ``.conv``, ``.core``: gates, normalisation
+and the chunked rule; ``.out``: gated norm and ``W_o``), ``attention``
+(``.core``) for the softmax layer, ``moe`` (``.router``, ``.dispatch``,
+``.experts``, ``.combine``), ``lm_loss``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from fedtpu.models.lm_layers import (
+    KEEP, SCOPE, Linear, SwiGLU, _expert_init, _rms, _row_loss_parts,
+    attention_core, held_range, routed_experts, sizes_from_keywords)
+from fedtpu.models.registry import register
+
+L2_EPS = 1e-6  # under the square root of q's and k's normalisation
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    """The config's keys by their published names, and what the cut and the
+    program add (``experts_held`` on)."""
+
+    vocab_size: int = 151936
+    hidden_size: int = 2048
+    num_hidden_layers: int = 48
+    full_attention_interval: int = 4
+    num_attention_heads: int = 16
+    num_key_value_heads: int = 2
+    head_dim: int = 256
+    partial_rotary_factor: float = 0.25
+    rope_theta: float = 1e7
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel_dim: int = 4
+    moe_intermediate_size: int = 512
+    shared_expert_intermediate_size: int = 512
+    num_experts: int = 512
+    num_experts_per_tok: int = 10
+    rms_norm_eps: float = 1e-6
+    experts_held: Optional[Tuple[int, int]] = None  # [lo, hi); None: all
+    # Read by the local step (fedtpu.core.client): how many rows of a batch
+    # go through forward and backward at a time (0: the whole batch).
+    micro_batch_rows: int = 0
+    gdn_chunk: int = 64
+    attn_q_block: int = 512
+    moe_chunk_pairs: int = 8192  # these two as the cell runs them: a held
+    moe_block_rows: int = 128  # expert of 16 sees 160 pairs a row of 8,192
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return held_range(self.experts_held, self.num_experts)
+
+    def is_softmax_layer(self, layer: int) -> bool:
+        return (layer + 1) % self.full_attention_interval == 0
+
+
+class Norm(nn.Module):
+    """``x * rsqrt(mean x^2 + eps) * (1 + scale)``, ``scale`` from 0."""
+
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.zeros_init(), (x.shape[-1],))
+        return _rms(x, 1.0 + scale, self.eps)
+
+
+def rope_half(x, theta: float, rot: int):
+    """Rotary embedding on the first ``rot`` dimensions of the last axis of
+    ``x [T, ..., d]`` in the rotate-half pairing: ``(x[i], x[i + rot/2])`` of
+    position ``t`` turn by ``t * theta^(-2i/rot)``; the rest pass."""
+    t, half = x.shape[0], rot // 2
+    inv = theta ** (-jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    ang = ang.reshape((t,) + (1,) * (x.ndim - 2) + (half,))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :half], xf[..., half:rot]
+    return jnp.concatenate(
+        [a * cos - b * sin, b * cos + a * sin, xf[..., rot:]], axis=-1
+    ).astype(x.dtype)
+
+
+def causal_conv(x, kernel):
+    """Depthwise causal convolution over time of ``x [T, channels]`` with
+    ``kernel [width, channels]``: ``y_t = sum_i kernel_i x_{t - width + 1 +
+    i}``, zeros before the row's start; float32 sums."""
+    t, width = x.shape[0], kernel.shape[0]
+    padded = jnp.pad(x, ((width - 1, 0), (0, 0))).astype(jnp.float32)
+    y = sum(padded[i:i + t] * kernel[i].astype(jnp.float32) for i in range(width))
+    return y.astype(x.dtype)
+
+
+def gated_delta_rule(q, k, v, g, beta, chunk):
+    """The gated delta rule of one sequence, a chunk at a time (module
+    docstring). ``q, k [T, Hk, dk]`` normalised, ``v [T, Hk, R, dv]`` (``R``
+    value heads a key head), ``g, beta [T, Hk, R]`` float32. Returns ``o [T,
+    Hk, R, dv]`` in ``v``'s dtype. Operands of ``v``'s dtype go into the
+    products, sums are float32, and so are the gates, the triangular solve and
+    the state between chunks."""
+    t, dtype = q.shape[0], v.dtype
+    pad = -t % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+            for a in (q, k, v, g, beta))
+    n = (t + pad) // chunk
+    cut = lambda a: a.reshape((n, chunk) + a.shape[1:])
+    q, k, v, g, beta = cut(q), cut(k), cut(v), cut(g), cut(beta)
+    f32 = dict(preferred_element_type=jnp.float32)
+
+    # Per value head, time last but one: [n, Hk, R, C, .]
+    g_h, beta_h = jnp.moveaxis(g, 1, -1), jnp.moveaxis(beta, 1, -1)
+    v_h = jnp.moveaxis(v, 1, 3).astype(jnp.float32)
+    q_h = jnp.moveaxis(q, 1, 2)[:, :, None].astype(jnp.float32)
+    k_h = jnp.moveaxis(k, 1, 2)[:, :, None].astype(jnp.float32)
+    run = jnp.cumsum(g_h, axis=-1)  # G [n, Hk, R, C]
+    at = jnp.arange(chunk)
+    # exp(G_i - G_j) where j <= i, else 0: [n, Hk, R, C, C]
+    decay = jnp.exp(jnp.where(
+        at[:, None] >= at[None, :],
+        run[..., :, None] - run[..., None, :], -jnp.inf))
+    kk = jnp.einsum("nchd,nshd->nhcs", k, k, **f32)[:, :, None]
+    qk = jnp.einsum("nchd,nshd->nhcs", q, k, **f32)[:, :, None]
+    a_mat = jnp.where(at[:, None] > at[None, :],
+                      beta_h[..., :, None] * decay * kk, 0.0)
+    # (I + A) [U~ | W] = beta [V | exp(G) K], every chunk's at once
+    rhs = jnp.concatenate([
+        beta_h[..., None] * v_h, (beta_h * jnp.exp(run))[..., None] * k_h,
+    ], axis=-1)
+    solved = jax.lax.linalg.triangular_solve(
+        a_mat, rhs, left_side=True, lower=True, unit_diagonal=True)
+    dv = v.shape[-1]
+    u_free, w = solved[..., :dv].astype(dtype), solved[..., dv:].astype(dtype)
+    attend = (decay * qk).astype(dtype)
+    q_run = (jnp.exp(run)[..., None] * q_h).astype(dtype)
+    last = run[..., -1:]  # G_C [n, Hk, R, 1]
+    k_left = (jnp.exp(last - run)[..., None] * k_h).astype(dtype)
+    keep = jnp.exp(last)[..., None]  # [n, Hk, R, 1, 1]
+
+    @jax.checkpoint
+    def one_chunk(state, xs):
+        u_free, w, attend, q_run, k_left, keep = xs
+        s = state.astype(dtype)
+        u = (u_free.astype(jnp.float32)
+             - jnp.einsum("hrcd,hrdv->hrcv", w, s, **f32)).astype(dtype)
+        o = (jnp.einsum("hrcd,hrdv->hrcv", q_run, s, **f32)
+             + jnp.einsum("hrcs,hrsv->hrcv", attend, u, **f32))
+        state = keep * state + jnp.einsum("hrcd,hrcv->hrdv", k_left, u, **f32)
+        return state, o.astype(dtype)
+
+    zero = jnp.zeros(v.shape[2:4] + (k.shape[-1], dv), jnp.float32)
+    _, o = jax.lax.scan(one_chunk, zero, (u_free, w, attend, q_run, k_left, keep))
+    # [n, Hk, R, C, dv] -> [T, Hk, R, dv]
+    return jnp.moveaxis(o, 3, 1).reshape((n * chunk,) + o.shape[1:3] + (dv,))[:t]
+
+
+class GatedDeltaNet(nn.Module):
+    sizes: Sizes
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.sizes
+        b, t, d = x.shape
+        hk, hv = c.linear_num_key_heads, c.linear_num_value_heads
+        dk, dv = c.linear_key_head_dim, c.linear_value_head_dim
+        r = hv // hk
+        if hk * r != hv:
+            raise ValueError(
+                f"linear_num_value_heads={hv} is no multiple of "
+                f"linear_num_key_heads={hk}")
+        conv_kernel = self.param(
+            "conv", nn.initializers.normal(1.0 / math.sqrt(c.linear_conv_kernel_dim)),
+            (c.linear_conv_kernel_dim, 2 * hk * dk + hv * dv))
+        a_log = self.param(
+            "A_log", lambda key, shape: jnp.log(
+                jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)), (hv,))
+        dt_bias = self.param("dt_bias", nn.initializers.ones_init(), (hv,))
+        norm = self.param("norm", nn.initializers.ones_init(), (dv,))
+        with jax.named_scope(SCOPE + "linear_attention.proj"):
+            qkvz = Linear(2 * hk * dk + 2 * hv * dv, name="in_proj_qkvz")(x)
+            ba = Linear(2 * hv, name="in_proj_ba")(x).astype(jnp.float32)
+        z = qkvz[..., 2 * hk * dk + hv * dv:].reshape(b, t, hk, r, dv)
+
+        def one_sequence(args):
+            qkv, ba = args
+            with jax.named_scope(SCOPE + "linear_attention.conv"):
+                qkv = jax.nn.silu(causal_conv(qkv, conv_kernel))
+            with jax.named_scope(SCOPE + "linear_attention.core"):
+                unit = lambda a: (
+                    a.astype(jnp.float32) * jax.lax.rsqrt(jnp.sum(
+                        jnp.square(a.astype(jnp.float32)), -1, keepdims=True)
+                        + L2_EPS))
+                q = (unit(qkv[:, :hk * dk].reshape(t, hk, dk)) * dk ** -0.5
+                     ).astype(x.dtype)
+                k = unit(qkv[:, hk * dk:2 * hk * dk].reshape(t, hk, dk)
+                         ).astype(x.dtype)
+                v = qkv[:, 2 * hk * dk:].reshape(t, hk, r, dv)
+                beta = jax.nn.sigmoid(ba[:, :hv]).reshape(t, hk, r)
+                g = (-jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
+                    ba[:, hv:] + dt_bias.astype(jnp.float32))).reshape(t, hk, r)
+                return gated_delta_rule(q, k, v, g, beta, c.gdn_chunk)
+
+        o = jax.lax.map(
+            one_sequence, (qkvz[..., :2 * hk * dk + hv * dv], ba))
+        with jax.named_scope(SCOPE + "linear_attention.out"):
+            o = _rms(o, norm, c.rms_norm_eps).astype(jnp.float32) * jax.nn.silu(
+                z.astype(jnp.float32))
+            return Linear(d, name="out_proj")(
+                o.astype(x.dtype).reshape(b, t, hv * dv))
+
+
+class GatedAttention(nn.Module):
+    sizes: Sizes
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.sizes
+        b, t, d = x.shape
+        h, kh, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+        group, rot = h // kh, int(hd * c.partial_rotary_factor)
+        if kh * group != h:
+            raise ValueError(
+                f"num_attention_heads={h} is no multiple of "
+                f"num_key_value_heads={kh}")
+        qg = Linear(h * 2 * hd, name="q_proj")(x).reshape(b, t, kh, group, 2 * hd)
+        q = Norm(c.rms_norm_eps, name="q_norm")(qg[..., :hd])
+        gate = qg[..., hd:]
+        k = Norm(c.rms_norm_eps, name="k_norm")(
+            Linear(kh * hd, name="k_proj")(x).reshape(b, t, kh, hd))
+        v = Linear(kh * hd, name="v_proj")(x).reshape(b, t, kh, hd)
+
+        def one_sequence(args):
+            q, k, v = args
+            with jax.named_scope(SCOPE + "attention.core"):
+                return attention_core(
+                    rope_half(q, c.rope_theta, rot), None,
+                    rope_half(k, c.rope_theta, rot), None, v,
+                    1.0 / math.sqrt(hd), c.attn_q_block)
+
+        o = jax.lax.map(one_sequence, (q, k, v))  # [b, t, kh, group, hd]
+        o = (o.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))
+             ).astype(x.dtype)
+        return Linear(d, name="o_proj")(o.reshape(b, t, h * hd))
+
+
+class ExpertLayer(nn.Module):
+    """Sigmoid-gated shared expert plus this chip's share of the routed
+    experts. Returns ``(y, pairs, load)``: the pairs computed here and the
+    busiest held expert's load over the held experts' mean load."""
+
+    sizes: Sizes
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.sizes
+        lo, hi = c.held
+        held, k = hi - lo, c.num_experts_per_tok
+        d, width = x.shape[-1], c.moe_intermediate_size
+        xf = x.reshape(-1, d)
+        shared = SwiGLU(c.shared_expert_intermediate_size, name="shared")(xf)
+        opened = jax.nn.sigmoid(
+            Linear(1, name="shared_gate")(xf).astype(jnp.float32))
+        shared = (opened * shared.astype(jnp.float32)).astype(x.dtype)
+        router = self.param(
+            "router", nn.initializers.variance_scaling(2.0, "fan_in", "normal"),
+            (d, c.num_experts))
+        w_gate = self.param("experts_gate", _expert_init, (held, d, width))
+        w_up = self.param("experts_up", _expert_init, (held, d, width))
+        w_down = self.param("experts_down", _expert_init, (held, width, d))
+
+        with jax.named_scope(SCOPE + "moe.router"):
+            p = jax.nn.softmax(jnp.dot(
+                xf, router.astype(xf.dtype),
+                preferred_element_type=jnp.float32), axis=-1)
+            _, chosen = jax.lax.top_k(p, k)
+            picked = (chosen[:, :, None] == jnp.arange(c.num_experts)).any(1)
+            p_picked = jnp.where(picked, p, 0.0)
+            gates = p_picked / jnp.sum(p_picked, axis=-1, keepdims=True)
+            # Held experts are a range: a token's gates for them are a slice.
+            gates_here, picked_here = gates[:, lo:hi], picked[:, lo:hi]
+
+        y, pairs, load = routed_experts(
+            xf, shared, gates_here, picked_here, w_gate, w_up, w_down, k,
+            c.moe_chunk_pairs, c.moe_block_rows)
+        return y.reshape(x.shape), pairs, load
+
+
+class Block(nn.Module):
+    """``remat``: the mixer and the expert layer are each rematerialised by
+    themselves, so a block's backward pass holds one of them at a time (the
+    two together pass a chip's memory beside an 8,192-token row)."""
+
+    sizes: Sizes
+    layer: int
+    remat: bool = False
+
+    @nn.compact
+    def __call__(self, h):
+        c = self.sizes
+        part = lambda cls: nn.remat(
+            cls, policy=jax.checkpoint_policies.save_only_these_names(KEEP)
+        ) if self.remat else cls
+        x = Norm(c.rms_norm_eps, name="mixer_norm")(h)
+        if c.is_softmax_layer(self.layer):
+            with jax.named_scope(SCOPE + "attention"):
+                h = h + part(GatedAttention)(c, name="self_attn")(x)
+        else:
+            with jax.named_scope(SCOPE + "linear_attention"):
+                h = h + part(GatedDeltaNet)(c, name="linear_attn")(x)
+        with jax.named_scope(SCOPE + "moe"):
+            y, pairs, load = part(ExpertLayer)(c, name="moe")(
+                Norm(c.rms_norm_eps, name="ffn_norm")(h))
+        return h + y, pairs, load
+
+
+class Qwen3NextModule(nn.Module):
+    sizes: Sizes
+    remat: bool = False
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False, targets=None):
+        """``tokens [B, T]`` int ids. In evaluation the next-token logits
+        ``[B, T, vocab]`` in float32. In training, with ``targets [B, T]``
+        (the next ids, negative where there is none), ``((cross-entropy sum,
+        count, hits),)``: one head."""
+        c = self.sizes
+        embed = nn.Embed(c.vocab_size, c.hidden_size, name="embed",
+                         embedding_init=nn.initializers.normal(1.0))
+        norm_scale = 1.0 + self.param(
+            "final_norm", nn.initializers.zeros_init(), (c.hidden_size,))
+        head = self.param(
+            "head", nn.initializers.variance_scaling(0.02, "fan_in", "normal"),
+            (c.hidden_size, c.vocab_size))
+        with jax.named_scope(SCOPE + "embed"):
+            h = embed(tokens)
+        pairs, loads = [], []
+        for i in range(c.num_hidden_layers):
+            h, p, l = Block(c, i, self.remat, name=f"layer_{i}")(h)
+            pairs.append(p)
+            loads.append(l)
+        if not train:
+            with jax.named_scope(SCOPE + "lm_loss"):
+                return jnp.dot(
+                    _rms(h, norm_scale, c.rms_norm_eps), head.astype(h.dtype),
+                    preferred_element_type=jnp.float32)
+        rows = jax.lax.map(
+            lambda a: _row_loss_parts(
+                a[0], a[1], norm_scale, head, c.rms_norm_eps), (h, targets))
+        self.sow("counters", "moe_pairs_here", sum(pairs),
+                 reduce_fn=lambda _, x: x, init_fn=lambda: 0)
+        self.sow("counters", "moe_load_max_over_mean",
+                 functools.reduce(jnp.maximum, loads),
+                 reduce_fn=lambda _, x: x, init_fn=lambda: 0)
+        return (tuple(jnp.sum(p) for p in rows),)
+
+
+@register("qwen3_next")
+def Qwen3Next(num_classes: int = 151936, remat: bool = False,
+              **sizes) -> nn.Module:
+    """``num_classes``: the vocabulary's rows held here; ``sizes``: any field
+    of :class:`Sizes` (lists from a JSON file become tuples)."""
+    return Qwen3NextModule(sizes_from_keywords(
+        Sizes, "qwen3_next", num_classes, sizes), remat=remat)

@@ -2,7 +2,7 @@
 softmax and ``P v`` of one sequence, forward and backward, with a block's
 float32 scores in VMEM only.
 
-What the plain body (``fedtpu.models.joyai_llm_flash.causal_attention``)
+What the plain body (``fedtpu.models.lm_layers.causal_attention``)
 computes, in the same arithmetic: operands of the inputs' dtype into every
 product, float32 accumulation, the scale, mask, maximum, exponential and sums
 in float32, ``P`` (and ``dS``) cast to the inputs' dtype before the products
@@ -68,9 +68,14 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 
 
 def _fits(q_nope, q_rope, v) -> bool:
-    """Shapes the kernels are built for: a length the blocks divide, head
-    parts of whole lanes (the rotary part of half lanes)."""
-    return (q_nope.shape[0] % BLOCK == 0
+    """Shapes the kernels are built for: queries ``[T, H, .]`` with a key and
+    value head each and a separate rotary operand (a key head that serves a
+    group of query heads, or no rotary operand, is the plain body's), a length
+    the blocks divide, head parts of whole lanes (the rotary part of half
+    lanes)."""
+    return (q_rope is not None and q_nope.ndim == 3
+            and v.shape[1] == q_nope.shape[1]
+            and q_nope.shape[0] % BLOCK == 0
             and q_nope.shape[-1] % _LANES == 0 and v.shape[-1] % _LANES == 0
             and q_rope.shape[-1] % (_LANES // 2) == 0)
 
@@ -326,7 +331,7 @@ _core.defvjp(_core_fwd, _core_bwd)
 def causal_attention(q_nope, q_rope, k_nope, k_rope, v, scale,
                      interpret: Optional[bool] = None):
     """Causal attention of one sequence ``[T, H, .]`` (``k_rope [T, .]``),
-    the function ``fedtpu.models.joyai_llm_flash.causal_attention`` is, at
+    the function ``fedtpu.models.lm_layers.causal_attention`` is, at
     the shapes :func:`takes` admits."""
     if not _fits(q_nope, q_rope, v):
         raise ValueError(

@@ -1,0 +1,252 @@
+"""What ``joyai_llm_flash`` and ``qwen3_next`` share
+(``fedtpu/models/lm_layers.py``), each case for both models at a small size
+on the CPU against that model's plain reference: the shares of the routed
+experts adding up to the uncut layer, routing so skewed that every token lands
+on one held expert with nothing dropped, and the plain causal-attention body
+at both models' shapes (a key head each with a separate rotary operand; a key
+head a group of query heads with none) with the choice of body counted.
+"""
+
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from fedtpu.models import joyai_llm_flash, lm_layers, qwen3_next
+from fedtpu.obs.registry import get_global_registry
+from fedtpu.ops import attention_kernels as ak
+
+T, D = 32, 64
+
+
+def _x(seed, *shape):
+    return jax.random.normal(jax.random.PRNGKey(seed), shape, jnp.float32)
+
+
+def _close(a, b, tol=2e-5):
+    """Norm of the difference over the reference's norm, leaf by leaf."""
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b), strict=True):
+        scale = max(float(jnp.linalg.norm(y)), 1e-12)
+        assert float(jnp.linalg.norm(x - y)) <= tol * scale, (x.shape, scale)
+
+
+def _value_and_grads(f, *args):
+    def scalar(*a):
+        out = f(*a)
+        return jnp.sum(out * _x(99, *out.shape)), out
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        scalar, argnums=tuple(range(len(args))), has_aux=True))(*args)
+    return out, grads
+
+
+class Model:
+    """One model's expert layer in the program and in its reference, under
+    the names the two give the same things."""
+
+    def __init__(self, name):
+        from benchmark import run
+
+        tiny = {"joyai_llm_flash": ("joyai_tiny", "joyai_tiny_f32"),
+                "qwen3_next": ("qwen_tiny", "qwen_tiny_f32")}[name]
+        with open(os.path.join(ROOT, "tests", "benchmark", tiny[0], "configs",
+                               tiny[1] + ".json")) as fh:
+            self.cfg = json.load(fh)
+        self.name = name
+        self.prog = {"joyai_llm_flash": joyai_llm_flash, "qwen3_next": qwen3_next}[name]
+        self.ref = run.load_py(os.path.join(ROOT, "benchmark", "reference", name + ".py"))
+        self.joyai = name == "joyai_llm_flash"
+        # the configuration's key for the experts HELD (the reference's count)
+        self.held_key = "n_routed_experts" if self.joyai else "num_experts"
+
+    def sizes(self, **over):
+        args = dict(self.cfg["program"]["round"]["model_args"])
+        args.pop("micro_batch_rows")
+        args.update(over)
+        args = {k: tuple(v) if isinstance(v, list) else v for k, v in args.items()}
+        return self.prog.Sizes(vocab_size=self.cfg["vocab_size"], **args)
+
+    def weights(self, cfg, seed=3):
+        from benchmark import seeded
+
+        params, _ = seeded.make_weights(seed, *self.ref.spec(cfg))
+        return jax.tree.map(jnp.asarray, params)["layer_1"]["moe"]
+
+    def layer(self, sizes):
+        return self.prog.ExpertLayer(sizes, 1) if self.joyai else self.prog.ExpertLayer(sizes)
+
+    def reference(self, cfg):
+        from benchmark.reference.layers import ident
+
+        f = self.ref.make_forward(cfg).expert_layer
+        return (lambda p, x: f(p, x, 1, ident)) if self.joyai else (
+            lambda p, x: f(p, x, ident))
+
+
+@pytest.fixture(scope="module", params=["joyai_llm_flash", "qwen3_next"])
+def model(request):
+    return Model(request.param)
+
+
+# ---------------------------------------------------------- the routed experts
+def test_the_shares_of_the_routed_experts_add_up_to_the_uncut_layer(model):
+    """The routed parts that all 16 / 4 = 4 shares compute, plus what every
+    chip computes alike (the shared expert, gated or not) counted once, are
+    the uncut reference layer's output and input gradient."""
+    uncut = dict(model.cfg, experts_held_from=0, **{model.held_key: 16})
+    p = model.weights(uncut)
+    x = _x(5, 2 * T, D)
+    theirs = _value_and_grads(lambda x: model.reference(uncut)(p, x), x)
+    # what a chip with no routed expert of its own would give: the shared part
+    none_held = dict(model.cfg, **{model.held_key: 0})
+    alike = lambda x: model.reference(none_held)(p, x)
+
+    def all_shares(x):
+        total, pairs = alike(x), 0
+        for lo in range(0, 16, 4):
+            held = dict(p, **{k: p[k][lo:lo + 4] for k in
+                              ("experts_gate", "experts_up", "experts_down")})
+            y, n, _ = model.layer(model.sizes(experts_held=(lo, lo + 4))).apply(
+                {"params": held}, x)
+            total, pairs = total + (y - alike(x)), pairs + n
+        return total, pairs
+
+    ours = _value_and_grads(lambda x: all_shares(x)[0], x)
+    _close(ours, theirs)
+    # every (token, chosen expert) pair is computed by exactly one share
+    assert int(jax.jit(all_shares)(x)[1]) == 2 * T * model.cfg["num_experts_per_tok"]
+
+
+def _everything_on_one_expert(model):
+    """``(cfg, params, x, lo)`` under which every token picks ONE expert, the
+    same one, of the held range ``[lo, lo + 4)``. JoyAI: a router of zeros
+    scores every expert alike, so the selection bias alone picks. Qwen3-Next
+    has no bias: tokens of positive entries against a router whose one column
+    of ones outscores the columns of zeros."""
+    one = dict(model.cfg, num_experts_per_tok=1)
+    if model.joyai:
+        busiest = int(jnp.argmax(model.ref.selection_bias(1, one)))
+        x = _x(6, 2 * T, D)
+    else:
+        busiest, x = 9, jnp.abs(_x(6, 2 * T, D)) + 0.5
+    lo = busiest // 4 * 4
+    one["experts_held_from"] = lo
+    p = dict(model.weights(one))
+    p["router"] = jnp.zeros_like(p["router"])
+    if not model.joyai:
+        p["router"] = p["router"].at[:, busiest].set(1.0)
+    return one, p, x, lo
+
+
+@pytest.mark.parametrize("chunk", [48, 4096])
+def test_every_token_on_one_held_expert_and_nothing_is_dropped(model, chunk):
+    """All the pairs fall on ONE of the four held experts, in as many chunks
+    as it takes."""
+    one, p, x, lo = _everything_on_one_expert(model)
+    layer = model.layer(model.sizes(
+        num_experts_per_tok=1, experts_held=(lo, lo + 4), moe_chunk_pairs=chunk,
+        moe_block_rows=16))
+    y, pairs, load = jax.jit(lambda x: layer.apply({"params": p}, x))(x)
+    assert int(pairs) == 2 * T
+    assert float(load) == pytest.approx(4.0)  # one expert has it all: 4 x the mean
+    reference = model.reference(one)
+    _close(y, reference(p, x))
+    ours = _value_and_grads(lambda x: layer.apply({"params": p}, x)[0], x)
+    _close(ours, _value_and_grads(lambda x: reference(p, x), x))
+
+
+def test_chunks_are_laid_out_for_the_pairs_a_token_can_have():
+    """A token picks at most ``per_token`` experts, so the sorted pairs end by
+    ``n * per_token``: 6 tokens x 8 held experts but 2 a token is 12 pairs at
+    most, 3 chunks of 4 and not 12; with every pair real nothing is lost."""
+    n, held, d, width = 6, 8, 16, 8
+    x = _x(1, n, d)
+    picked = jnp.zeros((n, held), bool).at[jnp.arange(n), jnp.arange(n) % held].set(
+        True).at[jnp.arange(n), (jnp.arange(n) + 3) % held].set(True)
+    gates = jnp.where(picked, 0.5, 0.0)
+    w = [_x(2 + i, held, *shape) for i, shape in
+         enumerate([(d, width), (d, width), (width, d)])]
+    run = lambda per_token: lm_layers.routed_experts(
+        x, jnp.zeros_like(x), gates, picked, *w, per_token, 4, 2)
+    y, pairs, _ = run(2)
+    assert int(pairs) == 12
+    dense = sum(gates[:, e, None] * (
+        (jax.nn.silu(x @ w[0][e]) * (x @ w[1][e])) @ w[2][e]) for e in range(held))
+    np.testing.assert_allclose(y, dense, rtol=1e-5, atol=1e-5)
+    conds = lambda per_token: str(jax.make_jaxpr(lambda: run(per_token)[0])()).count(" cond[")
+    assert (conds(2), conds(8)) == (3, 12)
+
+
+# --------------------------------------------------- the plain attention body
+def _attention_operands(grouped, t=T):
+    """JoyAI's shapes (4 heads, each its own key head, a rotary operand whose
+    key is every head's) or Qwen3-Next's (2 key heads of 2 query heads each,
+    no rotary operand)."""
+    if grouped:
+        return (_x(1, t, 2, 2, 16), None, _x(2, t, 2, 16), None, _x(3, t, 2, 16))
+    return (_x(1, t, 4, 16), _x(4, t, 4, 8), _x(2, t, 4, 16), _x(5, t, 8),
+            _x(3, t, 4, 16))
+
+
+def _one_head_at_a_time(q_nope, q_rope, k_nope, k_rope, v, scale):
+    """Full ``[T, T]`` scores of one query head at a time, its key head
+    copied out for it."""
+    t = q_nope.shape[0]
+    q_nope = q_nope.reshape(t, -1, q_nope.shape[-1])
+    per_key = q_nope.shape[1] // k_nope.shape[1]
+    out = []
+    for h in range(q_nope.shape[1]):
+        s = q_nope[:, h] @ k_nope[:, h // per_key].T
+        if q_rope is not None:
+            s = s + q_rope[:, h] @ k_rope.T
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s * scale, -jnp.inf)
+        out.append(jax.nn.softmax(s, axis=-1) @ v[:, h // per_key])
+    return jnp.stack(out, axis=1)
+
+
+@pytest.mark.parametrize("grouped", [False, True], ids=["joyai_llm_flash", "qwen3_next"])
+def test_the_plain_body_is_attention_one_head_at_a_time(grouped):
+    args = _attention_operands(grouped)
+    given = [a for a in args if a is not None]
+    fill = lambda given: [None if a is None else given.pop(0) for a in args]
+    got = _value_and_grads(
+        lambda *g: lm_layers.causal_attention(*fill(list(g)), 0.25, 16).reshape(
+            T, 4, 16), *given)
+    want = _value_and_grads(
+        lambda *g: _one_head_at_a_time(*fill(list(g)), 0.25), *given)
+    _close(got, want)
+    with pytest.raises(ValueError, match="attn_q_block"):
+        lm_layers.causal_attention(*args, 0.25, 24)
+
+
+@pytest.mark.parametrize("grouped", [False, True], ids=["joyai_llm_flash", "qwen3_next"])
+def test_the_kernels_take_the_shapes_they_were_built_for_and_the_choice_is_counted(
+        grouped, monkeypatch):
+    """On a TPU (here: the test says so) at the kernels' block and lane
+    widths, JoyAI's operands go to the kernels; Qwen3-Next's 256-wide grouped
+    heads without a rotary operand are refused by ``takes`` and take the plain
+    body, counted as such."""
+    t = ak.BLOCK
+    if grouped:
+        args = (_x(1, t, 2, 8, 256), None, _x(2, t, 2, 256), None, _x(3, t, 2, 256))
+    else:
+        args = (_x(1, t, 2, 128), _x(4, t, 2, 64), _x(2, t, 2, 128), _x(5, t, 64),
+                _x(3, t, 2, 128))
+    monkeypatch.setattr(ak, "_mode", lambda interpret: "mosaic")
+    assert ak.takes(args[0], args[1], args[4]) == (not grouped)
+    # as many value heads as query heads, and a rotary operand: both are asked
+    assert not ak.takes(_x(1, t, 2, 128), None, _x(3, t, 2, 128))
+    assert not ak.takes(_x(1, t, 4, 128), _x(4, t, 4, 64), _x(3, t, 2, 128))
+    if grouped:
+        count = lambda: get_global_registry().counter(
+            lm_layers.CORES_TRACED, labels={"body": "plain"}).value
+        before = count()
+        out = jax.eval_shape(lambda *a: lm_layers.attention_core(
+            a[0], None, a[1], None, a[2], 1 / 16, 256), args[0], args[2], args[4])
+        assert out.shape == (t, 2, 8, 256) and count() == before + 1

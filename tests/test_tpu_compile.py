@@ -140,3 +140,82 @@ def test_the_attention_layer_compiles_to_one_forward_and_one_backward_kernel(
             if sum(d >= ak.BLOCK for d in dims) >= 2:
                 scores.append(dims)
     assert not scores, scores[:5]
+
+
+def _mixer_gradient_text(one_chip, cls, scope):
+    """Compiled text and memory of one of Qwen3-Next's mixers at the
+    published widths on one row of 8,192 tokens, forward and backward under
+    ``nn.remat`` with the model's policy and under the scope its block gives
+    it, as ``qwen3_next_80b_a3b.fl4_seq8k`` runs a layer."""
+    import flax.linen as nn
+
+    from fedtpu.models import qwen3_next as m
+
+    layer = nn.remat(
+        cls(m), policy=jax.checkpoint_policies.save_only_these_names(m.KEEP))(m.Sizes())
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16, sharding=one_chip)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 64, 2048)))["params"])
+    params = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, jnp.bfloat16, sharding=one_chip), params)
+
+    def loss(params, x):
+        with jax.named_scope(m.SCOPE + scope):
+            return jnp.sum(layer.apply({"params": params}, x).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile()
+    return compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+
+
+def test_the_delta_net_layer_compiles_at_the_published_widths_with_its_scopes(one_chip):
+    """16 key and 32 value heads of 128 over 8,192 tokens in chunks of 64:
+    every product of the layer carries the layer's scope, and those of the
+    recurrence (the chunks' ``k k^T`` and ``q k^T``, the triangular solve the
+    compiler expands into products at HIGHEST precision, the scan's state
+    products) the core's, which ``gdn.core_roofline`` divides by; the scan
+    over the 128 chunks is a loop under that scope, once in the layer's
+    rematerialised forward and once backward; no tensor has a state a token
+    (``[8192, 32, 128, 128]``: 17 GB in float32), and the layer's
+    temporaries stay under 4 GB."""
+    text, temp = _mixer_gradient_text(
+        one_chip, lambda m: m.GatedDeltaNet, "linear_attention")
+    scope = "fed.local_step.fwd_bwd.linear_attention"
+    products = [l for l in text.splitlines() if " convolution(" in l]
+    assert products and all(scope in l for l in products)
+    assert sum(scope + ".core" in l for l in products) >= 20
+    solves = [l for l in products if "triangular_solve" in l]
+    assert solves and all(scope + ".core" in l for l in solves)
+    assert all("operand_precision={highest,highest}" in l for l in solves)
+    loops = [l for l in text.splitlines() if " while(" in l]
+    assert len(loops) == 2 and all(scope + ".core/while" in l for l in loops)
+    assert "tpu_custom_call" not in text
+    for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text):
+        dims = [int(d) for d in dims.split(",")]
+        assert math.prod(dims) < 8192 * 32 * 128 * 128, dims
+    assert temp < 4e9
+
+
+def test_the_grouped_softmax_layer_compiles_at_the_published_widths_with_its_scopes(
+        one_chip, monkeypatch):
+    """16 query heads on 2 key-value heads of 256 over 8,192 tokens. The test
+    says "Mosaic" where the program asks, and the kernels still refuse these
+    shapes (a key head serves a group, there is no rotary operand): the plain
+    body runs, its score and value products under the core's scope, the
+    projections under the layer's; a key-value head is read by its group of
+    8 (every score product has the 2 key heads as a dimension and none has
+    16), and no block of scores is wider than the 512 queries of a block."""
+    from fedtpu.ops import attention_kernels as ak
+
+    monkeypatch.setattr(ak, "_mode", lambda interpret: "mosaic")
+    text, temp = _mixer_gradient_text(one_chip, lambda m: m.GatedAttention, "attention")
+    assert "tpu_custom_call" not in text
+    scope = "fed.local_step.fwd_bwd.attention"
+    products = [l for l in text.splitlines() if " convolution(" in l]
+    assert products and all(scope in l for l in products)
+    core = [l for l in products if scope + ".core" in l]
+    assert len(core) >= 16 * 6  # 16 query blocks, 2 forward and 4 backward products
+    for line in core:
+        dims = [int(d) for d in re.search(r"= \w+\[([0-9,]+)\]", line).group(1).split(",")]
+        assert 2 in dims and 16 not in dims, line[:200]
+        assert sum(d > 512 for d in dims) <= 1, line[:200]
+    assert temp < 2e9

@@ -361,33 +361,44 @@ def leg_kernels():
         out[f"hadamard_rotate[{rows},{h}]_s"] = round(t, 5)
         out[f"hadamard_rotate[{rows},{h}]_max_abs_err"] = [err_fwd, err_back]
     # The attention core's kernels against the plain body, bfloat16 at the
-    # language model's head sizes, three blocks long: output and gradients
-    # within bfloat16 rounding of the plain body's largest value.
-    from fedtpu.models import joyai_llm_flash as lm
+    # language models' head sizes, three blocks long: output and gradients
+    # within bfloat16 rounding of the plain body's largest value. Latent
+    # attention's form (a key head each, a rotary operand) and the hybrid's
+    # (256-wide heads, a key head a group of eight, no rotary operand).
+    from fedtpu.models import lm_layers as lm
     from fedtpu.ops import attention_kernels as ak
 
-    t, heads, scale = 3 * ak.BLOCK, 4, 1.0 / math.sqrt(192)
-    shapes = [(t, heads, 128), (t, heads, 64), (t, heads, 128), (t, 64),
-              (t, heads, 128), (t, heads, 128)]
-    *ops, ct = (jnp.asarray(rng.normal(size=s), jnp.bfloat16) for s in shapes)
-    require(ak.takes(ops[0], ops[1], ops[4]), "the attention kernels do not engage")
-    both = [
-        jax.jit(lambda *a, f=f: (lambda o, vjp: (o,) + vjp(ct))(*jax.vjp(f, *a)))
-        for f in (lambda *a: ak.causal_attention(*a, scale),
-                  lambda *a: lm.causal_attention(*a, scale, ak.BLOCK))
-    ]
-    require("tpu_custom_call" in both[0].lower(*ops).as_text(),
-            "the attention core did not lower through Mosaic")
-    t_attn, got = timed(lambda: both[0](*ops), jax.block_until_ready)
-    errs = [
-        float(jnp.max(jnp.abs(g.astype(jnp.float32) - w.astype(jnp.float32)))
-              / jnp.max(jnp.abs(w.astype(jnp.float32))))
-        for g, w in zip(got, both[1](*ops))
-    ]
-    require(max(errs) <= 2e-2,
-            f"attention kernels differ from the plain body by {errs}")
-    out[f"attention_core[{t},{heads}]_first_s"] = round(t_attn, 3)
-    out[f"attention_core[{t},{heads}]_max_rel_err"] = errs
+    t, heads = 3 * ak.BLOCK, 4
+    forms = {
+        f"attention_core[{t},{heads}]": (192, [
+            (t, heads, 128), (t, heads, 64), (t, heads, 128), (t, 64),
+            (t, heads, 128), (t, heads, 128)]),
+        f"attention_core[{t},2,8,256]": (256, [
+            (t, 2, 8, 256), None, (t, 2, 256), None, (t, 2, 256), (t, 2, 8, 256)]),
+    }
+    for name, (width, shapes) in forms.items():
+        scale = 1.0 / math.sqrt(width)
+        *ops, ct = (None if s is None else jnp.asarray(rng.normal(size=s), jnp.bfloat16)
+                    for s in shapes)
+        require(ak.takes(*ops), f"the attention kernels do not engage: {name}")
+        both = [  # an absent operand is None all the way: a tree without leaves
+            jax.jit(lambda *a, f=f, ct=ct: (lambda o, vjp: (o,) + vjp(ct))(
+                *jax.vjp(f, *a)))
+            for f in (lambda *a, scale=scale: ak.causal_attention(*a, scale),
+                      lambda *a, scale=scale: lm.causal_attention(*a, scale, ak.BLOCK))
+        ]
+        require("tpu_custom_call" in both[0].lower(*ops).as_text(),
+                f"the attention core did not lower through Mosaic: {name}")
+        t_attn, got = timed(lambda: both[0](*ops), jax.block_until_ready)
+        errs = [
+            float(jnp.max(jnp.abs(g.astype(jnp.float32) - w.astype(jnp.float32)))
+                  / jnp.max(jnp.abs(w.astype(jnp.float32))))
+            for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(both[1](*ops)))
+        ]
+        require(max(errs) <= 2e-2,
+                f"attention kernels differ from the plain body by {errs}: {name}")
+        out[f"{name}_first_s"] = round(t_attn, 3)
+        out[f"{name}_max_rel_err"] = errs
     return out
 
 

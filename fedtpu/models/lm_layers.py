@@ -6,11 +6,11 @@ arrays and sizes, and names device time under ``fed.local_step.fwd_bwd.``.
 
 - :func:`attention_core`: causal attention of one sequence by the body its
   shapes and the backend call for: the fused kernels of
-  :mod:`fedtpu.ops.attention_kernels` (as many key heads as query heads, a
-  separate rotary operand, a length their blocks divide, on a TPU) or the
-  plain query blocks of :func:`causal_attention`, one function of every
-  shape: a key head may serve a group of query heads (it is read by its
-  group, never copied), and the rotary operands may be absent.
+  :mod:`fedtpu.ops.attention_kernels` (on a TPU: a length their blocks
+  divide, head parts of whole lanes) or the plain query blocks of
+  :func:`causal_attention`. Both are one function of every shape: a key head
+  may serve a group of query heads (it is read by its group, never copied),
+  and the rotary operands may be absent.
 - :func:`routed_experts`: the (token, expert) pairs that fall on the HELD
   experts, sorted by expert and multiplied group by group, a chunk of
   ``chunk_pairs`` sorted pairs at a time: within a chunk each expert's pairs
@@ -126,7 +126,7 @@ def attention_core(q_nope, q_rope, k_nope, k_rope, v, scale, q_block):
     call for: the fused kernels (:mod:`fedtpu.ops.attention_kernels`) or the
     plain query blocks above, one function of the same operands. Counted in
     the process's registry by the body taken, once a core traced."""
-    kernel = attention_kernels.takes(q_nope, q_rope, v)
+    kernel = attention_kernels.takes(q_nope, q_rope, k_nope, k_rope, v)
     get_global_registry().counter(
         CORES_TRACED, "attention cores traced, by the body taken",
         labels={"body": "kernel" if kernel else "plain"}).inc()

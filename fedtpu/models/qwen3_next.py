@@ -39,7 +39,9 @@ initialised 0, is every norm but the gated one:
   pass holds one chunk-start state a chunk. Every exponent is a difference
   ``G_i - G_j`` with ``j <= i``, never positive. A length the chunk does not
   divide is padded with tokens of ``beta = 0``, ``g = 0``, which leave the
-  state as it is.
+  state as it is. Two operands are handed over with their order in memory
+  stated (:func:`_lying`: no value changes): q and k heads-major, and the
+  scans' stacked ``(exp(G_i - G_j) q_i.k_j)`` chunk-major.
 - Gated softmax layer: ``[q, gate] = W_q x`` (a head's ``head_dim`` of ``q``,
   then its ``head_dim`` of ``gate``), ``k = W_k x``, ``v = W_v x``; ``q, k <-
   Norm(q), Norm(k)`` over ``head_dim``; rotary turns (rotate-half pairing:
@@ -47,8 +49,9 @@ initialised 0, is every norm but the gated one:
   partial_rotary_factor`` dimensions of each head; causal softmax of ``q.k /
   sqrt(head_dim)`` in float32, ``num_attention_heads / num_key_value_heads``
   query heads a key-value head, by :func:`fedtpu.models.lm_layers.attention_core`
-  (the body ``joyai_llm_flash`` runs off the chip; a key-value head is read by
-  its group, not copied); ``o * sigmoid(gate)``; ``W_o``.
+  (``joyai_llm_flash``'s: the fused kernels on a TPU, the plain query blocks
+  elsewhere; a key-value head is read by its group, not copied); ``o *
+  sigmoid(gate)``; ``W_o``.
 - Expert layer: ``p = softmax(W_r x)`` in float32 over ALL ``num_experts``;
   chosen = the ``num_experts_per_tok`` largest; ``g = p[chosen] / sum
   p[chosen]``; ``y = sigmoid(w_s . x) SwiGLU_shared(x) + sum over chosen e
@@ -83,6 +86,7 @@ from typing import Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from fedtpu.models.lm_layers import (
     KEEP, SCOPE, Linear, SwiGLU, _expert_init, _rms, _row_loss_parts,
@@ -170,6 +174,16 @@ def causal_conv(x, kernel):
     return y.astype(x.dtype)
 
 
+def _lying(x, *major_to_minor):
+    """``x``, with the order of its axes in memory said to the compiler
+    (``jax.experimental.layout``; the values are ``x``'s). Left to itself the
+    TPU compiler writes such an operand in the order of the products that
+    made it and turns it for its reader by a copy that carries no scope of
+    the program (a capture reads those as ``_unscoped_``); told the order the
+    reader wants, the producing fusion writes it so."""
+    return with_layout_constraint(x, Layout(major_to_minor=major_to_minor))
+
+
 def gated_delta_rule(q, k, v, g, beta, chunk):
     """The gated delta rule of one sequence, a chunk at a time (module
     docstring). ``q, k [T, Hk, dk]`` normalised, ``v [T, Hk, R, dv]`` (``R``
@@ -211,7 +225,9 @@ def gated_delta_rule(q, k, v, g, beta, chunk):
         a_mat, rhs, left_side=True, lower=True, unit_diagonal=True)
     dv = v.shape[-1]
     u_free, w = solved[..., :dv].astype(dtype), solved[..., dv:].astype(dtype)
-    attend = (decay * qk).astype(dtype)
+    # As the scans read it, a chunk's matrix at a time: chunk index major
+    # (the batched products that made it leave the chunk index minor).
+    attend = _lying((decay * qk).astype(dtype), 0, 1, 2, 3, 4)
     q_run = (jnp.exp(run)[..., None] * q_h).astype(dtype)
     last = run[..., -1:]  # G_C [n, Hk, R, 1]
     k_left = (jnp.exp(last - run)[..., None] * k_h).astype(dtype)
@@ -270,10 +286,13 @@ class GatedDeltaNet(nn.Module):
                     a.astype(jnp.float32) * jax.lax.rsqrt(jnp.sum(
                         jnp.square(a.astype(jnp.float32)), -1, keepdims=True)
                         + L2_EPS))
-                q = (unit(qkv[:, :hk * dk].reshape(t, hk, dk)) * dk ** -0.5
-                     ).astype(x.dtype)
-                k = unit(qkv[:, hk * dk:2 * hk * dk].reshape(t, hk, dk)
-                         ).astype(x.dtype)
+                # q and k heads-major: time stays in the sublanes, where
+                # the projection's [T, heads x dk] has it and the chunks'
+                # products want it.
+                q = _lying((unit(qkv[:, :hk * dk].reshape(t, hk, dk)) * dk ** -0.5
+                            ).astype(x.dtype), 1, 0, 2)
+                k = _lying(unit(qkv[:, hk * dk:2 * hk * dk].reshape(t, hk, dk)
+                                ).astype(x.dtype), 1, 0, 2)
                 v = qkv[:, 2 * hk * dk:].reshape(t, hk, r, dv)
                 beta = jax.nn.sigmoid(ba[:, :hv]).reshape(t, hk, r)
                 g = (-jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(

@@ -1,4 +1,4 @@
-"""The causal core of latent attention as two fused TPU kernels: scores,
+"""The causal core of softmax attention as two fused TPU kernels: scores,
 softmax and ``P v`` of one sequence, forward and backward, with a block's
 float32 scores in VMEM only.
 
@@ -6,34 +6,43 @@ What the plain body (``fedtpu.models.lm_layers.causal_attention``)
 computes, in the same arithmetic: operands of the inputs' dtype into every
 product, float32 accumulation, the scale, mask, maximum, exponential and sums
 in float32, ``P`` (and ``dS``) cast to the inputs' dtype before the products
-that read them. Queries and keys come in two parts, ``nope`` (a head's own)
-and ``rope`` (the rotary part: the key's ``[T, rope]`` is every head's), and
-enter the scores as two products; values keep their own width.
+that read them. The shapes say which form runs, one pair body for all of
+them. A key and value head ``[T, KH, .]`` serves a group of ``G`` query heads
+``[T, KH, G, .]`` (``[T, H, .]``: a key head each): the grid runs over the
+``KH * G`` query heads and reads a head's key and value blocks at ``head //
+G``. Queries and keys may come in two parts, ``nope`` (a head's own) and
+``rope`` (the rotary part: the key's ``[T, rope]`` is every head's), which
+enter the scores as two products; without the rotary operands there is the
+one product. Values keep their own width.
 
-Forward, grid ``(heads, block pairs)``: the pairs ``(i, j <= i)`` of query
-and key blocks on and under the diagonal are listed in two prefetched index
-vectors, so a key block above the diagonal is neither fetched nor computed;
-the mask is applied in the diagonal block alone. Running maximum, sum and
-output live in VMEM across a query block's pairs; what is written is the
-output ``[heads, T, v]`` and the row log-sum-exp ``[heads, 1, T]`` in float32.
+Forward, grid ``(query heads, block pairs)``: the pairs ``(i, j <= i)`` of
+query and key blocks on and under the diagonal are listed in two prefetched
+index vectors, so a key block above the diagonal is neither fetched nor
+computed; the mask is applied in the diagonal block alone. Running maximum,
+sum and output live in VMEM across a query block's pairs; what is written is
+the output ``[heads, T, v]`` and the row log-sum-exp ``[heads, 1, T]`` in
+float32.
 
 Backward, one kernel (``jax.custom_vjp``), pairs ordered by key block: a
 pair's scores are made again transposed (``[keys, queries]``, so the row
-statistics broadcast along sublanes), ``dP`` and ``dS`` formed, and five
+statistics broadcast along sublanes), ``dP`` and ``dS`` formed, and the
 products accumulate in float32: ``dv`` and ``dk`` in a key block's scratch,
-``dq`` in a whole head's ``[T, .]`` scratch (3 MB at 4,096 tokens), each
-written once in the inputs' dtype. ``dk_rope`` leaves a head at a time in
-float32 and is summed over heads outside. Nothing of ``heads x q x k`` size
-reaches HBM in either direction.
+``dq`` in a whole head's ``[T, .]`` scratch (3 MB at 4,096 tokens of 128 +
+64, 8 MiB at 8,192 of 256: one head at a time), each written once. A query
+head that has its key head to itself writes ``dk`` and ``dv`` in the inputs'
+dtype; the heads of a group write theirs in float32 (as every head its
+``dk_rope``) and the group's sum is taken outside. Nothing of ``heads x q x
+k`` size reaches HBM in either direction.
 
 Which body runs: :func:`takes` says whether this module does — on a TPU
-backend, for a length the blocks divide; the plain body everywhere else
+backend, at the shapes :func:`_fits` lists; the plain body everywhere else
 (``interpret`` as in :mod:`fedtpu.ops.pallas_kernels`: ``None`` decides by
 backend, ``False`` forces Mosaic for a deviceless compile, a true value
 interprets, for the CPU tests). Forward and backward both run under
-``jax.named_scope(SCOPE)``, the backward rule naming it itself, and the
-forward's output and log-sum-exp are named ``KEPT`` for a rematerialised
-block's policy, so its backward pass does not run the forward kernel again.
+``jax.named_scope(SCOPE)``, the heads-first relayouts around the kernels
+too, the backward rule naming it itself, and the forward's output and
+log-sum-exp are named ``KEPT`` for a rematerialised block's policy, so its
+backward pass does not run the forward kernel again.
 """
 
 from __future__ import annotations
@@ -67,27 +76,41 @@ _VMEM_LIMIT = 96 * 1024 * 1024
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 
 
-def _fits(q_nope, q_rope, v) -> bool:
-    """Shapes the kernels are built for: queries ``[T, H, .]`` with a key and
-    value head each and a separate rotary operand (a key head that serves a
-    group of query heads, or no rotary operand, is the plain body's), a length
-    the blocks divide, head parts of whole lanes (the rotary part of half
-    lanes)."""
-    return (q_rope is not None and q_nope.ndim == 3
-            and v.shape[1] == q_nope.shape[1]
+def _fits(q_nope, q_rope, k_nope, k_rope, v) -> bool:
+    """Shapes the kernels are built for: queries ``[T, KH, G, d]`` (or ``[T,
+    H, d]``: ``G = 1``) on keys and values ``[T, KH, .]``, a key head serving
+    its ``G`` query heads; the rotary operands both there (``q_rope`` shaped
+    as the queries, ``k_rope [T, .]`` every head's) or both ``None``; a length
+    the blocks divide; head parts of whole lanes (the rotary part of half
+    lanes). Everything else is the plain body's."""
+    rotary = q_rope is not None
+    return (q_nope.ndim in (3, 4) and k_nope.ndim == v.ndim == 3
+            and k_nope.shape[1] == v.shape[1] == q_nope.shape[1]
+            and rotary == (k_rope is not None)
             and q_nope.shape[0] % BLOCK == 0
             and q_nope.shape[-1] % _LANES == 0 and v.shape[-1] % _LANES == 0
-            and q_rope.shape[-1] % (_LANES // 2) == 0)
+            and (not rotary or (q_rope.shape[:-1] == q_nope.shape[:-1]
+                                and q_rope.shape[-1] % (_LANES // 2) == 0)))
 
 
-def takes(q_nope, q_rope, v, interpret: Optional[bool] = None) -> bool:
-    """Whether a sequence ``[T, H, .]`` goes through the kernels: on a TPU
-    (or where ``interpret`` says so), at shapes they are built for."""
-    return _mode(interpret) != "xla" and _fits(q_nope, q_rope, v)
+def takes(q_nope, q_rope, k_nope, k_rope, v,
+          interpret: Optional[bool] = None) -> bool:
+    """Whether a sequence goes through the kernels: on a TPU (or where
+    ``interpret`` says so), at shapes they are built for."""
+    return _mode(interpret) != "xla" and _fits(q_nope, q_rope, k_nope, k_rope, v)
 
 
 def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
     return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _scores(a_nope, a_rope, b_nope, b_rope, scale):
+    """``a b^T`` of the blocks in the refs, the rotary parts' product added
+    where the operands have rotary parts."""
+    s = _dot(a_nope[...], b_nope[...], _NT)
+    if a_rope is not None:
+        s = s + _dot(a_rope[...], b_rope[...], _NT)
+    return s * scale
 
 
 def _pairs(blocks: int, by_key: bool):
@@ -102,14 +125,20 @@ def _pairs(blocks: int, by_key: bool):
     return jnp.asarray(qi, jnp.int32), jnp.asarray(kj, jnp.int32)
 
 
-# Blocks of the operands at grid point (head, pair n), the pair's query and
-# key block read from the prefetched vectors.
+# Blocks of the operands at grid point (query head h, pair n), the pair's
+# query and key block read from the prefetched vectors.
 def _by_q(width):
     return pl.BlockSpec((None, BLOCK, width), lambda h, n, qi, kj: (h, qi[n], 0))
 
 
-def _by_k(width):
-    return pl.BlockSpec((None, BLOCK, width), lambda h, n, qi, kj: (h, kj[n], 0))
+def _by_k(width, group=1):
+    """The pair's key block of ``[KH, T, .]``, read at the key head that
+    serves query head ``h`` (``group=1``: of a query head's own ``[H, T,
+    .]``, and no division in its index map)."""
+    if group == 1:
+        return pl.BlockSpec((None, BLOCK, width), lambda h, n, qi, kj: (h, kj[n], 0))
+    return pl.BlockSpec(
+        (None, BLOCK, width), lambda h, n, qi, kj: (h // group, kj[n], 0))
 
 
 def _shared_by_k(width):  # k_rope [T, .]: every head's
@@ -118,6 +147,17 @@ def _shared_by_k(width):  # k_rope [T, .]: every head's
 
 def _row_by_q():  # a statistic [H, 1, T]
     return pl.BlockSpec((None, 1, BLOCK), lambda h, n, qi, kj: (h, 0, qi[n]))
+
+
+def _there(*xs):
+    return [x for x in xs if x is not None]
+
+
+def _named(refs, *there):
+    """A kernel's refs in the order of its names: ``None`` under the name of
+    an operand that is not there."""
+    refs = iter(refs)
+    return [next(refs) if t else None for t in there]
 
 
 def _call(kernel, name, pairs, heads, scale, interpret, out_shape, **grid_spec):
@@ -137,8 +177,9 @@ def _call(kernel, name, pairs, heads, scale, interpret, out_shape, **grid_spec):
         ), qi, kj)
 
 
-def _fwd_kernel(qi_ref, kj_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
-                o_ref, lse_ref, m_ref, l_ref, acc_ref, *, scale):
+def _fwd_kernel(qi_ref, kj_ref, *refs, scale, rotary):
+    (qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
+     acc_ref) = _named(refs, 1, rotary, 1, rotary, 1, 1, 1, 1, 1, 1)
     n = pl.program_id(1)
     i, j = qi_ref[n], kj_ref[n]
     block = qn_ref.shape[0]
@@ -150,8 +191,7 @@ def _fwd_kernel(qi_ref, kj_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def pair(diagonal):
-        s = (_dot(qn_ref[...], kn_ref[...], _NT)
-             + _dot(qr_ref[...], kr_ref[...], _NT)) * scale
+        s = _scores(qn_ref, qr_ref, kn_ref, kr_ref, scale)
         if diagonal:
             q_at = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             k_at = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -180,15 +220,19 @@ def _fwd_kernel(qi_ref, kj_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
 
 
 def _forward(q_nope, q_rope, k_nope, k_rope, v, scale, interpret):
-    """``[H, T, .]`` operands (``k_rope [T, .]``) -> output ``[H, T, v]`` and
-    log-sum-exp ``[H, 1, T]``."""
+    """Queries ``[H, T, .]`` on keys and values ``[KH, T, .]`` (``k_rope [T,
+    .]``; the rotary operands may be ``None``) -> output ``[H, T, v]`` and
+    log-sum-exp ``[H, 1, T]`` in float32."""
     h, t, nope = q_nope.shape
-    rope, vd = q_rope.shape[-1], v.shape[-1]
+    group, vd = h // v.shape[0], v.shape[-1]
+    rotary = q_rope is not None
+    if_rotary = lambda make: make(q_rope.shape[-1]) if rotary else None
     return _call(
-        _fwd_kernel, "latent_attention_core_fwd",
+        functools.partial(_fwd_kernel, rotary=rotary), "latent_attention_core_fwd",
         _pairs(t // BLOCK, by_key=False), h, scale, interpret,
-        in_specs=[_by_q(nope), _by_q(rope), _by_k(nope), _shared_by_k(rope),
-                  _by_k(vd)],
+        in_specs=_there(
+            _by_q(nope), if_rotary(_by_q), _by_k(nope, group),
+            if_rotary(_shared_by_k), _by_k(vd, group)),
         out_specs=[_by_q(vd), _row_by_q()],
         scratch_shapes=[
             pltpu.VMEM((BLOCK, _LANES), jnp.float32),
@@ -199,12 +243,15 @@ def _forward(q_nope, q_rope, k_nope, k_rope, v, scale, interpret):
             jax.ShapeDtypeStruct((h, t, vd), v.dtype),
             jax.ShapeDtypeStruct((h, 1, t), jnp.float32),
         ],
-    )(q_nope, q_rope, k_nope, k_rope, v)
+    )(*_there(q_nope, q_rope, k_nope, k_rope, v))
 
 
-def _bwd_kernel(qi_ref, kj_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
-                lse_ref, delta_ref, dqn_ref, dqr_ref, dkn_ref, dkr_ref, dv_ref,
-                dqn_acc, dqr_acc, dkn_acc, dkr_acc, dv_acc, *, scale):
+def _bwd_kernel(qi_ref, kj_ref, *refs, scale, rotary):
+    (qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref, delta_ref,
+     dqn_ref, dqr_ref, dkn_ref, dkr_ref, dv_ref,
+     dqn_acc, dqr_acc, dkn_acc, dkr_acc, dv_acc) = _named(
+        refs, 1, rotary, 1, rotary, 1, 1, 1, 1,
+        1, rotary, 1, rotary, 1, 1, rotary, 1, rotary, 1)
     n = pl.program_id(1)
     i, j = qi_ref[n], kj_ref[n]
     block = qn_ref.shape[0]
@@ -212,19 +259,17 @@ def _bwd_kernel(qi_ref, kj_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
 
     @pl.when(n == 0)
     def _():
-        dqn_acc[...] = jnp.zeros_like(dqn_acc)
-        dqr_acc[...] = jnp.zeros_like(dqr_acc)
+        for acc in _there(dqn_acc, dqr_acc):
+            acc[...] = jnp.zeros_like(acc)
 
     @pl.when(i == j)
     def _():
-        dkn_acc[...] = jnp.zeros_like(dkn_acc)
-        dkr_acc[...] = jnp.zeros_like(dkr_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
+        for acc in _there(dkn_acc, dkr_acc, dv_acc):
+            acc[...] = jnp.zeros_like(acc)
 
     def pair(diagonal):
         # Everything [keys, queries]: the queries' statistics are rows.
-        s = (_dot(kn_ref[...], qn_ref[...], _NT)
-             + _dot(kr_ref[...], qr_ref[...], _NT)) * scale
+        s = _scores(kn_ref, kr_ref, qn_ref, qr_ref, scale)
         if diagonal:
             k_at = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             q_at = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -236,11 +281,13 @@ def _bwd_kernel(qi_ref, kj_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
         ds = p * (dp - delta_ref[...]) * scale
         ds_kq = ds.astype(dtype)
         dkn_acc[...] += _dot(ds_kq, qn_ref[...])
-        dkr_acc[...] += _dot(ds_kq, qr_ref[...])
+        if rotary:
+            dkr_acc[...] += _dot(ds_kq, qr_ref[...])
         ds_qk = ds.T.astype(dtype)
         rows = pl.ds(pl.multiple_of(i * block, block), block)
         dqn_acc[rows, :] += _dot(ds_qk, kn_ref[...])
-        dqr_acc[rows, :] += _dot(ds_qk, kr_ref[...])
+        if rotary:
+            dqr_acc[rows, :] += _dot(ds_qk, kr_ref[...])
 
     @pl.when(i == j)
     def _():
@@ -253,48 +300,74 @@ def _bwd_kernel(qi_ref, kj_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
     @pl.when(i == dqn_acc.shape[0] // block - 1)
     def _():
         dkn_ref[...] = dkn_acc[...].astype(dkn_ref.dtype)
-        dkr_ref[...] = dkr_acc[...]
+        if rotary:
+            dkr_ref[...] = dkr_acc[...]
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
     @pl.when(n == pl.num_programs(1) - 1)
     def _():
         dqn_ref[...] = dqn_acc[...].astype(dqn_ref.dtype)
-        dqr_ref[...] = dqr_acc[...].astype(dqr_ref.dtype)
+        if rotary:
+            dqr_ref[...] = dqr_acc[...].astype(dqr_ref.dtype)
 
 
 def _backward(q_nope, q_rope, k_nope, k_rope, v, do, lse, delta, scale,
               interpret):
-    """``[H, T, .]`` operands, ``lse`` and ``delta [H, 1, T]`` -> ``dq_nope,
-    dq_rope, dk_nope, dv`` in the operands' dtype and each head's ``dk_rope
-    [H, T, .]`` in float32."""
+    """Operands as :func:`_forward` takes them, ``do [H, T, v]``, ``lse`` and
+    ``delta [H, 1, T]`` -> ``dq_nope, dq_rope, dk_nope, dk_rope, dv``, each
+    what ONE query head gives: ``dq`` in the operands' dtype; ``dk_rope [H,
+    T, .]`` in float32 (both ``None`` without rotary operands); ``dk_nope``
+    and ``dv [H, T, .]`` in the operands' dtype where a key head has one query
+    head, else in float32, for the sum over its group."""
     h, t, nope = q_nope.shape
-    rope, vd = q_rope.shape[-1], v.shape[-1]
+    group, vd = h // v.shape[0], v.shape[-1]
+    rotary = q_rope is not None
+    if_rotary = lambda make: make(q_rope.shape[-1]) if rotary else None
+    f32 = jnp.float32
     whole = lambda width: pl.BlockSpec(
         (None, t, width), lambda h, n, qi, kj: (h, 0, 0))
-    f32 = jnp.float32
-    return _call(
-        _bwd_kernel, "latent_attention_core_bwd",
+    of_a_head = lambda like: jax.ShapeDtypeStruct(
+        (h, t, like.shape[-1]), like.dtype if group == 1 else f32)
+    return _named(_call(
+        functools.partial(_bwd_kernel, rotary=rotary), "latent_attention_core_bwd",
         _pairs(t // BLOCK, by_key=True), h, scale, interpret,
-        in_specs=[_by_q(nope), _by_q(rope), _by_k(nope), _shared_by_k(rope),
-                  _by_k(vd), _by_q(vd), _row_by_q(), _row_by_q()],
-        out_specs=[whole(nope), whole(rope), _by_k(nope), _by_k(rope), _by_k(vd)],
-        scratch_shapes=[
-            pltpu.VMEM((t, nope), f32), pltpu.VMEM((t, rope), f32),
-            pltpu.VMEM((BLOCK, nope), f32), pltpu.VMEM((BLOCK, rope), f32),
-            pltpu.VMEM((BLOCK, vd), f32),
-        ],
-        out_shape=[
+        in_specs=_there(
+            _by_q(nope), if_rotary(_by_q), _by_k(nope, group),
+            if_rotary(_shared_by_k), _by_k(vd, group), _by_q(vd), _row_by_q(),
+            _row_by_q()),
+        out_specs=_there(
+            whole(nope), if_rotary(whole), _by_k(nope), if_rotary(_by_k),
+            _by_k(vd)),
+        scratch_shapes=_there(
+            pltpu.VMEM((t, nope), f32),
+            if_rotary(lambda rope: pltpu.VMEM((t, rope), f32)),
+            pltpu.VMEM((BLOCK, nope), f32),
+            if_rotary(lambda rope: pltpu.VMEM((BLOCK, rope), f32)),
+            pltpu.VMEM((BLOCK, vd), f32)),
+        out_shape=_there(
             jax.ShapeDtypeStruct(q_nope.shape, q_nope.dtype),
-            jax.ShapeDtypeStruct(q_rope.shape, q_rope.dtype),
-            jax.ShapeDtypeStruct(k_nope.shape, k_nope.dtype),
-            jax.ShapeDtypeStruct((h, t, rope), f32),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-    )(q_nope, q_rope, k_nope, k_rope, v, do, lse, delta)
+            if_rotary(lambda _: jax.ShapeDtypeStruct(q_rope.shape, q_rope.dtype)),
+            of_a_head(k_nope),
+            if_rotary(lambda rope: jax.ShapeDtypeStruct((h, t, rope), f32)),
+            of_a_head(v)),
+    )(*_there(q_nope, q_rope, k_nope, k_rope, v, do, lse, delta)),
+        1, rotary, 1, rotary, 1)
 
 
 def _heads_first(x):
-    return x.transpose(1, 0, 2)
+    """``[T, KH, G, .]`` or ``[T, H, .]`` -> ``[H, T, .]``, query head ``kh *
+    G + g`` of key head ``kh``."""
+    x = jnp.moveaxis(x, 0, -2)
+    return x.reshape((-1,) + x.shape[-2:])
+
+
+def _heads_last(x, like):
+    """``[H, T, .]`` -> the layout of ``like [T, ..., .]``."""
+    return jnp.moveaxis(x.reshape(like.shape[1:-1] + x.shape[-2:]), -2, 0)
+
+
+def _if_there(f, x, *args):
+    return None if x is None else f(x, *args)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
@@ -305,9 +378,9 @@ def _core(q_nope, q_rope, k_nope, k_rope, v, scale, interpret):
 def _core_fwd(q_nope, q_rope, k_nope, k_rope, v, scale, interpret):
     with jax.named_scope(SCOPE):
         o, lse = _forward(
-            _heads_first(q_nope), _heads_first(q_rope), _heads_first(k_nope),
-            k_rope, _heads_first(v), scale, interpret)
-        o = checkpoint_name(_heads_first(o), KEPT)
+            _heads_first(q_nope), _if_there(_heads_first, q_rope),
+            _heads_first(k_nope), k_rope, _heads_first(v), scale, interpret)
+        o = checkpoint_name(_heads_last(o, q_nope), KEPT)
         lse = checkpoint_name(lse, KEPT)
     return o, (q_nope, q_rope, k_nope, k_rope, v, o, lse)
 
@@ -317,12 +390,20 @@ def _core_bwd(scale, interpret, kept, do):
     with jax.named_scope(SCOPE):
         delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
         dq_nope, dq_rope, dk_nope, dk_rope, dv = _backward(
-            _heads_first(q_nope), _heads_first(q_rope), _heads_first(k_nope),
-            k_rope, _heads_first(v), _heads_first(do), lse,
-            delta.T[:, None, :], scale, interpret)
-        return (_heads_first(dq_nope), _heads_first(dq_rope),
-                _heads_first(dk_nope),
-                jnp.sum(dk_rope, axis=0).astype(k_rope.dtype), _heads_first(dv))
+            _heads_first(q_nope), _if_there(_heads_first, q_rope),
+            _heads_first(k_nope), k_rope, _heads_first(v), _heads_first(do), lse,
+            delta.reshape(delta.shape[0], -1).T[:, None, :], scale, interpret)
+
+        def of_key_heads(d, like):  # [H, T, .] -> like [T, KH, .]
+            if d.shape[0] != like.shape[1]:  # a group's float32 parts
+                d = jnp.sum(d.reshape((like.shape[1], -1) + d.shape[1:]), axis=1)
+            return _heads_last(d.astype(like.dtype), like)
+
+        return (_heads_last(dq_nope, q_nope),
+                _if_there(_heads_last, dq_rope, q_rope),
+                of_key_heads(dk_nope, k_nope),
+                _if_there(lambda d: jnp.sum(d, axis=0).astype(k_rope.dtype), dk_rope),
+                of_key_heads(dv, v))
 
 
 _core.defvjp(_core_fwd, _core_bwd)
@@ -330,12 +411,14 @@ _core.defvjp(_core_fwd, _core_bwd)
 
 def causal_attention(q_nope, q_rope, k_nope, k_rope, v, scale,
                      interpret: Optional[bool] = None):
-    """Causal attention of one sequence ``[T, H, .]`` (``k_rope [T, .]``),
-    the function ``fedtpu.models.lm_layers.causal_attention`` is, at
-    the shapes :func:`takes` admits."""
-    if not _fits(q_nope, q_rope, v):
+    """Causal attention of one sequence, the function
+    ``fedtpu.models.lm_layers.causal_attention`` is, at the shapes
+    :func:`takes` admits."""
+    if not _fits(q_nope, q_rope, k_nope, k_rope, v):
+        shapes = [_if_there(jnp.shape, a) for a in (q_nope, q_rope, k_nope, k_rope, v)]
         raise ValueError(
-            f"the kernels take a length that is a multiple of {BLOCK} and head "
-            f"parts of whole lanes, not {q_nope.shape}, {q_rope.shape}, {v.shape}")
+            f"the kernels take a length that is a multiple of {BLOCK}, head "
+            f"parts of whole lanes and the rotary operands together or not at "
+            f"all, not {shapes}")
     return _core(q_nope, q_rope, k_nope, k_rope, v, float(scale),
                  _mode(interpret) == "interpret")

@@ -4,7 +4,8 @@ on the CPU against that model's plain reference: the shares of the routed
 experts adding up to the uncut layer, routing so skewed that every token lands
 on one held expert with nothing dropped, and the plain causal-attention body
 at both models' shapes (a key head each with a separate rotary operand; a key
-head a group of query heads with none) with the choice of body counted.
+head a group of query heads with none) and which shapes the fused kernels take
+of each, with the choice of body counted.
 """
 
 import json
@@ -229,9 +230,10 @@ def test_the_plain_body_is_attention_one_head_at_a_time(grouped):
 def test_the_kernels_take_the_shapes_they_were_built_for_and_the_choice_is_counted(
         grouped, monkeypatch):
     """On a TPU (here: the test says so) at the kernels' block and lane
-    widths, JoyAI's operands go to the kernels; Qwen3-Next's 256-wide grouped
-    heads without a rotary operand are refused by ``takes`` and take the plain
-    body, counted as such."""
+    widths, both models' operands go to the kernels: JoyAI's (a key head
+    each, a rotary operand) and Qwen3-Next's (256-wide heads, a key head a
+    group of eight, no rotary operand), counted as such; off a TPU both take
+    the plain body."""
     t = ak.BLOCK
     if grouped:
         args = (_x(1, t, 2, 8, 256), None, _x(2, t, 2, 256), None, _x(3, t, 2, 256))
@@ -239,14 +241,24 @@ def test_the_kernels_take_the_shapes_they_were_built_for_and_the_choice_is_count
         args = (_x(1, t, 2, 128), _x(4, t, 2, 64), _x(2, t, 2, 128), _x(5, t, 64),
                 _x(3, t, 2, 128))
     monkeypatch.setattr(ak, "_mode", lambda interpret: "mosaic")
-    assert ak.takes(args[0], args[1], args[4]) == (not grouped)
-    # as many value heads as query heads, and a rotary operand: both are asked
-    assert not ak.takes(_x(1, t, 2, 128), None, _x(3, t, 2, 128))
-    assert not ak.takes(_x(1, t, 4, 128), _x(4, t, 4, 64), _x(3, t, 2, 128))
-    if grouped:
-        count = lambda: get_global_registry().counter(
-            lm_layers.CORES_TRACED, labels={"body": "plain"}).value
-        before = count()
-        out = jax.eval_shape(lambda *a: lm_layers.attention_core(
-            a[0], None, a[1], None, a[2], 1 / 16, 256), args[0], args[2], args[4])
-        assert out.shape == (t, 2, 8, 256) and count() == before + 1
+    assert ak.takes(*args)
+    # no rotary operand, a key head each: taken; more query heads than key
+    # heads on one axis (no group axis to read them by): not
+    assert ak.takes(_x(1, t, 2, 128), None, _x(2, t, 2, 128), None, _x(3, t, 2, 128))
+    assert not ak.takes(_x(1, t, 4, 128), _x(4, t, 4, 64), _x(2, t, 2, 128),
+                        _x(5, t, 64), _x(3, t, 2, 128))
+    # a rotary operand on one side only, a length the blocks do not divide,
+    # heads of part lanes
+    assert not ak.takes(args[0], None if grouped else args[1], args[2],
+                        _x(5, t, 64) if grouped else None, args[4])
+    assert not ak.takes(*(None if a is None else a[:t - 128] for a in args))
+    assert not ak.takes(*(None if a is None else a[..., :48] for a in args))
+    count = lambda body: get_global_registry().counter(
+        lm_layers.CORES_TRACED, labels={"body": body}).value
+    for mode, body in (("mosaic", "kernel"), ("xla", "plain")):
+        monkeypatch.setattr(ak, "_mode", lambda interpret, mode=mode: mode)
+        before = count(body)
+        out = jax.eval_shape(  # a function of its own each: traced each time
+            lambda *a: lm_layers.attention_core(*a, 1 / 16, 256), *args)
+        assert out.shape == args[0].shape[:-1] + (args[4].shape[-1],)
+        assert count(body) == before + 1

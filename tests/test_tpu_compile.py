@@ -175,8 +175,8 @@ def test_the_delta_net_layer_compiles_at_the_published_widths_with_its_scopes(on
     products) the core's, which ``gdn.core_roofline`` divides by; the scan
     over the 128 chunks is a loop under that scope, once in the layer's
     rematerialised forward and once backward; no tensor has a state a token
-    (``[8192, 32, 128, 128]``: 17 GB in float32), and the layer's
-    temporaries stay under 4 GB."""
+    (``[8192, 32, 128, 128]``: 17 GB in float32), the layer's temporaries stay
+    under 4 GB, and the layouts the program states are the compiled ones."""
     text, temp = _mixer_gradient_text(
         one_chip, lambda m: m.GatedDeltaNet, "linear_attention")
     scope = "fed.local_step.fwd_bwd.linear_attention"
@@ -193,29 +193,53 @@ def test_the_delta_net_layer_compiles_at_the_published_widths_with_its_scopes(on
         dims = [int(d) for d in dims.split(",")]
         assert math.prod(dims) < 8192 * 32 * 128 * 128, dims
     assert temp < 4e9
+    # What the program says of layouts holds (PR 39): the scans' stacked
+    # ``attend`` lies chunk-major wherever it stands, q and k lie heads-major
+    # (time in the sublanes, as the projection wrote them), and no copy that
+    # lacks the program's metadata, which a capture reads as ``_unscoped_``,
+    # turns either: not the ``bf16[128,16,2,64,64]`` relayouts between the
+    # stacked operand and the scans, not the ``f32[1024,8,16,128]`` copies of
+    # q and k for the solve's right-hand side.
+    assert set(re.findall(r"= bf16\[128,16,2,64,64\]\{([0-9,]+)", text)) == {"4,3,2,1,0"}
+    assert not [l for l in text.splitlines() if "op_name=" not in l and re.search(
+        r"= (?:bf16\[128,16,2,64,64\]|f32\[1024,8,16,128\])\S* copy\(", l)]
 
 
 def test_the_grouped_softmax_layer_compiles_at_the_published_widths_with_its_scopes(
         one_chip, monkeypatch):
     """16 query heads on 2 key-value heads of 256 over 8,192 tokens. The test
-    says "Mosaic" where the program asks, and the kernels still refuse these
-    shapes (a key head serves a group, there is no rotary operand): the plain
-    body runs, its score and value products under the core's scope, the
-    projections under the layer's; a key-value head is read by its group of
-    8 (every score product has the 2 key heads as a dimension and none has
-    16), and no block of scores is wider than the 512 queries of a block."""
+    says "Mosaic" where the program asks, and the kernels take these shapes
+    (a key head serves a group of 8, there is no rotary operand): the core is
+    TWO kernels named as JoyAI's, one forward (kept, so the rematerialised
+    forward pass runs none) and one backward, at ``[16, 8192, 256]`` queries
+    on ``[2, 8192, 256]`` keys (a key-value head is read by its group, not
+    copied), inside the VMEM limit (the compile would refuse them), both
+    under the scope ``full_attention``'s reader and the core's time are read
+    by, as are the heads-first relayouts around them; the projections carry
+    the layer's scope; and no float32 block of scores ``[., 8, 512, .]`` of
+    the plain body is left in the module."""
     from fedtpu.ops import attention_kernels as ak
 
     monkeypatch.setattr(ak, "_mode", lambda interpret: "mosaic")
     text, temp = _mixer_gradient_text(one_chip, lambda m: m.GatedAttention, "attention")
-    assert "tpu_custom_call" not in text
     scope = "fed.local_step.fwd_bwd.attention"
+    assert ak.SCOPE == scope + ".core"
+    kernels = [l for l in text.splitlines()
+               if " custom-call(" in l and 'custom_call_target="tpu_custom_call"' in l]
+    assert sorted(re.search(r"%(latent_attention_core_\w+?)[.\d]* =", l).group(1)
+                  for l in kernels) == [
+        "latent_attention_core_bwd", "latent_attention_core_fwd"], kernels
+    for line in kernels:
+        assert ak.SCOPE in re.search(r'op_name="([^"]*)"', line).group(1), line
+        operands = line[line.index("operand_layout_constraints="):line.index("metadata=")]
+        assert "bf16[16,8192,256]" in operands and "bf16[2,8192,256]" in operands, operands
+        used = re.search(r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', line)
+        assert 0 < int(used.group(1)) <= ak._VMEM_LIMIT
     products = [l for l in text.splitlines() if " convolution(" in l]
     assert products and all(scope in l for l in products)
-    core = [l for l in products if scope + ".core" in l]
-    assert len(core) >= 16 * 6  # 16 query blocks, 2 forward and 4 backward products
-    for line in core:
-        dims = [int(d) for d in re.search(r"= \w+\[([0-9,]+)\]", line).group(1).split(",")]
-        assert 2 in dims and 16 not in dims, line[:200]
-        assert sum(d > 512 for d in dims) <= 1, line[:200]
+    assert not any(ak.SCOPE in l for l in products)  # the core's are in the kernels
+    moved = [l for l in text.splitlines() if re.search(
+        r"= (?:bf16|f32)\[(?:2,8|16),8192,256\]\S* (?:copy|transpose|fusion)\(", l)]
+    assert moved and all(scope in l for l in moved), [l[:200] for l in moved]
+    assert not re.findall(r"f32\[(?:\d+,)*8,512,\d+\]", text)
     assert temp < 2e9

@@ -9,6 +9,7 @@ exist for the reference's multi-process edge topology.
 from __future__ import annotations
 
 import argparse
+import json
 import logging
 import time
 
@@ -38,6 +39,7 @@ from fedtpu.cli.common import (
 from fedtpu.core import Federation
 from fedtpu.data import load
 from fedtpu.obs import RoundRecordWriter
+from fedtpu.obs.telemetry import setup_snapshot
 
 
 def main(argv=None) -> int:
@@ -131,6 +133,13 @@ def main(argv=None) -> int:
                 "inherently O(clients) device state)"
             )
         return _run_async(args, cfg)
+    # A --profile-rounds window that starts at round 0 opens BEFORE the
+    # engine is built: set-up's spans (fed.setup.*) then lie in the capture
+    # beside the device's transfers and first execution. A resumed run
+    # learns its first round only from the checkpoint, after the build.
+    capture = make_capture_window(args, role="engine")
+    if capture is not None and not args.resume:
+        capture.maybe_start(0)
     if cfg.fed.sim.population:
         from fedtpu.sim import SimFederation
 
@@ -167,7 +176,8 @@ def main(argv=None) -> int:
     if mfu_mode != "off" and hasattr(fed, "enable_mfu_accounting"):
         profiler = fed.enable_mfu_accounting(xla_check=mfu_mode == "xla")
         logging.info("mfu cost model: %s", profiler.cost.as_dict())
-    capture = make_capture_window(args, role="engine", telemetry=fed.telemetry)
+    if capture is not None and fed.telemetry.tracer is not None:
+        capture.stamp(fed.telemetry.tracer.trace_id)
     ckpt, start_round, state = _restore_from(
         args, like=fed.state, telemetry=fed.telemetry, flight=flight,
         chaos=chaos,
@@ -270,6 +280,11 @@ def main(argv=None) -> int:
                     if "test_acc" in rec:
                         msg += f" test_acc {rec['test_acc']:.3f}"
                     bar.update(ri - start_round, msg)
+            if r == start_round and fed.telemetry.enabled:
+                # What the engine's set-up cost, phase by phase (the
+                # /statusz "setup" block; docs/OBSERVABILITY.md).
+                logging.info("set-up: %s", json.dumps(
+                    setup_snapshot(ndigits=3), sort_keys=True))
             if compile_w is not None and not compile_w.steady and (
                 crossed_eval or not args.eval_every
             ):

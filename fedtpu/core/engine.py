@@ -38,7 +38,8 @@ from fedtpu.data import (
     load,
     partition,
 )
-from fedtpu.obs import StatusBoard, Telemetry, validate_telemetry_mode
+from fedtpu.obs import StatusBoard, Telemetry
+from fedtpu.obs.telemetry import setup_snapshot
 from fedtpu.utils.metrics import MetricsLogger
 
 # NOTE: fedtpu.data.device imports from fedtpu.core.round, whose package
@@ -80,6 +81,21 @@ class Federation:
         engine a cohort's rows gathered from a much larger population."""
         self.cfg = cfg
         self.mesh = mesh
+        # Host-side telemetry (fedtpu.obs), built FIRST so that set-up runs
+        # under its spans too: spans wrap what the HOST does (fed.setup.*
+        # here and in the first dispatch, fed.plan / fed.enqueue in a
+        # round; device compute is async) and reach a --profile-rounds
+        # capture in every mode but "off", on the device operations' own
+        # clock; counters track rounds completed. Swappable
+        # post-construction — the jitted programs never close over it
+        # (bench.py --telemetry-microbench retimes one engine under all
+        # three modes).
+        self.telemetry = Telemetry(cfg.fed.telemetry, role="engine")
+        with self.telemetry.phase("fed.setup.build"):
+            self._build(cfg, seed, compressor, data, mesh, assignment)
+
+    def _build(self, cfg, seed, compressor, data, mesh, assignment):
+        tel = self.telemetry
         # Config validation FIRST — a bad flag must not cost a model build,
         # a dataset load, jit construction, or even backend initialisation
         # (enable_compile_cache below) before raising.
@@ -112,7 +128,6 @@ class Federation:
             raise ValueError(
                 f"unknown dtype {cfg.dtype!r}; have float32 | bfloat16"
             )
-        validate_telemetry_mode(cfg.fed.telemetry)
         shape, n_classes = dataset_info(cfg.data.dataset)
         # Token data: num_classes is the model's vocabulary (the rows it
         # holds), and a caller's corpus draws its ids from it.
@@ -159,25 +174,10 @@ class Federation:
         self.images, self.labels = images, labels
 
         n = cfg.fed.num_clients
-        if assignment is not None:
-            idx, mask = np.asarray(assignment[0]), np.asarray(assignment[1])
-            if idx.shape[0] != n or idx.shape != mask.shape:
-                raise ValueError(
-                    f"assignment must be [num_clients={n}, shard_len] "
-                    f"idx/mask pairs, got {idx.shape} vs {mask.shape}"
-                )
-        elif cfg.data.partition == "round_robin":
-            idx, mask = partition.round_robin(len(images), n, cfg.data.batch_size)
-        elif cfg.data.partition == "iid":
-            idx, mask = partition.iid(len(images), n, seed=cfg.data.seed)
-        elif cfg.data.partition == "dirichlet":
-            idx, mask = partition.dirichlet(
-                labels, n, alpha=cfg.data.dirichlet_alpha, seed=cfg.data.seed
-            )
-        else:
-            raise ValueError(f"unknown partition {cfg.data.partition}")
-        self.client_idx, self.client_mask = idx, mask
-        self.weights = jnp.asarray(partition.shard_sizes(mask))
+        with tel.phase("fed.setup.build.partition"):
+            idx, mask = self._assign(images, labels, assignment)
+            self.client_idx, self.client_mask = idx, mask
+            self.weights = jnp.asarray(partition.shard_sizes(mask))
 
         # Seeded adversarial participants (fedtpu.sim.adversary; the
         # SimConfig.malicious_fraction axis). On the resident engine the
@@ -219,9 +219,11 @@ class Federation:
             (1,) + tuple(images.shape[1:]),
             jnp.int32 if self._tokens else jnp.float32,
         )
-        self.state: FederatedState = init_state(
-            self.model, cfg, jax.random.PRNGKey(seed), sample, compressor
-        )
+        with tel.phase("fed.setup.build.init_state", compiles=True):
+            state = init_state(
+                self.model, cfg, jax.random.PRNGKey(seed), sample, compressor
+            )
+        self.state: FederatedState = state  # placed by the setter
         shuffle = cfg.data.partition != "round_robin"
         img_shape = tuple(images.shape[1:])
         layout = cfg.data.device_layout
@@ -249,32 +251,33 @@ class Federation:
                 )
                 layout = "gather"
         self._layout = layout
-        if mesh is None:
-            from fedtpu.data.device import make_data_round_step
+        with tel.phase("fed.setup.build.programs"):
+            if mesh is None:
+                from fedtpu.data.device import make_data_round_step
 
-            self._round_step = jax.jit(
-                make_round_step(self.model, cfg, compressor), donate_argnums=(0,)
-            )
-            self._data_step = jax.jit(
-                make_data_round_step(
-                    self.model, cfg, self._steps, compressor, shuffle=shuffle,
+                self._round_step = jax.jit(
+                    make_round_step(self.model, cfg, compressor), donate_argnums=(0,)
+                )
+                self._data_step = jax.jit(
+                    make_data_round_step(
+                        self.model, cfg, self._steps, compressor, shuffle=shuffle,
+                        image_shape=img_shape, layout=layout,
+                    ),
+                    donate_argnums=(0,),
+                )
+            else:
+                from fedtpu.data.device import make_sharded_data_round_step
+                from fedtpu.parallel.sharded import make_sharded_round_step
+
+                self._round_step = make_sharded_round_step(
+                    self.model, cfg, mesh, compressor
+                )
+                self._data_step = make_sharded_data_round_step(
+                    self.model, cfg, self._steps, mesh, compressor, shuffle=shuffle,
                     image_shape=img_shape, layout=layout,
-                ),
-                donate_argnums=(0,),
-            )
-        else:
-            from fedtpu.data.device import make_sharded_data_round_step
-            from fedtpu.parallel.sharded import make_sharded_round_step
-
-            self._round_step = make_sharded_round_step(
-                self.model, cfg, mesh, compressor
-            )
-            self._data_step = make_sharded_data_round_step(
-                self.model, cfg, self._steps, mesh, compressor, shuffle=shuffle,
-                image_shape=img_shape, layout=layout,
-            )
-            # self.state was already mesh-placed by the property setter.
-            self.weights = self._placed(self.weights, sharded=True)
+                )
+                # self.state was already mesh-placed by the property setter.
+                self.weights = self._placed(self.weights, sharded=True)
         # Device-resident data (uploaded lazily on the first device-path
         # step, so explicit-batch callers never pay the HBM footprint):
         # dataset + assignment matrix go to HBM once; each round gathers its
@@ -287,14 +290,10 @@ class Federation:
         self._shuffle = shuffle
         self._img_shape = img_shape
         self._multi_steps = {}  # num_rounds -> compiled scan program
-        # Host-side telemetry (fedtpu.obs): spans wrap what the HOST does
-        # in a round (fed.plan, fed.enqueue; device compute is async) and
-        # reach a --profile-rounds capture in every mode but "off", on the
-        # device operations' own clock; counters track rounds completed.
-        # Swappable post-construction — the jitted programs never close
-        # over it (bench.py --telemetry-microbench retimes one engine under
-        # all three modes).
-        self.telemetry = Telemetry(cfg.fed.telemetry, role="engine")
+        # Compiled programs called at least once ("round", "data", a fused
+        # block's num_rounds): the first call of each runs under
+        # fed.setup.first_dispatch.
+        self._called = set()
         # Live status feed (fedtpu.obs.http: /statusz via --obs-port):
         # round/phase updates are one locked dict merge each — cheap enough
         # to run unconditionally (bench.py --obs-plane-microbench).
@@ -313,6 +312,28 @@ class Federation:
         # (jax.monitoring listeners are global, so the process owns it, not
         # the engine) — surfaced on /statusz when present.
         self.compile_watcher = None
+
+    def _assign(self, images, labels, assignment):
+        """The client→example ``(idx, mask)``: the caller's, or the
+        configured partition of the loaded examples."""
+        cfg, n = self.cfg, self.cfg.fed.num_clients
+        if assignment is not None:
+            idx, mask = np.asarray(assignment[0]), np.asarray(assignment[1])
+            if idx.shape[0] != n or idx.shape != mask.shape:
+                raise ValueError(
+                    f"assignment must be [num_clients={n}, shard_len] "
+                    f"idx/mask pairs, got {idx.shape} vs {mask.shape}"
+                )
+            return idx, mask
+        if cfg.data.partition == "round_robin":
+            return partition.round_robin(len(images), n, cfg.data.batch_size)
+        if cfg.data.partition == "iid":
+            return partition.iid(len(images), n, seed=cfg.data.seed)
+        if cfg.data.partition == "dirichlet":
+            return partition.dirichlet(
+                labels, n, alpha=cfg.data.dirichlet_alpha, seed=cfg.data.seed
+            )
+        raise ValueError(f"unknown partition {cfg.data.partition}")
 
     def enable_mfu_accounting(self, xla_check: bool = True):
         """Arm per-round MFU/roofline gauges + round-record stamping.
@@ -343,6 +364,8 @@ class Federation:
         perf/compile observability blocks when armed)."""
         snap = self.status.snapshot()
         snap["alive"] = self.alive.tolist()
+        if self.telemetry.enabled:
+            snap["setup"] = setup_snapshot()
         if self.telemetry.tracer is not None:
             snap["trace_id"] = self.telemetry.tracer.trace_id
         if self.profiler is not None:
@@ -374,42 +397,58 @@ class Federation:
 
     def _ensure_device_data(self):
         if self._device_data is None:
-            store = self._store_dtype()
+            with self.telemetry.phase("fed.setup.first_dispatch.device_data"):
+                self._device_data = self._upload_device_data()
+        return self._device_data
+
+    def _upload_device_data(self):
+        """The dataset and the assignment matrix, made on the host
+        (``.host``) and put on the device or the mesh (``.h2d``: the puts
+        as the host sees them; the transfers themselves are asynchronous)."""
+        tel = self.telemetry
+        store = self._store_dtype()
+        with tel.phase("fed.setup.first_dispatch.device_data.host"):
             if self._layout == "presharded":
                 # Per-client contiguous rows ([n, 2L, F], see
                 # fedtpu.data.device.preshard_arrays) — sharded by CLIENT on
                 # a mesh, so each device stores only its own clients' data.
                 from fedtpu.data.device import preshard_arrays
 
-                xs_c, ys_c = preshard_arrays(
+                xs, ys = preshard_arrays(
                     self.images, self.labels, self.client_idx,
                     self.client_mask,
                 )
-                self._device_data = (
-                    self._placed(xs_c.astype(store), sharded=True),
-                    self._placed(ys_c, sharded=True),
-                    self._placed(self.client_idx, sharded=True),
-                    self._placed(self.client_mask, sharded=True),
-                )
-                return self._device_data
-            # Gather layout: dataset replicated (every device gathers its own
-            # clients' batches locally); assignment matrix sharded by client.
-            # Images live FLAT ([N, H*W*C]): NHWC tensors pad ~4x under TPU
-            # tiled layouts, flat rows tile exactly — the per-batch reshape
-            # after the gather is free.
-            if self._tokens:
-                flat = np.asarray(self.images, np.int32)  # ids, not pixels
+                xs = xs.astype(store)
             else:
-                flat = np.asarray(self.images, np.float32).reshape(
-                    len(self.images), -1
-                ).astype(store)
-            self._device_data = (
-                self._placed(flat, sharded=False),
-                self._placed(np.asarray(self.labels, np.int32), sharded=False),
+                # Gather layout: dataset replicated (every device gathers
+                # its own clients' batches locally); assignment matrix
+                # sharded by client. Images live FLAT ([N, H*W*C]): NHWC
+                # tensors pad ~4x under TPU tiled layouts, flat rows tile
+                # exactly — the per-batch reshape after the gather is free.
+                if self._tokens:
+                    xs = np.asarray(self.images, np.int32)  # ids, not pixels
+                else:
+                    xs = np.asarray(self.images, np.float32).reshape(
+                        len(self.images), -1
+                    ).astype(store)
+                ys = np.asarray(self.labels, np.int32)
+        by_client = self._layout == "presharded"
+        replicas = 1 if by_client or self.mesh is None else self.mesh.size
+        tel.setup_gauge(
+            "fedtpu_setup_device_data_bytes",
+            "bytes of dataset and assignment the engine put on the devices "
+            "(a replicated array counts once a device)",
+        ).inc(
+            (xs.nbytes + ys.nbytes) * replicas
+            + self.client_idx.nbytes + self.client_mask.nbytes
+        )
+        with tel.phase("fed.setup.first_dispatch.device_data.h2d"):
+            return (
+                self._placed(xs, sharded=by_client),
+                self._placed(ys, sharded=by_client),
                 self._placed(self.client_idx, sharded=True),
                 self._placed(self.client_mask, sharded=True),
             )
-        return self._device_data
 
     # ---------------------------------------------------------------- data
     def set_assignment(
@@ -556,18 +595,52 @@ class Federation:
         # the engine's shardings so resume Just Works; trees that already
         # hold non-addressable global arrays (multi-controller stepping
         # output) are left untouched.
-        if self.mesh is not None:
+        # Without a mesh, host leaves (seeded or restored weights as NumPy
+        # arrays) are put on the device here, where a span says so, and not
+        # inside the next dispatch's argument handling.
+        with self.telemetry.phase("fed.setup.place_state"):
             leaves = jax.tree_util.tree_leaves(s)
-            already_global = any(
+            if self.mesh is None:
+                moved = [l for l in leaves if not isinstance(l, jax.Array)]
+                if moved:
+                    s = jax.device_put(s)
+                self._count_placed(moved, 1)
+            elif not any(
                 isinstance(l, jax.Array) and not l.is_fully_addressable
                 for l in leaves
-            )
-            if not already_global:
+            ):
                 from fedtpu.parallel.sharded import shard_state
 
                 s = shard_state(s, self.mesh, self.cfg.mesh_axis)
+                placed = jax.tree_util.tree_leaves(s)
+                moved = [
+                    after for before, after in zip(leaves, placed)
+                    if not (isinstance(before, jax.Array)
+                            and before.sharding == after.sharding)
+                ]
+                self._count_placed(moved, self.mesh.size)
         self._state = s
         self._round_host = None
+
+    def _count_placed(self, moved, n_devices: int) -> None:
+        """Count the state leaves a placement moved, and their bytes times
+        the devices they went to (a replicated leaf goes to every one)."""
+        if not moved or not self.telemetry.enabled:
+            return
+        tel = self.telemetry
+        tel.setup_gauge(
+            "fedtpu_setup_state_leaves",
+            "state leaves the engine placed on the devices during set-up",
+        ).inc(len(moved))
+        tel.setup_gauge(
+            "fedtpu_setup_state_bytes",
+            "bytes of those leaves times the devices each went to",
+        ).inc(sum(
+            getattr(l, "nbytes", 0) * (
+                n_devices if n_devices > 1
+                and l.sharding.is_fully_replicated else 1)
+            for l in moved
+        ))
 
     def _round_number(self) -> int:
         """Host-tracked current round. Avoids a blocking device readback of
@@ -583,7 +656,12 @@ class Federation:
         self.status.update(round=r, phase="round")
         t0 = time.perf_counter()
         with tel.span("fed.round", step_num=r):
-            metrics = self._step_impl(batch)
+            program = "data" if batch is None else "round"
+            if program in self._called:
+                metrics = self._step_impl(batch)
+            else:
+                metrics = self._first_dispatch(
+                    program, self._step_impl, batch)
         self._observe(t0, metrics)
         self.status.update(round=r + 1, phase="idle")
         tel.counter(
@@ -591,6 +669,16 @@ class Federation:
             "simulated FedAvg rounds dispatched by this engine",
         ).inc()
         return metrics
+
+    def _first_dispatch(self, program, dispatch, *args) -> RoundMetrics:
+        """The first call of a compiled program (``program`` names it in
+        ``_called``): the same dispatch under ``fed.setup.first_dispatch``,
+        which also hears the trace, lowering, compile or cache load that
+        the call sets off, and holds the dataset's upload if this is the
+        first call to need it."""
+        self._called.add(program)
+        with self.telemetry.phase("fed.setup.first_dispatch", compiles=True):
+            return dispatch(*args)
 
     def _observe(self, t0: float, metrics: RoundMetrics, rounds: int = 1):
         """Feed the armed profiler the wall of a dispatch that has FINISHED.
@@ -683,37 +771,11 @@ class Federation:
                            fused_block=num_rounds)
         t0 = time.perf_counter()
         with tel.span("fed.fused_rounds", round=r, num_rounds=num_rounds):
-            with tel.span("fed.plan"):
-                alive = np.stack(
-                    [self._alive_for_round(r + i) for i in range(num_rounds)]
-                )
-                d_images, d_labels, d_idx, d_mask = self._ensure_device_data()
-                if self.mesh is None:
-                    alive_dev = jnp.asarray(alive)
-                else:
-                    from fedtpu.parallel.sharded import _put
-                    from jax.sharding import PartitionSpec as P
-
-                    alive_dev = _put(
-                        alive, self.mesh, P(None, self.cfg.mesh_axis)
-                    )
-                extra = (
-                    (jnp.asarray(self._attack_seats),)
-                    if self._attack_seats is not None else ()
-                )
-                multi_step = self._multi_step(num_rounds)
-            with tel.span("fed.enqueue"):
-                self._state, metrics = multi_step(
-                    self._state,
-                    d_images,
-                    d_labels,
-                    d_idx,
-                    d_mask,
-                    self.weights,
-                    alive_dev,
-                    self._data_key,
-                    *extra,
-                )
+            if num_rounds in self._called:
+                metrics = self._fused_impl(r, num_rounds)
+            else:
+                metrics = self._first_dispatch(
+                    num_rounds, self._fused_impl, r, num_rounds)
         self._observe(t0, metrics, rounds=num_rounds)
         self._round_host = r + num_rounds
         self.status.update(round=r + num_rounds, phase="idle")
@@ -721,6 +783,41 @@ class Federation:
             "fedtpu_rounds_completed_total",
             "simulated FedAvg rounds dispatched by this engine",
         ).inc(num_rounds)
+        return metrics
+
+    def _fused_impl(self, r: int, num_rounds: int) -> RoundMetrics:
+        tel = self.telemetry
+        with tel.span("fed.plan"):
+            alive = np.stack(
+                [self._alive_for_round(r + i) for i in range(num_rounds)]
+            )
+            d_images, d_labels, d_idx, d_mask = self._ensure_device_data()
+            if self.mesh is None:
+                alive_dev = jnp.asarray(alive)
+            else:
+                from fedtpu.parallel.sharded import _put
+                from jax.sharding import PartitionSpec as P
+
+                alive_dev = _put(
+                    alive, self.mesh, P(None, self.cfg.mesh_axis)
+                )
+            extra = (
+                (jnp.asarray(self._attack_seats),)
+                if self._attack_seats is not None else ()
+            )
+            multi_step = self._multi_step(num_rounds)
+        with tel.span("fed.enqueue"):
+            self._state, metrics = multi_step(
+                self._state,
+                d_images,
+                d_labels,
+                d_idx,
+                d_mask,
+                self.weights,
+                alive_dev,
+                self._data_key,
+                *extra,
+            )
         return metrics
 
     def run(

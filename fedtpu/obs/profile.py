@@ -627,18 +627,107 @@ def latency_summary(
     }
 
 
+# ------------------------------------------------ what jax says of a compile
+# jax 0.9.0's names, the one table both listeners below read. The four
+# durations arrive through ``jax.monitoring``'s duration listeners with the
+# program's ``fun_name`` (the cache's retrieval alone comes without); the two
+# events through its event listeners. ``backend_compile_duration`` wraps
+# ``compile_or_get_cached``, so on a cache hit it CONTAINS the retrieval.
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+COMPILE_DURATION_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    BACKEND_COMPILE_EVENT: "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+
+class SetupCompileListener:
+    """What jax says about compiling while ONE set-up phase of an engine is
+    open (``Telemetry.phase(..., compiles=True)``): each duration of
+    :data:`COMPILE_DURATION_EVENTS` is added to
+    ``fedtpu_setup_seconds{phase="<phase>.trace" | ".lower" | ".compile" |
+    ".cache_load"}``, each hit or miss of the persistent cache to
+    ``fedtpu_setup_cache_hits`` / ``fedtpu_setup_cache_misses`` (jax
+    records a miss when it WRITES the compiled program to the cache, so
+    neither fires without one).
+
+    A duration is charged NET of the durations that ended inside it: an
+    inner jit's trace inside the outer one's, and the cache's retrieval
+    inside ``backend_compile_duration`` (a program either compiled or
+    loaded; the hit or miss that fired in between says which). The four
+    gauges of a phase are therefore disjoint pieces of its wall.
+
+    Registered on the phase's entry and unregistered on its exit, so the
+    listener lives only while the engine's own set-up compiles: a caller's
+    jits, before or after, are never counted."""
+
+    def __init__(self, registry, phase: str, charge):
+        """``charge(phase, seconds)`` adds to the phase's seconds (the
+        owner's: ``fedtpu_setup_seconds`` is defined where phases are)."""
+        self.registry = registry
+        self.phase = phase
+        self._charge = charge
+        self._ended: List[Tuple[float, float]] = []  # (end, gross duration)
+        self._lock = threading.Lock()
+
+    def _cache_gauges(self):
+        help = ("programs of the newest engine's set-up that jax's "
+                "persistent compile cache served (hits) or that compiled "
+                "and were written to it (misses)")
+        return (self.registry.gauge("fedtpu_setup_cache_hits", help),
+                self.registry.gauge("fedtpu_setup_cache_misses", help))
+
+    def _on_duration(self, event: str, duration: float, **_kw) -> None:
+        kind = COMPILE_DURATION_EVENTS.get(event)
+        if kind is None:
+            return
+        end = time.perf_counter()  # the listener runs as the interval closes
+        with self._lock:
+            inside = 0.0
+            while self._ended and self._ended[-1][0] >= end - duration:
+                inside += self._ended.pop()[1]
+            self._ended.append((end, duration))
+        self._charge(f"{self.phase}.{kind}", max(0.0, duration - inside))
+
+    def _on_event(self, event: str, **_kw) -> None:
+        if event == CACHE_HIT_EVENT:
+            self._cache_gauges()[0].inc()
+        elif event == CACHE_MISS_EVENT:
+            self._cache_gauges()[1].inc()
+
+    def __enter__(self) -> "SetupCompileListener":
+        from jax import monitoring
+
+        # A phase that can compile states both counts: 0 is a reading.
+        for gauge in self._cache_gauges():
+            gauge.inc(0)
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+        monitoring.register_event_listener(self._on_event)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        from jax import monitoring
+
+        monitoring.unregister_event_duration_listener(self._on_duration)
+        monitoring.unregister_event_listener(self._on_event)
+
+
 # --------------------------------------------------------- compile watcher
-_COMPILE_EVENT_SUBSTR = "backend_compile"
+RECOMPILES_KEPT = 8  # the last steady-state recompiles snapshot() lists
 
 
 class CompileWatcher:
     """Count + time XLA compilations via ``jax.monitoring`` duration events
-    (``/jax/core/compile/backend_compile_duration`` fires once per backend
-    compile). After :meth:`mark_steady` — the owner's signal that every
-    program it intends to run has warmed up — any further compile is a
-    *steady-state recompile*: it warns, flight-records, and bumps
-    ``fedtpu_xla_recompiles_steady_total``, because a recompile inside the
-    round loop silently turns a ~ms round into a multi-second one.
+    (:data:`BACKEND_COMPILE_EVENT` fires once per backend compile, with the
+    program's ``fun_name``). After :meth:`mark_steady` — the owner's signal
+    that every program it intends to run has warmed up — any further
+    compile is a *steady-state recompile*: it warns, flight-records, and
+    bumps ``fedtpu_xla_recompiles_steady_total{fun_name}``, each naming the
+    program, because a recompile inside the round loop silently turns a
+    ~ms round into a multi-second one.
 
     ``install()``/``uninstall()`` manage the process-global listener; one
     active watcher per process (the registration API has no scoping)."""
@@ -651,19 +740,25 @@ class CompileWatcher:
         self.compiles = 0
         self.compile_seconds = 0.0
         self.recompiles_after_steady = 0
+        self._recompiled: List[Dict[str, Any]] = []
         self._steady = False
         self._installed = False
         self._lock = threading.Lock()
 
-    def _listener(self, event: str, duration: float, **kwargs) -> None:
-        if not self._installed or _COMPILE_EVENT_SUBSTR not in event:
+    def _listener(self, event: str, duration: float,
+                  fun_name: str = "", **kwargs) -> None:
+        if not self._installed or event != BACKEND_COMPILE_EVENT:
             return
+        fun_name = str(fun_name) or "unknown"
         with self._lock:
             self.compiles += 1
             self.compile_seconds += duration
             steady = self._steady
             if steady:
                 self.recompiles_after_steady += 1
+                self._recompiled.append(
+                    {"fun_name": fun_name, "seconds": round(duration, 4)})
+                del self._recompiled[:-RECOMPILES_KEPT]
         tel = self.telemetry
         if tel is not None:
             tel.counter(
@@ -676,19 +771,23 @@ class CompileWatcher:
             ).observe(duration)
         if steady:
             log.warning(
-                "steady-state XLA recompile (%.2fs): a program shape or "
-                "constant drifted after warmup — the classic silent round "
-                "slowdown (compiles so far: %d)", duration, self.compiles,
+                "steady-state XLA recompile of %s (%.2fs): a program shape "
+                "or constant drifted after warmup — the classic silent "
+                "round slowdown (compiles so far: %d)",
+                fun_name, duration, self.compiles,
             )
             if tel is not None:
                 tel.counter(
                     "fedtpu_xla_recompiles_steady_total",
                     "XLA compilations after the owner declared steady "
-                    "state (each one is a latent perf bug)",
+                    "state (each one is a latent perf bug), by the "
+                    "program that recompiled",
+                    labels={"fun_name": fun_name},
                 ).inc()
             if self.flight is not None:
                 self.flight.record(
                     "xla_recompile",
+                    fun_name=fun_name,
                     duration_s=round(duration, 4),
                     compiles_total=self.compiles,
                 )
@@ -733,6 +832,7 @@ class CompileWatcher:
                 "compile_seconds": round(self.compile_seconds, 4),
                 "steady": self._steady,
                 "recompiles_after_steady": self.recompiles_after_steady,
+                "recompiled": list(self._recompiled),
             }
 
 
@@ -813,6 +913,21 @@ class CaptureWindow:
     @property
     def active(self) -> bool:
         return self._ctx is not None
+
+    def stamp(self, trace_id: Optional[str]) -> None:
+        """The federation's trace id, where it is known only after the
+        window opened (a round-0 window of the run CLI opens before the
+        engine, and its tracer, exist): an open window's sidecar is
+        rewritten with it, its ``wall_start`` kept."""
+        self.trace_id = trace_id
+        if self._ctx is None or not trace_id:
+            return
+        with open(os.path.join(self.trace_dir, PROFILE_META)) as fh:
+            meta = json.load(fh)
+        write_profile_meta(
+            self.trace_dir, role=self.role, trace_id=trace_id,
+            extra={k: meta[k] for k in ("wall_start", "round_window")},
+        )
 
     def maybe_start(self, first_round: int, last_round: int = None) -> None:
         """Open the window if block ``[first_round, last_round]`` overlaps

@@ -191,6 +191,14 @@ class MetricsRegistry:
                   buckets: Iterable[float] = DEFAULT_BUCKETS) -> Histogram:
         return self._get(Histogram, name, help, labels, buckets=buckets)
 
+    def forget(self, prefix: str) -> None:
+        """Drop every metric whose name starts with ``prefix``: the facts
+        of an owner that has been replaced (a process's set-up gauges
+        describe its newest engine). A name stays bound to its kind."""
+        with self._lock:
+            for key in [k for k in self._metrics if k[0].startswith(prefix)]:
+                del self._metrics[key]
+
     def snapshot(self) -> dict:
         """Plain-dict dump: {name: [{"labels": {...}, ...metric fields}]}."""
         with self._lock:

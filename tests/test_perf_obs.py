@@ -1,10 +1,9 @@
 """Performance observatory (fedtpu.obs.profile + tools): MFU/roofline
-accounting, compile observability, device-trace fusion, idle-gap
-attribution, and the perf-regression CI harness.
+accounting, compile observability, device-trace fusion and idle-gap
+attribution.
 
-Everything here is tier-1 cheap: pure-python math on synthetic inputs,
-two tiny jit compiles, one tiny-engine round, and the seconds-scale
-perf_ci harness against the committed baseline. The full bench legs
+Everything here is tier-1 cheap: pure-python math on synthetic inputs, a
+few tiny jit compiles and one tiny-engine round. The full bench legs
 (``--mfu-profile``, ``--mfu-microbench``) re-run as ``slow`` in
 tests/test_bench.py; their committed artifacts are contract-checked here.
 """
@@ -31,7 +30,6 @@ from fedtpu.obs.profile import (
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "tools"))
 import gap_analyze  # noqa: E402
-import perf_ci  # noqa: E402
 import span_check  # noqa: E402
 import trace_merge  # noqa: E402
 
@@ -342,7 +340,8 @@ def test_compile_watcher_counts_and_flags_steady_recompiles():
         assert snap["recompiles_after_steady"] >= 1
         parsed = parse_prometheus_text(prometheus_text(tel.registry))
         assert parsed["fedtpu_xla_compiles_total"][""] == snap["compiles"]
-        assert (parsed["fedtpu_xla_recompiles_steady_total"][""]
+        # The recompile counter is labelled by the program that recompiled.
+        assert (sum(parsed["fedtpu_xla_recompiles_steady_total"].values())
                 == snap["recompiles_after_steady"])
     finally:
         watcher.uninstall()
@@ -350,6 +349,63 @@ def test_compile_watcher_counts_and_flags_steady_recompiles():
     w2 = CompileWatcher()
     w2.install()
     w2.uninstall()
+
+
+def test_compile_watcher_names_the_program_that_recompiled(caplog):
+    """A compile after mark_steady() is reported with jax's ``fun_name``:
+    in the warning, the flight record, the counter's label and the
+    snapshot's ``recompiled`` list."""
+    import jax
+    import jax.numpy as jnp
+
+    from fedtpu.obs import FlightRecorder
+
+    tel = Telemetry("basic")
+    flight = FlightRecorder(role="test")
+    watcher = CompileWatcher(telemetry=tel, flight=flight).install()
+    try:
+        def drifting_program(x, scale):
+            return x * scale
+
+        step = jax.jit(drifting_program, static_argnums=1)
+        step(jnp.ones(5), 2.0).block_until_ready()
+        watcher.mark_steady()
+        assert watcher.snapshot()["recompiled"] == []
+        with caplog.at_level("WARNING", logger="fedtpu.obs.profile"):
+            step(jnp.ones(5), 3.0).block_until_ready()  # a static drifted
+    finally:
+        watcher.uninstall()
+    snap = watcher.snapshot()
+    assert snap["recompiles_after_steady"] == 1
+    (row,) = snap["recompiled"]
+    name = "jit(drifting_program)"  # as jax names the compiled module
+    assert row["fun_name"] == name and row["seconds"] >= 0
+    assert f"steady-state XLA recompile of {name}" in caplog.text
+    parsed = parse_prometheus_text(prometheus_text(tel.registry))
+    assert parsed["fedtpu_xla_recompiles_steady_total"] == {
+        f"fun_name={name}": 1.0}
+    records = [e for e in flight.snapshot() if e["kind"] == "xla_recompile"]
+    assert [e["fun_name"] for e in records] == [name]
+
+
+def test_compile_watcher_keeps_the_last_few_recompiles_only():
+    from fedtpu.obs.profile import BACKEND_COMPILE_EVENT, RECOMPILES_KEPT
+
+    watcher = CompileWatcher()
+    watcher._installed = True  # the listener alone, no jax registration
+    watcher.mark_steady()
+    for i in range(RECOMPILES_KEPT + 3):
+        watcher._listener(BACKEND_COMPILE_EVENT, 0.5, fun_name=f"p{i}")
+    # Other durations of jax's are not compiles; a compile without a name
+    # (an older jax) is still counted.
+    watcher._listener("/jax/core/compile/jaxpr_trace_duration", 9.0,
+                      fun_name="traced")
+    watcher._listener(BACKEND_COMPILE_EVENT, 0.25)
+    snap = watcher.snapshot()
+    assert snap["compiles"] == snap["recompiles_after_steady"] == (
+        RECOMPILES_KEPT + 4)
+    assert [r["fun_name"] for r in snap["recompiled"]] == [
+        f"p{i}" for i in range(4, RECOMPILES_KEPT + 3)] + ["unknown"]
 
 
 # ------------------------------------------------------- capture windows
@@ -610,118 +666,6 @@ def test_span_check_polices_metric_names(tmp_path):
     # Labeled doc mentions document the base name.
     doc.write_text("`fedtpu_documented_total` `fedtpu_fake_metric{x=\"y\"}`")
     assert span_check.check_metrics(str(pkg), str(doc)) == []
-
-
-# ------------------------------------------------------------ perf CI
-def test_perf_ci_check_passes_on_committed_baseline(monkeypatch):
-    """The tier-1 perf gate itself: measure the live tree and compare
-    against the committed baseline — a real regression in any per-round
-    instrument fails this test."""
-    monkeypatch.delenv("FEDTPU_PERF_CI_INJECT", raising=False)
-    monkeypatch.setenv("FEDTPU_PERF_CI_REPS", "3")
-    with open(os.path.join(REPO, "artifacts", "PERF_BASELINE.json")) as fh:
-        baseline = json.load(fh)
-    assert baseline["schema_version"] == perf_ci.SCHEMA_VERSION
-    measured = perf_ci.measure()
-    assert set(measured["metrics"]) == set(baseline["metrics"])
-    verdict = perf_ci.compare(measured, baseline)
-    assert verdict["pass"] is True, verdict["failures"]
-    assert 0.25 <= verdict["calibration_scale"] <= 4.0
-
-
-def test_perf_ci_detects_2x_slowdown():
-    """Acceptance: --check demonstrably fails on a 2x slowdown. Pinned at
-    the compare layer with controlled noise floors so the verdict is
-    deterministic, not a race against scheduler jitter."""
-    base = {
-        "schema_version": perf_ci.SCHEMA_VERSION,
-        "metrics": {
-            "calibration_us": {"median_us": 100.0, "noise_floor_pct": 5.0},
-            "mfu_observe_us": {"median_us": 5.0, "noise_floor_pct": 5.0},
-            "span_trace_us": {"median_us": 6.0, "noise_floor_pct": 5.0},
-        },
-    }
-    good = json.loads(json.dumps(base))
-    verdict = perf_ci.compare(good, base)
-    assert verdict["pass"] is True
-    slow = json.loads(json.dumps(base))
-    slow["metrics"]["mfu_observe_us"]["median_us"] = 10.0  # the 2x
-    verdict = perf_ci.compare(slow, base)
-    assert verdict["pass"] is False
-    assert [f["metric"] for f in verdict["failures"]] == ["mfu_observe_us"]
-    f = verdict["failures"][0]
-    assert f["measured_us"] == 10.0 and f["measured_us"] > f["limit_us"]
-    # Dropping a metric from the harness is drift too, not a free pass.
-    gone = json.loads(json.dumps(base))
-    del gone["metrics"]["span_trace_us"]
-    verdict = perf_ci.compare(gone, base)
-    assert verdict["pass"] is False
-    assert "disappeared" in verdict["failures"][0]["problem"]
-
-
-def test_perf_ci_injection_hook_inflates_measurements(monkeypatch):
-    metrics = {
-        "mfu_observe_us": {"median_us": 5.0, "noise_floor_pct": 5.0},
-        "span_trace_us": {"median_us": 6.0, "noise_floor_pct": 5.0},
-    }
-    monkeypatch.setenv("FEDTPU_PERF_CI_INJECT", "mfu_observe_us=2.0")
-    perf_ci._apply_injection(metrics)
-    assert metrics["mfu_observe_us"]["median_us"] == 10.0
-    assert metrics["mfu_observe_us"]["injected_factor"] == 2.0
-    assert metrics["span_trace_us"]["median_us"] == 6.0
-    monkeypatch.setenv("FEDTPU_PERF_CI_INJECT", "all=2.0")
-    perf_ci._apply_injection(metrics)
-    assert metrics["span_trace_us"]["median_us"] == 12.0
-
-
-def test_perf_ci_check_cli_fails_on_injected_slowdown(tmp_path, monkeypatch):
-    """End-to-end --check exit codes: pass against a just-measured
-    baseline, fail when the injection hook doubles a low-noise metric."""
-    monkeypatch.delenv("FEDTPU_PERF_CI_INJECT", raising=False)
-    monkeypatch.setenv("FEDTPU_PERF_CI_REPS", "2")
-    measured = perf_ci.measure()
-    # Pin noise floors so the band is exactly the 75% minimum: this keeps
-    # the CLI-level assertion deterministic while the measurement itself
-    # stays real.
-    for row in measured["metrics"].values():
-        row["noise_floor_pct"] = 5.0
-    path = str(tmp_path / "baseline.json")
-    perf_ci.write_baseline(measured, path)
-    verdict = perf_ci.compare(measured, json.loads(open(path).read()))
-    assert verdict["pass"] is True
-    # Inject on specific metrics, NOT "all=": all= also doubles the
-    # calibration yardstick and partially neutralizes the check.
-    injected = json.loads(json.dumps(measured))
-    monkeypatch.setenv(
-        "FEDTPU_PERF_CI_INJECT",
-        "mfu_observe_us=2.0,counter_inc_us=2.0",
-    )
-    perf_ci._apply_injection(injected["metrics"])
-    verdict = perf_ci.compare(injected, json.loads(open(path).read()))
-    assert verdict["pass"] is False
-    assert {f["metric"] for f in verdict["failures"]} == {
-        "mfu_observe_us", "counter_inc_us",
-    }
-
-
-def test_perf_baseline_committed_artifact_contract():
-    path = os.path.join(REPO, "artifacts", "PERF_BASELINE.json")
-    assert os.path.exists(path), "artifacts/PERF_BASELINE.json missing"
-    with open(path) as fh:
-        baseline = json.load(fh)
-    assert baseline["schema_version"] == perf_ci.SCHEMA_VERSION
-    expected = {
-        "calibration_us", "span_trace_us", "counter_inc_us", "gauge_set_us",
-        "histogram_observe_us", "mfu_observe_us", "latency_summary_us",
-        "round_record_us", "prometheus_render_us", "trace_merge_us",
-        "gap_analyze_us", "mixed_precision_cast_us",
-        "partial_reduce_fold_us", "submit_partial_frame_us",
-        "hadamard_rotate_us", "randk_gather_us",
-    }
-    assert set(baseline["metrics"]) == expected
-    for row in baseline["metrics"].values():
-        assert row["median_us"] > 0
-        assert row["noise_floor_pct"] >= 0
 
 
 def test_mfu_microbench_committed_gate():

@@ -166,6 +166,56 @@ def test_engine_spans_off_mode_leaves_none(tmp_path):
                 if e["name"].startswith("fed.")]
 
 
+def test_a_session_over_a_build_holds_the_set_up_spans(tmp_path):
+    """A session opened BEFORE the engine is built (what a round-0
+    ``--profile-rounds`` window does) records set-up's phases on the device
+    operations' clock, nested as the program nests them."""
+    cfg = RoundConfig(
+        model="mlp", num_classes=10, opt=OptimizerConfig(learning_rate=0.05),
+        data=DataConfig(dataset="synthetic", batch_size=4, partition="iid",
+                        num_examples=64),
+        fed=FedConfig(num_clients=2), steps_per_round=2,
+    )
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fed = Federation(cfg, seed=0)
+        jax.block_until_ready(fed.step().loss)
+        jax.block_until_ready(fed.step().loss)
+    finally:
+        jax.profiler.stop_trace()
+    spans = [e for e in gap_analyze.load_capture(str(tmp_path))
+             if e["name"].startswith("fed.")]
+    names = [e["name"] for e in spans]
+    for name in ("fed.setup.build", "fed.setup.build.partition",
+                 "fed.setup.build.init_state", "fed.setup.build.programs",
+                 "fed.setup.place_state", "fed.setup.first_dispatch",
+                 "fed.setup.first_dispatch.device_data",
+                 "fed.setup.first_dispatch.device_data.host",
+                 "fed.setup.first_dispatch.device_data.h2d"):
+        assert names.count(name) == 1, name
+    assert names.count("fed.round") == names.count("fed.enqueue") == 2
+
+    def inside(child, parent):
+        return (parent["start_ns"] <= child["start_ns"] and
+                child["start_ns"] + child["dur_ns"]
+                <= parent["start_ns"] + parent["dur_ns"])
+
+    one = {e["name"]: e for e in spans if e["name"].startswith("fed.setup.")}
+    for child in ("partition", "init_state", "programs"):
+        assert inside(one[f"fed.setup.build.{child}"], one["fed.setup.build"])
+    first_round = min((e for e in spans if e["name"] == "fed.round"),
+                      key=lambda e: e["start_ns"])
+    assert inside(one["fed.setup.first_dispatch"], first_round)
+    assert inside(one["fed.setup.first_dispatch.device_data"],
+                  one["fed.setup.first_dispatch"])
+    for name in ("fed.plan", "fed.enqueue"):
+        first = min((e for e in spans if e["name"] == name),
+                    key=lambda e: e["start_ns"])
+        assert inside(first, one["fed.setup.first_dispatch"])
+    # The device's side of set-up is in the same capture.
+    assert gap_analyze.analyze_capture(str(tmp_path))["device_ops"] > 0
+
+
 def test_basic_span_keeps_nothing_without_a_session():
     from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
@@ -271,6 +321,41 @@ def test_hand_made_overlap_and_nested_while():
                       "fed.enqueue": pytest.approx(0.4),
                       "fed.round": pytest.approx(0.05),
                       gap_analyze.CALLER: pytest.approx(0.05)}
+    assert sum(phases.values()) == pytest.approx(report["device_idle_us"])
+
+
+def test_hand_made_set_up_idle_time_goes_to_the_set_up_phases():
+    """Between the model's init and the first round's execution the device
+    is idle while the host places state, uploads the dataset and loads the
+    round program: each gap is charged to the innermost ``fed.setup.*``
+    phase over it, like any ``fed.`` span."""
+    events = [
+        _op("fusion.init", 0, 100),  # init_state's program
+        _span("fed.setup.build", 0, 400),
+        _span("fed.setup.build.init_state", 0, 150),
+        _span("fed.setup.place_state", 200, 150),
+        _span("fed.round", 500, 1000),
+        _span("fed.setup.first_dispatch", 500, 900),
+        _span("fed.plan", 500, 300),
+        _span("fed.setup.first_dispatch.device_data", 520, 250),
+        _span("fed.setup.first_dispatch.device_data.h2d", 600, 170),
+        _span("fed.enqueue", 800, 600),
+        _op("while.1", 1450, 500, "fed.local_step"),
+    ]
+    report = gap_analyze.reduce_events(events, min_gap_us=0.0)
+    phases = {r["span"]: r["us"] for r in report["by_phase"]}
+    assert phases == {
+        "fed.setup.build.init_state": pytest.approx(0.05),
+        "fed.setup.build": pytest.approx(0.1),  # its own: [150,200), [350,400)
+        "fed.setup.place_state": pytest.approx(0.15),
+        gap_analyze.CALLER: pytest.approx(0.1),  # [400, 500): sut, harness
+        "fed.plan": pytest.approx(0.05),  # [500,520) and [770,800)
+        "fed.setup.first_dispatch.device_data": pytest.approx(0.08),
+        "fed.setup.first_dispatch.device_data.h2d": pytest.approx(0.17),
+        "fed.enqueue": pytest.approx(0.6),  # the cache load, the launch
+        # fed.setup.first_dispatch keeps nothing: its children cover it.
+        "fed.round": pytest.approx(0.05),  # until the device starts
+    }
     assert sum(phases.values()) == pytest.approx(report["device_idle_us"])
 
 

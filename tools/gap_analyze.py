@@ -37,7 +37,13 @@ The analyzer
    charges each to the host spans over it, innermost first (``fed.plan``
    inside ``fed.round``, not ``fed.round``); what no span covers is the
    ``caller``'s (its sync and read between two ``step()`` calls):
-   ``by_phase`` over all gaps and the ``--top`` longest in detail.
+   ``by_phase`` over all gaps and the ``--top`` longest in detail. A
+   capture that holds set-up (a ``--profile-rounds`` window from round 0
+   opens before the engine is built) charges the gaps between the model's
+   init and the first round's execution to the ``fed.setup.*`` phases the
+   same way: ``fed.setup.place_state``,
+   ``fed.setup.first_dispatch.device_data.h2d``, the first
+   ``fed.enqueue`` (the round program's compile or cache load).
 
 A capture whose device time carries no scope at all was run from an
 executable compiled by another commit: JAX's persistent compile cache keys

@@ -2,10 +2,12 @@
 """Span-, scope- and metric-name drift check: everything emitted must be
 documented.
 
-Scans ``fedtpu/`` for literal span names passed to ``*.span("name", ...)``,
+Scans ``fedtpu/`` for literal span names passed to ``*.span("name", ...)``
+or ``*.phase("name", ...)`` (a set-up phase is a span plus a gauge),
 literal ``jax.named_scope("name")`` scopes of the round program, literal
 ``TraceAnnotation("name")`` / ``StepTraceAnnotation("name")`` annotations
-and literal metric names passed to ``.counter/.gauge/.histogram(...)``, and
+and literal metric names passed to
+``.counter/.gauge/.histogram/.setup_gauge(...)``, and
 verifies each appears as inline code (`` `name` ``) in
 ``docs/OBSERVABILITY.md``. Catches the silent failure mode where a new
 subsystem adds spans or ``fedtpu_*`` metrics (or renames one) and the
@@ -33,10 +35,12 @@ from typing import Dict, List, Set
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# Literal first argument of a .span( call. Variables/f-strings never match
-# — fedtpu's span names are deliberately all literal (greppability is the
-# point of a fixed span vocabulary).
-_SPAN_CALL = re.compile(r"""\.span\(\s*(['"])([A-Za-z0-9_.:-]+)\1""")
+# Literal first argument of a .span( or .phase( call. Variables/f-strings
+# never match — fedtpu's span names are deliberately all literal
+# (greppability is the point of a fixed span vocabulary).
+_SPAN_CALL = re.compile(
+    r"""\.(?:span|phase)\(\s*(['"])([A-Za-z0-9_.:-]+)\1"""
+)
 # Literal argument of a named_scope( call or decorator: a layer of the round
 # program, written into the compiled module's op_name metadata.
 _SCOPE_CALL = re.compile(r"""named_scope\(\s*(['"])([A-Za-z0-9_.:-]+)\1""")
@@ -46,11 +50,12 @@ _ANNOTATION_CALL = re.compile(
 )
 TRACE_PREFIX = "fed."
 HARNESS_SPANS = ("dispatch", "sync", "record.read")  # benchmark/run.py's
-# Literal first argument of a .counter(/.gauge(/.histogram( call on the
-# telemetry facade or registry. Only the framework namespace is policed:
-# ad-hoc test instruments don't start with fedtpu_.
+# Literal first argument of a .counter(/.gauge(/.histogram(/.setup_gauge(
+# call on the telemetry facade or registry. Only the framework namespace
+# is policed: ad-hoc test instruments don't start with fedtpu_.
 _METRIC_CALL = re.compile(
-    r"""\.(?:counter|gauge|histogram)\(\s*(['"])(fedtpu_[A-Za-z0-9_]+)\1"""
+    r"""\.(?:counter|gauge|histogram|setup_gauge)\(\s*"""
+    r"""(['"])(fedtpu_[A-Za-z0-9_]+)\1"""
 )
 _INLINE_CODE = re.compile(r"`([^`]+)`")
 

@@ -18,7 +18,10 @@ only when every leg passed.
   kernels  every ``pallas_call`` reachable from ``fedtpu.ops.compression``
            through Mosaic at the shapes the codecs hand it, bitwise against
            the jnp bodies; the Hadamard rotation at the 2^20-column row
-           against the wire codec's numpy butterfly.
+           against the wire codec's numpy butterfly; the attention core's and
+           the gated delta rule's kernels against their plain bodies,
+           bfloat16, 1,536 tokens, output and gradients within 2e-2 of the
+           plain body's largest value.
   rotq     ``Federation(compression="rotq", delta_layout="flat")`` rounds.
   grpc     an in-process ``PrimaryServer`` + four ``serve_client`` agents
            over real localhost gRPC, flat layout, stream pipeline, top-k.
@@ -399,6 +402,47 @@ def leg_kernels():
                 f"attention kernels differ from the plain body by {errs}: {name}")
         out[f"{name}_first_s"] = round(t_attn, 3)
         out[f"{name}_max_rel_err"] = errs
+    # The gated delta rule's kernels against the plain chunks, bfloat16 at the
+    # hybrid's head sizes (four key heads of 128 with two value heads each),
+    # 1,536 tokens in chunks of 64, gates as the layer makes them: output and
+    # the five gradients as shares of the plain chunks' largest value, held to
+    # 2e-2 (bfloat16 rounding; the kernels alone read 0 to 0.0075 at 8,192
+    # tokens, PERF.md, PR 41).
+    from fedtpu.models import qwen3_next as qn
+    from fedtpu.ops import delta_rule_kernels as dr
+
+    t, heads, values, width, chunk = 1536, 4, 2, 128, 64
+    unit = lambda a: a / np.sqrt(np.sum(a * a, -1, keepdims=True) + 1e-6)
+    draw = lambda *shape: rng.normal(size=shape).astype(np.float32)
+    ops = (
+        jnp.asarray(unit(draw(t, heads, width)) * width ** -0.5, jnp.bfloat16),
+        jnp.asarray(unit(draw(t, heads, width)), jnp.bfloat16),
+        jnp.asarray(draw(t, heads, values, width), jnp.bfloat16),
+        -jax.nn.softplus(jnp.asarray(draw(t, heads, values))),
+        jax.nn.sigmoid(jnp.asarray(draw(t, heads, values))),
+    )
+    ct = jnp.asarray(draw(t, heads, values, width), jnp.bfloat16)
+    name = f"gated_delta_rule[{t},{heads},{values},{width}]"
+    require(dr.takes(*ops, chunk), f"the delta rule's kernels do not engage: {name}")
+    both = [
+        jax.jit(lambda *a, f=f: (lambda o, vjp: (o,) + vjp(ct))(*jax.vjp(f, *a)))
+        for f in (lambda *a: dr.gated_delta_rule(*a, chunk),
+                  lambda *a: qn._plain_chunks(*a, chunk))
+    ]
+    require("tpu_custom_call" in both[0].lower(*ops).as_text(),
+            f"the delta rule did not lower through Mosaic: {name}")
+    t_rule, got = timed(lambda: both[0](*ops), jax.block_until_ready)
+    errs = [
+        float(jnp.max(jnp.abs(g.astype(jnp.float32) - w.astype(jnp.float32)))
+              / jnp.max(jnp.abs(w.astype(jnp.float32))))
+        for g, w in zip(got, both[1](*ops))
+    ]
+    print(f"{name}: o, dq, dk, dv, dg, dbeta differ from the plain chunks by "
+          f"{errs} of their largest value (limit 2e-2)", flush=True)
+    require(max(errs) <= 2e-2,
+            f"the delta rule's kernels differ from the plain chunks by {errs}: {name}")
+    out[f"{name}_first_s"] = round(t_rule, 3)
+    out[f"{name}_max_rel_err"] = errs
     return out
 
 

@@ -32,16 +32,27 @@ initialised 0, is every norm but the gated one:
   ``gdn_chunk`` tokens at a time. With ``G_i`` the chunk's running sum of
   ``g`` and ``A_ij = beta_i exp(G_i - G_j) k_i.k_j`` for ``j < i``, the
   chunk's ``u`` solve the unit lower-triangular system ``(I + A) U = beta (V -
-  exp(G) K S_0)`` (float32), so ``U = U~ - W S_0`` with ``U~`` and ``W`` from
-  one solve over every chunk at once; then ``O = exp(G) Q S_0 + (exp(G_i -
-  G_j) q_i.k_j)_{j <= i} U`` and ``S_C = exp(G_C) S_0 + (exp(G_C - G) K)^T
-  U``, a ``lax.scan`` over the chunks that is rematerialised, so the backward
-  pass holds one chunk-start state a chunk. Every exponent is a difference
-  ``G_i - G_j`` with ``j <= i``, never positive. A length the chunk does not
-  divide is padded with tokens of ``beta = 0``, ``g = 0``, which leave the
-  state as it is. Two operands are handed over with their order in memory
-  stated (:func:`_lying`: no value changes): q and k heads-major, and the
-  scans' stacked ``(exp(G_i - G_j) q_i.k_j)`` chunk-major.
+  exp(G) K S_0)`` (float32), so ``U = U~ - W S_0`` with ``[U~ | W] = (I +
+  A)^-1 beta [V | exp(G) K]``; then ``O = exp(G) Q S_0 + (exp(G_i - G_j)
+  q_i.k_j)_{j <= i} U`` and ``S_C = exp(G_C) S_0 + (exp(G_C - G) K)^T U``.
+  Every exponent is a difference ``G_i - G_j`` with ``j <= i``, never
+  positive. A length the chunk does not divide is padded with tokens of
+  ``beta = 0``, ``g = 0``, which leave the state as it is. One function, two
+  bodies, chosen by the backend and the operands' shapes alone
+  (:func:`fedtpu.ops.delta_rule_kernels.takes`) and counted in
+  ``fedtpu_delta_rule_cores_traced_total{body}``: on a TPU, at heads of whole
+  lanes (the published 128) and a chunk of 16, 32, 64 or 128, two fused
+  kernels, forward and backward, that keep a chunk's matrices, the inverse
+  and the state in VMEM and leave in memory a chunk-start state and an
+  inverse a chunk (:mod:`fedtpu.ops.delta_rule_kernels`); everywhere else
+  (the CPU, the tiny twin's heads of 64, the tests' yardstick) the plain
+  chunks: every chunk's matrices at once, one triangular solve, a
+  rematerialised ``lax.scan`` over the chunks that carries the state. q and
+  k are handed over with their order in memory stated (:func:`_lying`: no
+  value changes), heads-major, and so is the float32 copy their
+  normalisation reads: left to itself the TPU compiler turns ``[T, heads x
+  dk]`` into ``[T, heads, dk]`` (heads in the sublanes) for the sum over
+  ``dk`` by a copy without the program's scope.
 - Gated softmax layer: ``[q, gate] = W_q x`` (a head's ``head_dim`` of ``q``,
   then its ``head_dim`` of ``gate``), ``k = W_k x``, ``v = W_v x``; ``q, k <-
   Norm(q), Norm(k)`` over ``head_dim``; rotary turns (rotate-half pairing:
@@ -71,7 +82,8 @@ constructor (``RoundConfig.model_args``); the defaults are the published ones.
 
 Device time is named under ``fed.local_step.fwd_bwd.``: ``embed``,
 ``linear_attention`` (``.proj``, ``.conv``, ``.core``: gates, normalisation
-and the chunked rule; ``.out``: gated norm and ``W_o``), ``attention``
+and the chunked rule, whichever body runs it; ``.out``: gated norm and
+``W_o``), ``attention``
 (``.core``) for the softmax layer, ``moe`` (``.router``, ``.dispatch``,
 ``.experts``, ``.combine``), ``lm_loss``.
 """
@@ -92,8 +104,11 @@ from fedtpu.models.lm_layers import (
     KEEP, SCOPE, Linear, SwiGLU, _expert_init, _rms, _row_loss_parts,
     attention_core, held_range, routed_experts, sizes_from_keywords)
 from fedtpu.models.registry import register
+from fedtpu.obs.registry import get_global_registry
+from fedtpu.ops import delta_rule_kernels
 
 L2_EPS = 1e-6  # under the square root of q's and k's normalisation
+DELTA_CORES_TRACED = "fedtpu_delta_rule_cores_traced_total"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -190,14 +205,30 @@ def gated_delta_rule(q, k, v, g, beta, chunk):
     value heads a key head), ``g, beta [T, Hk, R]`` float32. Returns ``o [T,
     Hk, R, dv]`` in ``v``'s dtype. Operands of ``v``'s dtype go into the
     products, sums are float32, and so are the gates, the triangular solve and
-    the state between chunks."""
-    t, dtype = q.shape[0], v.dtype
+    the state between chunks. One function of the same operands by the body
+    its shapes and the backend call for: the fused kernels
+    (:mod:`fedtpu.ops.delta_rule_kernels`) or the plain chunks below. Counted
+    in the process's registry by the body taken, once a core traced."""
+    t = q.shape[0]
     pad = -t % chunk
     if pad:
         q, k, v, g, beta = (
             jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
             for a in (q, k, v, g, beta))
-    n = (t + pad) // chunk
+    kernel = delta_rule_kernels.takes(q, k, v, g, beta, chunk)
+    get_global_registry().counter(
+        DELTA_CORES_TRACED, "gated delta rule cores traced, by the body taken",
+        labels={"body": "kernel" if kernel else "plain"}).inc()
+    body = delta_rule_kernels.gated_delta_rule if kernel else _plain_chunks
+    return body(q, k, v, g, beta, chunk)[:t]
+
+
+def _plain_chunks(q, k, v, g, beta, chunk):
+    """:func:`gated_delta_rule` at a length the chunk divides, in plain
+    ``jax.numpy``: every chunk's matrices at once, one triangular solve, a
+    rematerialised scan over the chunks."""
+    dtype = v.dtype
+    n = q.shape[0] // chunk
     cut = lambda a: a.reshape((n, chunk) + a.shape[1:])
     q, k, v, g, beta = cut(q), cut(k), cut(v), cut(g), cut(beta)
     f32 = dict(preferred_element_type=jnp.float32)
@@ -225,9 +256,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk):
         a_mat, rhs, left_side=True, lower=True, unit_diagonal=True)
     dv = v.shape[-1]
     u_free, w = solved[..., :dv].astype(dtype), solved[..., dv:].astype(dtype)
-    # As the scans read it, a chunk's matrix at a time: chunk index major
-    # (the batched products that made it leave the chunk index minor).
-    attend = _lying((decay * qk).astype(dtype), 0, 1, 2, 3, 4)
+    attend = (decay * qk).astype(dtype)
     q_run = (jnp.exp(run)[..., None] * q_h).astype(dtype)
     last = run[..., -1:]  # G_C [n, Hk, R, 1]
     k_left = (jnp.exp(last - run)[..., None] * k_h).astype(dtype)
@@ -247,7 +276,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk):
     zero = jnp.zeros(v.shape[2:4] + (k.shape[-1], dv), jnp.float32)
     _, o = jax.lax.scan(one_chunk, zero, (u_free, w, attend, q_run, k_left, keep))
     # [n, Hk, R, C, dv] -> [T, Hk, R, dv]
-    return jnp.moveaxis(o, 3, 1).reshape((n * chunk,) + o.shape[1:3] + (dv,))[:t]
+    return jnp.moveaxis(o, 3, 1).reshape((n * chunk,) + o.shape[1:3] + (dv,))
 
 
 class GatedDeltaNet(nn.Module):
@@ -282,12 +311,12 @@ class GatedDeltaNet(nn.Module):
             with jax.named_scope(SCOPE + "linear_attention.conv"):
                 qkv = jax.nn.silu(causal_conv(qkv, conv_kernel))
             with jax.named_scope(SCOPE + "linear_attention.core"):
-                unit = lambda a: (
-                    a.astype(jnp.float32) * jax.lax.rsqrt(jnp.sum(
-                        jnp.square(a.astype(jnp.float32)), -1, keepdims=True)
-                        + L2_EPS))
+                def unit(a):
+                    a = _lying(a.astype(jnp.float32), 1, 0, 2)
+                    return a * jax.lax.rsqrt(
+                        jnp.sum(jnp.square(a), -1, keepdims=True) + L2_EPS)
                 # q and k heads-major: time stays in the sublanes, where
-                # the projection's [T, heads x dk] has it and the chunks'
+                # the projection's [T, heads x dk] has it and the rule's
                 # products want it.
                 q = _lying((unit(qkv[:, :hk * dk].reshape(t, hk, dk)) * dk ** -0.5
                             ).astype(x.dtype), 1, 0, 2)

@@ -167,42 +167,58 @@ def _mixer_gradient_text(one_chip, cls, scope):
     return compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
 
 
-def test_the_delta_net_layer_compiles_at_the_published_widths_with_its_scopes(one_chip):
-    """16 key and 32 value heads of 128 over 8,192 tokens in chunks of 64:
-    every product of the layer carries the layer's scope, and those of the
-    recurrence (the chunks' ``k k^T`` and ``q k^T``, the triangular solve the
-    compiler expands into products at HIGHEST precision, the scan's state
-    products) the core's, which ``gdn.core_roofline`` divides by; the scan
-    over the 128 chunks is a loop under that scope, once in the layer's
-    rematerialised forward and once backward; no tensor has a state a token
-    (``[8192, 32, 128, 128]``: 17 GB in float32), the layer's temporaries stay
-    under 4 GB, and the layouts the program states are the compiled ones."""
+def test_the_delta_net_layer_compiles_at_the_published_widths_with_its_scopes(
+        one_chip, monkeypatch):
+    """16 key and 32 value heads of 128 over 8,192 tokens in chunks of 64. The
+    test says "Mosaic" where the program asks, and the kernels take these
+    shapes: the recurrence is TWO kernels, ``gated_delta_rule_fwd`` (its
+    output, the chunks' states and inverses are kept, so the rematerialised
+    forward pass runs none) and ``gated_delta_rule_bwd``, both under the scope
+    ``gdn.core_roofline`` divides by, as is every relayout around them; q and
+    k go in heads-major as the program states them, v and the output as
+    ``[T, heads x width]``. Nothing of the plain chunks is left: no loop and
+    no triangular solve, no product under the core's scope outside the
+    kernels, no float32 tensor of a chunk's matrices ``[128, 16, 2, 64, 64]``
+    or of the solve's sides ``[128, 16, 2, 64, 256]`` in any order of axes
+    (the kernels' own residuals are the states ``[16, 128, 2, 128, 128]`` and
+    the inverses, two value heads' side by side, ``[16, 128, 64, 128]``), no
+    tensor with a state a token (``[8192, 32, 128, 128]``); every product of
+    the layer carries the layer's scope; the layer's temporaries stay under 4
+    GB; and no copy that lacks the program's metadata, which a capture reads
+    as ``_unscoped_``, turns q or k."""
+    from fedtpu.ops import delta_rule_kernels as dr
+
+    monkeypatch.setattr(dr, "_mode", lambda interpret: "mosaic")
     text, temp = _mixer_gradient_text(
         one_chip, lambda m: m.GatedDeltaNet, "linear_attention")
     scope = "fed.local_step.fwd_bwd.linear_attention"
+    assert dr.SCOPE == scope + ".core"
+    kernels = [l for l in text.splitlines()
+               if " custom-call(" in l and 'custom_call_target="tpu_custom_call"' in l]
+    assert sorted(re.search(r"%(gated_delta_rule_\w+?)[.\d]* =", l).group(1)
+                  for l in kernels) == [
+        "gated_delta_rule_bwd", "gated_delta_rule_fwd"], kernels
+    for line in kernels:
+        assert dr.SCOPE in re.search(r'op_name="([^"]*)"', line).group(1), line
+        operands = line[line.index("operand_layout_constraints="):line.index("metadata=")]
+        assert operands.count("bf16[16,8192,128]{2,1,0}") == 2, operands
+        assert "bf16[8192,4096]{1,0}" in operands and "f32[16,128,4,64]" in operands
+    forward = next(l for l in kernels if "gated_delta_rule_fwd" in l)
+    assert "f32[16,128,2,128,128]" in forward and "f32[16,128,64,128]" in forward
+    assert not [l for l in text.splitlines() if " while(" in l]
+    assert "triangular_solve" not in text
     products = [l for l in text.splitlines() if " convolution(" in l]
     assert products and all(scope in l for l in products)
-    assert sum(scope + ".core" in l for l in products) >= 20
-    solves = [l for l in products if "triangular_solve" in l]
-    assert solves and all(scope + ".core" in l for l in solves)
-    assert all("operand_precision={highest,highest}" in l for l in solves)
-    loops = [l for l in text.splitlines() if " while(" in l]
-    assert len(loops) == 2 and all(scope + ".core/while" in l for l in loops)
-    assert "tpu_custom_call" not in text
-    for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text):
+    assert not any(dr.SCOPE in l for l in products)  # the rule's are in the kernels
+    gone = [sorted([128, 16, 2, 64, 64]), sorted([128, 16, 2, 64, 256])]
+    for kind, dims in re.findall(r"(f32|bf16)\[([0-9,]+)\]", text):
         dims = [int(d) for d in dims.split(",")]
         assert math.prod(dims) < 8192 * 32 * 128 * 128, dims
+        assert kind != "f32" or sorted(d for d in dims if d > 1) not in gone, dims
     assert temp < 4e9
-    # What the program says of layouts holds (PR 39): the scans' stacked
-    # ``attend`` lies chunk-major wherever it stands, q and k lie heads-major
-    # (time in the sublanes, as the projection wrote them), and no copy that
-    # lacks the program's metadata, which a capture reads as ``_unscoped_``,
-    # turns either: not the ``bf16[128,16,2,64,64]`` relayouts between the
-    # stacked operand and the scans, not the ``f32[1024,8,16,128]`` copies of
-    # q and k for the solve's right-hand side.
-    assert set(re.findall(r"= bf16\[128,16,2,64,64\]\{([0-9,]+)", text)) == {"4,3,2,1,0"}
     assert not [l for l in text.splitlines() if "op_name=" not in l and re.search(
-        r"= (?:bf16\[128,16,2,64,64\]|f32\[1024,8,16,128\])\S* copy\(", l)]
+        r"= (?:bf16|f32)\[(?:1024,8,16,128|8192,16,128|16,8192,128|8192,2048)\]"
+        r"\S* copy\(", l)]
 
 
 def test_the_grouped_softmax_layer_compiles_at_the_published_widths_with_its_scopes(

@@ -223,10 +223,14 @@ def make_local_update(
     grad_fn = jax.value_and_grad(
         token_loss_fn if tokens_task else loss_fn, has_aux=True
     )
-    in_micro_batches = tokens_task and 0 < micro_rows < cfg.data.batch_size
-    if in_micro_batches and (
-        cfg.data.batch_size % micro_rows or cfg.opt.momentum or cfg.opt.nesterov
-    ):
+    buffers = bool(cfg.opt.momentum or cfg.opt.nesterov)
+    # micro_rows equal to the batch is ONE micro-batch through the same path
+    # under plain SGD, and the whole-batch step, as ever, with momentum.
+    in_micro_batches = tokens_task and (
+        0 < micro_rows < cfg.data.batch_size
+        or (micro_rows == cfg.data.batch_size and not buffers)
+    )
+    if in_micro_batches and (cfg.data.batch_size % micro_rows or buffers):
         raise ValueError(
             f"micro_batch_rows={micro_rows} needs a batch_size it divides "
             f"({cfg.data.batch_size}) and plain SGD (momentum 0): each "

@@ -48,6 +48,7 @@ from fedtpu.models.dla import DLA
 from fedtpu.models.dla_simple import SimpleDLA
 from fedtpu.models.joyai_llm_flash import JoyAILLMFlash
 from fedtpu.models.qwen3_next import Qwen3Next
+from fedtpu.models.lfm2_moe import Lfm2Moe
 
 __all__ = [
     "available",
@@ -95,4 +96,5 @@ __all__ = [
     "SimpleDLA",
     "JoyAILLMFlash",
     "Qwen3Next",
+    "Lfm2Moe",
 ]

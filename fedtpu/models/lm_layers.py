@@ -1,8 +1,10 @@
-"""What the language models share (``joyai_llm_flash``, ``qwen3_next``): the
-norm, the plain layers, one sequence's causal softmax attention, the routed
-experts' path and a row's head-and-loss. Each model keeps its own scoring
-rule, its own projections and its own parameter names; what is here takes
-arrays and sizes, and names device time under ``fed.local_step.fwd_bwd.``.
+"""What the language models share (``joyai_llm_flash``, ``qwen3_next``,
+``lfm2_moe``): the norm, the plain layers, the rotate-half rotary turn, the
+causal depthwise convolution, one sequence's causal softmax attention, the
+routed experts' path and a row's head-and-loss. Each model keeps its own
+scoring rule, its own projections and its own parameter names; what is here
+takes arrays and sizes, and names device time under
+``fed.local_step.fwd_bwd.``.
 
 - :func:`attention_core`: causal attention of one sequence by the body its
   shapes and the backend call for: the fused kernels of
@@ -82,6 +84,32 @@ class SwiGLU(nn.Module):
         h = jax.nn.silu(Linear(self.width, name="gate")(x)) * Linear(
             self.width, name="up")(x)
         return Linear(x.shape[-1], name="down")(h)
+
+
+def rope_half(x, theta: float, rot: int):
+    """Rotary embedding on the first ``rot`` dimensions of the last axis of
+    ``x [T, ..., d]`` in the rotate-half pairing: ``(x[i], x[i + rot/2])`` of
+    position ``t`` turn by ``t * theta^(-2i/rot)``; the rest pass."""
+    t, half = x.shape[0], rot // 2
+    inv = theta ** (-jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    ang = ang.reshape((t,) + (1,) * (x.ndim - 2) + (half,))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :half], xf[..., half:rot]
+    return jnp.concatenate(
+        [a * cos - b * sin, b * cos + a * sin, xf[..., rot:]], axis=-1
+    ).astype(x.dtype)
+
+
+def causal_conv(x, kernel):
+    """Depthwise causal convolution over time of ``x [T, channels]`` with
+    ``kernel [width, channels]``: ``y_t = sum_i kernel_i x_{t - width + 1 +
+    i}``, zeros before the row's start; float32 sums."""
+    t, width = x.shape[0], kernel.shape[0]
+    padded = jnp.pad(x, ((width - 1, 0), (0, 0))).astype(jnp.float32)
+    y = sum(padded[i:i + t] * kernel[i].astype(jnp.float32) for i in range(width))
+    return y.astype(x.dtype)
 
 
 @functools.partial(jax.checkpoint, static_argnums=(5, 6, 7))
@@ -171,7 +199,8 @@ def _expert_init(key, shape, dtype=jnp.float32):
 def routed_experts(xf, shared, gates_here, picked_here, w_gate, w_up, w_down,
                    per_token, chunk_pairs, block_rows):
     """An expert layer's sum: ``shared [n, d]`` (what every chip computes
-    alike, the model's own) plus the held experts' part (module docstring).
+    alike, the model's own; ``None`` where the model has no such part, and
+    nothing stands in for it) plus the held experts' part (module docstring).
     ``xf [n, d]`` tokens; ``gates_here``, ``picked_here [n, held]``: each
     token's gate for each held expert (0 where not chosen) and whether it was
     chosen; ``w_gate``, ``w_up [held, d, width]``, ``w_down [held, width,
@@ -254,7 +283,9 @@ def routed_experts(xf, shared, gates_here, picked_here, w_gate, w_up, w_down,
             j * chunk < pairs, add_chunk, lambda routed, _: routed,
             routed, jnp.int32(j * chunk))
     with jax.named_scope(SCOPE + "moe.combine"):
-        y = (shared.astype(jnp.float32) + routed).astype(xf.dtype)
+        if shared is not None:
+            routed = shared.astype(jnp.float32) + routed
+        y = routed.astype(xf.dtype)
     load = jnp.max(counts) * held / jnp.maximum(pairs, 1)
     return y, pairs, load.astype(jnp.float32)
 

@@ -102,7 +102,8 @@ from jax.experimental.layout import Layout, with_layout_constraint
 
 from fedtpu.models.lm_layers import (
     KEEP, SCOPE, Linear, SwiGLU, _expert_init, _rms, _row_loss_parts,
-    attention_core, held_range, routed_experts, sizes_from_keywords)
+    attention_core, causal_conv, held_range, rope_half, routed_experts,
+    sizes_from_keywords)
 from fedtpu.models.registry import register
 from fedtpu.obs.registry import get_global_registry
 from fedtpu.ops import delta_rule_kernels
@@ -161,32 +162,6 @@ class Norm(nn.Module):
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.zeros_init(), (x.shape[-1],))
         return _rms(x, 1.0 + scale, self.eps)
-
-
-def rope_half(x, theta: float, rot: int):
-    """Rotary embedding on the first ``rot`` dimensions of the last axis of
-    ``x [T, ..., d]`` in the rotate-half pairing: ``(x[i], x[i + rot/2])`` of
-    position ``t`` turn by ``t * theta^(-2i/rot)``; the rest pass."""
-    t, half = x.shape[0], rot // 2
-    inv = theta ** (-jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
-    ang = ang.reshape((t,) + (1,) * (x.ndim - 2) + (half,))
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    xf = x.astype(jnp.float32)
-    a, b = xf[..., :half], xf[..., half:rot]
-    return jnp.concatenate(
-        [a * cos - b * sin, b * cos + a * sin, xf[..., rot:]], axis=-1
-    ).astype(x.dtype)
-
-
-def causal_conv(x, kernel):
-    """Depthwise causal convolution over time of ``x [T, channels]`` with
-    ``kernel [width, channels]``: ``y_t = sum_i kernel_i x_{t - width + 1 +
-    i}``, zeros before the row's start; float32 sums."""
-    t, width = x.shape[0], kernel.shape[0]
-    padded = jnp.pad(x, ((width - 1, 0), (0, 0))).astype(jnp.float32)
-    y = sum(padded[i:i + t] * kernel[i].astype(jnp.float32) for i in range(width))
-    return y.astype(x.dtype)
 
 
 def _lying(x, *major_to_minor):

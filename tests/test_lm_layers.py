@@ -1,6 +1,6 @@
-"""What ``joyai_llm_flash`` and ``qwen3_next`` share
-(``fedtpu/models/lm_layers.py``), each case for both models at a small size
-on the CPU against that model's plain reference: the shares of the routed
+"""What ``joyai_llm_flash``, ``qwen3_next`` and ``lfm2_moe`` share
+(``fedtpu/models/lm_layers.py``), each case for every model that runs it, at a
+small size on the CPU against that model's plain reference: the shares of the routed
 experts adding up to the uncut layer, routing so skewed that every token lands
 on one held expert with nothing dropped, and the plain causal-attention body
 at both models' shapes (a key head each with a separate rotary operand; a key
@@ -20,7 +20,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-from fedtpu.models import joyai_llm_flash, lm_layers, qwen3_next
+from fedtpu.models import joyai_llm_flash, lfm2_moe, lm_layers, qwen3_next
 from fedtpu.obs.registry import get_global_registry
 from fedtpu.ops import attention_kernels as ak
 
@@ -55,16 +55,21 @@ class Model:
         from benchmark import run
 
         tiny = {"joyai_llm_flash": ("joyai_tiny", "joyai_tiny_f32"),
-                "qwen3_next": ("qwen_tiny", "qwen_tiny_f32")}[name]
+                "qwen3_next": ("qwen_tiny", "qwen_tiny_f32"),
+                "lfm2_moe": ("lfm2_tiny", "lfm2_tiny_f32")}[name]
         with open(os.path.join(ROOT, "tests", "benchmark", tiny[0], "configs",
                                tiny[1] + ".json")) as fh:
             self.cfg = json.load(fh)
         self.name = name
-        self.prog = {"joyai_llm_flash": joyai_llm_flash, "qwen3_next": qwen3_next}[name]
+        self.prog = {"joyai_llm_flash": joyai_llm_flash, "qwen3_next": qwen3_next,
+                     "lfm2_moe": lfm2_moe}[name]
         self.ref = run.load_py(os.path.join(ROOT, "benchmark", "reference", name + ".py"))
-        self.joyai = name == "joyai_llm_flash"
+        # a selection bias drawn from the layer's index: the layer is told it
+        self.by_layer = name != "qwen3_next"
         # the configuration's key for the experts HELD (the reference's count)
-        self.held_key = "n_routed_experts" if self.joyai else "num_experts"
+        self.held_key = ("n_routed_experts" if name == "joyai_llm_flash"
+                         else "num_experts")
+        self.share = self.cfg[self.held_key]  # experts a chip holds, of 16
 
     def sizes(self, **over):
         args = dict(self.cfg["program"]["round"]["model_args"])
@@ -80,26 +85,27 @@ class Model:
         return jax.tree.map(jnp.asarray, params)["layer_1"]["moe"]
 
     def layer(self, sizes):
-        return self.prog.ExpertLayer(sizes, 1) if self.joyai else self.prog.ExpertLayer(sizes)
+        return self.prog.ExpertLayer(sizes, 1) if self.by_layer else self.prog.ExpertLayer(sizes)
 
     def reference(self, cfg):
         from benchmark.reference.layers import ident
 
         f = self.ref.make_forward(cfg).expert_layer
-        return (lambda p, x: f(p, x, 1, ident)) if self.joyai else (
+        return (lambda p, x: f(p, x, 1, ident)) if self.by_layer else (
             lambda p, x: f(p, x, ident))
 
 
-@pytest.fixture(scope="module", params=["joyai_llm_flash", "qwen3_next"])
+@pytest.fixture(scope="module", params=["joyai_llm_flash", "qwen3_next", "lfm2_moe"])
 def model(request):
     return Model(request.param)
 
 
 # ---------------------------------------------------------- the routed experts
 def test_the_shares_of_the_routed_experts_add_up_to_the_uncut_layer(model):
-    """The routed parts that all 16 / 4 = 4 shares compute, plus what every
-    chip computes alike (the shared expert, gated or not) counted once, are
-    the uncut reference layer's output and input gradient."""
+    """The routed parts that all the shares compute (four of 4 experts; of
+    ``lfm2_moe`` eight of 2), plus what every chip computes alike (the shared
+    expert, gated or not; ``lfm2_moe`` has none) counted once, are the uncut
+    reference layer's output and input gradient."""
     uncut = dict(model.cfg, experts_held_from=0, **{model.held_key: 16})
     p = model.weights(uncut)
     x = _x(5, 2 * T, D)
@@ -110,10 +116,10 @@ def test_the_shares_of_the_routed_experts_add_up_to_the_uncut_layer(model):
 
     def all_shares(x):
         total, pairs = alike(x), 0
-        for lo in range(0, 16, 4):
-            held = dict(p, **{k: p[k][lo:lo + 4] for k in
+        for lo in range(0, 16, model.share):
+            held = dict(p, **{k: p[k][lo:lo + model.share] for k in
                               ("experts_gate", "experts_up", "experts_down")})
-            y, n, _ = model.layer(model.sizes(experts_held=(lo, lo + 4))).apply(
+            y, n, _ = model.layer(model.sizes(experts_held=(lo, lo + model.share))).apply(
                 {"params": held}, x)
             total, pairs = total + (y - alike(x)), pairs + n
         return total, pairs
@@ -126,36 +132,37 @@ def test_the_shares_of_the_routed_experts_add_up_to_the_uncut_layer(model):
 
 def _everything_on_one_expert(model):
     """``(cfg, params, x, lo)`` under which every token picks ONE expert, the
-    same one, of the held range ``[lo, lo + 4)``. JoyAI: a router of zeros
-    scores every expert alike, so the selection bias alone picks. Qwen3-Next
+    same one, of the held range ``[lo, lo + share)``. JoyAI, LFM2: a router of
+    zeros scores every expert alike, so the selection bias alone picks. Qwen3-Next
     has no bias: tokens of positive entries against a router whose one column
     of ones outscores the columns of zeros."""
     one = dict(model.cfg, num_experts_per_tok=1)
-    if model.joyai:
+    if model.by_layer:
         busiest = int(jnp.argmax(model.ref.selection_bias(1, one)))
         x = _x(6, 2 * T, D)
     else:
         busiest, x = 9, jnp.abs(_x(6, 2 * T, D)) + 0.5
-    lo = busiest // 4 * 4
+    lo = busiest // model.share * model.share
     one["experts_held_from"] = lo
     p = dict(model.weights(one))
     p["router"] = jnp.zeros_like(p["router"])
-    if not model.joyai:
+    if not model.by_layer:
         p["router"] = p["router"].at[:, busiest].set(1.0)
     return one, p, x, lo
 
 
 @pytest.mark.parametrize("chunk", [48, 4096])
 def test_every_token_on_one_held_expert_and_nothing_is_dropped(model, chunk):
-    """All the pairs fall on ONE of the four held experts, in as many chunks
-    as it takes."""
+    """All the pairs fall on ONE of the held experts, in as many chunks as it
+    takes."""
     one, p, x, lo = _everything_on_one_expert(model)
     layer = model.layer(model.sizes(
-        num_experts_per_tok=1, experts_held=(lo, lo + 4), moe_chunk_pairs=chunk,
+        num_experts_per_tok=1, experts_held=(lo, lo + model.share), moe_chunk_pairs=chunk,
         moe_block_rows=16))
     y, pairs, load = jax.jit(lambda x: layer.apply({"params": p}, x))(x)
     assert int(pairs) == 2 * T
-    assert float(load) == pytest.approx(4.0)  # one expert has it all: 4 x the mean
+    # one expert has it all: as many times the mean as experts are held
+    assert float(load) == pytest.approx(float(model.share))
     reference = model.reference(one)
     _close(y, reference(p, x))
     ours = _value_and_grads(lambda x: layer.apply({"params": p}, x)[0], x)

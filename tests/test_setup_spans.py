@@ -377,11 +377,12 @@ def test_the_manifest_lists_the_six_under_setup_s():
 
     with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
         manifest = json.load(fh)
-    mine = manifest["per_layer"][-len(READERS):]
-    assert [m["name"] for m in mine] == [
-        "engine.build_s", "engine.state_place_s", "engine.device_data_s",
-        "engine.first_dispatch_s", "entry.trace_lower_s",
-        "entry.cache_misses"]
+    # in the manifest and in this order, wherever later PRs' entries stand
+    names = ["engine.build_s", "engine.state_place_s", "engine.device_data_s",
+             "engine.first_dispatch_s", "entry.trace_lower_s",
+             "entry.cache_misses"]
+    mine = [m for m in manifest["per_layer"] if m["name"] in names]
+    assert [m["name"] for m in mine] == names
     for m in mine:
         assert m["moves"] == "setup_s" and m["better"] == "lower"
         assert m["source"] == "program_counter" and "workloads" not in m

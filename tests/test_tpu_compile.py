@@ -259,3 +259,72 @@ def test_the_grouped_softmax_layer_compiles_at_the_published_widths_with_its_sco
     assert moved and all(scope in l for l in moved), [l[:200] for l in moved]
     assert not re.findall(r"f32\[(?:\d+,)*8,512,\d+\]", text)
     assert temp < 2e9
+
+
+def _lfm2_gradient(one_chip, layer, scope):
+    """Compiled text and temporaries of one of LFM2-MoE's layers at the
+    published widths on ``lfm2_24b_a2b.fl4_b8_seq4k``'s micro-batch, 8 rows of
+    4,096 tokens, forward and backward under ``nn.remat`` with the model's
+    policy and under the scope its block gives it."""
+    import flax.linen as nn
+
+    from fedtpu.models import lfm2_moe as m
+
+    layer = nn.remat(
+        layer[0], policy=jax.checkpoint_policies.save_only_these_names(m.KEEP)
+    )(m.Sizes(experts_held=(0, 8)), *layer[1:])
+    x = jax.ShapeDtypeStruct((8, 4096, 2048), jnp.bfloat16, sharding=one_chip)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 2048)))["params"])
+    params = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, jnp.bfloat16, sharding=one_chip), params)
+
+    def loss(params, x):
+        with jax.named_scope(m.SCOPE + scope):
+            y = layer.apply({"params": params}, x)
+        return jnp.sum((y[0] if isinstance(y, tuple) else y).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile()
+    return compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+
+
+def test_the_short_convolution_compiles_at_the_published_widths_with_its_scopes(one_chip):
+    """Hidden 2,048, three taps, 32,768 tokens: ``W_in`` and ``W_out`` and
+    their transposes are products under ``short_conv.proj`` and ``.out``; the
+    two gates and the taps are elementwise fusions under ``short_conv.core``
+    (what ``short_conv.core_roofline`` divides by), no product among them;
+    the layer's temporaries stay under 2 GB."""
+    from fedtpu.models import lfm2_moe as m
+
+    text, temp = _lfm2_gradient(one_chip, (m.ShortConv,), "short_conv")
+    scope = "fed.local_step.fwd_bwd.short_conv"
+    products = [l for l in text.splitlines() if " convolution(" in l]
+    assert products and all(scope in l for l in products)
+    named = lambda part: [l for l in products if scope + part in l]
+    # the forward product (W_in's once more in the backward pass) and two transposes
+    assert len(named(".proj")) >= 3 and len(named(".out")) >= 2
+    core = [l for l in text.splitlines() if scope + ".core" in l and " fusion(" in l]
+    assert core, "no fusion carries the core's scope"
+    assert not named(".core")  # gates and taps are no products
+    assert temp < 2e9
+
+
+def test_the_lfm2_expert_layer_compiles_at_a_deployments_rows_a_product(one_chip):
+    """8 of 64 experts of width 1,536 on a micro-batch of 32,768 tokens, 4 a
+    token: a held expert's expected 2,048 pairs are two blocks of 1,024 rows,
+    the grouped products are batched products over a chunk's 32 + 8 blocks
+    under ``fed.local_step.fwd_bwd.moe.experts`` (what
+    ``moe.experts_device_share`` reads), no ``ragged-dot`` kernel without the
+    program's scope, no product over every expert's copy of the tokens, no
+    tensor of zeros stands in for the shared expert this model lacks, and the
+    layer's temporaries stay under 5 GB."""
+    from fedtpu.models import lfm2_moe as m
+
+    text, temp = _lfm2_gradient(one_chip, (m.ExpertLayer, 2), "moe")
+    assert "ragged-dot" not in text
+    grouped = [l for l in text.splitlines() if " convolution(" in l
+               and "fed.local_step.fwd_bwd.moe.experts" in l]
+    assert len(grouped) >= 9 * 4  # 3 forward, 6 transposed products a chunk, 4 chunks
+    assert any("bf16[40,1024,2048]" in l or "bf16[40,1024,1536]" in l for l in grouped)
+    assert not re.search(r"bf16\[8,32768,2048\]|bf16\[8,131072,2048\]", text)
+    assert temp < 5e9

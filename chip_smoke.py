@@ -366,8 +366,10 @@ def leg_kernels():
     # The attention core's kernels against the plain body, bfloat16 at the
     # language models' head sizes, three blocks long: output and gradients
     # within bfloat16 rounding of the plain body's largest value. Latent
-    # attention's form (a key head each, a rotary operand) and the hybrid's
-    # (256-wide heads, a key head a group of eight, no rotary operand).
+    # attention's form (a key head each, a rotary operand), the hybrid's
+    # (256-wide heads, a key head a group of eight, no rotary operand) and
+    # LFM2's (64-wide heads, a key head a group of four whose blocks one grid
+    # step takes stacked, no rotary operand).
     from fedtpu.models import lm_layers as lm
     from fedtpu.ops import attention_kernels as ak
 
@@ -378,6 +380,8 @@ def leg_kernels():
             (t, heads, 128), (t, heads, 128)]),
         f"attention_core[{t},2,8,256]": (256, [
             (t, 2, 8, 256), None, (t, 2, 256), None, (t, 2, 256), (t, 2, 8, 256)]),
+        f"attention_core[{t},2,4,64]": (64, [
+            (t, 2, 4, 64), None, (t, 2, 64), None, (t, 2, 64), (t, 2, 4, 64)]),
     }
     for name, (width, shapes) in forms.items():
         scale = 1.0 / math.sqrt(width)

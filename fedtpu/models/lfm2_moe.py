@@ -27,9 +27,10 @@ weight entering as ``w``:
   head, causal softmax of ``q.k / sqrt(head)`` in float32, key-value head
   ``j`` serving query heads ``j G .. j G + G - 1``, by
   :func:`fedtpu.models.lm_layers.attention_core` (a key-value head is read by
-  its group, not copied; at the published heads of 64 the fused kernels'
-  :func:`fedtpu.ops.attention_kernels.takes` answers no and the plain query
-  blocks run), then ``W_o``. No bias, no gate.
+  its group, not copied; on a TPU, at a length their blocks divide, the fused
+  kernels of :mod:`fedtpu.ops.attention_kernels` take the published heads of
+  64, a key head's four query heads stacked in one grid step, and the plain
+  query blocks run everywhere else), then ``W_o``. No bias, no gate.
 - ``FF_i`` where ``i < num_dense_layers``: SwiGLU of ``intermediate_size``.
 - ``FF_i`` otherwise: ``s = sigmoid(W_r u)`` in float32 over ALL
   ``num_experts``; chosen = the ``num_experts_per_tok`` largest of ``s + b``;

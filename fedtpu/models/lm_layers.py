@@ -9,7 +9,8 @@ takes arrays and sizes, and names device time under
 - :func:`attention_core`: causal attention of one sequence by the body its
   shapes and the backend call for: the fused kernels of
   :mod:`fedtpu.ops.attention_kernels` (on a TPU: a length their blocks
-  divide, head parts of whole lanes) or the plain query blocks of
+  divide, head parts of whole lanes or all of half a lane group) or the
+  plain query blocks of
   :func:`causal_attention`. Both are one function of every shape: a key head
   may serve a group of query heads (it is read by its group, never copied),
   and the rotary operands may be absent.

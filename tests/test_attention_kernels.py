@@ -6,8 +6,10 @@ a full and a diagonal key block), the published head sizes (128 + 64 and
 128) and two heads; float32 operands agree to float32 rounding, bfloat16
 operands to bfloat16 rounding. The same for the grouped form without rotary
 operands (``qwen3_next``'s softmax layer: a key head serves a group of query
-heads) at a group of 1, 2 and 8 and a head of 128 and 256. Which body a
-sequence takes, and that the counter says so.
+heads) at a group of 1, 2 and 8 and a head of 128 and 256, and at a head of 64
+(``lfm2_moe``'s: up to four heads of a group stacked in a grid step) at a
+group of 1, 2, 4 and 8. Which body a sequence takes, and that the counter says
+so.
 """
 
 import math
@@ -89,7 +91,9 @@ def _grouped_operands(dtype, group, width, t, seed=3):
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("group, width", [
-    (0, 128), (1, 128), (2, 128), (8, 128), (2, 256), (8, 256)])
+    (0, 128), (1, 128), (2, 128), (8, 128), (2, 256), (8, 256),
+    # heads of half a lane group: a step takes up to four of a group, stacked
+    (0, 64), (1, 64), (2, 64), (4, 64), (8, 64)])
 def test_the_grouped_kernels_without_rotary_operands_are_the_plain_body(
         dtype, group, width):
     """Output and the gradients of q, k, v; two blocks (a full and two
@@ -112,11 +116,13 @@ def test_the_grouped_kernels_without_rotary_operands_are_the_plain_body(
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_a_grouped_query_that_sees_one_key_returns_its_value(dtype):
-    """Every query head of a group reads ITS key head's first value."""
-    (q, k, v), ct = _grouped_operands(jnp.dtype(dtype), 8, 256, ak.BLOCK, seed=4)
+@pytest.mark.parametrize("group, width", [(8, 256), (4, 64)])
+def test_a_grouped_query_that_sees_one_key_returns_its_value(dtype, group, width):
+    """Every query head of a group reads ITS key head's first value, the
+    stacked heads of a narrow group too."""
+    (q, k, v), ct = _grouped_operands(jnp.dtype(dtype), group, width, ak.BLOCK, seed=4)
     out, vjp = jax.vjp(lambda q, k, v: ak.causal_attention(
-        q, None, k, None, v, 1 / 16, interpret=True), q, k, v)
+        q, None, k, None, v, width ** -0.5, interpret=True), q, k, v)
     np.testing.assert_array_equal(
         np.asarray(out[0], np.float32),
         np.broadcast_to(np.asarray(v[0], np.float32)[:, None], out.shape[1:]))
@@ -157,6 +163,19 @@ def _grouped(args):
      "interpret", "plain"),
     (ak.BLOCK, dict(nope=16, rope=8, vd=16),
      lambda a: _without(_grouped(a), "q_rope", "k_rope"), "interpret", "plain"),
+    # heads of half a lane group without rotary operands: a key head each, a group
+    (ak.BLOCK, dict(nope=64, vd=64), lambda a: _without(a, "q_rope", "k_rope"),
+     "interpret", "kernel"),
+    (ak.BLOCK, dict(nope=64, vd=64),
+     lambda a: _without(_grouped(a), "q_rope", "k_rope"), "interpret", "kernel"),
+    (ak.BLOCK, dict(nope=64, vd=64),
+     lambda a: _without(_grouped(a), "q_rope", "k_rope"), "xla", "plain"),
+    (ak.BLOCK + 128, dict(nope=64, vd=64),
+     lambda a: _without(_grouped(a), "q_rope", "k_rope"), "interpret", "plain"),
+    # ... with rotary operands, or beside values of another width: the plain body's
+    (ak.BLOCK, dict(nope=64, vd=64), None, "interpret", "plain"),
+    (ak.BLOCK, dict(nope=64, vd=128), lambda a: _without(a, "q_rope", "k_rope"),
+     "interpret", "plain"),
 ])
 def test_the_body_follows_backend_and_shapes_and_the_counter_says_which(
         monkeypatch, t, widths, shape, mode, body):
@@ -227,22 +246,18 @@ def test_the_layer_trains_the_same_through_either_body(monkeypatch):
             atol=5e-5 * float(jnp.abs(want).max()))
 
 
-def test_the_hybrid_softmax_layer_trains_the_same_through_either_body(monkeypatch):
-    """``qwen3_next.GatedAttention`` under ``nn.remat`` with the model's
-    policy, two sequences of one block, two key-value heads of 128 with two
-    query heads each and a quarter of each head turned: output and every
-    gradient through the kernels (interpreted) equal those through the plain
-    body to float32 rounding, and the counter says which body a core took."""
+def _trains_the_same_through_either_body(monkeypatch, module, cls, sizes, seed):
+    """``cls(sizes)`` under ``nn.remat`` with ``module``'s policy on two
+    sequences of one block: output and every gradient through the kernels
+    (interpreted) equal those through the plain body to float32 rounding, and
+    the counter says which body a core took."""
     import flax.linen as nn
 
-    sizes = qwen3_next.Sizes(
-        hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
-        head_dim=128, attn_q_block=128)
     layer = nn.remat(
-        qwen3_next.GatedAttention,
-        policy=jax.checkpoint_policies.save_only_these_names(qwen3_next.KEEP))(sizes)
-    x = jax.random.normal(jax.random.PRNGKey(7), (2, ak.BLOCK, 64), jnp.float32)
-    params = layer.init(jax.random.PRNGKey(8), x[:, :8])["params"]
+        cls, policy=jax.checkpoint_policies.save_only_these_names(module.KEEP))(sizes)
+    x = jax.random.normal(
+        jax.random.PRNGKey(seed), (2, ak.BLOCK, sizes.hidden_size), jnp.float32)
+    params = layer.init(jax.random.PRNGKey(seed + 1), x[:, :8])["params"]
 
     def loss(params, x):
         y = layer.apply({"params": params}, x)
@@ -264,3 +279,24 @@ def test_the_hybrid_softmax_layer_trains_the_same_through_either_body(monkeypatc
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=0,
             atol=5e-5 * float(jnp.abs(want).max()))
+
+
+def test_the_hybrid_softmax_layer_trains_the_same_through_either_body(monkeypatch):
+    """``qwen3_next.GatedAttention``: two key-value heads of 128 with two
+    query heads each and a quarter of each head turned."""
+    _trains_the_same_through_either_body(
+        monkeypatch, qwen3_next, qwen3_next.GatedAttention, qwen3_next.Sizes(
+            hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+            head_dim=128, attn_q_block=128), seed=7)
+
+
+def test_the_lfm2_softmax_layer_trains_the_same_through_either_body(monkeypatch):
+    """``lfm2_moe.Attention``: two key-value heads of the published 64 with
+    two query heads each (one grid step takes a key head's two, stacked), the
+    whole head turned."""
+    from fedtpu.models import lfm2_moe
+
+    _trains_the_same_through_either_body(
+        monkeypatch, lfm2_moe, lfm2_moe.Attention, lfm2_moe.Sizes(
+            hidden_size=256, num_attention_heads=4, num_key_value_heads=2,
+            attn_q_block=128), seed=9)

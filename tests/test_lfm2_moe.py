@@ -4,7 +4,8 @@ shifted sums, one head's whole scores at a time, nothing of the program): each
 operator and a whole block of each kind, forward and gradient, from the same
 seeded weights, in float32 and in bfloat16; the gated convolution against
 ``jax.lax.conv_general_dilated`` and against a recurrence over a two-token
-cache; grouped-query attention at the published heads of 64; a token none of
+cache; grouped-query attention at the published heads of 64 through either
+body of its core; a token none of
 whose experts is held; the program's tree; a step in one micro-batch against
 micro-batches of one row through a federation.
 
@@ -166,29 +167,33 @@ def test_the_gated_convolution_is_a_depthwise_convolution_and_a_two_token_cache(
     assert qwen3_next.rope_half is lm_layers.rope_half is prog.rope_half
 
 
-def test_grouped_queries_at_heads_of_64_run_the_plain_body_and_agree(
-        cfg, ref, monkeypatch):
-    """4 query heads on 2 key-value heads of the published 64 (hidden 256):
-    even on a TPU (here: the test says so) the fused kernels do not take a
-    head narrower than a lane group, the plain query blocks run and are
-    counted, and the layer is the reference's, rotary over the whole head
-    included."""
+@pytest.mark.parametrize("t, body", [(T, "plain"), (ak.BLOCK, "kernel")])
+def test_grouped_queries_at_heads_of_64_run_the_body_their_length_calls_for_and_agree(
+        cfg, ref, monkeypatch, t, body):
+    """4 query heads on 2 key-value heads of the published 64 (hidden 256).
+    Where the program asks, the test says "a TPU" (the kernels interpreted):
+    the fused kernels take heads of half a lane group as they take whole ones,
+    at a length their blocks divide the layer's core is counted as theirs and
+    at the tiny length as the plain query blocks', and either way the layer
+    is the reference's, rotary over the whole head included."""
     from benchmark.reference.layers import ident
 
     wide = dict(cfg, hidden_size=256)
     assert wide["hidden_size"] // wide["num_attention_heads"] == 64
     p = _weights(ref, wide)["layer_1"]["self_attn"]
-    x = _x(2, 1, T, 256)
+    x = _x(2, 1, t, 256)
     layer = prog.Attention(_sizes(cfg, hidden_size=256))
-    monkeypatch.setattr(ak, "_mode", lambda interpret: "mosaic")
-    for width, taken in ((64, False), (128, True)):
+    monkeypatch.setattr(ak, "_mode", lambda interpret: "interpret")
+    for width in (64, 128):
         q = jnp.zeros((ak.BLOCK, 2, 2, width))
-        assert ak.takes(q, None, q[:, :, 0], None, q[:, :, 0]) == taken
-    plain = get_global_registry().counter(
-        lm_layers.CORES_TRACED, labels={"body": "plain"})
-    before = plain.value
+        assert ak.takes(q, None, q[:, :, 0], None, q[:, :, 0])
+        assert not ak.takes(q[:T], None, q[:T, :, 0], None, q[:T, :, 0])
+    traced = lambda b: get_global_registry().counter(
+        lm_layers.CORES_TRACED, labels={"body": b}).value
+    before = {b: traced(b) for b in ("kernel", "plain")}
     got = _value_and_grads(lambda p, x: layer.apply({"params": p}, x), p, x)
-    assert plain.value > before
+    other = "plain" if body == "kernel" else "kernel"
+    assert traced(body) > before[body] and traced(other) == before[other]
     theirs = ref.make_forward(wide).attention
     want = _value_and_grads(
         lambda p, x: jnp.stack([theirs(p, row, ident) for row in x]), p, x)

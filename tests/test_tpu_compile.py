@@ -288,6 +288,51 @@ def _lfm2_gradient(one_chip, layer, scope):
     return compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
 
 
+def test_the_lfm2_softmax_layer_compiles_to_one_forward_and_one_backward_kernel(
+        one_chip, monkeypatch):
+    """32 query heads on 8 key-value heads of 64, forward and backward under
+    the block's remat policy on the cell's micro-batch, a row of 4,096 tokens
+    at a time. The test says "Mosaic" where the program asks, and the kernels
+    take heads of half a lane group: the core is TWO kernels named as the
+    other models', one forward (kept, so the rematerialised forward pass runs
+    none) and one backward, a key head's four query heads stacked in a grid
+    step (``[8, 16384, 64]`` queries on ``[8, 4096, 64]`` keys: a key-value
+    head is read once a step, not copied, and ``dk`` / ``dv`` leave the
+    kernel summed over the group, no float32 part a head), inside the VMEM
+    limit, both under the scope ``gqa.core_roofline`` divides by (a kernel
+    outside it would read over 100 %); the relayouts around them carry the
+    layer's scope, the core's where no projection's product absorbs them; and
+    no float32 block of scores ``[., 4, 512, k]`` of the plain body is left
+    in the module."""
+    from fedtpu.models import lfm2_moe as m
+    from fedtpu.ops import attention_kernels as ak
+
+    monkeypatch.setattr(ak, "_mode", lambda interpret: "mosaic")
+    text, temp = _lfm2_gradient(one_chip, (m.Attention,), "attention")
+    scope = "fed.local_step.fwd_bwd.attention"
+    assert ak.SCOPE == scope + ".core"
+    kernels = [l for l in text.splitlines()
+               if " custom-call(" in l and 'custom_call_target="tpu_custom_call"' in l]
+    assert sorted(re.search(r"%(latent_attention_core_\w+?)[.\d]* =", l).group(1)
+                  for l in kernels) == [
+        "latent_attention_core_bwd", "latent_attention_core_fwd"], kernels
+    for line in kernels:
+        assert ak.SCOPE in re.search(r'op_name="([^"]*)"', line).group(1), line
+        operands = line[line.index("operand_layout_constraints="):line.index("metadata=")]
+        assert "bf16[8,16384,64]" in operands and "bf16[8,4096,64]" in operands, operands
+        assert "f32[8,4096,64]" not in line  # no group parts to sum outside
+        used = re.search(r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', line)
+        assert 0 < int(used.group(1)) <= ak._VMEM_LIMIT
+    moved = [l for l in text.splitlines() if re.search(
+        r"= bf16\[8,(?:512,8,4|16384|4096),64\]\S* (?:copy|transpose|fusion)\(", l)]
+    assert moved and all(scope in l for l in moved), [l[:200] for l in moved]
+    assert any(ak.SCOPE in l for l in moved)
+    scores = [dims for dims in re.findall(r"f32\[((?:\d+,)*4,512,\d+)\]", text)
+              if int(dims.rsplit(",", 1)[1]) >= ak.BLOCK]
+    assert not scores, scores[:5]
+    assert temp < 2e9
+
+
 def test_the_short_convolution_compiles_at_the_published_widths_with_its_scopes(one_chip):
     """Hidden 2,048, three taps, 32,768 tokens: ``W_in`` and ``W_out`` and
     their transposes are products under ``short_conv.proj`` and ``.out``; the

@@ -49,6 +49,7 @@ from fedtpu.models.dla_simple import SimpleDLA
 from fedtpu.models.joyai_llm_flash import JoyAILLMFlash
 from fedtpu.models.qwen3_next import Qwen3Next
 from fedtpu.models.lfm2_moe import Lfm2Moe
+from fedtpu.models.laguna import Laguna
 
 __all__ = [
     "available",
@@ -97,4 +98,5 @@ __all__ = [
     "JoyAILLMFlash",
     "Qwen3Next",
     "Lfm2Moe",
+    "Laguna",
 ]

@@ -71,8 +71,8 @@ import jax.numpy as jnp
 
 from fedtpu.models.lm_layers import (
     KEEP, SCOPE, Linear, RMSNorm, SwiGLU, _expert_init, _rms, _row_loss_parts,
-    attention_core, causal_conv, held_range, rope_half, routed_experts,
-    sizes_from_keywords)
+    causal_conv, grouped_query_attention, held_range, rope_half,
+    routed_experts, sizes_from_keywords)
 from fedtpu.models.registry import register
 
 GATE_EPS = 1e-6  # beside the sum of a token's chosen scores
@@ -184,16 +184,9 @@ class Attention(nn.Module):
             Linear(kh * hd, name="k_proj")(x).reshape(b, t, kh, hd))
         v = Linear(kh * hd, name="v_proj")(x).reshape(b, t, kh, hd)
 
-        def one_sequence(args):
-            q, k, v = args
-            with jax.named_scope(SCOPE + "attention.core"):
-                return attention_core(
-                    rope_half(q, c.rope_theta, hd), None,
-                    rope_half(k, c.rope_theta, hd), None, v,
-                    1.0 / math.sqrt(hd), c.attn_q_block)
-
-        o = jax.lax.map(one_sequence, (q, k, v))  # [b, t, kh, group, hd]
-        return Linear(d, name="out_proj")(o.reshape(b, t, h * hd))
+        rotary = lambda a: rope_half(a, c.rope_theta, hd)
+        return Linear(d, name="out_proj")(
+            grouped_query_attention(q, k, v, rotary, c.attn_q_block))
 
 
 class ExpertLayer(nn.Module):

@@ -1,7 +1,9 @@
 """What the language models share (``joyai_llm_flash``, ``qwen3_next``,
-``lfm2_moe``): the norm, the plain layers, the rotate-half rotary turn, the
-causal depthwise convolution, one sequence's causal softmax attention, the
-routed experts' path and a row's head-and-loss. Each model keeps its own
+``lfm2_moe``, ``laguna``): the norm, the plain layers, the rotate-half rotary
+turn (plain or YaRN's frequencies), the causal depthwise convolution, one
+sequence's causal softmax attention over the whole prefix or a window of it,
+the grouped-query body around it, the routed experts' path and a row's
+head-and-loss. Each model keeps its own
 scoring rule, its own projections and its own parameter names; what is here
 takes arrays and sizes, and names device time under
 ``fed.local_step.fwd_bwd.``.
@@ -13,7 +15,15 @@ takes arrays and sizes, and names device time under
   plain query blocks of
   :func:`causal_attention`. Both are one function of every shape: a key head
   may serve a group of query heads (it is read by its group, never copied),
-  and the rotary operands may be absent.
+  and the rotary operands may be absent. A ``window`` (a query sees itself and
+  the ``window - 1`` positions before it) is the plain body's alone: a query
+  block cuts its keys to the band and masks both edges, so no score outside
+  the band is formed.
+- :func:`grouped_query_attention`: what every grouped-query softmax layer
+  does between its projections and its output projection, whichever model's:
+  the rotary rule handed in (its turns inside or outside the core's scope),
+  the core a sequence at a time under the layer's ``.core`` scope, an
+  optional sigmoid gate.
 - :func:`routed_experts`: the (token, expert) pairs that fall on the HELD
   experts, sorted by expert and multiplied group by group, a chunk of
   ``chunk_pairs`` sorted pairs at a time: within a chunk each expert's pairs
@@ -34,6 +44,7 @@ import math
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from fedtpu.obs.registry import get_global_registry
@@ -45,6 +56,9 @@ SCOPE = "fed.local_step.fwd_bwd."
 # [T, heads, v] and, where the kernels run, the rows' log-sum-exp.
 KEEP = attention_kernels.KEPT
 CORES_TRACED = "fedtpu_attention_cores_traced_total"
+# The same cores by body AND by what they attend over (kind = full | window):
+# a series of its own, so that ``CORES_TRACED{body}`` stays what it was.
+CORES_BY_KIND = "fedtpu_attention_cores_by_kind_total"
 
 
 def _rms(x, scale, eps):
@@ -87,15 +101,42 @@ class SwiGLU(nn.Module):
         return Linear(x.shape[-1], name="down")(h)
 
 
-def rope_half(x, theta: float, rot: int):
+def yarn_inv_freq(theta: float, rot: int, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float):
+    """YaRN's ``rot / 2`` inverse frequencies (``rope_type: yarn``): each a
+    blend of the plain ``theta^(-2i/rot)`` and the same over ``factor``, by a
+    linear ramp over the dimensions between the one that turns ``beta_fast``
+    times in ``original_max`` positions (rounded down) and the one that turns
+    ``beta_slow`` times (rounded up), clipped to ``[0, 1]``: the fast
+    dimensions keep their frequency, the slow ones are stretched. A float32
+    constant, computed on the host."""
+    turns_at = lambda turns: rot * math.log(
+        original_max / (turns * 2 * math.pi)) / (2 * math.log(theta))
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), rot - 1)
+    plain = theta ** (-np.arange(0, rot, 2, dtype=np.float64) / rot)
+    ramp = np.clip((np.arange(rot // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return np.asarray(plain / factor * ramp + plain * (1.0 - ramp), np.float32)
+
+
+def rope_half(x, theta: float, rot: int, inv_freq=None, factor=None):
     """Rotary embedding on the first ``rot`` dimensions of the last axis of
     ``x [T, ..., d]`` in the rotate-half pairing: ``(x[i], x[i + rot/2])`` of
-    position ``t`` turn by ``t * theta^(-2i/rot)``; the rest pass."""
+    position ``t`` turn by ``t * theta^(-2i/rot)``; the rest pass. With
+    ``inv_freq [rot / 2]`` the pairs turn by ``t * inv_freq[i]`` instead
+    (:func:`yarn_inv_freq`), and ``factor`` multiplies cos and sin (YaRN's
+    ``attention_factor``)."""
     t, half = x.shape[0], rot // 2
-    inv = theta ** (-jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+    if inv_freq is None:
+        inv = theta ** (-jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+    else:
+        inv = jnp.asarray(inv_freq, jnp.float32)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
     ang = ang.reshape((t,) + (1,) * (x.ndim - 2) + (half,))
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if factor is not None:
+        cos, sin = cos * factor, sin * factor
     xf = x.astype(jnp.float32)
     a, b = xf[..., :half], xf[..., half:rot]
     return jnp.concatenate(
@@ -113,57 +154,121 @@ def causal_conv(x, kernel):
     return y.astype(x.dtype)
 
 
-@functools.partial(jax.checkpoint, static_argnums=(5, 6, 7))
-def _attend_block(q_nope, q_rope, k_nope, k_rope, v, lo, hi, scale):
+@functools.partial(jax.checkpoint, static_argnums=(5, 6, 7, 8))
+def _attend_block(q_nope, q_rope, k_nope, k_rope, v, lo, hi, scale, window=None):
     """Queries ``[lo, hi)`` of a sequence against the keys up to ``hi``:
     float32 scores and softmax. ``q_nope [T, H, ..., d]``: whatever axes lie
     between a key head and the width are the query heads that read it;
     ``k_nope``, ``v [T, H, .]``. The rotary operands enter the scores as a
-    second product (``k_rope [T, .]`` is every head's) or are ``None``. The
-    whole sequence comes in and is cut here, so that what the backward pass
-    keeps of a block is the sequence itself and no copy of a prefix."""
+    second product (``k_rope [T, .]`` is every head's) or are ``None``. With a
+    ``window`` the keys start at ``lo - window + 1`` (the first the block's
+    first query sees) and the band's lower edge is masked too. The whole
+    sequence comes in and is cut here, so that what the backward pass keeps of
+    a block is the sequence itself and no copy of a prefix."""
+    first = 0 if window is None else max(0, lo - window + 1)
     cut = lambda a, lo: None if a is None else a[lo:hi]
     q_nope, q_rope = cut(q_nope, lo), cut(q_rope, lo)
-    k_nope, k_rope, v = cut(k_nope, 0), cut(k_rope, 0), cut(v, 0)
+    k_nope, k_rope, v = cut(k_nope, first), cut(k_rope, first), cut(v, first)
     s = jnp.einsum("qh...d,khd->h...qk", q_nope, k_nope,
                    preferred_element_type=jnp.float32)
     if q_rope is not None:
         s = s + jnp.einsum("qh...d,kd->h...qk", q_rope, k_rope,
                            preferred_element_type=jnp.float32)
-    seen = jnp.arange(hi)[None, :] <= (lo + jnp.arange(hi - lo))[:, None]
+    if window is None:
+        seen = jnp.arange(hi)[None, :] <= (lo + jnp.arange(hi - lo))[:, None]
+    else:
+        at, key = (lo + jnp.arange(hi - lo))[:, None], jnp.arange(first, hi)[None, :]
+        seen = (key <= at) & (key > at - window)
     s = jnp.where(jnp.expand_dims(seen, tuple(range(s.ndim - 2))),
                   s * scale, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("h...qk,khd->qh...d", p.astype(v.dtype), v)
 
 
-def causal_attention(q_nope, q_rope, k_nope, k_rope, v, scale, q_block):
+def causal_attention(q_nope, q_rope, k_nope, k_rope, v, scale, q_block,
+                     window=None):
     """Causal attention of one sequence in query blocks (shapes as
-    :func:`_attend_block` takes them)."""
+    :func:`_attend_block` takes them), over the whole prefix or, with a
+    ``window``, over a query's own position and the ``window - 1`` before."""
     t = q_nope.shape[0]
     qb = min(q_block, t)
     if t % qb:
-        raise ValueError(f"attn_q_block={q_block} does not divide T={t}")
+        asked = ("a full layer" if window is None
+                 else f"a window layer, window={window}")
+        raise ValueError(
+            f"attn_q_block={q_block} does not divide T={t} ({asked})")
     return jnp.concatenate([
-        _attend_block(q_nope, q_rope, k_nope, k_rope, v, lo, lo + qb, scale)
+        _attend_block(q_nope, q_rope, k_nope, k_rope, v, lo, lo + qb, scale,
+                      window)
         for lo in range(0, t, qb)
     ], axis=0)
 
 
-def attention_core(q_nope, q_rope, k_nope, k_rope, v, scale, q_block):
+def attention_core(q_nope, q_rope, k_nope, k_rope, v, scale, q_block,
+                   window=None):
     """One sequence's causal attention by the body its shapes and the backend
     call for: the fused kernels (:mod:`fedtpu.ops.attention_kernels`) or the
-    plain query blocks above, one function of the same operands. Counted in
-    the process's registry by the body taken, once a core traced."""
-    kernel = attention_kernels.takes(q_nope, q_rope, k_nope, k_rope, v)
-    get_global_registry().counter(
+    plain query blocks above, one function of the same operands. The kernels
+    refuse, and the plain body takes (``attention_kernels._fits`` is the same
+    list): a ``window`` (their block pairs are the causal half: they would
+    compute another function), a length their blocks do not divide, head
+    parts that are no whole lane groups (but for queries, keys and values all
+    of half a group WITHOUT rotary operands: a half-lane head beside rotary
+    operands is refused), a rotary operand on one side only, more query heads
+    than key heads without a group axis; and every call off a TPU. Counted in
+    the process's registry by the body taken, once a core traced, and a
+    second time by body and kind (``full`` | ``window``)."""
+    kernel = attention_kernels.takes(
+        q_nope, q_rope, k_nope, k_rope, v, window=window)
+    body = "kernel" if kernel else "plain"
+    registry = get_global_registry()
+    registry.counter(
         CORES_TRACED, "attention cores traced, by the body taken",
-        labels={"body": "kernel" if kernel else "plain"}).inc()
+        labels={"body": body}).inc()
+    registry.counter(
+        CORES_BY_KIND, "attention cores traced, by the body taken and by "
+        "what they attend over (the whole prefix or a window)",
+        labels={"body": body, "kind": "full" if window is None else "window"}
+    ).inc()
     if kernel:
         return attention_kernels.causal_attention(
             q_nope, q_rope, k_nope, k_rope, v, scale)
     return checkpoint_name(causal_attention(
-        q_nope, q_rope, k_nope, k_rope, v, scale, q_block), KEEP)
+        q_nope, q_rope, k_nope, k_rope, v, scale, q_block, window), KEEP)
+
+
+def grouped_query_attention(q, k, v, rotary, q_block, gate=None, window=None,
+                            scope="attention", turn_in_core=True):
+    """A grouped-query softmax layer between its projections and its output
+    projection: ``q [B, T, KH, G, hd]`` (key-value head ``j`` serves the ``G``
+    query heads ``q[:, :, j]``), ``k``, ``v [B, T, KH, hd]``, already normed
+    where the model norms them. A sequence at a time, under ``<scope>.core``:
+    ``rotary`` (one sequence's ``[T, ..., hd]`` to the same, the model's own
+    rule) turns q and k, then :func:`attention_core` at ``1 / sqrt(hd)``.
+    With ``turn_in_core=False`` the turns run under the caller's scope, and
+    the core's holds scores, softmax and ``P v`` alone, which is what a core's
+    roofline counts; the default keeps the hybrid's and LFM2's programs as
+    they were lowered before they shared this body.
+    ``gate`` (what broadcasts against ``[B, T, KH, G, hd]``: a number a head
+    or a head's width of them) multiplies the output by its sigmoid, in
+    float32. Returns ``[B, T, KH * G * hd]``, the heads side by side."""
+    b, t, kh, group, hd = q.shape
+
+    def one_sequence(args):
+        q, k, v = args
+        if not turn_in_core:
+            q, k = rotary(q), rotary(k)
+        with jax.named_scope(SCOPE + scope + ".core"):
+            if turn_in_core:
+                q, k = rotary(q), rotary(k)
+            return attention_core(
+                q, None, k, None, v, 1.0 / math.sqrt(hd), q_block, window)
+
+    o = jax.lax.map(one_sequence, (q, k, v))  # [b, t, kh, group, hd]
+    if gate is not None:
+        o = (o.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))
+             ).astype(q.dtype)
+    return o.reshape(b, t, kh * group * hd)
 
 
 def sizes_from_keywords(cls, model: str, num_classes: int, sizes: dict):

@@ -102,8 +102,8 @@ from jax.experimental.layout import Layout, with_layout_constraint
 
 from fedtpu.models.lm_layers import (
     KEEP, SCOPE, Linear, SwiGLU, _expert_init, _rms, _row_loss_parts,
-    attention_core, causal_conv, held_range, rope_half, routed_experts,
-    sizes_from_keywords)
+    causal_conv, grouped_query_attention, held_range, rope_half,
+    routed_experts, sizes_from_keywords)
 from fedtpu.models.registry import register
 from fedtpu.obs.registry import get_global_registry
 from fedtpu.ops import delta_rule_kernels
@@ -332,18 +332,9 @@ class GatedAttention(nn.Module):
             Linear(kh * hd, name="k_proj")(x).reshape(b, t, kh, hd))
         v = Linear(kh * hd, name="v_proj")(x).reshape(b, t, kh, hd)
 
-        def one_sequence(args):
-            q, k, v = args
-            with jax.named_scope(SCOPE + "attention.core"):
-                return attention_core(
-                    rope_half(q, c.rope_theta, rot), None,
-                    rope_half(k, c.rope_theta, rot), None, v,
-                    1.0 / math.sqrt(hd), c.attn_q_block)
-
-        o = jax.lax.map(one_sequence, (q, k, v))  # [b, t, kh, group, hd]
-        o = (o.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))
-             ).astype(x.dtype)
-        return Linear(d, name="o_proj")(o.reshape(b, t, h * hd))
+        rotary = lambda a: rope_half(a, c.rope_theta, rot)
+        return Linear(d, name="o_proj")(grouped_query_attention(
+            q, k, v, rotary, c.attn_q_block, gate=gate))
 
 
 class ExpertLayer(nn.Module):

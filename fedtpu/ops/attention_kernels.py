@@ -104,8 +104,10 @@ _VMEM_LIMIT = 96 * 1024 * 1024
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 
 
-def _fits(q_nope, q_rope, k_nope, k_rope, v) -> bool:
-    """Shapes the kernels are built for: queries ``[T, KH, G, d]`` (or ``[T,
+def _fits(q_nope, q_rope, k_nope, k_rope, v, window=None) -> bool:
+    """Calls the kernels are built for: the whole causal prefix (no
+    ``window``: the block pairs listed are the causal half, and a banded call
+    would get another function's answer); queries ``[T, KH, G, d]`` (or ``[T,
     H, d]``: ``G = 1``) on keys and values ``[T, KH, .]``, a key head serving
     its ``G`` query heads; the rotary operands both there (``q_rope`` shaped
     as the queries, ``k_rope [T, .]`` every head's) or both ``None``; a length
@@ -113,11 +115,11 @@ def _fits(q_nope, q_rope, k_nope, k_rope, v) -> bool:
     lanes) or, without rotary operands, queries, keys and values of half a
     lane group, 64, whose heads go through a grid step stacked
     (:func:`_stacked`, module docstring). Everything else is the plain body's:
-    narrower heads, other fractions of a lane group, a half-lane head beside
-    rotary operands or beside values of another width."""
+    a window, narrower heads, other fractions of a lane group, a half-lane
+    head beside rotary operands or beside values of another width."""
     rotary = q_rope is not None
     widths = (q_nope.shape[-1], v.shape[-1])
-    return (q_nope.ndim in (3, 4) and k_nope.ndim == v.ndim == 3
+    return (window is None and q_nope.ndim in (3, 4) and k_nope.ndim == v.ndim == 3
             and k_nope.shape[1] == v.shape[1] == q_nope.shape[1]
             and rotary == (k_rope is not None)
             and q_nope.shape[0] % BLOCK == 0
@@ -136,10 +138,12 @@ def _stacked(q_nope) -> int:
 
 
 def takes(q_nope, q_rope, k_nope, k_rope, v,
-          interpret: Optional[bool] = None) -> bool:
+          interpret: Optional[bool] = None, window: Optional[int] = None) -> bool:
     """Whether a sequence goes through the kernels: on a TPU (or where
-    ``interpret`` says so), at shapes they are built for."""
-    return _mode(interpret) != "xla" and _fits(q_nope, q_rope, k_nope, k_rope, v)
+    ``interpret`` says so), at shapes they are built for, over the whole
+    causal prefix (a ``window`` is the plain body's)."""
+    return _mode(interpret) != "xla" and _fits(
+        q_nope, q_rope, k_nope, k_rope, v, window)
 
 
 def _dot(a, b, dims=(((1,), (0,)), ((), ()))):

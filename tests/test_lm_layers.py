@@ -1,11 +1,12 @@
-"""What ``joyai_llm_flash``, ``qwen3_next`` and ``lfm2_moe`` share
+"""What ``joyai_llm_flash``, ``qwen3_next``, ``lfm2_moe`` and ``laguna`` share
 (``fedtpu/models/lm_layers.py``), each case for every model that runs it, at a
 small size on the CPU against that model's plain reference: the shares of the routed
 experts adding up to the uncut layer, routing so skewed that every token lands
 on one held expert with nothing dropped, and the plain causal-attention body
 at both models' shapes (a key head each with a separate rotary operand; a key
 head a group of query heads with none) and which shapes the fused kernels take
-of each, with the choice of body counted.
+of each, with the choice of body counted; the same body over a window (a
+query's own position and the ``window - 1`` before it) against a dense mask.
 """
 
 import json
@@ -20,7 +21,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-from fedtpu.models import joyai_llm_flash, lfm2_moe, lm_layers, qwen3_next
+from fedtpu.models import joyai_llm_flash, laguna, lfm2_moe, lm_layers, qwen3_next
 from fedtpu.obs.registry import get_global_registry
 from fedtpu.ops import attention_kernels as ak
 
@@ -56,16 +57,17 @@ class Model:
 
         tiny = {"joyai_llm_flash": ("joyai_tiny", "joyai_tiny_f32"),
                 "qwen3_next": ("qwen_tiny", "qwen_tiny_f32"),
-                "lfm2_moe": ("lfm2_tiny", "lfm2_tiny_f32")}[name]
+                "lfm2_moe": ("lfm2_tiny", "lfm2_tiny_f32"),
+                "laguna": ("laguna_tiny", "laguna_tiny_f32")}[name]
         with open(os.path.join(ROOT, "tests", "benchmark", tiny[0], "configs",
                                tiny[1] + ".json")) as fh:
             self.cfg = json.load(fh)
         self.name = name
         self.prog = {"joyai_llm_flash": joyai_llm_flash, "qwen3_next": qwen3_next,
-                     "lfm2_moe": lfm2_moe}[name]
+                     "lfm2_moe": lfm2_moe, "laguna": laguna}[name]
         self.ref = run.load_py(os.path.join(ROOT, "benchmark", "reference", name + ".py"))
         # a selection bias drawn from the layer's index: the layer is told it
-        self.by_layer = name != "qwen3_next"
+        self.by_layer = name not in ("qwen3_next", "laguna")
         # the configuration's key for the experts HELD (the reference's count)
         self.held_key = ("n_routed_experts" if name == "joyai_llm_flash"
                          else "num_experts")
@@ -95,7 +97,8 @@ class Model:
             lambda p, x: f(p, x, ident))
 
 
-@pytest.fixture(scope="module", params=["joyai_llm_flash", "qwen3_next", "lfm2_moe"])
+@pytest.fixture(scope="module", params=["joyai_llm_flash", "qwen3_next", "lfm2_moe",
+                                        "laguna"])
 def model(request):
     return Model(request.param)
 
@@ -134,7 +137,7 @@ def _everything_on_one_expert(model):
     """``(cfg, params, x, lo)`` under which every token picks ONE expert, the
     same one, of the held range ``[lo, lo + share)``. JoyAI, LFM2: a router of
     zeros scores every expert alike, so the selection bias alone picks. Qwen3-Next
-    has no bias: tokens of positive entries against a router whose one column
+    and Laguna have no bias: tokens of positive entries against a router whose one column
     of ones outscores the columns of zeros."""
     one = dict(model.cfg, num_experts_per_tok=1)
     if model.by_layer:
@@ -229,8 +232,63 @@ def test_the_plain_body_is_attention_one_head_at_a_time(grouped):
     want = _value_and_grads(
         lambda *g: _one_head_at_a_time(*fill(list(g)), 0.25), *given)
     _close(got, want)
-    with pytest.raises(ValueError, match="attn_q_block"):
+    with pytest.raises(ValueError, match=r"attn_q_block=24 does not divide T=32 \(a full layer\)"):
         lm_layers.causal_attention(*args, 0.25, 24)
+    with pytest.raises(ValueError, match=r"T=32 \(a window layer, window=8\)"):
+        lm_layers.causal_attention(*args, 0.25, 24, 8)
+
+
+def _under_a_dense_mask(q, k, v, scale, window):
+    """Full ``[T, T]`` scores of one query head at a time under the dense
+    mask ``t - window < j <= t``, its key head copied out for it."""
+    t = q.shape[0]
+    q = q.reshape(t, -1, q.shape[-1])
+    per_key = q.shape[1] // k.shape[1]
+    at = jnp.arange(t)
+    seen = (at[None, :] <= at[:, None]) & (at[None, :] > at[:, None] - window)
+    out = []
+    for h in range(q.shape[1]):
+        s = jnp.where(seen, q[:, h] @ k[:, h // per_key].T * scale, -jnp.inf)
+        out.append(jax.nn.softmax(s, axis=-1) @ v[:, h // per_key])
+    return jnp.stack(out, axis=1)
+
+
+@pytest.mark.parametrize("t, q_block, window", [
+    (32, 16, 8), (32, 16, 16), (32, 16, 20), (32, 8, 5), (24, 32, 7), (48, 16, 33),
+    (32, 16, 1)])
+def test_the_banded_body_is_attention_under_a_dense_window_mask(t, q_block, window):
+    """Windows the query block divides, equals and does not divide, a length
+    of one block, a window wider than two blocks and a window of one (a query
+    sees itself: the output is its own value): output and gradients of the
+    plain body cut to the band are a dense-mask softmax's, at a key head each
+    and at a group of three query heads on one key head."""
+    for q, k, v in ((_x(1, t, 2, 16), _x(2, t, 2, 16), _x(3, t, 2, 16)),
+                    (_x(1, t, 1, 3, 16), _x(2, t, 1, 16), _x(3, t, 1, 16))):
+        got = _value_and_grads(
+            lambda q, k, v: lm_layers.causal_attention(
+                q, None, k, None, v, 0.25, q_block, window).reshape(t, -1, 16), q, k, v)
+        want = _value_and_grads(
+            lambda q, k, v: _under_a_dense_mask(q, k, v, 0.25, window), q, k, v)
+        _close(got, want)
+        if window == 1:
+            np.testing.assert_allclose(
+                got[0], jnp.broadcast_to(
+                    v.reshape(t, -1, 1, 16), (t, v.shape[1], got[0].shape[1] // v.shape[1], 16)
+                ).reshape(t, -1, 16), rtol=1e-6)
+
+
+def test_a_window_of_the_length_or_more_is_the_causal_body_bit_for_bit():
+    args = _attention_operands(True)
+    causal = jax.jit(lambda *a: lm_layers.causal_attention(
+        a[0], None, a[1], None, a[2], 0.25, 16))(args[0], args[2], args[4])
+    for window in (T, T + 1, 100 * T):
+        wide = jax.jit(lambda *a: lm_layers.causal_attention(
+            a[0], None, a[1], None, a[2], 0.25, 16, window))(args[0], args[2], args[4])
+        np.testing.assert_array_equal(wide, causal)
+    narrow = lm_layers.causal_attention(
+        args[0], None, args[2], None, args[4], 0.25, 16, T - 1)
+    assert float(jnp.max(jnp.abs(narrow[-1] - causal[-1]))) > 0  # the last query lost a key
+    np.testing.assert_array_equal(narrow[:-1], causal[:-1])
 
 
 @pytest.mark.parametrize("grouped", [False, True], ids=["joyai_llm_flash", "qwen3_next"])

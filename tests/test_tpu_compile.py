@@ -373,3 +373,163 @@ def test_the_lfm2_expert_layer_compiles_at_a_deployments_rows_a_product(one_chip
     assert any("bf16[40,1024,2048]" in l or "bf16[40,1024,1536]" in l for l in grouped)
     assert not re.search(r"bf16\[8,32768,2048\]|bf16\[8,131072,2048\]", text)
     assert temp < 5e9
+
+
+def _laguna_gradient(one_chip, layer):
+    """Compiled text and temporaries of one of Laguna's attention layers (the
+    published layer ``layer``: 0 is full, 1 sliding) at the published widths
+    and this chip's share of the heads (one key-value head of 128 with its 6
+    or 9 query heads) on ``laguna_s_2_1.fl4_seq8k``'s micro-batch, one row of
+    8,192 tokens, forward and backward under ``nn.remat`` with the model's
+    policy and under the scope its block gives it."""
+    import flax.linen as nn
+
+    from fedtpu.models import laguna as m
+
+    sizes = m.Sizes(kv_heads_held=(0, 1))
+    scope = "window_attention" if sizes.kind(layer) == m.KINDS[1] else "attention"
+    mixer = nn.remat(
+        m.Attention, policy=jax.checkpoint_policies.save_only_these_names(m.KEEP)
+    )(sizes, layer)
+    x = jax.ShapeDtypeStruct((1, 8192, 3072), jnp.bfloat16, sharding=one_chip)
+    params = jax.eval_shape(
+        lambda: mixer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 3072)))["params"])
+    params = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, jnp.bfloat16, sharding=one_chip), params)
+
+    def loss(params, x):
+        with jax.named_scope(m.SCOPE + scope):
+            return jnp.sum(mixer.apply({"params": params}, x).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile()
+    return compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+
+
+def _kernel_lines(text):
+    return [l for l in text.splitlines()
+            if " custom-call(" in l and 'custom_call_target="tpu_custom_call"' in l]
+
+
+def test_the_laguna_full_layer_compiles_to_one_forward_and_one_backward_kernel(
+        one_chip, monkeypatch):
+    """6 query heads on ONE key-value head of 128 over 8,192 tokens, YaRN on
+    half a head and a gate a head. The test says "Mosaic" where the program
+    asks, and the kernels take this share of the heads as they take whole
+    layers: the core is TWO kernels named as the other models', one forward
+    (kept, so the rematerialised forward pass runs none) and one backward, at
+    ``[6, 8192, 128]`` queries on ``[1, 8192, 128]`` keys, inside the VMEM
+    limit, both under the scope ``window_attention.full_core_roofline``
+    divides by; projections, gate and ``W_o`` carry the layer's scope and not
+    the core's; no float32 block of scores ``[., 6, 512, k]`` of the plain
+    body is left in the module."""
+    from fedtpu.ops import attention_kernels as ak
+
+    monkeypatch.setattr(ak, "_mode", lambda interpret: "mosaic")
+    text, temp = _laguna_gradient(one_chip, 0)
+    scope = "fed.local_step.fwd_bwd.attention"
+    kernels = _kernel_lines(text)
+    assert sorted(re.search(r"%(latent_attention_core_\w+?)[.\d]* =", l).group(1)
+                  for l in kernels) == [
+        "latent_attention_core_bwd", "latent_attention_core_fwd"], kernels
+    for line in kernels:
+        assert ak.SCOPE in re.search(r'op_name="([^"]*)"', line).group(1), line
+        operands = line[line.index("operand_layout_constraints="):line.index("metadata=")]
+        assert "bf16[6,8192,128]" in operands and "bf16[1,8192,128]" in operands, operands
+        used = re.search(r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', line)
+        assert 0 < int(used.group(1)) <= ak._VMEM_LIMIT
+    products = [l for l in text.splitlines() if " convolution(" in l]
+    assert products and all(scope in l for l in products)
+    assert not any(ak.SCOPE in l for l in products)  # the core's are in the kernels
+    turns = [l for l in text.splitlines() if " cosine(" in l or " sine(" in l]
+    assert turns and all(scope in l and ak.SCOPE not in l for l in turns)  # YaRN's
+    assert "window_attention" not in text
+    scores = [dims for dims in re.findall(r"f32\[((?:\d+,)*6,512,\d+)\]", text)
+              if int(dims.rsplit(",", 1)[1]) >= ak.BLOCK]
+    assert not scores, scores[:5]
+    assert temp < 2e9
+
+
+def test_the_laguna_sliding_layer_compiles_to_banded_plain_blocks_with_its_scopes(
+        one_chip, monkeypatch):
+    """9 query heads on ONE key-value head of 128 over 8,192 tokens, window
+    512, plain rotary over the whole head. The test says "Mosaic" where the
+    program asks and the kernels still refuse a windowed call: no custom call
+    in the module. The core is the plain query blocks cut to the band, under
+    ``fed.local_step.fwd_bwd.window_attention.core`` (what
+    ``window_attention.core_roofline`` divides by): a block of 512 queries
+    meets at most ``512 + 511`` keys, so no float32 scores wider than 1,023
+    keys exist (the causal body's widest are 8,192); the projections, the
+    gate, the rotary turns and ``W_o`` carry the layer's scope and not the
+    core's; nothing carries the full layers' scope."""
+    from fedtpu.ops import attention_kernels as ak
+
+    monkeypatch.setattr(ak, "_mode", lambda interpret: "mosaic")
+    text, temp = _laguna_gradient(one_chip, 1)
+    scope = "fed.local_step.fwd_bwd.window_attention"
+    assert not _kernel_lines(text)
+    products = [l for l in text.splitlines() if " convolution(" in l]
+    core = [l for l in products if scope + ".core" in l]
+    assert products and all(scope in l for l in products)
+    assert core and len(products) - len(core) >= 5 * 3 - 1  # five projections
+    # the rotary turns too: the core's seconds are scores, softmax and ``P v``
+    turns = [l for l in text.splitlines() if " cosine(" in l or " sine(" in l]
+    assert turns and all(scope in l and scope + ".core" not in l for l in turns)
+    assert "fwd_bwd.attention" not in text
+    keys = [int(dims.rsplit(",", 1)[1])
+            for dims in re.findall(r"f32\[((?:\d+,)*9,512,\d+)\]", text)]
+    assert keys and max(keys) == 1023 and min(keys) == 512, sorted(set(keys))
+    assert temp < 2e9
+
+
+def test_the_laguna_round_program_fits_one_chip_at_the_cells_size(one_chip, monkeypatch):
+    """``laguna_s_2_1.fl4_seq8k``'s whole round program from its own files (4
+    clients in sequence, 2 steps of 2 rows of 8,192 tokens in micro-batches of
+    one row, plain SGD on bfloat16 over the 568 M float32 masters, mixer and
+    feed-forward rematerialised each by itself), from shapes alone, compiled
+    for one described v5e with the kernels as the chip would choose them: the
+    chip's compiler refuses a program that does not fit its memory, so the
+    compile IS the check (14.60 GB "Total bytes used" in its memory report at
+    PR 44, of the 15.75 GiB a chip gives; PERF.md section 6). The two full
+    layers' cores are two kernels each, forward and backward, the three
+    sliding layers' are plain blocks under their own scope, and every scope
+    the cell's readers read is in the module."""
+    from benchmark import run as bench, sut
+    from fedtpu import models
+    from fedtpu.core.round import init_state
+    from fedtpu.data.device import make_data_round_step
+    from fedtpu.ops import attention_kernels as ak
+
+    monkeypatch.setattr(ak, "_mode", lambda interpret: "mosaic")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cell = bench.Cell(os.path.join(root, "BENCHMARK.json"), "laguna_s_2_1.fl4_seq8k")
+    cfg = sut.round_config(cell.config, cell.traffic, cell.task)
+    t, clients = cell.config["seq_len"], cell.traffic["clients"]
+    shard = cell.config["rows_per_client"]
+    model = models.create(cfg.model, num_classes=cfg.num_classes, remat=cfg.remat,
+                          **dict(cfg.model_args))
+    state = jax.eval_shape(
+        lambda key: init_state(model, cfg, key, jnp.zeros((1, t), jnp.int32)),
+        jax.random.PRNGKey(0))
+    assert sum(math.prod(l.shape) for l in jax.tree.leaves(state.params)) == 567_957_504
+    step = jax.jit(make_data_round_step(
+        model, cfg, cfg.steps_per_round, shuffle=False, image_shape=(t,),
+        layout="gather"), donate_argnums=(0,))
+    shapes = (
+        state, jnp.zeros((clients * shard, t), jnp.int32),
+        jnp.zeros((clients * shard, t), jnp.int32),
+        jnp.zeros((clients, shard), jnp.int32), jnp.ones((clients, shard), bool),
+        jnp.ones((clients,), jnp.float32), jnp.ones((clients,), bool),
+        jax.random.PRNGKey(0))
+    shapes = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip), shapes)
+    text = step.lower(*shapes).compile().as_text()  # raises where it does not fit
+    kernels = _kernel_lines(text)
+    assert sorted(re.search(r"%(latent_attention_core_\w+?)[.\d]* =", l).group(1)
+                  for l in kernels) == 2 * ["latent_attention_core_bwd"] + 2 * [
+        "latent_attention_core_fwd"], kernels
+    pre = "fed.local_step.fwd_bwd."
+    for scope in ("window_attention", "window_attention.core", "attention",
+                  "attention.core", "dense_ffn", "moe.router", "moe.dispatch",
+                  "moe.experts", "moe.combine", "embed", "lm_loss"):
+        assert pre + scope + "/" in text, scope
+    assert "ragged-dot" not in text

@@ -447,6 +447,53 @@ def leg_kernels():
             f"the delta rule's kernels differ from the plain chunks by {errs}: {name}")
     out[f"{name}_first_s"] = round(t_rule, 3)
     out[f"{name}_max_rel_err"] = errs
+    # The held experts' grouped products through the kernels against the plain
+    # batched product, bfloat16: an expert layer's routed part
+    # (``lm_layers.routed_experts``) on 1,536 tokens of width 256, four held
+    # experts of width 128, two a token over eight scored (so some pairs fall
+    # on absent experts and a held expert may get none), blocks of 128 rows in
+    # chunks of 1,024 pairs (a second chunk runs): output and the gradients of
+    # the tokens, the gates and the three weight stacks as shares of the plain
+    # body's largest value, held to 2e-2.
+    from fedtpu.ops import expert_kernels as ek
+
+    n, d, width, held = 1536, 256, 128, 4
+    chosen = np.argsort(draw(n, 2 * held), axis=1)[:, :2]
+    picked = jnp.asarray((chosen[:, :, None] == np.arange(held)).any(1))
+    ops = (
+        jnp.asarray(draw(n, d), jnp.bfloat16),
+        jnp.where(picked, jax.nn.sigmoid(jnp.asarray(draw(n, held))), 0.0),
+        jnp.asarray(draw(held, d, width) * d ** -0.5, jnp.bfloat16),
+        jnp.asarray(draw(held, d, width) * d ** -0.5, jnp.bfloat16),
+        jnp.asarray(draw(held, width, d) * width ** -0.5, jnp.bfloat16),
+    )
+    ct = jnp.asarray(draw(n, d), jnp.bfloat16)
+    name = f"routed_experts[{n},{d},{width},{held}]"
+    layer = lambda x, gates, *w: lm.routed_experts(
+        x, None, gates, picked, *w, 2, 1024, 128)[0]
+    require(ek.takes(jax.ShapeDtypeStruct((12 * 128, d), jnp.bfloat16), ops[2], 128),
+            f"the expert kernels do not engage: {name}")
+    both, backend = [], ek._mode
+    for mode in (backend, lambda interpret: "xla"):  # the kernels, the plain body
+        ek._mode = mode
+        try:
+            f = jax.jit(lambda *a: (lambda o, vjp: (o,) + vjp(ct))(*jax.vjp(layer, *a)))
+            text = f.lower(*ops).as_text()
+            both.append(jax.block_until_ready(f(*ops)))
+        finally:
+            ek._mode = backend
+        require(("tpu_custom_call" in text) == (not both[1:]),
+                f"the expert products took the wrong body: {name}")
+    errs = [
+        float(jnp.max(jnp.abs(g.astype(jnp.float32) - w.astype(jnp.float32)))
+              / jnp.max(jnp.abs(w.astype(jnp.float32))))
+        for g, w in zip(*both)
+    ]
+    print(f"{name}: y, dx, dgates, dw_gate, dw_up, dw_down differ from the plain "
+          f"body by {errs} of their largest value (limit 2e-2)", flush=True)
+    require(max(errs) <= 2e-2,
+            f"the expert kernels differ from the plain body by {errs}: {name}")
+    out[f"{name}_max_rel_err"] = errs
     return out
 
 

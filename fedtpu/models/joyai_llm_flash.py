@@ -27,12 +27,18 @@ writes them too:
   expert) pairs that fall on held experts are sorted by expert and
   multiplied group by group, a chunk of ``moe_chunk_pairs`` sorted pairs at
   a time: within a chunk each expert's pairs start at a boundary of
-  ``moe_block_rows`` rows, so every block has one expert and the grouped
-  product is a batched product over blocks (not ``jax.lax.ragged_dot``: the
-  chip's compiler turns that into kernels named ``ragged-dot-none``, which
-  carry no scope of the program, and a capture would read the experts' time
-  as ``_unscoped_``). A chunk past the last pair is skipped, so the work
-  follows the load and no pair is ever dropped.
+  ``moe_block_rows`` rows, so every block has one expert. On a TPU at the
+  published widths the grouped product is
+  :mod:`fedtpu.ops.expert_kernels`' (a block's weights read in place, the
+  blocks no pair fell in skipped); on the CPU and at the tiny test models'
+  widths it is a batched product over all the blocks, each with a copy of
+  its expert's matrices (:func:`fedtpu.models.lm_layers.routed_experts`
+  says which, ``fedtpu_expert_products_traced_total{body}`` counts it;
+  ``jax.lax.ragged_dot`` in neither: the chip's compiler turns that into
+  kernels named ``ragged-dot-none``, which carry no scope of the program,
+  and a capture would read the experts' time as ``_unscoped_``). A chunk
+  past the last pair is skipped, so the work follows the load and no pair
+  is ever dropped.
 - ``b`` (the config's ``e_score_correction_bias``) is a constant here: a
   normal draw of standard deviation ``bias_std`` from a key fixed by the
   layer's index. It shifts choices, takes no gradient and no round changes

@@ -45,7 +45,9 @@ entering as ``w``; no bias anywhere:
   the shared expert ungated. ``experts_held = (lo, hi)`` says which experts
   live here (all by default); what the absent ones would add is left out and
   the partial sum goes on. The routed path is
-  :func:`fedtpu.models.lm_layers.routed_experts`.
+  :func:`fedtpu.models.lm_layers.routed_experts` (its grouped
+  products: :mod:`fedtpu.ops.expert_kernels` on a TPU at the published
+  widths, a batched product over blocks elsewhere).
 - ``layers_held`` names the published layers built here, in order (all by
   default): ``layer_types``, ``num_attention_heads_per_layer`` and
   ``mlp_only_layers`` are read at those indices, so a cut states the

@@ -39,7 +39,9 @@ weight entering as ``w``:
   ``experts_held = (lo, hi)`` says which experts live here (all by default);
   what the absent ones would add is left out and the partial sum goes on, so a
   token whose experts are all absent keeps its residual. The routed path is
-  :func:`fedtpu.models.lm_layers.routed_experts`.
+  :func:`fedtpu.models.lm_layers.routed_experts` (its grouped
+  products: :mod:`fedtpu.ops.expert_kernels` on a TPU at the published
+  widths, a batched product over blocks elsewhere).
 - ``b`` (``use_expert_bias``) is a constant here: a normal draw of standard
   deviation ``bias_std`` from a key fixed by the layer's index. It shifts
   choices, takes no gradient and no round changes it.

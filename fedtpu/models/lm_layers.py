@@ -27,12 +27,25 @@ takes arrays and sizes, and names device time under
 - :func:`routed_experts`: the (token, expert) pairs that fall on the HELD
   experts, sorted by expert and multiplied group by group, a chunk of
   ``chunk_pairs`` sorted pairs at a time: within a chunk each expert's pairs
-  start at a boundary of ``block_rows`` rows, so every block has one expert
-  and the grouped product is a batched product over blocks (not
-  ``jax.lax.ragged_dot``: the chip's compiler turns that into kernels named
+  start at a boundary of ``block_rows`` rows, so every block has one expert,
+  the used blocks first. The grouped product has two bodies, one function of
+  the same operands, chosen by :func:`fedtpu.ops.expert_kernels.takes` from
+  the backend and the shapes alone: on a TPU, at widths of whole lanes and
+  blocks of whole sublane tiles (every published size), the kernels of
+  :mod:`fedtpu.ops.expert_kernels`, which read a block's weights in place
+  through a prefetched block-to-expert map and skip the blocks no pair fell
+  in, forward and backward; everywhere else (the CPU, the tiny test models'
+  widths) a batched product over ALL the blocks, each with a copy of its
+  expert's matrices picked by a one-hot product (not ``jax.lax.ragged_dot``
+  in either: the chip's compiler turns that into kernels named
   ``ragged-dot-none``, which carry no scope of the program, and a capture
-  would read the experts' time as ``_unscoped_``). A chunk past the last pair
-  is skipped, so the work follows the load and no pair is ever dropped.
+  would read the experts' time as ``_unscoped_``). Counted in the process's
+  registry by the body taken, three products a layer traced
+  (``fedtpu_expert_products_traced_total{body}``). The chunks are ONE loop
+  that runs while pairs are left, under one differentiation rule whose
+  backward pass is the same loop over the chunks' gradients (the first chunk
+  nearly always holds every pair): the work follows the load and no pair is
+  ever dropped.
 """
 
 from __future__ import annotations
@@ -48,7 +61,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from fedtpu.obs.registry import get_global_registry
-from fedtpu.ops import attention_kernels
+from fedtpu.ops import attention_kernels, expert_kernels
 from fedtpu.ops.losses import next_token_ce_parts
 
 SCOPE = "fed.local_step.fwd_bwd."
@@ -59,6 +72,8 @@ CORES_TRACED = "fedtpu_attention_cores_traced_total"
 # The same cores by body AND by what they attend over (kind = full | window):
 # a series of its own, so that ``CORES_TRACED{body}`` stays what it was.
 CORES_BY_KIND = "fedtpu_attention_cores_by_kind_total"
+# An expert layer's three grouped products, by the body taken.
+PRODUCTS_TRACED = "fedtpu_expert_products_traced_total"
 
 
 def _rms(x, scale, eps):
@@ -339,13 +354,25 @@ def routed_experts(xf, shared, gates_here, picked_here, w_gate, w_up, w_down,
             f"of {chunk} pairs")
     n_blocks = chunk // block + held  # every expert may end in a part block
 
-    @jax.checkpoint
-    def one_chunk(base):
+    kernel = expert_kernels.takes(
+        jax.ShapeDtypeStruct((n_blocks * block, d), xf.dtype), w_gate, block
+    ) and expert_kernels.takes(
+        jax.ShapeDtypeStruct((n_blocks * block, w_down.shape[1]), xf.dtype),
+        w_down, block)
+    get_global_registry().counter(
+        PRODUCTS_TRACED, "expert layers' grouped products traced (three a "
+        "layer), by the body taken",
+        labels={"body": "kernel" if kernel else "plain"}).inc(3)
+
+    def one_chunk(base, order, starts, ends, xf, flat_gates, w_gate, w_up, w_down):
         """Sorted pairs ``[base, base + chunk)`` through their experts:
         ``(gated outputs [rows, d] float32, their tokens [rows])``. Each
         expert's pairs are laid out from a block boundary on, so a block of
-        ``block`` rows has ONE expert and the grouped product is a batched
-        one over blocks; rows past an expert's last pair are zeros."""
+        ``block`` rows has ONE expert, the used blocks first; rows past an
+        expert's last pair are zeros. The grouped product is the kernels'
+        (:mod:`fedtpu.ops.expert_kernels`: a block's weights read in place,
+        the unused blocks skipped) or, off a TPU and at widths they refuse, a
+        batched one over every block with a copy of its expert's weights."""
         with jax.named_scope(SCOPE + "moe.dispatch"):
             sizes = jnp.clip(
                 jnp.minimum(ends, base + chunk) - jnp.maximum(starts, base),
@@ -362,32 +389,79 @@ def routed_experts(xf, shared, gates_here, picked_here, w_gate, w_up, w_down,
             src = jax.lax.dynamic_slice(order, (base,), (chunk,))[
                 jnp.where(live, at.reshape(-1), 0)]
             token = src // held
-            rows = jnp.where(live[:, None], xf[token], 0).reshape(
-                n_blocks, block, d)
-            pick = jax.nn.one_hot(expert, held, dtype=rows.dtype)
-            of_block = lambda w: jnp.einsum("be,eio->bio", pick, w.astype(rows.dtype))
+            rows = jnp.where(live[:, None], xf[token], 0)  # [blocks x block, d]
+            if kernel:
+                product = functools.partial(
+                    expert_kernels.grouped_product, expert=expert,
+                    live_blocks=last[-1], block=block)
+            else:
+                pick = jax.nn.one_hot(expert, held, dtype=rows.dtype)
+
+                def product(x, w, out_dtype=None):
+                    return jnp.einsum(
+                        "bri,bio->bro", x.reshape(n_blocks, block, -1),
+                        jnp.einsum("be,eio->bio", pick, w.astype(x.dtype)),
+                        preferred_element_type=out_dtype,
+                    ).reshape(n_blocks * block, -1)
         with jax.named_scope(SCOPE + "moe.experts"):
-            hidden = jax.nn.silu(
-                jnp.einsum("bri,bio->bro", rows, of_block(w_gate))
-            ) * jnp.einsum("bri,bio->bro", rows, of_block(w_up))
-            out = jnp.einsum("bri,bio->bro", hidden, of_block(w_down),
-                             preferred_element_type=jnp.float32)
+            hidden = jax.nn.silu(product(rows, w_gate)) * product(rows, w_up)
+            out = product(hidden, w_down, out_dtype=jnp.float32)
         with jax.named_scope(SCOPE + "moe.combine"):
             gate = jnp.where(live, flat_gates[src], 0.0)
-            return out.reshape(-1, d) * gate[:, None], token
+            return out * gate[:, None], token
 
-    def add_chunk(routed, base):
-        out, token = one_chunk(base)
-        with jax.named_scope(SCOPE + "moe.combine"):
-            return routed.at[token].add(out)
+    def chunk_by_chunk(pairs, first, and_chunk):
+        """``first`` (what the chunk at 0 gave) and, while pairs are left,
+        ``and_chunk(so far, base)`` for the chunks behind it: the first
+        nearly always holds them all, the loop is there so that nothing is
+        ever dropped."""
+        return jax.lax.while_loop(
+            lambda c: c[1] < pairs,
+            lambda c: (and_chunk(c[0], c[1]), c[1] + chunk),
+            (first, jnp.int32(chunk)))[0]
 
-    # Chunk by chunk while pairs are left: the first nearly always holds
-    # them all, the others are there so that nothing is ever dropped.
-    routed = jnp.zeros((n, d), jnp.float32)
-    for j in range(n_chunks):
-        routed = jax.lax.cond(
-            j * chunk < pairs, add_chunk, lambda routed, _: routed,
-            routed, jnp.int32(j * chunk))
+    # The chunks under ONE differentiation rule, the loops written out in
+    # both directions: a loop whose length the pairs decide has no reverse
+    # rule of jax's own, and a chain of ``cond``s (one a chunk the layout
+    # allows) hands every operand through each branch as an output and fills
+    # a skipped chunk's gradients with zeros, 200 MB a chunk at the published
+    # sizes. The backward pass makes a chunk's rows and hidden rows again
+    # (nothing block-sized is kept: the residuals are the operands).
+    @jax.custom_vjp
+    def chunks(ints, *operands):
+        order, starts, ends, pairs = ints
+
+        def and_chunk(routed, base):
+            out, token = one_chunk(base, order, starts, ends, *operands)
+            with jax.named_scope(SCOPE + "moe.combine"):
+                return routed.at[token].add(out)
+
+        return chunk_by_chunk(
+            pairs, and_chunk(jnp.zeros((n, d), jnp.float32), jnp.int32(0)),
+            and_chunk)
+
+    def chunks_fwd(ints, *operands):
+        return chunks(ints, *operands), (ints, operands)
+
+    def chunks_bwd(kept, d_routed):
+        (order, starts, ends, pairs), operands = kept
+
+        def of_chunk(base):
+            _, vjp, token = jax.vjp(
+                lambda *operands: one_chunk(base, order, starts, ends, *operands),
+                *operands, has_aux=True)
+            with jax.named_scope(SCOPE + "moe.combine"):
+                d_out = d_routed[token]  # the scatter-add, transposed
+            return vjp(d_out)
+
+        with jax.named_scope(SCOPE + "moe"):
+            return (None,) + chunk_by_chunk(
+                pairs, of_chunk(jnp.int32(0)),
+                lambda so_far, base: jax.tree.map(jnp.add, so_far, of_chunk(base)))
+
+    chunks.defvjp(chunks_fwd, chunks_bwd)
+    routed = chunks(
+        (order, starts, ends, pairs), xf, flat_gates, w_gate, w_up, w_down)
     with jax.named_scope(SCOPE + "moe.combine"):
         if shared is not None:
             routed = shared.astype(jnp.float32) + routed

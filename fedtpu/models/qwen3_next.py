@@ -69,7 +69,9 @@ initialised 0, is every norm but the gated one:
   that are HELD of g_e SwiGLU_e(x)``. ``experts_held = (lo, hi)`` says which
   experts live here (all by default); what the absent ones would add is left
   out and the partial sum goes on. The routed path is
-  :func:`fedtpu.models.lm_layers.routed_experts`, ``joyai_llm_flash``'s too.
+  :func:`fedtpu.models.lm_layers.routed_experts`, ``joyai_llm_flash``'s too (its grouped
+  products: :mod:`fedtpu.ops.expert_kernels` on a TPU at the published
+  widths, a batched product over blocks elsewhere).
 - Embedding, final ``Norm``, head, next-token cross-entropy over the
   vocabulary's rows held here. No prediction module: the config has no key
   for one.

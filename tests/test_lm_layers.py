@@ -175,7 +175,10 @@ def test_every_token_on_one_held_expert_and_nothing_is_dropped(model, chunk):
 def test_chunks_are_laid_out_for_the_pairs_a_token_can_have():
     """A token picks at most ``per_token`` experts, so the sorted pairs end by
     ``n * per_token``: 6 tokens x 8 held experts but 2 a token is 12 pairs at
-    most, 3 chunks of 4 and not 12; with every pair real nothing is lost."""
+    most, and a chunk that could hold more is laid out for those 12 (6 + 8
+    blocks of 2 rows), not for the 48 of every (token, held expert) (24 + 8
+    blocks); in chunks of 4 they take three turns of ONE loop (no ``cond`` a
+    chunk the layout allows), and with every pair real nothing is lost."""
     n, held, d, width = 6, 8, 16, 8
     x = _x(1, n, d)
     picked = jnp.zeros((n, held), bool).at[jnp.arange(n), jnp.arange(n) % held].set(
@@ -183,15 +186,58 @@ def test_chunks_are_laid_out_for_the_pairs_a_token_can_have():
     gates = jnp.where(picked, 0.5, 0.0)
     w = [_x(2 + i, held, *shape) for i, shape in
          enumerate([(d, width), (d, width), (width, d)])]
-    run = lambda per_token: lm_layers.routed_experts(
-        x, jnp.zeros_like(x), gates, picked, *w, per_token, 4, 2)
-    y, pairs, _ = run(2)
-    assert int(pairs) == 12
+    run = lambda per_token, chunk=4: lm_layers.routed_experts(
+        x, jnp.zeros_like(x), gates, picked, *w, per_token, chunk, 2)
     dense = sum(gates[:, e, None] * (
         (jax.nn.silu(x @ w[0][e]) * (x @ w[1][e])) @ w[2][e]) for e in range(held))
-    np.testing.assert_allclose(y, dense, rtol=1e-5, atol=1e-5)
-    conds = lambda per_token: str(jax.make_jaxpr(lambda: run(per_token)[0])()).count(" cond[")
-    assert (conds(2), conds(8)) == (3, 12)
+    for per_token, chunk in ((2, 4), (8, 4), (2, 64)):
+        y, pairs, _ = run(per_token, chunk)
+        assert int(pairs) == 12
+        np.testing.assert_allclose(y, dense, rtol=1e-5, atol=1e-5)
+    program = lambda per_token, chunk: str(
+        jax.make_jaxpr(lambda: run(per_token, chunk)[0])())
+    for per_token in (2, 8):
+        text = program(per_token, 4)
+        assert " cond[" not in text and text.count(" while[") == 1
+    rows = lambda blocks: f"f32[{2 * blocks},{d}]"  # a chunk's rows, laid out
+    assert rows(6 + 8) in program(2, 64) and rows(24 + 8) not in program(2, 64)
+    assert rows(24 + 8) in program(8, 64)
+
+
+def test_the_expert_layer_is_the_same_through_either_body(model, monkeypatch):
+    """A model's expert layer at widths of whole lanes (hidden 128, experts of
+    128), blocks of 16 rows and chunks of ``T * k`` pairs: through the kernels
+    (``fedtpu/ops/expert_kernels.py``, interpreted: the test says so where
+    the program asks the backend) and through the plain batched product it
+    gives the same ``(y, pairs, load)`` and the same gradients of the tokens
+    and of every parameter, and the counter says which body the three products
+    of a trace took."""
+    from fedtpu.ops import expert_kernels as ek
+
+    k = model.cfg["num_experts_per_tok"]
+    layer = model.layer(model.sizes(
+        hidden_size=128, moe_intermediate_size=128, moe_block_rows=16,
+        moe_chunk_pairs=T * k, experts_held=(4, 4 + model.share)))
+    x = _x(11, 2 * T, 128)
+    params = layer.init(jax.random.PRNGKey(12), x)["params"]
+    traced = lambda body: get_global_registry().counter(
+        lm_layers.PRODUCTS_TRACED, labels={"body": body}).value
+
+    def run(mode):
+        monkeypatch.setattr(ek, "_mode", lambda interpret: mode)
+        before = {b: traced(b) for b in ("kernel", "plain")}
+        apply = lambda p, x: layer.apply({"params": p}, x)
+        out = _value_and_grads(lambda p, x: apply(p, x)[0], params, x)
+        _, pairs, load = jax.jit(apply)(params, x)
+        return out, int(pairs), float(load), {
+            b: traced(b) - before[b] for b in before}
+
+    kernel, plain = run("interpret"), run("xla")
+    assert kernel[3]["kernel"] >= 3 and kernel[3]["plain"] == 0
+    assert plain[3]["plain"] >= 3 and plain[3]["kernel"] == 0
+    assert kernel[1] == plain[1] > 0 and kernel[2] == plain[2]
+    _close(kernel[0], plain[0])
+    assert float(jnp.abs(plain[0][1][0]["experts_down"]).max()) > 0
 
 
 # --------------------------------------------------- the plain attention body

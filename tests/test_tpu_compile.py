@@ -6,6 +6,7 @@ library, so the topology is described inside a fixture (never at import)
 and every compile happens in the test's own process.
 """
 
+import collections
 import math
 import os
 import re
@@ -63,7 +64,10 @@ def test_rotation_compiles_to_three_f32_products_and_one_copy(one_chip, inverse)
 def test_the_expert_layer_compiles_at_the_published_widths_with_its_scopes(one_chip):
     """One row of 4,096 tokens through an expert layer that holds 8 of 256
     experts of width 768 (``joyai_llm_flash.fl4_seq4k``'s micro-batch),
-    forward and backward: the grouped products are batched products over
+    forward and backward, through the PLAIN body (the backend here is the CPU,
+    and this test leaves the choice alone; the kernels' compile is
+    ``test_the_expert_kernels_compile_at_a_cells_chunk``): the grouped
+    products are batched products over
     blocks of 256 rows that carry the program's scope (``jax.lax.ragged_dot``
     would compile to kernels named ``ragged-dot-none``, which a capture reads
     as ``_unscoped_``), no product runs over every expert's copy of the
@@ -87,7 +91,8 @@ def test_the_expert_layer_compiles_at_the_published_widths_with_its_scopes(one_c
     assert "ragged-dot" not in text
     grouped = [l for l in text.splitlines() if " convolution(" in l
                and "fed.local_step.fwd_bwd.moe.experts" in l]
-    assert len(grouped) >= 9 * 8  # 3 forward, 6 transposed products a chunk
+    # 3 forward, 6 transposed products a chunk body: the first chunk's and the loop's
+    assert len(grouped) >= 9 * 2
     assert not re.search(r"bf16\[8,4096,2048\]|bf16\[8,32768,2048\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
@@ -357,7 +362,8 @@ def test_the_short_convolution_compiles_at_the_published_widths_with_its_scopes(
 def test_the_lfm2_expert_layer_compiles_at_a_deployments_rows_a_product(one_chip):
     """8 of 64 experts of width 1,536 on a micro-batch of 32,768 tokens, 4 a
     token: a held expert's expected 2,048 pairs are two blocks of 1,024 rows,
-    the grouped products are batched products over a chunk's 32 + 8 blocks
+    the grouped products (the plain body's: the backend here is the CPU) are
+    batched products over a chunk's 32 + 8 blocks
     under ``fed.local_step.fwd_bwd.moe.experts`` (what
     ``moe.experts_device_share`` reads), no ``ragged-dot`` kernel without the
     program's scope, no product over every expert's copy of the tokens, no
@@ -369,10 +375,65 @@ def test_the_lfm2_expert_layer_compiles_at_a_deployments_rows_a_product(one_chip
     assert "ragged-dot" not in text
     grouped = [l for l in text.splitlines() if " convolution(" in l
                and "fed.local_step.fwd_bwd.moe.experts" in l]
-    assert len(grouped) >= 9 * 4  # 3 forward, 6 transposed products a chunk, 4 chunks
+    # 3 forward, 6 transposed products a chunk body: the first chunk's and the loop's
+    assert len(grouped) >= 9 * 2
     assert any("bf16[40,1024,2048]" in l or "bf16[40,1024,1536]" in l for l in grouped)
     assert not re.search(r"bf16\[8,32768,2048\]|bf16\[8,131072,2048\]", text)
     assert temp < 5e9
+
+
+def _kernel_lines(text):
+    return [l for l in text.splitlines()
+            if " custom-call(" in l and 'custom_call_target="tpu_custom_call"' in l]
+
+
+EXPERT_CELLS = {  # (block, in, out, held, blocks a chunk)
+    "laguna_s_2_1.fl4_seq8k": (128, 3072, 1024, 8, 72),
+    "lfm2_24b_a2b.fl4_b8_seq4k": (1024, 2048, 1536, 8, 40),
+    "qwen3_next_80b_a3b.fl4_seq8k": (128, 2048, 512, 16, 80),
+    "joyai_llm_flash.fl4_seq4k": (256, 2048, 768, 8, 24),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
+def test_the_expert_kernels_compile_at_a_cells_chunk(one_chip, cell):
+    """An expert layer's three grouped products and their gradients on one
+    chunk of a language cell (its block, widths, held experts and blocks a
+    chunk, bfloat16) through ``fedtpu/ops/expert_kernels.py`` with Mosaic
+    asked for: nine kernels (three forward products, three on the transposed
+    contraction, three weight gradients), each inside the VMEM limit, the
+    backward ones under the scope the rule names itself; the weights go in as
+    the stacks they are (no ``[blocks, in, out]`` copy of a block's expert
+    anywhere) and the temporaries stay under a gigabyte."""
+    from fedtpu.ops import expert_kernels as ek
+
+    block, d, width, held, n_blocks = EXPERT_CELLS[cell]
+    of = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(rows, w_gate, w_up, w_down, expert, live):
+        product = lambda x, w, dtype=None: ek.grouped_product(
+            x, w, expert, live, block, dtype, interpret=False)
+        hidden = jax.nn.silu(product(rows, w_gate)) * product(rows, w_up)
+        return jnp.sum(product(hidden, w_down, jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        of(jnp.bfloat16, n_blocks * block, d), of(jnp.bfloat16, held, d, width),
+        of(jnp.bfloat16, held, d, width), of(jnp.bfloat16, held, width, d),
+        of(jnp.int32, n_blocks), of(jnp.int32)).compile()
+    text = compiled.as_text()
+    kernels = [(re.search(r"%(expert_\w+?)[.\d]* =", l).group(1), l)
+               for l in _kernel_lines(text)]
+    assert collections.Counter(name for name, _ in kernels) == {
+        "expert_product": 3, "expert_product_transposed": 3,
+        "expert_weights_gradient": 3}, kernels
+    for name, line in kernels:
+        used = re.search(r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', line)
+        assert 0 < int(used.group(1)) <= ek._VMEM_LIMIT
+        if name != "expert_product":  # the forward's scope is its caller's
+            assert ek.SCOPE in re.search(r'op_name="([^"]*)"', line).group(1), line
+    assert not re.search(
+        rf"\[{n_blocks},(?:{d},{width}|{width},{d})\]", text)  # a block's copy
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
 
 
 def _laguna_gradient(one_chip, layer):
@@ -403,11 +464,6 @@ def _laguna_gradient(one_chip, layer):
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile()
     return compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
-
-
-def _kernel_lines(text):
-    return [l for l in text.splitlines()
-            if " custom-call(" in l and 'custom_call_target="tpu_custom_call"' in l]
 
 
 def test_the_laguna_full_layer_compiles_to_one_forward_and_one_backward_kernel(
@@ -489,17 +545,22 @@ def test_the_laguna_round_program_fits_one_chip_at_the_cells_size(one_chip, monk
     for one described v5e with the kernels as the chip would choose them: the
     chip's compiler refuses a program that does not fit its memory, so the
     compile IS the check (14.60 GB "Total bytes used" in its memory report at
-    PR 44, of the 15.75 GiB a chip gives; PERF.md section 6). The two full
-    layers' cores are two kernels each, forward and backward, the three
-    sliding layers' are plain blocks under their own scope, and every scope
-    the cell's readers read is in the module."""
+    PR 44, 10.60 GB since PR 45 took the per-block copies of the experts'
+    weights and the chain of conditionals a chunk out, of the 15.75 GiB a
+    chip gives; PERF.md section 6). The two
+    full layers' cores are two kernels each, forward and backward, the three
+    sliding layers' are plain blocks under their own scope, the four sparse
+    layers' grouped products are the expert kernels, chunk by chunk, and
+    every scope the cell's readers read is in the module."""
     from benchmark import run as bench, sut
     from fedtpu import models
     from fedtpu.core.round import init_state
     from fedtpu.data.device import make_data_round_step
     from fedtpu.ops import attention_kernels as ak
+    from fedtpu.ops import expert_kernels as ek
 
     monkeypatch.setattr(ak, "_mode", lambda interpret: "mosaic")
+    monkeypatch.setattr(ek, "_mode", lambda interpret: "mosaic")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     cell = bench.Cell(os.path.join(root, "BENCHMARK.json"), "laguna_s_2_1.fl4_seq8k")
     cfg = sut.round_config(cell.config, cell.traffic, cell.task)
@@ -523,10 +584,21 @@ def test_the_laguna_round_program_fits_one_chip_at_the_cells_size(one_chip, monk
     shapes = jax.tree.map(
         lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip), shapes)
     text = step.lower(*shapes).compile().as_text()  # raises where it does not fit
-    kernels = _kernel_lines(text)
-    assert sorted(re.search(r"%(latent_attention_core_\w+?)[.\d]* =", l).group(1)
-                  for l in kernels) == 2 * ["latent_attention_core_bwd"] + 2 * [
-        "latent_attention_core_fwd"], kernels
+    kernels = collections.Counter(
+        re.search(r"%(\w+?)[.\d]* =", l).group(1) for l in _kernel_lines(text))
+    # a sparse layer's chunk bodies: the first chunk and the loop's, each
+    # forward and again where the backward pass makes the rows anew
+    bodies = 4 * 2
+    assert kernels == {
+        "latent_attention_core_fwd": 2, "latent_attention_core_bwd": 2,
+        "expert_product": 3 * 2 * bodies,
+        "expert_product_transposed": 3 * bodies,
+        "expert_weights_gradient": 3 * bodies}, kernels
+    assert not re.search(r"bf16\[72,(?:3072,1024|1024,3072)\]", text)  # a block's copy
+    # no chain of conditionals a chunk: none hands the stacks through a
+    # branch as a copy or fills a skipped chunk's gradients with zeros
+    assert " conditional(" not in text
+    assert not re.search(r"= bf16\[8,(?:3072,1024|1024,3072)\]\S* (?:copy|broadcast)\(", text)
     pre = "fed.local_step.fwd_bwd."
     for scope in ("window_attention", "window_attention.core", "attention",
                   "attention.core", "dense_ffn", "moe.router", "moe.dispatch",

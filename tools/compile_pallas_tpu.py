@@ -9,7 +9,9 @@ sandbox without spending chip time. Whether the program then RUNS is
 
 1. the Pallas compression kernels at MobileNet scale (64 clients x ~3.2M
    params — the ``-c Y`` hot path) with ``interpret=False``, proving Mosaic
-   lowering + VMEM fit;
+   lowering + VMEM fit; and the held experts' grouped product
+   (``fedtpu/ops/expert_kernels.py``), forward and both backward kernels, at
+   the four language cells' chunks;
 2. the full single-chip federated round step (bench.py's exact config);
 3. the sharded 4-chip round step (shard_map + psum over the clients mesh) —
    the multichip program compiled for actual TPU hardware, not just the
@@ -78,6 +80,61 @@ def compile_kernels(dev):
                 "shape": [NUM_CLIENTS, MOBILENET_PARAMS],
                 "compile_s": round(time.perf_counter() - t0, 2),
                 "ok": True,
+                **_mem(compiled),
+            }
+        )
+    return results
+
+
+# The four language cells' expert layers, a chunk of each: (block, in, out,
+# held experts, blocks a chunk).
+EXPERT_CHUNKS = {
+    "laguna_s_2_1.fl4_seq8k": (128, 3072, 1024, 8, 72),
+    "lfm2_24b_a2b.fl4_b8_seq4k": (1024, 2048, 1536, 8, 40),
+    "qwen3_next_80b_a3b.fl4_seq8k": (128, 2048, 512, 16, 80),
+    "joyai_llm_flash.fl4_seq4k": (256, 2048, 768, 8, 24),
+}
+
+
+def compile_expert_kernels(dev):
+    """The held experts' grouped product (``fedtpu/ops/expert_kernels.py``)
+    at each language cell's chunk, bfloat16: the forward product and the two
+    backward kernels (``d rows`` on the transposed contraction, ``d w`` summed
+    over an expert's run of blocks), through Mosaic; ``vmem_bytes`` is the
+    most any of the three took of the kernels' scoped limit."""
+    import re
+
+    from fedtpu.ops import expert_kernels as ek
+
+    s = jax.sharding.SingleDeviceSharding(dev)
+    of = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype, sharding=s)
+    results = []
+    for cell, (block, d, width, held, n_blocks) in EXPERT_CHUNKS.items():
+        def product_and_gradients(rows, w, expert, live, ct):
+            out, vjp = jax.vjp(lambda rows, w: ek.grouped_product(
+                rows, w, expert, live, block, interpret=False), rows, w)
+            return (out,) + vjp(ct)
+
+        t0 = time.perf_counter()
+        compiled = jax.jit(product_and_gradients).lower(
+            of(jnp.bfloat16, n_blocks * block, d), of(jnp.bfloat16, held, d, width),
+            of(jnp.int32, n_blocks), of(jnp.int32),
+            of(jnp.bfloat16, n_blocks * block, width)).compile()
+        text = compiled.as_text()
+        kernels = sorted(set(re.findall(r"%(expert_\w+?)[.\d]* = ", text)))
+        vmem = [int(n) for n in re.findall(
+            r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', text)]
+        results.append(
+            {
+                "artifact": f"pallas:expert_kernels:{cell}",
+                "target": dev.device_kind,
+                "shape": {"rows": [n_blocks * block, d], "weights": [held, d, width],
+                          "block": block},
+                "kernels": kernels,
+                "vmem_bytes": max(vmem, default=0),
+                "compile_s": round(time.perf_counter() - t0, 2),
+                "ok": kernels == ["expert_product", "expert_product_transposed",
+                                  "expert_weights_gradient"],
                 **_mem(compiled),
             }
         )
@@ -487,6 +544,7 @@ def main():
     results = []
     for fn in (
         lambda: compile_kernels(dev),
+        lambda: compile_expert_kernels(dev),
         lambda: [compile_round_step(dev)],
         # The flagship model (MobileNet — the reference's hardcoded default,
         # src/main.py:69) at the bench scale, single chip.

@@ -19,7 +19,12 @@ weight entering as ``w``:
   ``qwen3_next``'s too); ``Op = W_out (C x z)``. No activation between the
   gates, no state beyond ``L - 1`` tokens. The gates and the taps work in
   float32 from the compute dtype's ``B``, ``C``, ``X`` and leave the compute
-  dtype's ``C x z`` (:func:`gated_short_conv`).
+  dtype's ``C x z`` (:func:`gated_short_conv`). The convolution's operand is
+  the float32 ``B x X``, written once by the gate in front and kept for the
+  backward pass beside the taps; the convolution's own rule writes nothing
+  else of that size in float32 (its cotangent passes the reversed taps in
+  one fusion, no padded copy a tap), and the taps' gradient is summed over
+  time and rows in float32 before it is rounded.
 - ``Op_i`` where ``layer_types[i] == "full_attention"``: ``q = W_q u``
   (``num_attention_heads`` heads of ``hidden / heads``), ``k = W_k u``, ``v =
   W_v u`` (``num_key_value_heads`` heads), RMSNorm over each q and k head's

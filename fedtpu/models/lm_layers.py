@@ -162,11 +162,58 @@ def rope_half(x, theta: float, rot: int, inv_freq=None, factor=None):
 def causal_conv(x, kernel):
     """Depthwise causal convolution over time of ``x [T, channels]`` with
     ``kernel [width, channels]``: ``y_t = sum_i kernel_i x_{t - width + 1 +
-    i}``, zeros before the row's start; float32 sums."""
-    t, width = x.shape[0], kernel.shape[0]
-    padded = jnp.pad(x, ((width - 1, 0), (0, 0))).astype(jnp.float32)
-    y = sum(padded[i:i + t] * kernel[i].astype(jnp.float32) for i in range(width))
-    return y.astype(x.dtype)
+    i}``, zeros before the row's start; float32 sums, the result in
+    ``x.dtype``. One differentiation rule on every backend
+    (:func:`_conv_rule`): forward and backward are each ONE pass of shifted
+    slices over an operand that stays in its own dtype in memory (``x``
+    forward, the cotangent backward: read once, written once), and what the
+    backward pass keeps is ``x`` and the taps. ``jax.grad`` of the same sums
+    writes a float32 copy of the padded operand and one of the cotangent a
+    tap: six times the bytes at ``[8192, 8192]`` bfloat16 (PERF.md §6, PR 46).
+    The taps enter the rule in float32, so their gradient leaves it in
+    float32 and is rounded to ``kernel.dtype`` once, after ``vmap`` has summed
+    it over rows."""
+    return _conv_rule(x, kernel.astype(jnp.float32))
+
+
+def _shifted(x, width, lead):
+    """The ``width`` slices ``x_{t - lead + i}`` of ``x [T, channels]`` in
+    float32, ``x`` padded in its OWN dtype with ``lead`` zero rows in front
+    and ``width - 1 - lead`` behind: a slice turns float32 where it is used,
+    inside the fusion, so no float32 copy of the operand is written."""
+    t = x.shape[0]
+    padded = jnp.pad(x, ((lead, width - 1 - lead), (0, 0)))
+    return [padded[i:i + t].astype(jnp.float32) for i in range(width)]
+
+
+def _tap_sum(x, taps, lead):
+    """``sum_i taps_i x_{t - lead + i}`` in float32."""
+    return sum(rows * tap for rows, tap in zip(_shifted(x, taps.shape[0], lead), taps))
+
+
+@jax.custom_vjp
+def _conv_rule(x, taps):
+    return _tap_sum(x, taps, taps.shape[0] - 1).astype(x.dtype)
+
+
+def _conv_rule_fwd(x, taps):
+    return _conv_rule(x, taps), (x, taps)
+
+
+def _conv_rule_bwd(kept, dy):
+    """``d x_t = sum_i taps_i dy_{t + width - 1 - i}``: the taps reversed
+    over the cotangent padded BEHIND, so the zeros before the row's start
+    receive nothing. ``d taps_i = sum_t x_{t - width + 1 + i} dy_t``:
+    ``width`` float32 reductions over time that read the padded ``x``."""
+    x, taps = kept
+    width = taps.shape[0]
+    dx = _tap_sum(dy, taps[::-1], 0).astype(x.dtype)
+    dy = dy.astype(jnp.float32)
+    dtaps = jnp.stack([jnp.sum(rows * dy, axis=0) for rows in _shifted(x, width, width - 1)])
+    return dx, dtaps
+
+
+_conv_rule.defvjp(_conv_rule_fwd, _conv_rule_bwd)
 
 
 @functools.partial(jax.checkpoint, static_argnums=(5, 6, 7, 8))

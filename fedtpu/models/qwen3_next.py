@@ -16,7 +16,13 @@ initialised 0, is every norm but the gated one:
   ``dv``; key head ``j`` serves value heads ``j * Hv / Hk`` on): ``[q, k, v,
   z] = W_qkvz x``, ``[b, a] = W_ba x``; a causal depthwise convolution of
   width ``linear_conv_kernel_dim`` without bias over the channels of ``[q, k,
-  v]`` (``y_t = sum_i w_i x_{t - width + 1 + i}``), then SiLU; ``q, k`` divided
+  v]`` (``y_t = sum_i w_i x_{t - width + 1 + i}``:
+  :func:`fedtpu.models.lm_layers.causal_conv`, float32 sums over an operand
+  that stays bfloat16 in memory, one pass over ``[T, 2 Hk dk + Hv dv]``
+  forward and one over its cotangent backward under one differentiation rule;
+  the backward pass keeps the operand and the taps. Four multiply-adds a
+  channel a token: its cost is its bytes, so nothing the size of the operand
+  is written in float32), then SiLU; ``q, k`` divided
   by ``sqrt(sum of squares + 1e-6)`` over their width, ``q`` scaled by
   ``dk^-0.5``; ``beta_t = sigmoid(b_t)``, ``g_t = -exp(A_log) softplus(a_t +
   dt_bias)`` in float32, one a value head. A value head's state ``S`` (``dk x

@@ -359,6 +359,49 @@ def test_the_short_convolution_compiles_at_the_published_widths_with_its_scopes(
     assert temp < 2e9
 
 
+def _bytes_and_entry(one_chip, fn, *shapes):
+    """Bytes the compiled ``fn`` accesses, and its entry computation's lines:
+    what is written to memory is an instruction's output THERE (inside a
+    fused computation a value lives in registers)."""
+    compiled = jax.jit(fn).lower(*(
+        jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip) for s in shapes)).compile()
+    text = compiled.as_text()
+    return compiled.cost_analysis()["bytes accessed"], text[text.index("ENTRY"):].splitlines()
+
+
+def test_the_causal_convolution_moves_its_operand_once_forward_and_once_backward(one_chip):
+    """``lm_layers.causal_conv`` alone with each caller's elementwise
+    neighbours, bfloat16. The hybrid's ``[8192, 8192]`` under four taps and
+    SiLU: the forward is one read and one write of the operand (0.269 GB; the
+    form left to ``jax.grad`` read 1.611), ``jax.grad`` with its forward
+    0.672 GB (4.162), and nothing the size of the operand is written in
+    float32, padded or not (the old form wrote one ``f32[8195, 8192]`` forward
+    and four ``f32[8192, 8192]`` backward). LFM2's ``[8, 4096, 2048]`` double
+    gate under three taps, vmapped over rows: ``jax.grad`` 3.088 GB (3.624; the
+    float32 ``B x X`` that remains is the caller's)."""
+    from fedtpu.models import lfm2_moe, lm_layers
+
+    weighed = lambda y, dy: jnp.sum(y.astype(jnp.float32) * dy.astype(jnp.float32))
+    act = lambda x, taps: jax.nn.silu(lm_layers.causal_conv(x, taps))
+    rows, taps = (8192, 8192), (4, 8192)
+    forward, entry = _bytes_and_entry(one_chip, act, rows, taps)
+    both, entry_grad = _bytes_and_entry(
+        one_chip, jax.grad(lambda x, taps, dy: weighed(act(x, taps), dy), argnums=(0, 1)),
+        rows, taps, rows)
+    assert forward < 0.35e9, forward
+    assert both < 0.9e9, both
+    written = [l.strip()[:120] for l in entry + entry_grad
+               if re.search(r"= [^=]*f32\[819[25],8192\][^=]* (?!parameter)\w+\(", l)]
+    assert not written, written
+
+    gated = jax.vmap(lfm2_moe.gated_short_conv, in_axes=(0, 0, 0, None))
+    rows = (8, 4096, 2048)
+    lfm2, _ = _bytes_and_entry(
+        one_chip, jax.grad(lambda b, c, x, taps, dy: weighed(gated(b, c, x, taps), dy),
+                           argnums=(0, 1, 2, 3)), rows, rows, rows, (3, 2048), rows)
+    assert lfm2 < 3.4e9, lfm2
+
+
 def test_the_lfm2_expert_layer_compiles_at_a_deployments_rows_a_product(one_chip):
     """8 of 64 experts of width 1,536 on a micro-batch of 32,768 tokens, 4 a
     token: a held expert's expected 2,048 pairs are two blocks of 1,024 rows,

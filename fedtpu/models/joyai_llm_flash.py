@@ -23,22 +23,10 @@ writes them too:
   ``y = SwiGLU_shared(x) + sum over chosen e that are HELD of g_e
   SwiGLU_e(x)``. ``experts_held = (lo, hi)`` says which experts live here
   (expert parallelism's share; all of them by default). What the absent
-  experts would add is left out and the partial sum goes on. The (token,
-  expert) pairs that fall on held experts are sorted by expert and
-  multiplied group by group, a chunk of ``moe_chunk_pairs`` sorted pairs at
-  a time: within a chunk each expert's pairs start at a boundary of
-  ``moe_block_rows`` rows, so every block has one expert. On a TPU at the
-  published widths the grouped product is
-  :mod:`fedtpu.ops.expert_kernels`' (a block's weights read in place, the
-  blocks no pair fell in skipped); on the CPU and at the tiny test models'
-  widths it is a batched product over all the blocks, each with a copy of
-  its expert's matrices (:func:`fedtpu.models.lm_layers.routed_experts`
-  says which, ``fedtpu_expert_products_traced_total{body}`` counts it;
-  ``jax.lax.ragged_dot`` in neither: the chip's compiler turns that into
-  kernels named ``ragged-dot-none``, which carry no scope of the program,
-  and a capture would read the experts' time as ``_unscoped_``). A chunk
-  past the last pair is skipped, so the work follows the load and no pair
-  is ever dropped.
+  experts would add is left out and the partial sum goes on. The layer is
+  :class:`fedtpu.models.lm_layers.ExpertLayer`, every language model's, with
+  this rule handed in (:func:`experts`); how the held experts' pairs are
+  laid out and multiplied is :func:`fedtpu.models.lm_layers.routed_experts`'.
 - ``b`` (the config's ``e_score_correction_bias``) is a constant here: a
   normal draw of standard deviation ``bias_std`` from a key fixed by the
   layer's index. It shifts choices, takes no gradient and no round changes
@@ -48,13 +36,12 @@ writes them too:
   norm, one expert-layer block, the model's final norm and head: logits for
   ``t_{i+2}``. Embedding, final norm and head are the model's own.
 
-In training the module takes the targets and returns each head's
-cross-entropy ``(sum, count, hits)``, the final norm, head and loss worked
-out a row at a time (the local step weighs the prediction module's by
-``mtp_loss_weight``, :mod:`fedtpu.core.client`); in evaluation the next-token
-logits. Every size is a keyword of the
-constructor (``RoundConfig.model_args``); the defaults are the published
-ones. ``num_classes`` is the vocabulary's rows held here.
+Embedding, blocks, final norm, head and loss are
+:class:`fedtpu.models.lm_layers.DecoderStack`'s; in training this model
+returns a ``(sum, count, hits)`` for each head (the local step weighs the
+prediction module's by ``mtp_loss_weight``, :mod:`fedtpu.core.client`).
+Every size is a keyword of the constructor (``RoundConfig.model_args``); the
+defaults are the published ones.
 
 Device time is named under ``fed.local_step.fwd_bwd.``: ``embed``,
 ``attention`` (``attention.core``: scores, softmax, ``P v``), ``moe``
@@ -67,17 +54,16 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from fedtpu.models.lm_layers import (  # noqa: F401 (names the tests and tools reach through this module)
-    CORES_TRACED, KEEP, SCOPE, Linear, RMSNorm, SwiGLU, _expert_init, _rms,
-    _row_loss_parts, attention_core, causal_attention, held_range,
-    routed_experts, sizes_from_keywords)
-from fedtpu.models.registry import register
+    CORES_TRACED, KEEP, SCOPE, DecoderStack, Linear, RMSNorm, Trunk,
+    attention_core, causal_attention, feed_forward, head_sums, held_range,
+    register_language_model, rematerialised, top_k_gates)
 from fedtpu.ops.losses import shift_targets
 
 
@@ -116,10 +102,6 @@ class Sizes:
     attn_q_block: int = 512
     moe_chunk_pairs: int = 16384
     moe_block_rows: int = 256
-
-    @property
-    def held(self) -> Tuple[int, int]:
-        return held_range(self.experts_held, self.n_routed_experts)
 
 
 def correction_bias(layer: int, sizes: Sizes) -> jnp.ndarray:
@@ -178,48 +160,19 @@ class LatentAttention(nn.Module):
         return Linear(x.shape[-1], name="o")(o.reshape(b, t, h * vd))
 
 
-class ExpertLayer(nn.Module):
-    """Shared expert plus this chip's share of the routed experts. Returns
-    ``(y, pairs, load)``: the pairs computed here and the busiest held
-    expert's load over the held experts' mean load."""
-
-    sizes: Sizes
-    layer: int
-
-    @nn.compact
-    def __call__(self, x):
-        c = self.sizes
-        lo, hi = c.held
-        held, k = hi - lo, c.num_experts_per_tok
-        d, width = x.shape[-1], c.moe_intermediate_size
-        xf = x.reshape(-1, d)
-        shared = SwiGLU(width * c.n_shared_experts, name="shared")(xf)
-        router = self.param(
-            "router", nn.initializers.variance_scaling(2.0, "fan_in", "normal"),
-            (d, c.n_routed_experts))
-        w_gate = self.param("experts_gate", _expert_init, (held, d, width))
-        w_up = self.param("experts_up", _expert_init, (held, d, width))
-        w_down = self.param("experts_down", _expert_init, (held, width, d))
-
-        with jax.named_scope(SCOPE + "moe.router"):
-            # Float32 out of the accumulator: exact products of the compute
-            # dtype's operands, summed in float32.
-            s = jax.nn.sigmoid(jnp.dot(
-                xf, router.astype(xf.dtype),
-                preferred_element_type=jnp.float32))
-            _, chosen = jax.lax.top_k(s + correction_bias(self.layer, c), k)
-            picked = (chosen[:, :, None] == jnp.arange(c.n_routed_experts)).any(1)
-            s_picked = jnp.where(picked, s, 0.0)
-            gates = c.routed_scaling_factor * s_picked / jnp.sum(
-                s_picked, axis=-1, keepdims=True)
-            # Held experts are a range: a token's gates for them are a slice.
-            gates_here = gates[:, lo:hi]  # [n, held], 0 where not chosen
-            picked_here = picked[:, lo:hi]
-
-        y, pairs, load = routed_experts(
-            xf, shared, gates_here, picked_here, w_gate, w_up, w_down, k,
-            c.moe_chunk_pairs, c.moe_block_rows)
-        return y.reshape(x.shape), pairs, load
+def experts(sizes: Sizes, layer: int) -> dict:
+    """Expert layer ``layer``'s fields of :class:`lm_layers.ExpertLayer`: the
+    shared experts as one SwiGLU, the module docstring's gate rule."""
+    c = sizes
+    return dict(
+        routed=c.n_routed_experts, k=c.num_experts_per_tok,
+        held=held_range(c.experts_held, c.n_routed_experts),
+        width=c.moe_intermediate_size, chunk_pairs=c.moe_chunk_pairs,
+        block_rows=c.moe_block_rows,
+        shared_width=c.moe_intermediate_size * c.n_shared_experts,
+        gate_rule=lambda logits, k: top_k_gates(
+            jax.nn.sigmoid(logits), k, bias=correction_bias(layer, c),
+            scale=c.routed_scaling_factor))
 
 
 class Block(nn.Module):
@@ -233,20 +186,20 @@ class Block(nn.Module):
         with jax.named_scope(SCOPE + "attention"):
             h = h + LatentAttention(c, name="attn")(
                 RMSNorm(c.rms_norm_eps, name="attn_norm")(h))
-        x = RMSNorm(c.rms_norm_eps, name="ffn_norm")(h)
-        if self.dense:
-            with jax.named_scope(SCOPE + "dense_ffn"):
-                y = SwiGLU(c.intermediate_size, name="ffn")(x)
-            pairs, load = jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32)
-        else:
-            with jax.named_scope(SCOPE + "moe"):
-                y, pairs, load = ExpertLayer(c, self.layer, name="moe")(x)
+        # the whole block is rematerialised (``JoyAILLMFlash``), not its halves
+        y, pairs, load = feed_forward(
+            RMSNorm(c.rms_norm_eps, name="ffn_norm")(h), False,
+            experts(c, self.layer),
+            dense=("ffn", c.intermediate_size) if self.dense else None)
         return h + y, pairs, load
 
 
-class JoyAILLMFlashModule(nn.Module):
-    sizes: Sizes
-    remat: bool = False
+class JoyAILLMFlashModule(DecoderStack):
+    """The stack and, on its last block's output, the prediction modules
+    (module docstring): ``prediction_blocks``, a block's constructor a module,
+    called with the block's ``name``."""
+
+    prediction_blocks: Tuple[Callable[..., nn.Module], ...] = ()
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, targets=None):
@@ -256,65 +209,34 @@ class JoyAILLMFlashModule(nn.Module):
         ``(cross-entropy sum, count, hits)`` a head: the next-token head, then
         the prediction modules', module ``k`` against the targets moved
         ``k + 1`` further."""
-        c = self.sizes
-        block = nn.remat(
-            Block, policy=jax.checkpoint_policies.save_only_these_names(KEEP)
-        ) if self.remat else Block
-        embed = nn.Embed(c.vocab_size, c.hidden_size, name="embed",
-                         embedding_init=nn.initializers.normal(1.0))
-        norm_scale = self.param(
-            "final_norm", nn.initializers.ones_init(), (c.hidden_size,))
-        head = self.param(
-            "head", nn.initializers.variance_scaling(0.02, "fan_in", "normal"),
-            (c.hidden_size, c.vocab_size))
-
-        def parts_of(h, depth):
-            rows = jax.lax.map(
-                lambda a: _row_loss_parts(
-                    a[0], a[1], norm_scale, head, c.rms_norm_eps),
-                (h, shift_targets(targets, depth)))
-            return tuple(jnp.sum(p) for p in rows)
-
-        with jax.named_scope(SCOPE + "embed"):
-            h = embed(tokens)
-        pairs, loads = [], []
-        for i in range(c.num_hidden_layers):
-            h, p, l = block(c, i, i < c.first_k_dense_replace,
-                            name=f"layer_{i}")(h)
-            pairs.append(p)
-            loads.append(l)
+        trunk = Trunk(self, tokens)
         if not train:
-            with jax.named_scope(SCOPE + "lm_loss"):
-                return jnp.dot(
-                    _rms(h, norm_scale, c.rms_norm_eps), head.astype(h.dtype),
-                    preferred_element_type=jnp.float32)
-        heads = [parts_of(h, 0)]
-        for depth in range(c.num_nextn_predict_layers):
+            return trunk.logits()
+        h, heads = trunk.h, [head_sums(trunk.head_rows(trunk.h, targets))]
+        for depth, block in enumerate(self.prediction_blocks):
             with jax.named_scope(SCOPE + "mtp"):
                 with jax.named_scope(SCOPE + "embed"):
-                    nxt = embed(jnp.roll(tokens, -(depth + 1), axis=1))
+                    nxt = trunk.embed(jnp.roll(tokens, -(depth + 1), axis=1))
                 both = jnp.concatenate([
-                    RMSNorm(c.rms_norm_eps, name=f"mtp_{depth}_enorm")(nxt),
-                    RMSNorm(c.rms_norm_eps, name=f"mtp_{depth}_hnorm")(h),
+                    RMSNorm(self.eps, name=f"mtp_{depth}_enorm")(nxt),
+                    RMSNorm(self.eps, name=f"mtp_{depth}_hnorm")(h),
                 ], axis=-1)
-                h = Linear(c.hidden_size, name=f"mtp_{depth}_eh_proj")(both)
-                h, p, l = block(c, c.num_hidden_layers + depth, False,
-                                name=f"mtp_{depth}_block")(h)
-                pairs.append(p)
-                loads.append(l)
-                heads.append(parts_of(h, depth + 1))
-        self.sow("counters", "moe_pairs_here", sum(pairs),
-                 reduce_fn=lambda _, x: x, init_fn=lambda: 0)
-        self.sow("counters", "moe_load_max_over_mean",
-                 functools.reduce(jnp.maximum, loads),
-                 reduce_fn=lambda _, x: x, init_fn=lambda: 0)
+                h = Linear(self.hidden_size, name=f"mtp_{depth}_eh_proj")(both)
+                h = trunk.run(block(name=f"mtp_{depth}_block"), h)
+                heads.append(head_sums(trunk.head_rows(
+                    h, shift_targets(targets, depth + 1))))
+        trunk.sow()
         return tuple(heads)
 
 
-@register("joyai_llm_flash")
-def JoyAILLMFlash(num_classes: int = 129280, remat: bool = False,
-                  **sizes) -> nn.Module:
-    """``num_classes``: the vocabulary's rows held here; ``sizes``: any field
-    of :class:`Sizes` (lists from a JSON file become tuples)."""
-    return JoyAILLMFlashModule(sizes_from_keywords(
-        Sizes, "joyai_llm_flash", num_classes, sizes), remat=remat)
+@register_language_model("joyai_llm_flash", Sizes)
+def JoyAILLMFlash(sizes: Sizes, remat: bool) -> nn.Module:
+    """``remat``: every block is rematerialised, whole."""
+    c, block = sizes, rematerialised(Block, remat)
+    return JoyAILLMFlashModule(
+        vocab_size=c.vocab_size, hidden_size=c.hidden_size, eps=c.rms_norm_eps,
+        blocks=tuple(functools.partial(block, c, i, i < c.first_k_dense_replace)
+                     for i in range(c.num_hidden_layers)),
+        prediction_blocks=tuple(
+            functools.partial(block, c, c.num_hidden_layers + depth, False)
+            for depth in range(c.num_nextn_predict_layers)))

@@ -44,20 +44,17 @@ entering as ``w``; no bias anywhere:
   SwiGLU_shared(u) + sum over chosen e that are HELD of g_e SwiGLU_e(u)``,
   the shared expert ungated. ``experts_held = (lo, hi)`` says which experts
   live here (all by default); what the absent ones would add is left out and
-  the partial sum goes on. The routed path is
-  :func:`fedtpu.models.lm_layers.routed_experts` (its grouped
-  products: :mod:`fedtpu.ops.expert_kernels` on a TPU at the published
-  widths, a batched product over blocks elsewhere).
+  the partial sum goes on. The layer is
+  :class:`fedtpu.models.lm_layers.ExpertLayer` with this rule handed in
+  (:func:`experts`).
 - ``layers_held`` names the published layers built here, in order (all by
   default): ``layer_types``, ``num_attention_heads_per_layer`` and
   ``mlp_only_layers`` are read at those indices, so a cut states the
   published lists and the layers it holds.
 
-In training the module takes the targets and returns ``((cross-entropy sum,
-count, hits),)``, the final norm, head and loss worked out a row at a time;
-in evaluation the next-token logits. Every size is a keyword of the
-constructor (``RoundConfig.model_args``); the defaults are the published ones.
-``num_classes`` is the vocabulary's rows held here.
+The stack around the blocks is :class:`fedtpu.models.lm_layers.DecoderStack`.
+Every size is a keyword of the constructor (``RoundConfig.model_args``); the
+defaults are the published ones.
 
 Device time is named under ``fed.local_step.fwd_bwd.``: ``embed``,
 ``window_attention`` (``.core``) for a sliding layer, ``attention``
@@ -74,13 +71,11 @@ from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
-import jax.numpy as jnp
 
 from fedtpu.models.lm_layers import (
-    KEEP, SCOPE, Linear, RMSNorm, SwiGLU, _expert_init, _rms, _row_loss_parts,
-    grouped_query_attention, held_range, rope_half, routed_experts,
-    sizes_from_keywords, yarn_inv_freq)
-from fedtpu.models.registry import register
+    SCOPE, DecoderStack, Linear, RMSNorm, feed_forward,
+    grouped_query_attention, held_range, register_language_model,
+    rematerialised, rope_half, top_k_gates, yarn_inv_freq)
 
 KINDS = ("full_attention", "sliding_attention")
 
@@ -129,10 +124,6 @@ class Sizes:
     # of 8 expects 320 pairs, 2,560 in all.
     moe_chunk_pairs: int = 8192
     moe_block_rows: int = 128
-
-    @property
-    def held(self) -> Tuple[int, int]:
-        return held_range(self.experts_held, self.num_experts)
 
     @property
     def kv_held(self) -> Tuple[int, int]:
@@ -219,44 +210,20 @@ class Attention(nn.Module):
             scope=c.mixer_scope(self.layer), turn_in_core=False))
 
 
-class ExpertLayer(nn.Module):
-    """The ungated shared expert plus this chip's share of the routed
-    experts. Returns ``(y, pairs, load)``: the pairs computed here and the
-    busiest held expert's load over the held experts' mean load."""
-
-    sizes: Sizes
-
-    @nn.compact
-    def __call__(self, x):
-        c = self.sizes
-        lo, hi = c.held
-        held, k = hi - lo, c.num_experts_per_tok
-        d, width = x.shape[-1], c.moe_intermediate_size
-        xf = x.reshape(-1, d)
-        shared = SwiGLU(c.shared_expert_intermediate_size, name="shared")(xf)
-        router = self.param(
-            "router", nn.initializers.variance_scaling(2.0, "fan_in", "normal"),
-            (d, c.num_experts))
-        w_gate = self.param("experts_gate", _expert_init, (held, d, width))
-        w_up = self.param("experts_up", _expert_init, (held, d, width))
-        w_down = self.param("experts_down", _expert_init, (held, width, d))
-
-        with jax.named_scope(SCOPE + "moe.router"):
-            p = jax.nn.softmax(jnp.dot(
-                xf, router.astype(xf.dtype),
-                preferred_element_type=jnp.float32), axis=-1)
-            _, chosen = jax.lax.top_k(p, k)
-            picked = (chosen[:, :, None] == jnp.arange(c.num_experts)).any(1)
-            p_picked = jnp.where(picked, p, 0.0)
-            gates = c.moe_routed_scaling_factor * p_picked / jnp.sum(
-                p_picked, axis=-1, keepdims=True)
-            # Held experts are a range: a token's gates for them are a slice.
-            gates_here, picked_here = gates[:, lo:hi], picked[:, lo:hi]
-
-        y, pairs, load = routed_experts(
-            xf, shared, gates_here, picked_here, w_gate, w_up, w_down, k,
-            c.moe_chunk_pairs, c.moe_block_rows)
-        return y.reshape(x.shape), pairs, load
+def experts(sizes: Sizes, layer: int) -> dict:
+    """Expert layer ``layer``'s fields of :class:`lm_layers.ExpertLayer`,
+    every layer's alike: the shared expert ungated, the module docstring's
+    gate rule."""
+    c = sizes
+    return dict(
+        routed=c.num_experts, k=c.num_experts_per_tok,
+        held=held_range(c.experts_held, c.num_experts),
+        width=c.moe_intermediate_size, chunk_pairs=c.moe_chunk_pairs,
+        block_rows=c.moe_block_rows,
+        shared_width=c.shared_expert_intermediate_size,
+        gate_rule=lambda logits, k: top_k_gates(
+            jax.nn.softmax(logits, axis=-1), k,
+            scale=c.moe_routed_scaling_factor))
 
 
 class Block(nn.Module):
@@ -270,68 +237,23 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, h):
         c = self.sizes
-        part = lambda cls: nn.remat(
-            cls, policy=jax.checkpoint_policies.save_only_these_names(KEEP)
-        ) if self.remat else cls
         x = RMSNorm(c.rms_norm_eps, name="mixer_norm")(h)
         with jax.named_scope(SCOPE + c.mixer_scope(self.layer)):
-            h = h + part(Attention)(c, self.layer, name="self_attn")(x)
-        x = RMSNorm(c.rms_norm_eps, name="ffn_norm")(h)
-        if self.layer in c.mlp_only_layers:
-            with jax.named_scope(SCOPE + "dense_ffn"):
-                y = part(SwiGLU)(c.intermediate_size, name="feed_forward")(x)
-            pairs, load = jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32)
-        else:
-            with jax.named_scope(SCOPE + "moe"):
-                y, pairs, load = part(ExpertLayer)(c, name="moe")(x)
+            h = h + rematerialised(Attention, self.remat)(
+                c, self.layer, name="self_attn")(x)
+        dense = self.layer in c.mlp_only_layers
+        y, pairs, load = feed_forward(
+            RMSNorm(c.rms_norm_eps, name="ffn_norm")(h), self.remat,
+            experts(c, self.layer),
+            dense=("feed_forward", c.intermediate_size) if dense else None)
         return h + y, pairs, load
 
 
-class LagunaModule(nn.Module):
-    sizes: Sizes
-    remat: bool = False
-
-    @nn.compact
-    def __call__(self, tokens, train: bool = False, targets=None):
-        """``tokens [B, T]`` int ids. In evaluation the next-token logits
-        ``[B, T, vocab]`` in float32. In training, with ``targets [B, T]``
-        (the next ids, negative where there is none), ``((cross-entropy sum,
-        count, hits),)``: one head."""
-        c = self.sizes
-        embed = nn.Embed(c.vocab_size, c.hidden_size, name="embed",
-                         embedding_init=nn.initializers.normal(1.0))
-        norm_scale = self.param(
-            "final_norm", nn.initializers.ones_init(), (c.hidden_size,))
-        head = self.param(
-            "head", nn.initializers.variance_scaling(0.02, "fan_in", "normal"),
-            (c.hidden_size, c.vocab_size))
-        with jax.named_scope(SCOPE + "embed"):
-            h = embed(tokens)
-        pairs, loads = [], []
-        for i, layer in enumerate(c.layers):
-            h, p, l = Block(c, layer, self.remat, name=f"layer_{i}")(h)
-            pairs.append(p)
-            loads.append(l)
-        if not train:
-            with jax.named_scope(SCOPE + "lm_loss"):
-                return jnp.dot(
-                    _rms(h, norm_scale, c.rms_norm_eps), head.astype(h.dtype),
-                    preferred_element_type=jnp.float32)
-        rows = jax.lax.map(
-            lambda a: _row_loss_parts(
-                a[0], a[1], norm_scale, head, c.rms_norm_eps), (h, targets))
-        self.sow("counters", "moe_pairs_here", sum(pairs),
-                 reduce_fn=lambda _, x: x, init_fn=lambda: 0)
-        self.sow("counters", "moe_load_max_over_mean",
-                 functools.reduce(jnp.maximum, loads),
-                 reduce_fn=lambda _, x: x, init_fn=lambda: 0)
-        return (tuple(jnp.sum(p) for p in rows),)
-
-
-@register("laguna")
-def Laguna(num_classes: int = 100352, remat: bool = False,
-           **sizes) -> nn.Module:
-    """``num_classes``: the vocabulary's rows held here; ``sizes``: any field
-    of :class:`Sizes` (lists from a JSON file become tuples)."""
-    return LagunaModule(sizes_from_keywords(
-        Sizes, "laguna", num_classes, sizes), remat=remat)
+@register_language_model("laguna", Sizes)
+def Laguna(sizes: Sizes, remat: bool) -> nn.Module:
+    """A block a layer held, under its PUBLISHED index."""
+    c = sizes
+    return DecoderStack(
+        vocab_size=c.vocab_size, hidden_size=c.hidden_size, eps=c.rms_norm_eps,
+        blocks=tuple(functools.partial(Block, c, layer, remat)
+                     for layer in c.layers))

@@ -43,21 +43,18 @@ weight entering as ``w``:
   sum over chosen e that are HELD of g_e SwiGLU_e(u)``: no shared expert.
   ``experts_held = (lo, hi)`` says which experts live here (all by default);
   what the absent ones would add is left out and the partial sum goes on, so a
-  token whose experts are all absent keeps its residual. The routed path is
-  :func:`fedtpu.models.lm_layers.routed_experts` (its grouped
-  products: :mod:`fedtpu.ops.expert_kernels` on a TPU at the published
-  widths, a batched product over blocks elsewhere).
+  token whose experts are all absent keeps its residual. The layer is
+  :class:`fedtpu.models.lm_layers.ExpertLayer` with this rule handed in
+  (:func:`experts`).
 - ``b`` (``use_expert_bias``) is a constant here: a normal draw of standard
   deviation ``bias_std`` from a key fixed by the layer's index. It shifts
   choices, takes no gradient and no round changes it.
 - The head is the embedding's transpose (tied), next-token cross-entropy over
   the vocabulary's rows held here.
 
-In training the module takes the targets and returns ``((cross-entropy sum,
-count, hits),)``, the final norm, head and loss worked out a row at a time;
-in evaluation the next-token logits. Every size is a keyword of the
-constructor (``RoundConfig.model_args``); the defaults are the published ones.
-``num_classes`` is the vocabulary's rows held here.
+The stack around the blocks is :class:`fedtpu.models.lm_layers.DecoderStack`.
+Every size is a keyword of the constructor (``RoundConfig.model_args``); the
+defaults are the published ones.
 
 Device time is named under ``fed.local_step.fwd_bwd.``: ``embed``,
 ``short_conv`` (``.proj``: ``W_in``; ``.core``: the two gates and the taps;
@@ -76,11 +73,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from fedtpu.models.lm_layers import (
-    KEEP, SCOPE, Linear, RMSNorm, SwiGLU, _expert_init, _rms, _row_loss_parts,
-    causal_conv, grouped_query_attention, held_range, rope_half,
-    routed_experts, sizes_from_keywords)
-from fedtpu.models.registry import register
+from fedtpu.models.lm_layers import (  # noqa: F401 (KEEP: the tests reach it through this module)
+    KEEP, SCOPE, DecoderStack, Linear, RMSNorm, causal_conv, feed_forward,
+    grouped_query_attention, held_range, register_language_model,
+    rematerialised, rope_half, top_k_gates)
 
 GATE_EPS = 1e-6  # beside the sum of a token's chosen scores
 BIAS_KEY = 20261001
@@ -120,10 +116,6 @@ class Sizes:
     # chosen for steady rounds, not speed (1.5 x is 5 % faster and swings).
     moe_chunk_pairs: int = 32768
     moe_block_rows: int = 1024
-
-    @property
-    def held(self) -> Tuple[int, int]:
-        return held_range(self.experts_held, self.num_experts)
 
     @property
     def kinds(self) -> Tuple[str, ...]:
@@ -196,44 +188,18 @@ class Attention(nn.Module):
             grouped_query_attention(q, k, v, rotary, c.attn_q_block))
 
 
-class ExpertLayer(nn.Module):
-    """This chip's share of the routed experts, and nothing else. Returns
-    ``(y, pairs, load)``: the pairs computed here and the busiest held
-    expert's load over the held experts' mean load."""
-
-    sizes: Sizes
-    layer: int
-
-    @nn.compact
-    def __call__(self, x):
-        c = self.sizes
-        lo, hi = c.held
-        held, k = hi - lo, c.num_experts_per_tok
-        d, width = x.shape[-1], c.moe_intermediate_size
-        xf = x.reshape(-1, d)
-        router = self.param(
-            "router", nn.initializers.variance_scaling(2.0, "fan_in", "normal"),
-            (d, c.num_experts))
-        w_gate = self.param("experts_gate", _expert_init, (held, d, width))
-        w_up = self.param("experts_up", _expert_init, (held, d, width))
-        w_down = self.param("experts_down", _expert_init, (held, width, d))
-
-        with jax.named_scope(SCOPE + "moe.router"):
-            s = jax.nn.sigmoid(jnp.dot(
-                xf, router.astype(xf.dtype),
-                preferred_element_type=jnp.float32))
-            _, chosen = jax.lax.top_k(s + selection_bias(self.layer, c), k)
-            picked = (chosen[:, :, None] == jnp.arange(c.num_experts)).any(1)
-            s_picked = jnp.where(picked, s, 0.0)
-            gates = c.routed_scaling_factor * s_picked / (
-                jnp.sum(s_picked, axis=-1, keepdims=True) + GATE_EPS)
-            # Held experts are a range: a token's gates for them are a slice.
-            gates_here, picked_here = gates[:, lo:hi], picked[:, lo:hi]
-
-        y, pairs, load = routed_experts(
-            xf, None, gates_here, picked_here, w_gate, w_up, w_down, k,
-            c.moe_chunk_pairs, c.moe_block_rows)
-        return y.reshape(x.shape), pairs, load
+def experts(sizes: Sizes, layer: int) -> dict:
+    """Expert layer ``layer``'s fields of :class:`lm_layers.ExpertLayer`: no
+    shared expert, the module docstring's gate rule."""
+    c = sizes
+    return dict(
+        routed=c.num_experts, k=c.num_experts_per_tok,
+        held=held_range(c.experts_held, c.num_experts),
+        width=c.moe_intermediate_size, chunk_pairs=c.moe_chunk_pairs,
+        block_rows=c.moe_block_rows,
+        gate_rule=lambda logits, k: top_k_gates(
+            jax.nn.sigmoid(logits), k, bias=selection_bias(layer, c),
+            scale=c.routed_scaling_factor, eps=GATE_EPS))
 
 
 class Block(nn.Module):
@@ -247,70 +213,28 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, h):
         c = self.sizes
-        part = lambda cls: nn.remat(
-            cls, policy=jax.checkpoint_policies.save_only_these_names(KEEP)
-        ) if self.remat else cls
         x = RMSNorm(c.norm_eps, name="operator_norm")(h)
         if c.kinds[self.layer] == "full_attention":
             with jax.named_scope(SCOPE + "attention"):
-                h = h + part(Attention)(c, name="self_attn")(x)
+                h = h + rematerialised(Attention, self.remat)(
+                    c, name="self_attn")(x)
         else:
             with jax.named_scope(SCOPE + "short_conv"):
-                h = h + part(ShortConv)(c, name="conv")(x)
-        x = RMSNorm(c.norm_eps, name="ffn_norm")(h)
-        if self.layer < c.num_dense_layers:
-            with jax.named_scope(SCOPE + "dense_ffn"):
-                y = part(SwiGLU)(c.intermediate_size, name="feed_forward")(x)
-            pairs, load = jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32)
-        else:
-            with jax.named_scope(SCOPE + "moe"):
-                y, pairs, load = part(ExpertLayer)(c, self.layer, name="moe")(x)
+                h = h + rematerialised(ShortConv, self.remat)(c, name="conv")(x)
+        dense = self.layer < c.num_dense_layers
+        y, pairs, load = feed_forward(
+            RMSNorm(c.norm_eps, name="ffn_norm")(h), self.remat,
+            experts(c, self.layer),
+            dense=("feed_forward", c.intermediate_size) if dense else None)
         return h + y, pairs, load
 
 
-class Lfm2MoeModule(nn.Module):
-    sizes: Sizes
-    remat: bool = False
-
-    @nn.compact
-    def __call__(self, tokens, train: bool = False, targets=None):
-        """``tokens [B, T]`` int ids. In evaluation the next-token logits
-        ``[B, T, vocab]`` in float32. In training, with ``targets [B, T]``
-        (the next ids, negative where there is none), ``((cross-entropy sum,
-        count, hits),)``: one head."""
-        c = self.sizes
-        embed = nn.Embed(c.vocab_size, c.hidden_size, name="embed",
-                         embedding_init=nn.initializers.normal(0.02))
-        norm_scale = self.param(
-            "final_norm", nn.initializers.ones_init(), (c.hidden_size,))
-        with jax.named_scope(SCOPE + "embed"):
-            h = embed(tokens)
-        head = embed.embedding.T  # tied
-        pairs, loads = [], []
-        for i in range(c.num_hidden_layers):
-            h, p, l = Block(c, i, self.remat, name=f"layer_{i}")(h)
-            pairs.append(p)
-            loads.append(l)
-        if not train:
-            with jax.named_scope(SCOPE + "lm_loss"):
-                return jnp.dot(
-                    _rms(h, norm_scale, c.norm_eps), head.astype(h.dtype),
-                    preferred_element_type=jnp.float32)
-        rows = jax.lax.map(
-            lambda a: _row_loss_parts(a[0], a[1], norm_scale, head, c.norm_eps),
-            (h, targets))
-        self.sow("counters", "moe_pairs_here", sum(pairs),
-                 reduce_fn=lambda _, x: x, init_fn=lambda: 0)
-        self.sow("counters", "moe_load_max_over_mean",
-                 functools.reduce(jnp.maximum, loads),
-                 reduce_fn=lambda _, x: x, init_fn=lambda: 0)
-        return (tuple(jnp.sum(p) for p in rows),)
-
-
-@register("lfm2_moe")
-def Lfm2Moe(num_classes: int = 65536, remat: bool = False,
-            **sizes) -> nn.Module:
-    """``num_classes``: the vocabulary's rows held here; ``sizes``: any field
-    of :class:`Sizes` (lists from a JSON file become tuples)."""
-    return Lfm2MoeModule(sizes_from_keywords(
-        Sizes, "lfm2_moe", num_classes, sizes), remat=remat)
+@register_language_model("lfm2_moe", Sizes)
+def Lfm2Moe(sizes: Sizes, remat: bool) -> nn.Module:
+    """The head is tied; the embedding starts at the published 0.02."""
+    c = sizes
+    return DecoderStack(
+        vocab_size=c.vocab_size, hidden_size=c.hidden_size, eps=c.norm_eps,
+        blocks=tuple(functools.partial(Block, c, i, remat)
+                     for i in range(c.num_hidden_layers)),
+        tied_head=True, embedding_init=nn.initializers.normal(0.02))

@@ -2,11 +2,13 @@
 ``lfm2_moe``, ``laguna``): the norm, the plain layers, the rotate-half rotary
 turn (plain or YaRN's frequencies), the causal depthwise convolution, one
 sequence's causal softmax attention over the whole prefix or a window of it,
-the grouped-query body around it, the routed experts' path and a row's
-head-and-loss. Each model keeps its own
-scoring rule, its own projections and its own parameter names; what is here
-takes arrays and sizes, and names device time under
-``fed.local_step.fwd_bwd.``.
+the grouped-query body around it, the expert layer with its routed experts'
+path, a block's feed-forward half, the rematerialisation of a part, and the
+decoder stack from the embedding to a row's head-and-loss. A model file holds
+what the model alone has: its ``Sizes`` under the published config's key
+names, its mixers, its router's scoring rule and the line that says which
+layers are dense; what is here takes arrays and plain values, never a model's
+``Sizes``, and names device time under ``fed.local_step.fwd_bwd.``.
 
 - :func:`attention_core`: causal attention of one sequence by the body its
   shapes and the backend call for: the fused kernels of
@@ -46,6 +48,14 @@ takes arrays and sizes, and names device time under
   backward pass is the same loop over the chunks' gradients (the first chunk
   nearly always holds every pair): the work follows the load and no pair is
   ever dropped.
+- :class:`ExpertLayer`: router, held experts and shared expert under every
+  model's parameter names; the model hands in its gate rule (its tail is
+  :func:`top_k_gates`) and what its shared expert is.
+- :func:`feed_forward`: a block's second half, a dense SwiGLU or the expert
+  layer; :func:`rematerialised`: the ONE place that says what a
+  rematerialised part keeps.
+- :class:`DecoderStack`: embedding, blocks, final norm, head and loss, made
+  of :class:`Trunk`'s pieces; :func:`register_language_model`.
 """
 
 from __future__ import annotations
@@ -53,6 +63,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+from typing import Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -60,6 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from fedtpu.models.registry import register
 from fedtpu.obs.registry import get_global_registry
 from fedtpu.ops import attention_kernels, expert_kernels
 from fedtpu.ops.losses import next_token_ce_parts
@@ -347,6 +359,23 @@ def sizes_from_keywords(cls, model: str, num_classes: int, sizes: dict):
         for k, v in sizes.items()})
 
 
+def register_language_model(name: str, sizes_cls):
+    """Decorates ``stack(sizes, remat) -> module`` into the registry's
+    constructor of ``name``: ``num_classes`` is the vocabulary's rows held
+    here, ``sizes`` any field of ``sizes_cls`` (the model's ``Sizes``)."""
+
+    def constructor(stack):
+        @register(name)
+        def build(num_classes: int = sizes_cls.vocab_size, remat: bool = False,
+                  **sizes) -> nn.Module:
+            return stack(
+                sizes_from_keywords(sizes_cls, name, num_classes, sizes), remat)
+
+        return build
+
+    return constructor
+
+
 def held_range(experts_held, routed: int):
     """``experts_held = (lo, hi)`` as a checked range of the ``routed``
     experts a router scores; ``None``: all of them."""
@@ -517,6 +546,105 @@ def routed_experts(xf, shared, gates_here, picked_here, w_gate, w_up, w_down,
     return y, pairs, load.astype(jnp.float32)
 
 
+def top_k_gates(scores, k: int, bias=None, scale=None, eps=None):
+    """The tail every model's gate rule ends in, from a token's float32
+    ``scores [n, routed]`` over ALL the routed experts (what the model's own
+    rule made of the logits: a sigmoid, a softmax) to ``(gates, picked) [n,
+    routed]``: chosen = the ``k`` largest of ``scores + bias`` (``bias
+    [routed]`` shifts choices and nothing else; a tie goes to the lower
+    index); a chosen expert's gate is ``scale`` times its score over the sum
+    of the token's chosen scores plus ``eps``, and 0 where not chosen. What is
+    ``None`` is not traced: no ``+ 0.0``, no ``* 1.0``."""
+    _, chosen = jax.lax.top_k(scores if bias is None else scores + bias, k)
+    picked = (chosen[:, :, None] == jnp.arange(scores.shape[-1])).any(1)
+    s_picked = jnp.where(picked, scores, 0.0)
+    scaled = s_picked if scale is None else scale * s_picked
+    total = jnp.sum(s_picked, axis=-1, keepdims=True)
+    return scaled / (total if eps is None else total + eps), picked
+
+
+class ExpertLayer(nn.Module):
+    """A shared expert, where the model has one, plus this chip's share of
+    the routed experts. Returns ``(y, pairs, load)``: the pairs computed here
+    and the busiest held expert's load over the held experts' mean load.
+
+    ``held = (lo, hi)``: the range of the ``routed`` experts that lives here;
+    ``k``: experts a token picks; ``width``: a routed expert's. ``gate_rule(
+    logits [n, routed] float32, k) -> (gates, picked)``: the model's own, a
+    layer's selection bias in its closure. ``shared_width``: the shared
+    expert's SwiGLU (0: none, and nothing stands in for it); ``shared_gated``:
+    behind ``sigmoid(w_s . x)``, a number a token."""
+
+    routed: int
+    held: Tuple[int, int]
+    k: int
+    width: int
+    chunk_pairs: int
+    block_rows: int
+    gate_rule: Callable
+    shared_width: int = 0
+    shared_gated: bool = False
+
+    @nn.compact
+    def __call__(self, x):
+        lo, hi = self.held
+        held, d, width = hi - lo, x.shape[-1], self.width
+        xf = x.reshape(-1, d)
+        shared = None
+        if self.shared_width:
+            shared = SwiGLU(self.shared_width, name="shared")(xf)
+            if self.shared_gated:
+                opened = jax.nn.sigmoid(
+                    Linear(1, name="shared_gate")(xf).astype(jnp.float32))
+                shared = (opened * shared.astype(jnp.float32)).astype(x.dtype)
+        router = self.param(
+            "router", nn.initializers.variance_scaling(2.0, "fan_in", "normal"),
+            (d, self.routed))
+        w_gate = self.param("experts_gate", _expert_init, (held, d, width))
+        w_up = self.param("experts_up", _expert_init, (held, d, width))
+        w_down = self.param("experts_down", _expert_init, (held, width, d))
+
+        with jax.named_scope(SCOPE + "moe.router"):
+            # Float32 out of the accumulator: exact products of the compute
+            # dtype's operands, summed in float32.
+            gates, picked = self.gate_rule(jnp.dot(
+                xf, router.astype(xf.dtype),
+                preferred_element_type=jnp.float32), self.k)
+            # Held experts are a range: a token's gates for them are a slice.
+            gates_here = gates[:, lo:hi]  # [n, held], 0 where not chosen
+            picked_here = picked[:, lo:hi]
+
+        y, pairs, load = routed_experts(
+            xf, shared, gates_here, picked_here, w_gate, w_up, w_down, self.k,
+            self.chunk_pairs, self.block_rows)
+        return y.reshape(x.shape), pairs, load
+
+
+def rematerialised(cls, remat: bool = True):
+    """``cls`` (a module class), its forward pass made again in the backward
+    pass but for what the attention cores name (``KEEP``: no backward pass
+    runs a core's forward kernel again); ``cls`` itself without ``remat``. The
+    model says what: JoyAI a whole block, the others a block's halves."""
+    return nn.remat(
+        cls, policy=jax.checkpoint_policies.save_only_these_names(KEEP)
+    ) if remat else cls
+
+
+def feed_forward(x, remat: bool, experts, dense=None):
+    """A block's feed-forward half on the normed ``x``, called inside the
+    block: ``(y, pairs, load)``. ``dense = (parameter name, width)``: a SwiGLU
+    under ``dense_ffn``, no pairs, no load; ``None``: under ``moe`` the
+    :class:`ExpertLayer` of the fields ``experts`` (a dict). ``remat``:
+    rematerialised by itself."""
+    if dense is not None:
+        name, width = dense
+        with jax.named_scope(SCOPE + "dense_ffn"):
+            y = rematerialised(SwiGLU, remat)(width, name=name)(x)
+        return y, jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32)
+    with jax.named_scope(SCOPE + "moe"):
+        return rematerialised(ExpertLayer, remat)(**experts, name="moe")(x)
+
+
 @functools.partial(jax.checkpoint, static_argnums=(4,))
 def _row_loss_parts(h, targets, scale, kernel, eps):
     """One row's final norm, head and cross-entropy, ``(sum, count, hits)``;
@@ -526,3 +654,99 @@ def _row_loss_parts(h, targets, scale, kernel, eps):
         logits = jnp.dot(_rms(h, scale, eps), kernel.astype(h.dtype),
                          preferred_element_type=jnp.float32)
         return next_token_ce_parts(logits, targets)
+
+
+class Trunk:
+    """A :class:`DecoderStack`'s call on ``tokens [B, T]`` as far as the last
+    block (``h``: its output, before the final norm), and the pieces the rest
+    is made of. Built inside ``stack``'s compact ``__call__``: parameters,
+    blocks (``layer_<i>``) and counters are that module's."""
+
+    def __init__(self, stack, tokens):
+        self.stack = stack
+        self.embed = nn.Embed(stack.vocab_size, stack.hidden_size, name="embed",
+                              embedding_init=stack.embedding_init)
+        offset, ones = stack.final_norm_offset, nn.initializers.ones_init()
+        weight = stack.param(
+            "final_norm", ones if offset is None else nn.initializers.zeros_init(),
+            (stack.hidden_size,))
+        self.norm_scale = weight if offset is None else offset + weight
+        if not stack.tied_head:
+            self.head = stack.param(
+                "head", nn.initializers.variance_scaling(0.02, "fan_in", "normal"),
+                (stack.hidden_size, stack.vocab_size))
+        with jax.named_scope(SCOPE + "embed"):
+            h = self.embed(tokens)
+        if stack.tied_head:
+            self.head = self.embed.embedding.T
+        self.pairs, self.loads = [], []
+        for i, block in enumerate(stack.blocks):
+            h = self.run(block(name=f"layer_{i}"), h)
+        self.h = h
+
+    def run(self, block, h):
+        """``block(h)``'s stream, its pairs and its load counted."""
+        h, pairs, load = block(h)
+        self.pairs.append(pairs)
+        self.loads.append(load)
+        return h
+
+    def logits(self):
+        """Evaluation: the next-token logits ``[B, T, vocab]`` in float32."""
+        with jax.named_scope(SCOPE + "lm_loss"):
+            return jnp.dot(
+                _rms(self.h, self.norm_scale, self.stack.eps),
+                self.head.astype(self.h.dtype), preferred_element_type=jnp.float32)
+
+    def head_rows(self, h, targets):
+        """The final norm, the head and the cross-entropy of ``h`` against
+        ``targets [B, T]``, a row at a time: ``(sum, count, hits)``, each
+        ``[B]``."""
+        return jax.lax.map(
+            lambda a: _row_loss_parts(
+                a[0], a[1], self.norm_scale, self.head, self.stack.eps),
+            (h, targets))
+
+    def sow(self):
+        """The ``counters`` collection: the pairs every block run so far
+        computed here, and the worst of their loads."""
+        self.stack.sow("counters", "moe_pairs_here", sum(self.pairs),
+                       reduce_fn=lambda _, x: x, init_fn=lambda: 0)
+        self.stack.sow("counters", "moe_load_max_over_mean",
+                       functools.reduce(jnp.maximum, self.loads),
+                       reduce_fn=lambda _, x: x, init_fn=lambda: 0)
+
+
+def head_sums(rows):
+    """One head's ``(cross-entropy sum, count, hits)`` of its rows'."""
+    return tuple(jnp.sum(p) for p in rows)
+
+
+class DecoderStack(nn.Module):
+    """Embedding, blocks, final norm, head. ``blocks``: a constructor a layer,
+    in order, each called with the block's ``name``; a block maps the stream
+    to ``(stream, pairs, load)``. ``tied_head``: the head is the embedding's
+    transpose, else a parameter of its own. ``final_norm_offset``: ``None``
+    for a scale that enters as it is, from ones; a number for a scale of that
+    number plus a weight from zero (the hybrid's ``1 + w``)."""
+
+    vocab_size: int
+    hidden_size: int
+    eps: float
+    blocks: Tuple[Callable[..., nn.Module], ...]
+    tied_head: bool = False
+    embedding_init: Callable = nn.initializers.normal(1.0)
+    final_norm_offset: Optional[float] = None
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False, targets=None):
+        """``tokens [B, T]`` int ids. In evaluation the next-token logits
+        ``[B, T, vocab]`` in float32. In training, with ``targets [B, T]``
+        (the next ids, negative where there is none), ``((cross-entropy sum,
+        count, hits),)``: one head."""
+        trunk = Trunk(self, tokens)
+        if not train:
+            return trunk.logits()
+        rows = trunk.head_rows(trunk.h, targets)
+        trunk.sow()
+        return (head_sums(rows),)

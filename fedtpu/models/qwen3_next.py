@@ -74,19 +74,16 @@ initialised 0, is every norm but the gated one:
   p[chosen]``; ``y = sigmoid(w_s . x) SwiGLU_shared(x) + sum over chosen e
   that are HELD of g_e SwiGLU_e(x)``. ``experts_held = (lo, hi)`` says which
   experts live here (all by default); what the absent ones would add is left
-  out and the partial sum goes on. The routed path is
-  :func:`fedtpu.models.lm_layers.routed_experts`, ``joyai_llm_flash``'s too (its grouped
-  products: :mod:`fedtpu.ops.expert_kernels` on a TPU at the published
-  widths, a batched product over blocks elsewhere).
+  out and the partial sum goes on. The layer is
+  :class:`fedtpu.models.lm_layers.ExpertLayer` with this rule handed in
+  (:func:`experts`).
 - Embedding, final ``Norm``, head, next-token cross-entropy over the
   vocabulary's rows held here. No prediction module: the config has no key
   for one.
 
-In training the module takes the targets and returns ``((cross-entropy sum,
-count, hits),)``, the final norm, head and loss worked out a row at a time;
-in evaluation the next-token logits. Every size is a keyword of the
-constructor (``RoundConfig.model_args``); the defaults are the published ones.
-``num_classes`` is the vocabulary's rows held here.
+The stack around the blocks is :class:`fedtpu.models.lm_layers.DecoderStack`.
+Every size is a keyword of the constructor (``RoundConfig.model_args``); the
+defaults are the published ones.
 
 Device time is named under ``fed.local_step.fwd_bwd.``: ``embed``,
 ``linear_attention`` (``.proj``, ``.conv``, ``.core``: gates, normalisation
@@ -108,11 +105,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.layout import Layout, with_layout_constraint
 
-from fedtpu.models.lm_layers import (
-    KEEP, SCOPE, Linear, SwiGLU, _expert_init, _rms, _row_loss_parts,
-    causal_conv, grouped_query_attention, held_range, rope_half,
-    routed_experts, sizes_from_keywords)
-from fedtpu.models.registry import register
+from fedtpu.models.lm_layers import (  # noqa: F401 (KEEP: the tests reach it through this module)
+    KEEP, SCOPE, DecoderStack, Linear, _rms, causal_conv, feed_forward,
+    grouped_query_attention, held_range, register_language_model,
+    rematerialised, rope_half, top_k_gates)
 from fedtpu.obs.registry import get_global_registry
 from fedtpu.ops import delta_rule_kernels
 
@@ -152,10 +148,6 @@ class Sizes:
     attn_q_block: int = 512
     moe_chunk_pairs: int = 8192  # these two as the cell runs them: a held
     moe_block_rows: int = 128  # expert of 16 sees 160 pairs a row of 8,192
-
-    @property
-    def held(self) -> Tuple[int, int]:
-        return held_range(self.experts_held, self.num_experts)
 
     def is_softmax_layer(self, layer: int) -> bool:
         return (layer + 1) % self.full_attention_interval == 0
@@ -345,46 +337,19 @@ class GatedAttention(nn.Module):
             q, k, v, rotary, c.attn_q_block, gate=gate))
 
 
-class ExpertLayer(nn.Module):
-    """Sigmoid-gated shared expert plus this chip's share of the routed
-    experts. Returns ``(y, pairs, load)``: the pairs computed here and the
-    busiest held expert's load over the held experts' mean load."""
-
-    sizes: Sizes
-
-    @nn.compact
-    def __call__(self, x):
-        c = self.sizes
-        lo, hi = c.held
-        held, k = hi - lo, c.num_experts_per_tok
-        d, width = x.shape[-1], c.moe_intermediate_size
-        xf = x.reshape(-1, d)
-        shared = SwiGLU(c.shared_expert_intermediate_size, name="shared")(xf)
-        opened = jax.nn.sigmoid(
-            Linear(1, name="shared_gate")(xf).astype(jnp.float32))
-        shared = (opened * shared.astype(jnp.float32)).astype(x.dtype)
-        router = self.param(
-            "router", nn.initializers.variance_scaling(2.0, "fan_in", "normal"),
-            (d, c.num_experts))
-        w_gate = self.param("experts_gate", _expert_init, (held, d, width))
-        w_up = self.param("experts_up", _expert_init, (held, d, width))
-        w_down = self.param("experts_down", _expert_init, (held, width, d))
-
-        with jax.named_scope(SCOPE + "moe.router"):
-            p = jax.nn.softmax(jnp.dot(
-                xf, router.astype(xf.dtype),
-                preferred_element_type=jnp.float32), axis=-1)
-            _, chosen = jax.lax.top_k(p, k)
-            picked = (chosen[:, :, None] == jnp.arange(c.num_experts)).any(1)
-            p_picked = jnp.where(picked, p, 0.0)
-            gates = p_picked / jnp.sum(p_picked, axis=-1, keepdims=True)
-            # Held experts are a range: a token's gates for them are a slice.
-            gates_here, picked_here = gates[:, lo:hi], picked[:, lo:hi]
-
-        y, pairs, load = routed_experts(
-            xf, shared, gates_here, picked_here, w_gate, w_up, w_down, k,
-            c.moe_chunk_pairs, c.moe_block_rows)
-        return y.reshape(x.shape), pairs, load
+def experts(sizes: Sizes, layer: int) -> dict:
+    """Expert layer ``layer``'s fields of :class:`lm_layers.ExpertLayer`,
+    every layer's alike: the shared expert behind its sigmoid gate, the module
+    docstring's gate rule."""
+    c = sizes
+    return dict(
+        routed=c.num_experts, k=c.num_experts_per_tok,
+        held=held_range(c.experts_held, c.num_experts),
+        width=c.moe_intermediate_size, chunk_pairs=c.moe_chunk_pairs,
+        block_rows=c.moe_block_rows,
+        shared_width=c.shared_expert_intermediate_size, shared_gated=True,
+        gate_rule=lambda logits, k: top_k_gates(
+            jax.nn.softmax(logits, axis=-1), k))
 
 
 class Block(nn.Module):
@@ -399,67 +364,29 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, h):
         c = self.sizes
-        part = lambda cls: nn.remat(
-            cls, policy=jax.checkpoint_policies.save_only_these_names(KEEP)
-        ) if self.remat else cls
         x = Norm(c.rms_norm_eps, name="mixer_norm")(h)
         if c.is_softmax_layer(self.layer):
             with jax.named_scope(SCOPE + "attention"):
-                h = h + part(GatedAttention)(c, name="self_attn")(x)
+                h = h + rematerialised(GatedAttention, self.remat)(
+                    c, name="self_attn")(x)
         else:
             with jax.named_scope(SCOPE + "linear_attention"):
-                h = h + part(GatedDeltaNet)(c, name="linear_attn")(x)
+                h = h + rematerialised(GatedDeltaNet, self.remat)(
+                    c, name="linear_attn")(x)
+        # This model's norm in front of the expert layer has always run under
+        # the layer's scope: ``moe.device_share`` counts it.
         with jax.named_scope(SCOPE + "moe"):
-            y, pairs, load = part(ExpertLayer)(c, name="moe")(
-                Norm(c.rms_norm_eps, name="ffn_norm")(h))
+            x = Norm(c.rms_norm_eps, name="ffn_norm")(h)
+        y, pairs, load = feed_forward(x, self.remat, experts(c, self.layer))
         return h + y, pairs, load
 
 
-class Qwen3NextModule(nn.Module):
-    sizes: Sizes
-    remat: bool = False
-
-    @nn.compact
-    def __call__(self, tokens, train: bool = False, targets=None):
-        """``tokens [B, T]`` int ids. In evaluation the next-token logits
-        ``[B, T, vocab]`` in float32. In training, with ``targets [B, T]``
-        (the next ids, negative where there is none), ``((cross-entropy sum,
-        count, hits),)``: one head."""
-        c = self.sizes
-        embed = nn.Embed(c.vocab_size, c.hidden_size, name="embed",
-                         embedding_init=nn.initializers.normal(1.0))
-        norm_scale = 1.0 + self.param(
-            "final_norm", nn.initializers.zeros_init(), (c.hidden_size,))
-        head = self.param(
-            "head", nn.initializers.variance_scaling(0.02, "fan_in", "normal"),
-            (c.hidden_size, c.vocab_size))
-        with jax.named_scope(SCOPE + "embed"):
-            h = embed(tokens)
-        pairs, loads = [], []
-        for i in range(c.num_hidden_layers):
-            h, p, l = Block(c, i, self.remat, name=f"layer_{i}")(h)
-            pairs.append(p)
-            loads.append(l)
-        if not train:
-            with jax.named_scope(SCOPE + "lm_loss"):
-                return jnp.dot(
-                    _rms(h, norm_scale, c.rms_norm_eps), head.astype(h.dtype),
-                    preferred_element_type=jnp.float32)
-        rows = jax.lax.map(
-            lambda a: _row_loss_parts(
-                a[0], a[1], norm_scale, head, c.rms_norm_eps), (h, targets))
-        self.sow("counters", "moe_pairs_here", sum(pairs),
-                 reduce_fn=lambda _, x: x, init_fn=lambda: 0)
-        self.sow("counters", "moe_load_max_over_mean",
-                 functools.reduce(jnp.maximum, loads),
-                 reduce_fn=lambda _, x: x, init_fn=lambda: 0)
-        return (tuple(jnp.sum(p) for p in rows),)
-
-
-@register("qwen3_next")
-def Qwen3Next(num_classes: int = 151936, remat: bool = False,
-              **sizes) -> nn.Module:
-    """``num_classes``: the vocabulary's rows held here; ``sizes``: any field
-    of :class:`Sizes` (lists from a JSON file become tuples)."""
-    return Qwen3NextModule(sizes_from_keywords(
-        Sizes, "qwen3_next", num_classes, sizes), remat=remat)
+@register_language_model("qwen3_next", Sizes)
+def Qwen3Next(sizes: Sizes, remat: bool) -> nn.Module:
+    """The final norm is the model's ``Norm``: ``1 + w``."""
+    c = sizes
+    return DecoderStack(
+        vocab_size=c.vocab_size, hidden_size=c.hidden_size, eps=c.rms_norm_eps,
+        blocks=tuple(functools.partial(Block, c, i, remat)
+                     for i in range(c.num_hidden_layers)),
+        final_norm_offset=1.0)

@@ -63,6 +63,13 @@ def _weights(ref, cfg, seed=3):
     return jax.tree.map(jnp.asarray, params)
 
 
+@pytest.fixture(scope="module")
+def weights(ref, cfg):
+    """The tiny configuration's seeded weights, drawn once for the module's
+    cases (a draw is the whole model's, three seconds)."""
+    return _weights(ref, cfg)
+
+
 def _x(seed, *shape):
     return jax.random.normal(jax.random.PRNGKey(seed), shape, jnp.float32)
 
@@ -84,10 +91,10 @@ def _value_and_grads(f, *args):
     return out, grads
 
 
-def test_latent_attention_is_the_references_forward_and_gradient(cfg, ref):
+def test_latent_attention_is_the_references_forward_and_gradient(cfg, ref, weights):
     from benchmark.reference.layers import ident
 
-    p = _weights(ref, cfg)["layer_0"]["attn"]
+    p = weights["layer_0"]["attn"]
     x = _x(1, 2, T, D)
     layer = prog.LatentAttention(_sizes(cfg))
     ours = _value_and_grads(lambda p, x: layer.apply({"params": p}, x), p, x)
@@ -99,10 +106,10 @@ def test_latent_attention_is_the_references_forward_and_gradient(cfg, ref):
 
 @pytest.mark.parametrize("name,layer,dense", [("layer_0", 0, True), ("layer_1", 1, False),
                                               ("mtp_0_block", 2, False)])
-def test_a_block_is_the_references_forward_and_gradient(cfg, ref, name, layer, dense):
+def test_a_block_is_the_references_forward_and_gradient(cfg, ref, weights, name, layer, dense):
     from benchmark.reference.layers import ident
 
-    p = _weights(ref, cfg)[name]
+    p = weights[name]
     h = _x(2, 2, T, D)
     block = prog.Block(_sizes(cfg), layer, dense)
     ours = _value_and_grads(lambda p, h: block.apply({"params": p}, h)[0], p, h)
@@ -145,7 +152,7 @@ def test_the_programs_tree_is_the_references_parameter_list(cfg, ref):
     with pytest.raises(ValueError, match="no size"):
         models.create("joyai_llm_flash", widht=3)
     with pytest.raises(ValueError, match="no range"):
-        prog.Sizes(experts_held=(250, 260)).held
+        prog.experts(prog.Sizes(experts_held=(250, 260)), 1)
 
 
 # ------------------------------------------------ the round, clients in sequence

@@ -69,6 +69,13 @@ def _weights(ref, cfg, seed=3):
     return jax.tree.map(jnp.asarray, params)
 
 
+@pytest.fixture(scope="module")
+def weights(ref, cfg):
+    """The tiny configuration's seeded weights, drawn once for the module's
+    cases (a draw is the whole model's, three seconds)."""
+    return _weights(ref, cfg)
+
+
 def _x(seed, *shape):
     return jax.random.normal(jax.random.PRNGKey(seed), shape, jnp.float32)
 
@@ -118,11 +125,11 @@ LAYERS = {
     ("full_dense_block", "float32"),
     ("sliding_sparse_block", "float32"), ("sliding_sparse_block", "bfloat16"),
     ("full_sparse_block", "bfloat16")])
-def test_a_layer_is_the_references_forward_and_gradient(cfg, ref, name, dtype):
+def test_a_layer_is_the_references_forward_and_gradient(cfg, ref, weights, name, dtype):
     from benchmark.reference.layers import ident
 
     path, make, of = LAYERS[name]
-    p = _weights(ref, cfg)
+    p = weights
     for key in path:
         p = p[key]
     x = _x(1, 1, T, D)
@@ -189,12 +196,12 @@ def test_the_shares_of_the_heads_add_up_to_the_uncut_layer(cfg, ref, layer):
         _sizes(cfg, kv_heads_held=(7, 9)).kv_held
 
 
-def test_a_window_of_one_returns_the_gated_value_through_the_output_product(cfg, ref):
+def test_a_window_of_one_returns_the_gated_value_through_the_output_product(cfg, weights):
     """A query that sees only itself: the softmax is 1 on the diagonal, so a
     sliding layer of window 1 is ``(sigmoid(W_g u)_h v_{h // G}) W_o``,
     whatever the rotary turn; and a window of ``T`` or more is the same
     layer as a full one given the sliding layer's rotary rule."""
-    p = _weights(ref, cfg)["layer_1"]["self_attn"]
+    p = weights["layer_1"]["self_attn"]
     x = _x(6, 2, T, D)
     got = prog.Attention(_sizes(cfg, sliding_window=1), 1).apply({"params": p}, x)
     v = x @ p["v_proj"]["kernel"]  # [2, T, 16]: the one key-value head held
@@ -319,7 +326,8 @@ def test_the_programs_tree_is_the_references_parameter_list(cfg, ref):
     assert [i for i, k in enumerate(kinds) if k == FULL] == list(range(0, 48, 4))
     assert {(c.kind(i), c.query_heads(i)) for i in c.layers} == {
         (FULL, 48), (SLIDING, 72)}
-    assert c.mlp_only_layers == (0,) and c.kv_held == (0, 8) and c.held == (0, 256)
+    assert c.mlp_only_layers == (0,) and c.kv_held == (0, 8)
+    assert prog.experts(c, 1)["held"] == (0, 256)
     # a cut names published layers: the kinds and head counts are read there
     cut = prog.Sizes(num_hidden_layers=2, layers_held=(4, 7))
     assert [(cut.kind(i), cut.query_heads(i)) for i in cut.layers] == [
@@ -333,7 +341,7 @@ def test_the_programs_tree_is_the_references_parameter_list(cfg, ref):
     with pytest.raises(ValueError, match="no entry for layer 3"):
         prog.Sizes(num_attention_heads_per_layer=(48, 72)).query_heads(3)
     with pytest.raises(ValueError, match="no range"):
-        prog.Sizes(experts_held=(250, 260)).held
+        prog.experts(prog.Sizes(experts_held=(250, 260)), 1)
 
 
 def _round_config(cfg, micro_batch_rows, dtype="float32"):

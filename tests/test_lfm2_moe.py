@@ -68,6 +68,13 @@ def _weights(ref, cfg, seed=3):
     return jax.tree.map(jnp.asarray, params)
 
 
+@pytest.fixture(scope="module")
+def weights(ref, cfg):
+    """The tiny configuration's seeded weights, drawn once for the module's
+    cases (a draw is the whole model's, three seconds)."""
+    return _weights(ref, cfg)
+
+
 def _x(seed, *shape):
     return jax.random.normal(jax.random.PRNGKey(seed), shape, jnp.float32)
 
@@ -114,11 +121,11 @@ LAYERS = {
     ("dense_conv_block", "float32"),
     ("attention_expert_block", "float32"), ("attention_expert_block", "bfloat16"),
     ("conv_expert_block", "bfloat16")])
-def test_a_layer_is_the_references_forward_and_gradient(cfg, ref, name, dtype):
+def test_a_layer_is_the_references_forward_and_gradient(cfg, ref, weights, name, dtype):
     from benchmark.reference.layers import ident
 
     path, make, of = LAYERS[name]
-    p = _weights(ref, cfg)
+    p = weights
     for key in path:
         p = p[key]
     x = _x(1, 1, T, D)
@@ -204,23 +211,24 @@ def test_grouped_queries_at_heads_of_64_run_the_body_their_length_calls_for_and_
         lm_layers.rope_half(h, 1e6, 64), ref.rotate_half(h, 1e6), atol=2e-6)
 
 
-def test_a_token_whose_experts_are_all_absent_keeps_its_residual(cfg, ref):
+def test_a_token_whose_experts_are_all_absent_keeps_its_residual(cfg, weights):
     """There is no shared expert: where none of a token's four experts is
     held, the layer adds exactly nothing and the block hands on ``h +
     Op(h)``; where one is, it adds something."""
     sizes = _sizes(cfg)
-    p = _weights(ref, cfg)["layer_2"]
+    p = weights["layer_2"]
     x = _x(7, 2, T, D)
     mixed = x + prog.ShortConv(sizes).apply(
         {"params": p["conv"]}, lm_layers._rms(x, p["operator_norm"]["scale"], 1e-5))
     u = lm_layers._rms(mixed, p["ffn_norm"]["scale"], 1e-5)
     s = jax.nn.sigmoid(u.reshape(-1, D) @ p["moe"]["router"])
     _, chosen = jax.lax.top_k(s + prog.selection_bias(2, sizes), 4)
-    lo, hi = sizes.held
+    lo, hi = prog.experts(sizes, 2)["held"]
     here = np.asarray((chosen >= lo) & (chosen < hi))
     absent = ~here.any(axis=1)
     assert 0 < absent.sum() < absent.size
-    y, pairs, _ = prog.ExpertLayer(sizes, 2).apply({"params": p["moe"]}, u)
+    y, pairs, _ = lm_layers.ExpertLayer(**prog.experts(sizes, 2)).apply(
+        {"params": p["moe"]}, u)
     assert int(pairs) == here.sum()
     flat = np.asarray(y.reshape(-1, D))
     assert not flat[absent].any()
@@ -259,7 +267,7 @@ def test_the_programs_tree_is_the_references_parameter_list(cfg, ref):
     with pytest.raises(ValueError, match="layer_types"):
         prog.Sizes(num_hidden_layers=2, layer_types=("conv", "window")).kinds
     with pytest.raises(ValueError, match="no range"):
-        prog.Sizes(experts_held=(60, 70)).held
+        prog.experts(prog.Sizes(experts_held=(60, 70)), 1)
 
 
 def _round_config(cfg, micro_batch_rows, dtype="float32", momentum=0.0):

@@ -39,13 +39,16 @@ def _close(a, b, tol=2e-5):
         assert float(jnp.linalg.norm(x - y)) <= tol * scale, (x.shape, scale)
 
 
-def _value_and_grads(f, *args):
+def _value_and_grads(f, *args, more=False):
+    """``f``'s output contracted with a fixed cotangent, and its gradients;
+    with ``more``, ``f`` returns ``(output, what else the same program
+    computes)`` and that comes back third: one compile, not two."""
     def scalar(*a):
-        out = f(*a)
-        return jnp.sum(out * _x(99, *out.shape)), out
-    (_, out), grads = jax.jit(jax.value_and_grad(
+        out, rest = f(*a) if more else (f(*a), None)
+        return jnp.sum(out * _x(99, *out.shape)), (out, rest)
+    (_, (out, rest)), grads = jax.jit(jax.value_and_grad(
         scalar, argnums=tuple(range(len(args))), has_aux=True))(*args)
-    return out, grads
+    return (out, grads, rest) if more else (out, grads)
 
 
 class Model:
@@ -66,12 +69,14 @@ class Model:
         self.prog = {"joyai_llm_flash": joyai_llm_flash, "qwen3_next": qwen3_next,
                      "lfm2_moe": lfm2_moe, "laguna": laguna}[name]
         self.ref = run.load_py(os.path.join(ROOT, "benchmark", "reference", name + ".py"))
-        # a selection bias drawn from the layer's index: the layer is told it
-        self.by_layer = name not in ("qwen3_next", "laguna")
+        # a reference whose router has a selection bias drawn from the layer's
+        # index takes that index
+        self.biased = hasattr(self.ref, "selection_bias")
         # the configuration's key for the experts HELD (the reference's count)
         self.held_key = ("n_routed_experts" if name == "joyai_llm_flash"
                          else "num_experts")
         self.share = self.cfg[self.held_key]  # experts a chip holds, of 16
+        self.drawn = {}
 
     def sizes(self, **over):
         args = dict(self.cfg["program"]["round"]["model_args"])
@@ -81,19 +86,25 @@ class Model:
         return self.prog.Sizes(vocab_size=self.cfg["vocab_size"], **args)
 
     def weights(self, cfg, seed=3):
+        """The expert layer's of the model's seeded weights; a configuration
+        two cases ask for is drawn once (a draw is the whole model's, three
+        seconds)."""
         from benchmark import seeded
 
-        params, _ = seeded.make_weights(seed, *self.ref.spec(cfg))
-        return jax.tree.map(jnp.asarray, params)["layer_1"]["moe"]
+        key = json.dumps(cfg, sort_keys=True), seed
+        if key not in self.drawn:
+            params, _ = seeded.make_weights(seed, *self.ref.spec(cfg))
+            self.drawn[key] = jax.tree.map(jnp.asarray, params)["layer_1"]["moe"]
+        return self.drawn[key]
 
     def layer(self, sizes):
-        return self.prog.ExpertLayer(sizes, 1) if self.by_layer else self.prog.ExpertLayer(sizes)
+        return lm_layers.ExpertLayer(**self.prog.experts(sizes, 1))
 
     def reference(self, cfg):
         from benchmark.reference.layers import ident
 
         f = self.ref.make_forward(cfg).expert_layer
-        return (lambda p, x: f(p, x, 1, ident)) if self.by_layer else (
+        return (lambda p, x: f(p, x, 1, ident)) if self.biased else (
             lambda p, x: f(p, x, ident))
 
 
@@ -118,19 +129,20 @@ def test_the_shares_of_the_routed_experts_add_up_to_the_uncut_layer(model):
     alike = lambda x: model.reference(none_held)(p, x)
 
     def all_shares(x):
-        total, pairs = alike(x), 0
+        once = alike(x)
+        total, pairs = once, 0
         for lo in range(0, 16, model.share):
             held = dict(p, **{k: p[k][lo:lo + model.share] for k in
                               ("experts_gate", "experts_up", "experts_down")})
             y, n, _ = model.layer(model.sizes(experts_held=(lo, lo + model.share))).apply(
                 {"params": held}, x)
-            total, pairs = total + (y - alike(x)), pairs + n
+            total, pairs = total + (y - once), pairs + n
         return total, pairs
 
-    ours = _value_and_grads(lambda x: all_shares(x)[0], x)
-    _close(ours, theirs)
+    *ours, pairs = _value_and_grads(all_shares, x, more=True)
+    _close(tuple(ours), theirs)
     # every (token, chosen expert) pair is computed by exactly one share
-    assert int(jax.jit(all_shares)(x)[1]) == 2 * T * model.cfg["num_experts_per_tok"]
+    assert int(pairs) == 2 * T * model.cfg["num_experts_per_tok"]
 
 
 def _everything_on_one_expert(model):
@@ -140,7 +152,7 @@ def _everything_on_one_expert(model):
     and Laguna have no bias: tokens of positive entries against a router whose one column
     of ones outscores the columns of zeros."""
     one = dict(model.cfg, num_experts_per_tok=1)
-    if model.by_layer:
+    if model.biased:
         busiest = int(jnp.argmax(model.ref.selection_bias(1, one)))
         x = _x(6, 2 * T, D)
     else:
@@ -149,7 +161,7 @@ def _everything_on_one_expert(model):
     one["experts_held_from"] = lo
     p = dict(model.weights(one))
     p["router"] = jnp.zeros_like(p["router"])
-    if not model.by_layer:
+    if not model.biased:
         p["router"] = p["router"].at[:, busiest].set(1.0)
     return one, p, x, lo
 
@@ -162,14 +174,76 @@ def test_every_token_on_one_held_expert_and_nothing_is_dropped(model, chunk):
     layer = model.layer(model.sizes(
         num_experts_per_tok=1, experts_held=(lo, lo + model.share), moe_chunk_pairs=chunk,
         moe_block_rows=16))
-    y, pairs, load = jax.jit(lambda x: layer.apply({"params": p}, x))(x)
+
+    def apply(x):
+        y, pairs, load = layer.apply({"params": p}, x)
+        return y, (pairs, load)
+
+    *ours, (pairs, load) = _value_and_grads(apply, x, more=True)
     assert int(pairs) == 2 * T
     # one expert has it all: as many times the mean as experts are held
     assert float(load) == pytest.approx(float(model.share))
     reference = model.reference(one)
-    _close(y, reference(p, x))
-    ours = _value_and_grads(lambda x: layer.apply({"params": p}, x)[0], x)
-    _close(ours, _value_and_grads(lambda x: reference(p, x), x))
+    _close(tuple(ours), _value_and_grads(lambda x: reference(p, x), x))
+
+
+# A model's gate rule as its configuration states it, nothing of the model's
+# file: (scores of the logits, the configuration's key for the scale, epsilon).
+GATE_RULES = {
+    "joyai_llm_flash": (jax.nn.sigmoid, "routed_scaling_factor", None),
+    "qwen3_next": (jax.nn.softmax, None, None),
+    "lfm2_moe": (jax.nn.sigmoid, "routed_scaling_factor", 1e-6),
+    "laguna": (jax.nn.softmax, "moe_routed_scaling_factor", None),
+}
+
+
+def test_the_gate_rules_shared_tail_is_each_references_router(model):
+    """``top_k_gates`` alone, with a model's rule stated here, against the
+    reference's router read off the reference's uncut expert layer: tokens
+    whose entries 1..16 ARE the router's logits and whose entry 0 is one,
+    experts that each answer that one with a one in their own column, a shared
+    expert of zeros, so column ``e`` of the layer's output is expert ``e``'s
+    gate and its support is the chosen mask. A seeded batch, a row whose
+    scores are all nearly nothing (sigmoid: the chosen scores sum to 2e-17,
+    LFM2's epsilon is all of the divisor) and a row whose k-th and k+1-th
+    largest logits are equal (one of the two is chosen, by both alike)."""
+    uncut = dict(model.cfg, experts_held_from=0, **{model.held_key: 16})
+    k, at = uncut["num_experts_per_tok"], jnp.arange(16)
+    logits = jnp.concatenate([
+        _x(21, 30, 16),
+        (-40.0 - 0.1 * at)[None],
+        # three above, experts 3 and 11 level at the k-th place, the rest below
+        jnp.where(at < 3, 2.0 + at, jnp.where((at == 3) | (at == 11), 1.0, -1.0 - at))[None],
+    ])
+    x = jnp.zeros((32, D)).at[:, 0].set(1.0).at[:, 1:17].set(logits)
+    alpha = 1.5
+    p = jax.tree.map(jnp.zeros_like, model.weights(uncut))
+    p["router"] = p["router"].at[1 + at, at].set(1.0)
+    p["experts_gate"] = p["experts_gate"].at[:, 0, 0].set(alpha)
+    p["experts_up"] = p["experts_up"].at[:, 0, 0].set(1.0)
+    p["experts_down"] = p["experts_down"].at[at, 0, at].set(1.0 / jax.nn.silu(alpha))
+    theirs = model.reference(uncut)(p, x)
+    assert not np.asarray(theirs[:, 16:]).any()
+    theirs = theirs[:, :16]
+
+    scores, scale, eps = GATE_RULES[model.name]
+    gates, picked = lm_layers.top_k_gates(
+        scores(logits), k, scale=uncut[scale] if scale else None, eps=eps,
+        bias=model.ref.selection_bias(1, uncut) if model.biased else None)
+    np.testing.assert_array_equal(picked, theirs != 0)
+    np.testing.assert_allclose(gates, theirs, rtol=1e-5, atol=0)
+    assert (np.asarray(picked).sum(1) == k).all()
+    assert int(picked[-1, 3]) + int(picked[-1, 11]) == 1 and bool(picked[-1, :3].all())
+    # what a token's gates add up to: the scale, and in LFM2's thin row nearly nothing
+    total = np.asarray(gates.sum(1))
+    np.testing.assert_allclose(
+        total[:30], uncut[scale] if scale else 1.0, rtol=1e-4)
+    if eps:
+        assert 0 < total[30] < 1e-10
+    # and the program's own rule is that tail: the model file's closure
+    ours = model.prog.experts(model.sizes(experts_held=(0, 16)), 1)["gate_rule"](logits, k)
+    np.testing.assert_array_equal(ours[1], picked)
+    np.testing.assert_array_equal(ours[0], gates)
 
 
 def test_chunks_are_laid_out_for_the_pairs_a_token_can_have():

@@ -62,6 +62,13 @@ def _weights(ref, cfg, seed=3):
     return jax.tree.map(jnp.asarray, params)
 
 
+@pytest.fixture(scope="module")
+def weights(ref, cfg):
+    """The tiny configuration's seeded weights, drawn once for the module's
+    cases (a draw is the whole model's, three seconds)."""
+    return _weights(ref, cfg)
+
+
 def _x(seed, *shape):
     return jax.random.normal(jax.random.PRNGKey(seed), shape, jnp.float32)
 
@@ -106,11 +113,11 @@ LAYERS = {
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("name", list(LAYERS))
-def test_a_layer_is_the_references_forward_and_gradient(cfg, ref, name, dtype):
+def test_a_layer_is_the_references_forward_and_gradient(cfg, ref, weights, name, dtype):
     from benchmark.reference.layers import ident
 
     path, make, of = LAYERS[name]
-    p = _weights(ref, cfg)
+    p = weights
     for key in path:
         p = p[key]
     x = _x(1, 2, T, D)
@@ -224,7 +231,7 @@ def test_the_programs_tree_is_the_references_parameter_list(cfg, ref):
     with pytest.raises(ValueError, match="no size"):
         models.create("qwen3_next", widht=3)
     with pytest.raises(ValueError, match="no range"):
-        prog.Sizes(experts_held=(500, 520)).held
+        prog.experts(prog.Sizes(experts_held=(500, 520)), 1)
 
 
 def test_a_language_model_federation_trains_counts_and_evaluates(cfg):

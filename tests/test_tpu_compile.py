@@ -72,9 +72,10 @@ def test_the_expert_layer_compiles_at_the_published_widths_with_its_scopes(one_c
     would compile to kernels named ``ragged-dot-none``, which a capture reads
     as ``_unscoped_``), no product runs over every expert's copy of the
     tokens, and the layer's temporaries stay under 2 GB."""
-    from fedtpu.models import joyai_llm_flash as m
+    from fedtpu.models import joyai_llm_flash as m, lm_layers
 
-    layer = m.ExpertLayer(m.Sizes(experts_held=(0, 8), moe_chunk_pairs=4096), 1)
+    layer = lm_layers.ExpertLayer(
+        **m.experts(m.Sizes(experts_held=(0, 8), moe_chunk_pairs=4096), 1))
     x = jax.ShapeDtypeStruct((1, 4096, 2048), jnp.bfloat16, sharding=one_chip)
     params = jax.eval_shape(
         lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 2048)))["params"])
@@ -109,16 +110,12 @@ def test_the_attention_layer_compiles_to_one_forward_and_one_backward_kernel(
     (``mla.core_roofline`` divides by the time under it: a kernel outside it
     would read over 100 %); and no float32 tensor of heads x queries x keys
     is left in the module (the plain body's ``f32[32,512,k]`` scores)."""
-    import flax.linen as nn
-
-    from fedtpu.models import joyai_llm_flash as m
+    from fedtpu.models import joyai_llm_flash as m, lm_layers
     from fedtpu.ops import attention_kernels as ak
 
     monkeypatch.setattr(ak, "_mode", lambda interpret: "mosaic")
     sizes = m.Sizes()
-    layer = nn.remat(
-        m.LatentAttention,
-        policy=jax.checkpoint_policies.save_only_these_names(m.KEEP))(sizes)
+    layer = lm_layers.rematerialised(m.LatentAttention)(sizes)
     x = jax.ShapeDtypeStruct((1, 4096, 2048), jnp.bfloat16, sharding=one_chip)
     params = jax.eval_shape(
         lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 2048)))["params"])
@@ -152,12 +149,9 @@ def _mixer_gradient_text(one_chip, cls, scope):
     published widths on one row of 8,192 tokens, forward and backward under
     ``nn.remat`` with the model's policy and under the scope its block gives
     it, as ``qwen3_next_80b_a3b.fl4_seq8k`` runs a layer."""
-    import flax.linen as nn
+    from fedtpu.models import lm_layers, qwen3_next as m
 
-    from fedtpu.models import qwen3_next as m
-
-    layer = nn.remat(
-        cls(m), policy=jax.checkpoint_policies.save_only_these_names(m.KEEP))(m.Sizes())
+    layer = lm_layers.rematerialised(cls(m))(m.Sizes())
     x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16, sharding=one_chip)
     params = jax.eval_shape(
         lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 64, 2048)))["params"])
@@ -266,18 +260,16 @@ def test_the_grouped_softmax_layer_compiles_at_the_published_widths_with_its_sco
     assert temp < 2e9
 
 
-def _lfm2_gradient(one_chip, layer, scope):
-    """Compiled text and temporaries of one of LFM2-MoE's layers at the
-    published widths on ``lfm2_24b_a2b.fl4_b8_seq4k``'s micro-batch, 8 rows of
-    4,096 tokens, forward and backward under ``nn.remat`` with the model's
-    policy and under the scope its block gives it."""
-    import flax.linen as nn
+def _lfm2_gradient(one_chip, make, scope):
+    """Compiled text and temporaries of one of LFM2-MoE's layers
+    (``make(sizes)``: its class and its fields) at the published
+    widths on ``lfm2_24b_a2b.fl4_b8_seq4k``'s micro-batch, 8 rows of 4,096
+    tokens, forward and backward rematerialised as a block's part is
+    (``lm_layers.rematerialised``) and under the scope its block gives it."""
+    from fedtpu.models import lfm2_moe as m, lm_layers
 
-    from fedtpu.models import lfm2_moe as m
-
-    layer = nn.remat(
-        layer[0], policy=jax.checkpoint_policies.save_only_these_names(m.KEEP)
-    )(m.Sizes(experts_held=(0, 8)), *layer[1:])
+    cls, fields = make(m.Sizes(experts_held=(0, 8)))
+    layer = lm_layers.rematerialised(cls)(**fields)
     x = jax.ShapeDtypeStruct((8, 4096, 2048), jnp.bfloat16, sharding=one_chip)
     params = jax.eval_shape(
         lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 2048)))["params"])
@@ -313,7 +305,8 @@ def test_the_lfm2_softmax_layer_compiles_to_one_forward_and_one_backward_kernel(
     from fedtpu.ops import attention_kernels as ak
 
     monkeypatch.setattr(ak, "_mode", lambda interpret: "mosaic")
-    text, temp = _lfm2_gradient(one_chip, (m.Attention,), "attention")
+    text, temp = _lfm2_gradient(
+        one_chip, lambda sizes: (m.Attention, dict(sizes=sizes)), "attention")
     scope = "fed.local_step.fwd_bwd.attention"
     assert ak.SCOPE == scope + ".core"
     kernels = [l for l in text.splitlines()
@@ -346,7 +339,8 @@ def test_the_short_convolution_compiles_at_the_published_widths_with_its_scopes(
     the layer's temporaries stay under 2 GB."""
     from fedtpu.models import lfm2_moe as m
 
-    text, temp = _lfm2_gradient(one_chip, (m.ShortConv,), "short_conv")
+    text, temp = _lfm2_gradient(
+        one_chip, lambda sizes: (m.ShortConv, dict(sizes=sizes)), "short_conv")
     scope = "fed.local_step.fwd_bwd.short_conv"
     products = [l for l in text.splitlines() if " convolution(" in l]
     assert products and all(scope in l for l in products)
@@ -412,9 +406,10 @@ def test_the_lfm2_expert_layer_compiles_at_a_deployments_rows_a_product(one_chip
     program's scope, no product over every expert's copy of the tokens, no
     tensor of zeros stands in for the shared expert this model lacks, and the
     layer's temporaries stay under 5 GB."""
-    from fedtpu.models import lfm2_moe as m
+    from fedtpu.models import lfm2_moe as m, lm_layers
 
-    text, temp = _lfm2_gradient(one_chip, (m.ExpertLayer, 2), "moe")
+    text, temp = _lfm2_gradient(
+        one_chip, lambda sizes: (lm_layers.ExpertLayer, m.experts(sizes, 2)), "moe")
     assert "ragged-dot" not in text
     grouped = [l for l in text.splitlines() if " convolution(" in l
                and "fed.local_step.fwd_bwd.moe.experts" in l]
@@ -486,15 +481,11 @@ def _laguna_gradient(one_chip, layer):
     or 9 query heads) on ``laguna_s_2_1.fl4_seq8k``'s micro-batch, one row of
     8,192 tokens, forward and backward under ``nn.remat`` with the model's
     policy and under the scope its block gives it."""
-    import flax.linen as nn
-
-    from fedtpu.models import laguna as m
+    from fedtpu.models import laguna as m, lm_layers
 
     sizes = m.Sizes(kv_heads_held=(0, 1))
     scope = "window_attention" if sizes.kind(layer) == m.KINDS[1] else "attention"
-    mixer = nn.remat(
-        m.Attention, policy=jax.checkpoint_policies.save_only_these_names(m.KEEP)
-    )(sizes, layer)
+    mixer = lm_layers.rematerialised(m.Attention)(sizes, layer)
     x = jax.ShapeDtypeStruct((1, 8192, 3072), jnp.bfloat16, sharding=one_chip)
     params = jax.eval_shape(
         lambda: mixer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 3072)))["params"])

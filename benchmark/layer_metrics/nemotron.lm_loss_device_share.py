@@ -1,0 +1,12 @@
+"""Device-busy time under the final norm, the head and the cross-entropy of
+the state-space hybrid (``fed.local_step.fwd_bwd.lm_loss``: a row at a time
+over the 16,384 vocabulary rows held, forward and backward). The scope
+``lm_loss.device_share`` reads, for a cell its list does not name. Nothing to
+read, so nothing returned, where the program has no such scope."""
+
+
+def read(ctx):
+    from benchmark import trace_reduce
+
+    return trace_reduce.scope_share(
+        ctx["trace"], "fed.local_step.fwd_bwd.lm_loss")

@@ -470,7 +470,7 @@ def leg_kernels():
     ct = jnp.asarray(draw(n, d), jnp.bfloat16)
     name = f"routed_experts[{n},{d},{width},{held}]"
     layer = lambda x, gates, *w: lm.routed_experts(
-        x, None, gates, picked, *w, 2, 1024, 128)[0]
+        x, None, gates, picked, w, 2, 1024, 128)[0]
     require(ek.takes(jax.ShapeDtypeStruct((12 * 128, d), jnp.bfloat16), ops[2], 128),
             f"the expert kernels do not engage: {name}")
     both, backend = [], ek._mode

@@ -50,6 +50,7 @@ from fedtpu.models.joyai_llm_flash import JoyAILLMFlash
 from fedtpu.models.qwen3_next import Qwen3Next
 from fedtpu.models.lfm2_moe import Lfm2Moe
 from fedtpu.models.laguna import Laguna
+from fedtpu.models.nemotron_h import NemotronH
 
 __all__ = [
     "available",
@@ -99,4 +100,5 @@ __all__ = [
     "Qwen3Next",
     "Lfm2Moe",
     "Laguna",
+    "NemotronH",
 ]

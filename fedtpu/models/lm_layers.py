@@ -1,5 +1,5 @@
 """What the language models share (``joyai_llm_flash``, ``qwen3_next``,
-``lfm2_moe``, ``laguna``): the norm, the plain layers, the rotate-half rotary
+``lfm2_moe``, ``laguna``, ``nemotron_h``): the norm, the plain layers, the rotate-half rotary
 turn (plain or YaRN's frequencies), the causal depthwise convolution, one
 sequence's causal softmax attention over the whole prefix or a window of it,
 the grouped-query body around it, the expert layer with its routed experts'
@@ -33,7 +33,8 @@ layers are dense; what is here takes arrays and plain values, never a model's
   the used blocks first. The grouped product has two bodies, one function of
   the same operands, chosen by :func:`fedtpu.ops.expert_kernels.takes` from
   the backend and the shapes alone: on a TPU, at widths of whole lanes and
-  blocks of whole sublane tiles (every published size), the kernels of
+  blocks of whole sublane tiles (every published size but Nemotron-H's
+  1,856), the kernels of
   :mod:`fedtpu.ops.expert_kernels`, which read a block's weights in place
   through a prefetched block-to-expert map and skip the blocks no pair fell
   in, forward and backward; everywhere else (the CPU, the tiny test models'
@@ -42,18 +43,26 @@ layers are dense; what is here takes arrays and plain values, never a model's
   in either: the chip's compiler turns that into kernels named
   ``ragged-dot-none``, which carry no scope of the program, and a capture
   would read the experts' time as ``_unscoped_``). Counted in the process's
-  registry by the body taken, three products a layer traced
+  registry by the body taken, a product a stack of weights a layer traced
   (``fedtpu_expert_products_traced_total{body}``). The chunks are ONE loop
   that runs while pairs are left, under one differentiation rule whose
   backward pass is the same loop over the chunks' gradients (the first chunk
   nearly always holds every pair): the work follows the load and no pair is
-  ever dropped.
+  ever dropped. An expert has one of two forms, the layer's: gated, three
+  matrices (``w_down (silu(w_gate u) * w_up u)``: a SwiGLU, four of the five
+  models), or two with the activation handed in (``w_down act(w_up u)``:
+  Nemotron-H's ``relu2``), and then two products a layer are counted and no
+  stack stands where the second input matrix would be. Where a TPU run takes
+  the plain body at a width of a lane group or more, one warning a process
+  names the width.
 - :class:`ExpertLayer`: router, held experts and shared expert under every
   model's parameter names; the model hands in its gate rule (its tail is
-  :func:`top_k_gates`) and what its shared expert is.
-- :func:`feed_forward`: a block's second half, a dense SwiGLU or the expert
-  layer; :func:`rematerialised`: the ONE place that says what a
-  rematerialised part keeps.
+  :func:`top_k_gates`), what its shared expert is and the experts' form
+  (:class:`SwiGLU`'s, or :class:`MLP`'s with its activation).
+- :func:`feed_forward`: a feed-forward half, a dense SwiGLU or the expert
+  layer, the second half of a block or a layer by itself;
+  :func:`rematerialised`: the ONE place that says what a rematerialised part
+  keeps.
 - :class:`DecoderStack`: embedding, blocks, final norm, head and loss, made
   of :class:`Trunk`'s pieces; :func:`register_language_model`.
 """
@@ -62,6 +71,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 import math
 from typing import Callable, Optional, Tuple
 
@@ -128,6 +138,23 @@ class SwiGLU(nn.Module):
         return Linear(x.shape[-1], name="down")(h)
 
 
+class MLP(nn.Module):
+    """SwiGLU's sibling of TWO matrices: ``down(activation(up(x)))``."""
+
+    width: int
+    activation: Callable
+
+    @nn.compact
+    def __call__(self, x):
+        h = self.activation(Linear(self.width, name="up")(x))
+        return Linear(x.shape[-1], name="down")(h)
+
+
+def relu2(x):
+    """``relu(x)^2`` (``mlp_hidden_act: relu2``)."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def yarn_inv_freq(theta: float, rot: int, factor: float, original_max: int,
                   beta_fast: float, beta_slow: float):
     """YaRN's ``rot / 2`` inverse frequencies (``rope_type: yarn``): each a
@@ -171,21 +198,24 @@ def rope_half(x, theta: float, rot: int, inv_freq=None, factor=None):
     ).astype(x.dtype)
 
 
-def causal_conv(x, kernel):
+def causal_conv(x, kernel, bias=None):
     """Depthwise causal convolution over time of ``x [T, channels]`` with
     ``kernel [width, channels]``: ``y_t = sum_i kernel_i x_{t - width + 1 +
-    i}``, zeros before the row's start; float32 sums, the result in
-    ``x.dtype``. One differentiation rule on every backend
+    i}``, zeros before the row's start, plus ``bias [channels]`` where there
+    is one (added to the float32 sum, every position's alike, the row's first
+    too); float32 sums, the result in ``x.dtype``. One differentiation rule on
+    every backend
     (:func:`_conv_rule`): forward and backward are each ONE pass of shifted
     slices over an operand that stays in its own dtype in memory (``x``
     forward, the cotangent backward: read once, written once), and what the
     backward pass keeps is ``x`` and the taps. ``jax.grad`` of the same sums
     writes a float32 copy of the padded operand and one of the cotangent a
     tap: six times the bytes at ``[8192, 8192]`` bfloat16 (PERF.md §6, PR 46).
-    The taps enter the rule in float32, so their gradient leaves it in
-    float32 and is rounded to ``kernel.dtype`` once, after ``vmap`` has summed
-    it over rows."""
-    return _conv_rule(x, kernel.astype(jnp.float32))
+    The taps (and the bias) enter the rule in float32, so their gradient
+    leaves it in float32 and is rounded to ``kernel.dtype`` once, after
+    ``vmap`` has summed it over rows."""
+    return _conv_rule(x, kernel.astype(jnp.float32),
+                      None if bias is None else bias.astype(jnp.float32))
 
 
 def _shifted(x, width, lead):
@@ -204,25 +234,27 @@ def _tap_sum(x, taps, lead):
 
 
 @jax.custom_vjp
-def _conv_rule(x, taps):
-    return _tap_sum(x, taps, taps.shape[0] - 1).astype(x.dtype)
+def _conv_rule(x, taps, bias):
+    y = _tap_sum(x, taps, taps.shape[0] - 1)
+    return (y if bias is None else y + bias).astype(x.dtype)
 
 
-def _conv_rule_fwd(x, taps):
-    return _conv_rule(x, taps), (x, taps)
+def _conv_rule_fwd(x, taps, bias):
+    return _conv_rule(x, taps, bias), (x, taps, bias)
 
 
 def _conv_rule_bwd(kept, dy):
     """``d x_t = sum_i taps_i dy_{t + width - 1 - i}``: the taps reversed
     over the cotangent padded BEHIND, so the zeros before the row's start
     receive nothing. ``d taps_i = sum_t x_{t - width + 1 + i} dy_t``:
-    ``width`` float32 reductions over time that read the padded ``x``."""
-    x, taps = kept
+    ``width`` float32 reductions over time that read the padded ``x``. ``d
+    bias = sum_t dy_t``, one more."""
+    x, taps, bias = kept
     width = taps.shape[0]
     dx = _tap_sum(dy, taps[::-1], 0).astype(x.dtype)
     dy = dy.astype(jnp.float32)
     dtaps = jnp.stack([jnp.sum(rows * dy, axis=0) for rows in _shifted(x, width, width - 1)])
-    return dx, dtaps
+    return dx, dtaps, None if bias is None else jnp.sum(dy, axis=0)
 
 
 _conv_rule.defvjp(_conv_rule_fwd, _conv_rule_bwd)
@@ -393,17 +425,46 @@ def _expert_init(key, shape, dtype=jnp.float32):
     return jax.random.normal(key, shape, dtype) / math.sqrt(shape[1])
 
 
-def routed_experts(xf, shared, gates_here, picked_here, w_gate, w_up, w_down,
-                   per_token, chunk_pairs, block_rows):
+_PLAIN_WIDTHS_WARNED = set()
+
+
+def _warn_of_plain_products(width: int):
+    """One warning a process and width, at trace time, where a TPU run takes
+    the plain grouped products at an expert width of a lane group or more
+    (a width the kernels refuse: no whole number of lanes, 1,856 = 14.5 x
+    128): the run is right and slower than its neighbours, and says so."""
+    if (expert_kernels.on_a_tpu() and width >= expert_kernels.LANES
+            and width not in _PLAIN_WIDTHS_WARNED):
+        _PLAIN_WIDTHS_WARNED.add(width)
+        logging.getLogger(__name__).warning(
+            "expert layer of width %d: the held experts' grouped products "
+            "take the plain batched body on this TPU (fedtpu.ops."
+            "expert_kernels takes widths of whole %d-lane groups, rows and "
+            "weights of one dtype and blocks of whole sublane tiles)",
+            width, expert_kernels.LANES)
+
+
+def routed_experts(xf, shared, gates_here, picked_here, weights, per_token,
+                   chunk_pairs, block_rows, activation=None):
     """An expert layer's sum: ``shared [n, d]`` (what every chip computes
     alike, the model's own; ``None`` where the model has no such part, and
     nothing stands in for it) plus the held experts' part (module docstring).
     ``xf [n, d]`` tokens; ``gates_here``, ``picked_here [n, held]``: each
     token's gate for each held expert (0 where not chosen) and whether it was
-    chosen; ``w_gate``, ``w_up [held, d, width]``, ``w_down [held, width,
-    d]``; ``per_token``: the most experts a token picks. Returns ``(y [n, d], pairs, load)``: the sum (added in float32), the
+    chosen; ``per_token``: the most experts a token picks. ``weights``: the
+    held experts' stacks in one of the two forms an expert has. Gated, three
+    matrices, ``(w_gate, w_up [held, d, width], w_down [held, width, d])``
+    with ``activation`` ``None``: ``w_down (silu(w_gate u) * w_up u)``. Plain,
+    two, ``(w_up, w_down)`` with the ``activation`` handed in: ``w_down
+    activation(w_up u)``; no stack stands where the second input matrix would
+    be. Returns ``(y [n, d], pairs, load)``: the sum (added in float32), the
     pairs computed here and the busiest held expert's load over the held
     experts' mean load."""
+    if len(weights) != (3 if activation is None else 2):
+        raise ValueError(
+            f"{len(weights)} stacks of expert weights: a gated expert has "
+            "three (activation=None), one with an activation handed in two")
+    w_up, w_down = weights[-2:]
     n, d = xf.shape
     held = gates_here.shape[1]
     with jax.named_scope(SCOPE + "moe.dispatch"):
@@ -431,16 +492,19 @@ def routed_experts(xf, shared, gates_here, picked_here, w_gate, w_up, w_down,
     n_blocks = chunk // block + held  # every expert may end in a part block
 
     kernel = expert_kernels.takes(
-        jax.ShapeDtypeStruct((n_blocks * block, d), xf.dtype), w_gate, block
+        jax.ShapeDtypeStruct((n_blocks * block, d), xf.dtype), w_up, block
     ) and expert_kernels.takes(
         jax.ShapeDtypeStruct((n_blocks * block, w_down.shape[1]), xf.dtype),
         w_down, block)
     get_global_registry().counter(
-        PRODUCTS_TRACED, "expert layers' grouped products traced (three a "
-        "layer), by the body taken",
-        labels={"body": "kernel" if kernel else "plain"}).inc(3)
+        PRODUCTS_TRACED, "expert layers' grouped products traced (one a "
+        "stack of weights: three a gated layer, two a plain one), by the "
+        "body taken",
+        labels={"body": "kernel" if kernel else "plain"}).inc(len(weights))
+    if not kernel:
+        _warn_of_plain_products(w_down.shape[1])
 
-    def one_chunk(base, order, starts, ends, xf, flat_gates, w_gate, w_up, w_down):
+    def one_chunk(base, order, starts, ends, xf, flat_gates, *weights):
         """Sorted pairs ``[base, base + chunk)`` through their experts:
         ``(gated outputs [rows, d] float32, their tokens [rows])``. Each
         expert's pairs are laid out from a block boundary on, so a block of
@@ -480,7 +544,12 @@ def routed_experts(xf, shared, gates_here, picked_here, w_gate, w_up, w_down,
                         preferred_element_type=out_dtype,
                     ).reshape(n_blocks * block, -1)
         with jax.named_scope(SCOPE + "moe.experts"):
-            hidden = jax.nn.silu(product(rows, w_gate)) * product(rows, w_up)
+            if activation is None:
+                w_gate, w_up, w_down = weights
+                hidden = jax.nn.silu(product(rows, w_gate)) * product(rows, w_up)
+            else:
+                w_up, w_down = weights
+                hidden = activation(product(rows, w_up))
             out = product(hidden, w_down, out_dtype=jnp.float32)
         with jax.named_scope(SCOPE + "moe.combine"):
             gate = jnp.where(live, flat_gates[src], 0.0)
@@ -536,8 +605,7 @@ def routed_experts(xf, shared, gates_here, picked_here, w_gate, w_up, w_down,
                 lambda so_far, base: jax.tree.map(jnp.add, so_far, of_chunk(base)))
 
     chunks.defvjp(chunks_fwd, chunks_bwd)
-    routed = chunks(
-        (order, starts, ends, pairs), xf, flat_gates, w_gate, w_up, w_down)
+    routed = chunks((order, starts, ends, pairs), xf, flat_gates, *weights)
     with jax.named_scope(SCOPE + "moe.combine"):
         if shared is not None:
             routed = shared.astype(jnp.float32) + routed
@@ -572,8 +640,14 @@ class ExpertLayer(nn.Module):
     ``k``: experts a token picks; ``width``: a routed expert's. ``gate_rule(
     logits [n, routed] float32, k) -> (gates, picked)``: the model's own, a
     layer's selection bias in its closure. ``shared_width``: the shared
-    expert's SwiGLU (0: none, and nothing stands in for it); ``shared_gated``:
-    behind ``sigmoid(w_s . x)``, a number a token."""
+    expert's (0: none, and nothing stands in for it); ``shared_gated``:
+    behind ``sigmoid(w_s . x)``, a number a token. ``activation``: the form
+    of every expert here, routed and shared alike. ``None``: gated, a SwiGLU
+    of three matrices (``experts_gate``, ``experts_up``, ``experts_down``;
+    the shared one a :class:`SwiGLU`). A function: two matrices with it
+    between them (``experts_up``, ``experts_down``; the shared one an
+    :class:`MLP`), and no parameter where the second input matrix would
+    be."""
 
     routed: int
     held: Tuple[int, int]
@@ -584,15 +658,18 @@ class ExpertLayer(nn.Module):
     gate_rule: Callable
     shared_width: int = 0
     shared_gated: bool = False
+    activation: Optional[Callable] = None
 
     @nn.compact
     def __call__(self, x):
         lo, hi = self.held
         held, d, width = hi - lo, x.shape[-1], self.width
+        gated = self.activation is None
         xf = x.reshape(-1, d)
         shared = None
         if self.shared_width:
-            shared = SwiGLU(self.shared_width, name="shared")(xf)
+            shared = (SwiGLU(self.shared_width, name="shared") if gated else MLP(
+                self.shared_width, self.activation, name="shared"))(xf)
             if self.shared_gated:
                 opened = jax.nn.sigmoid(
                     Linear(1, name="shared_gate")(xf).astype(jnp.float32))
@@ -600,9 +677,10 @@ class ExpertLayer(nn.Module):
         router = self.param(
             "router", nn.initializers.variance_scaling(2.0, "fan_in", "normal"),
             (d, self.routed))
-        w_gate = self.param("experts_gate", _expert_init, (held, d, width))
-        w_up = self.param("experts_up", _expert_init, (held, d, width))
-        w_down = self.param("experts_down", _expert_init, (held, width, d))
+        weights = tuple(
+            self.param("experts_" + name, _expert_init,
+                       (held, width, d) if name == "down" else (held, d, width))
+            for name in (("gate", "up", "down") if gated else ("up", "down")))
 
         with jax.named_scope(SCOPE + "moe.router"):
             # Float32 out of the accumulator: exact products of the compute
@@ -615,8 +693,8 @@ class ExpertLayer(nn.Module):
             picked_here = picked[:, lo:hi]
 
         y, pairs, load = routed_experts(
-            xf, shared, gates_here, picked_here, w_gate, w_up, w_down, self.k,
-            self.chunk_pairs, self.block_rows)
+            xf, shared, gates_here, picked_here, weights, self.k,
+            self.chunk_pairs, self.block_rows, self.activation)
         return y.reshape(x.shape), pairs, load
 
 
@@ -630,17 +708,23 @@ def rematerialised(cls, remat: bool = True):
     ) if remat else cls
 
 
+def no_pairs():
+    """``(pairs, load)`` of a half that routes nothing: a dense feed-forward,
+    a mixer that is a layer by itself."""
+    return jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32)
+
+
 def feed_forward(x, remat: bool, experts, dense=None):
-    """A block's feed-forward half on the normed ``x``, called inside the
-    block: ``(y, pairs, load)``. ``dense = (parameter name, width)``: a SwiGLU
-    under ``dense_ffn``, no pairs, no load; ``None``: under ``moe`` the
-    :class:`ExpertLayer` of the fields ``experts`` (a dict). ``remat``:
-    rematerialised by itself."""
+    """A feed-forward half on the normed ``x``, called inside the block, be
+    the block two halves or this one alone: ``(y, pairs, load)``. ``dense =
+    (parameter name, width)``: a SwiGLU under ``dense_ffn``, no pairs, no
+    load; ``None``: under ``moe`` the :class:`ExpertLayer` of the fields
+    ``experts`` (a dict). ``remat``: rematerialised by itself."""
     if dense is not None:
         name, width = dense
         with jax.named_scope(SCOPE + "dense_ffn"):
             y = rematerialised(SwiGLU, remat)(width, name=name)(x)
-        return y, jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32)
+        return (y,) + no_pairs()
     with jax.named_scope(SCOPE + "moe"):
         return rematerialised(ExpertLayer, remat)(**experts, name="moe")(x)
 
@@ -725,7 +809,10 @@ def head_sums(rows):
 class DecoderStack(nn.Module):
     """Embedding, blocks, final norm, head. ``blocks``: a constructor a layer,
     in order, each called with the block's ``name``; a block maps the stream
-    to ``(stream, pairs, load)``. ``tied_head``: the head is the embedding's
+    to ``(stream, pairs, load)``, be it two halves (a mixer, then a
+    feed-forward) or ONE (Nemotron-H: a mixer or a feed-forward alone, with
+    one norm; :func:`no_pairs` for a half that routes nothing).
+    ``tied_head``: the head is the embedding's
     transpose, else a parameter of its own. ``final_norm_offset``: ``None``
     for a scale that enters as it is, from ones; a number for a scale of that
     number plus a weight from zero (the hybrid's ``1 + w``)."""

@@ -54,7 +54,7 @@ from jax.experimental.pallas import tpu as pltpu
 from fedtpu.ops.pallas_kernels import _mode
 
 SCOPE = "fed.local_step.fwd_bwd.moe.experts"
-_LANES = 128
+LANES = _LANES = 128
 _SUBLANES = 16  # a bfloat16 tile's rows; float32's 8 divide it
 # The most a weight tile may take (two of them are in flight): every published
 # matrix (6.3 MB the largest) goes whole.
@@ -82,10 +82,16 @@ def _fits(rows, w, block) -> bool:
             and block % _SUBLANES == 0 and rows.shape[0] % block == 0)
 
 
+def on_a_tpu(interpret: Optional[bool] = None) -> bool:
+    """Whether the backend runs the kernels at all (or ``interpret`` says
+    so), whatever the shapes."""
+    return _mode(interpret) != "xla"
+
+
 def takes(rows, w, block, interpret: Optional[bool] = None) -> bool:
     """Whether a grouped product goes through the kernels: on a TPU (or where
     ``interpret`` says so), at shapes they are built for."""
-    return _mode(interpret) != "xla" and _fits(rows, w, block)
+    return on_a_tpu(interpret) and _fits(rows, w, block)
 
 
 def _columns(depth: int, width: int, itemsize: int) -> int:

@@ -58,7 +58,16 @@ def rng():
 # the one four-chip cell, its four metrics, no tail) are asserted, word for
 # word, by
 # tests/benchmark/test_qwen3_next_cell.py::test_the_earlier_language_cell_is_as_it_was.
-_ASSERTS_IT_IS_LAST = "test_joyai_cell.py::test_the_cell_is_the_issues"
+#
+# PR 48 meets the same with PR 44's cell: test_laguna_cell.py's test of the
+# same name asserts, in four of its lines, that Laguna's entries are the
+# manifest's last (`workloads[-1]`, `configs[-1]`, `per_layer[-8:]`) and that
+# the traffic file `fl4_seq8k` has two cells; PR 48 appends a configuration,
+# a cell of that traffic file and seven metrics. Its other lines are asserted,
+# word for word, by
+# tests/benchmark/test_nemotron_cell.py::test_the_earlier_cell_of_this_traffic_is_as_it_was.
+_ASSERTS_IT_IS_LAST = ("test_joyai_cell.py::test_the_cell_is_the_issues",
+                       "test_laguna_cell.py::test_the_cell_is_the_issues")
 
 
 # tests/test_tpu_compile.py holds the suite's longest single test (a whole
@@ -75,5 +84,5 @@ def pytest_collection_modifyitems(items):
     for item in items:
         if item.nodeid.endswith(_ASSERTS_IT_IS_LAST):
             item.add_marker(pytest.mark.xfail(
-                reason="asserts its cell is the manifest's last; PR 36 appended one",
+                reason="asserts its cell is the manifest's last; a later PR appended one",
                 raises=AssertionError, strict=True))

@@ -235,7 +235,7 @@ def test_the_counter_says_which_body_an_expert_layers_products_took(
         w = [jax.ShapeDtypeStruct((held,) + s, jnp.float32)
              for s in [(d, width), (d, width), (width, d)]]
         return jax.eval_shape(lambda x, g, p, *w: lm_layers.routed_experts(
-            x, None, g, p, *w, 2, 64, 16), x, gates, picked, *w)
+            x, None, g, p, w, 2, 64, 16), x, gates, picked, *w)
 
     other = "plain" if body == "kernel" else "kernel"
     before = {b: _traced(b) for b in ("kernel", "plain")}

@@ -1,4 +1,5 @@
-"""What ``joyai_llm_flash``, ``qwen3_next``, ``lfm2_moe`` and ``laguna`` share
+"""What ``joyai_llm_flash``, ``qwen3_next``, ``lfm2_moe``, ``laguna`` and
+``nemotron_h`` (its experts of TWO matrices beside the others' three) share
 (``fedtpu/models/lm_layers.py``), each case for every model that runs it, at a
 small size on the CPU against that model's plain reference: the shares of the routed
 experts adding up to the uncut layer, routing so skewed that every token lands
@@ -21,7 +22,8 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-from fedtpu.models import joyai_llm_flash, laguna, lfm2_moe, lm_layers, qwen3_next
+from fedtpu.models import (
+    joyai_llm_flash, laguna, lfm2_moe, lm_layers, nemotron_h, qwen3_next)
 from fedtpu.obs.registry import get_global_registry
 from fedtpu.ops import attention_kernels as ak
 
@@ -61,21 +63,30 @@ class Model:
         tiny = {"joyai_llm_flash": ("joyai_tiny", "joyai_tiny_f32"),
                 "qwen3_next": ("qwen_tiny", "qwen_tiny_f32"),
                 "lfm2_moe": ("lfm2_tiny", "lfm2_tiny_f32"),
-                "laguna": ("laguna_tiny", "laguna_tiny_f32")}[name]
+                "laguna": ("laguna_tiny", "laguna_tiny_f32"),
+                "nemotron_h": ("nemotron_tiny", "nemotron_tiny_f32")}[name]
         with open(os.path.join(ROOT, "tests", "benchmark", tiny[0], "configs",
                                tiny[1] + ".json")) as fh:
             self.cfg = json.load(fh)
         self.name = name
         self.prog = {"joyai_llm_flash": joyai_llm_flash, "qwen3_next": qwen3_next,
-                     "lfm2_moe": lfm2_moe, "laguna": laguna}[name]
+                     "lfm2_moe": lfm2_moe, "laguna": laguna,
+                     "nemotron_h": nemotron_h}[name]
         self.ref = run.load_py(os.path.join(ROOT, "benchmark", "reference", name + ".py"))
         # a reference whose router has a selection bias drawn from the layer's
         # index takes that index
         self.biased = hasattr(self.ref, "selection_bias")
         # the configuration's key for the experts HELD (the reference's count)
-        self.held_key = ("n_routed_experts" if name == "joyai_llm_flash"
-                         else "num_experts")
-        self.share = self.cfg[self.held_key]  # experts a chip holds, of 16
+        self.held_key = ("n_routed_experts" if name in (
+            "joyai_llm_flash", "nemotron_h") else "num_experts")
+        self.share = self.cfg[self.held_key]  # experts a chip holds
+        self.routed = self.cfg["router_width"]  # of 16; Nemotron-H's twin: of 32
+        # an expert's form: three stacks (a SwiGLU) or Nemotron-H's two
+        self.two_matrices = name == "nemotron_h"
+        self.stacks = ("experts_up", "experts_down") if self.two_matrices else (
+            "experts_gate", "experts_up", "experts_down")
+        # the first expert layer of the tiny twin's stack
+        self.moe_at = "layer_0" if name == "nemotron_h" else "layer_1"
         self.drawn = {}
 
     def sizes(self, **over):
@@ -94,11 +105,28 @@ class Model:
         key = json.dumps(cfg, sort_keys=True), seed
         if key not in self.drawn:
             params, _ = seeded.make_weights(seed, *self.ref.spec(cfg))
-            self.drawn[key] = jax.tree.map(jnp.asarray, params)["layer_1"]["moe"]
+            self.drawn[key] = jax.tree.map(jnp.asarray, params)[self.moe_at]["moe"]
         return self.drawn[key]
 
     def layer(self, sizes):
         return lm_layers.ExpertLayer(**self.prog.experts(sizes, 1))
+
+    def answering_with_a_one(self, p, alpha=1.5):
+        """``p`` of zeros but for experts that each answer a token's entry 0
+        with that entry in their own column of the output, whichever form an
+        expert has: ``silu(alpha) / silu(alpha)`` through three matrices,
+        ``relu(alpha)^2 / alpha^2`` through two."""
+        at = jnp.arange(self.routed)
+        p = jax.tree.map(jnp.zeros_like, p)
+        if self.two_matrices:
+            p["experts_up"] = p["experts_up"].at[:, 0, 0].set(alpha)
+            through = alpha ** 2
+        else:
+            p["experts_gate"] = p["experts_gate"].at[:, 0, 0].set(alpha)
+            p["experts_up"] = p["experts_up"].at[:, 0, 0].set(1.0)
+            through = jax.nn.silu(alpha)
+        p["experts_down"] = p["experts_down"].at[at, 0, at].set(1.0 / through)
+        return p
 
     def reference(self, cfg):
         from benchmark.reference.layers import ident
@@ -109,7 +137,7 @@ class Model:
 
 
 @pytest.fixture(scope="module", params=["joyai_llm_flash", "qwen3_next", "lfm2_moe",
-                                        "laguna"])
+                                        "laguna", "nemotron_h"])
 def model(request):
     return Model(request.param)
 
@@ -117,10 +145,11 @@ def model(request):
 # ---------------------------------------------------------- the routed experts
 def test_the_shares_of_the_routed_experts_add_up_to_the_uncut_layer(model):
     """The routed parts that all the shares compute (four of 4 experts; of
-    ``lfm2_moe`` eight of 2), plus what every chip computes alike (the shared
-    expert, gated or not; ``lfm2_moe`` has none) counted once, are the uncut
-    reference layer's output and input gradient."""
-    uncut = dict(model.cfg, experts_held_from=0, **{model.held_key: 16})
+    ``lfm2_moe`` eight of 2; of ``nemotron_h`` sixteen of 2, two matrices an
+    expert), plus what every chip computes alike (the shared expert, gated or
+    not; ``lfm2_moe`` has none) counted once, are the uncut reference layer's
+    output and input gradient."""
+    uncut = dict(model.cfg, experts_held_from=0, **{model.held_key: model.routed})
     p = model.weights(uncut)
     x = _x(5, 2 * T, D)
     theirs = _value_and_grads(lambda x: model.reference(uncut)(p, x), x)
@@ -131,9 +160,8 @@ def test_the_shares_of_the_routed_experts_add_up_to_the_uncut_layer(model):
     def all_shares(x):
         once = alike(x)
         total, pairs = once, 0
-        for lo in range(0, 16, model.share):
-            held = dict(p, **{k: p[k][lo:lo + model.share] for k in
-                              ("experts_gate", "experts_up", "experts_down")})
+        for lo in range(0, model.routed, model.share):
+            held = dict(p, **{k: p[k][lo:lo + model.share] for k in model.stacks})
             y, n, _ = model.layer(model.sizes(experts_held=(lo, lo + model.share))).apply(
                 {"params": held}, x)
             total, pairs = total + (y - once), pairs + n
@@ -194,37 +222,36 @@ GATE_RULES = {
     "qwen3_next": (jax.nn.softmax, None, None),
     "lfm2_moe": (jax.nn.sigmoid, "routed_scaling_factor", 1e-6),
     "laguna": (jax.nn.softmax, "moe_routed_scaling_factor", None),
+    "nemotron_h": (jax.nn.sigmoid, "routed_scaling_factor", None),
 }
 
 
 def test_the_gate_rules_shared_tail_is_each_references_router(model):
     """``top_k_gates`` alone, with a model's rule stated here, against the
     reference's router read off the reference's uncut expert layer: tokens
-    whose entries 1..16 ARE the router's logits and whose entry 0 is one,
+    whose entries 1..R ARE the router's logits (R experts: 16, Nemotron-H's
+    twin 32) and whose entry 0 is one,
     experts that each answer that one with a one in their own column, a shared
     expert of zeros, so column ``e`` of the layer's output is expert ``e``'s
     gate and its support is the chosen mask. A seeded batch, a row whose
     scores are all nearly nothing (sigmoid: the chosen scores sum to 2e-17,
     LFM2's epsilon is all of the divisor) and a row whose k-th and k+1-th
     largest logits are equal (one of the two is chosen, by both alike)."""
-    uncut = dict(model.cfg, experts_held_from=0, **{model.held_key: 16})
-    k, at = uncut["num_experts_per_tok"], jnp.arange(16)
+    routed = model.routed
+    uncut = dict(model.cfg, experts_held_from=0, **{model.held_key: routed})
+    k, at = uncut["num_experts_per_tok"], jnp.arange(routed)
     logits = jnp.concatenate([
-        _x(21, 30, 16),
+        _x(21, 30, routed),
         (-40.0 - 0.1 * at)[None],
         # three above, experts 3 and 11 level at the k-th place, the rest below
         jnp.where(at < 3, 2.0 + at, jnp.where((at == 3) | (at == 11), 1.0, -1.0 - at))[None],
     ])
-    x = jnp.zeros((32, D)).at[:, 0].set(1.0).at[:, 1:17].set(logits)
-    alpha = 1.5
-    p = jax.tree.map(jnp.zeros_like, model.weights(uncut))
+    x = jnp.zeros((32, D)).at[:, 0].set(1.0).at[:, 1:1 + routed].set(logits)
+    p = model.answering_with_a_one(model.weights(uncut))
     p["router"] = p["router"].at[1 + at, at].set(1.0)
-    p["experts_gate"] = p["experts_gate"].at[:, 0, 0].set(alpha)
-    p["experts_up"] = p["experts_up"].at[:, 0, 0].set(1.0)
-    p["experts_down"] = p["experts_down"].at[at, 0, at].set(1.0 / jax.nn.silu(alpha))
     theirs = model.reference(uncut)(p, x)
-    assert not np.asarray(theirs[:, 16:]).any()
-    theirs = theirs[:, :16]
+    assert not np.asarray(theirs[:, routed:]).any()
+    theirs = theirs[:, :routed]
 
     scores, scale, eps = GATE_RULES[model.name]
     gates, picked = lm_layers.top_k_gates(
@@ -241,7 +268,7 @@ def test_the_gate_rules_shared_tail_is_each_references_router(model):
     if eps:
         assert 0 < total[30] < 1e-10
     # and the program's own rule is that tail: the model file's closure
-    ours = model.prog.experts(model.sizes(experts_held=(0, 16)), 1)["gate_rule"](logits, k)
+    ours = model.prog.experts(model.sizes(experts_held=(0, routed)), 1)["gate_rule"](logits, k)
     np.testing.assert_array_equal(ours[1], picked)
     np.testing.assert_array_equal(ours[0], gates)
 
@@ -261,7 +288,7 @@ def test_chunks_are_laid_out_for_the_pairs_a_token_can_have():
     w = [_x(2 + i, held, *shape) for i, shape in
          enumerate([(d, width), (d, width), (width, d)])]
     run = lambda per_token, chunk=4: lm_layers.routed_experts(
-        x, jnp.zeros_like(x), gates, picked, *w, per_token, chunk, 2)
+        x, jnp.zeros_like(x), gates, picked, w, per_token, chunk, 2)
     dense = sum(gates[:, e, None] * (
         (jax.nn.silu(x @ w[0][e]) * (x @ w[1][e])) @ w[2][e]) for e in range(held))
     for per_token, chunk in ((2, 4), (8, 4), (2, 64)):
@@ -284,8 +311,8 @@ def test_the_expert_layer_is_the_same_through_either_body(model, monkeypatch):
     (``fedtpu/ops/expert_kernels.py``, interpreted: the test says so where
     the program asks the backend) and through the plain batched product it
     gives the same ``(y, pairs, load)`` and the same gradients of the tokens
-    and of every parameter, and the counter says which body the three products
-    of a trace took."""
+    and of every parameter, and the counter says which body the products of a
+    trace took, one a stack of weights: three, of Nemotron-H's two."""
     from fedtpu.ops import expert_kernels as ek
 
     k = model.cfg["num_experts_per_tok"]
@@ -307,8 +334,10 @@ def test_the_expert_layer_is_the_same_through_either_body(model, monkeypatch):
             b: traced(b) - before[b] for b in before}
 
     kernel, plain = run("interpret"), run("xla")
-    assert kernel[3]["kernel"] >= 3 and kernel[3]["plain"] == 0
-    assert plain[3]["plain"] >= 3 and plain[3]["kernel"] == 0
+    products = len(model.stacks)
+    assert kernel[3]["kernel"] >= products and kernel[3]["plain"] == 0
+    assert plain[3]["plain"] >= products and plain[3]["kernel"] == 0
+    assert kernel[3]["kernel"] % products == plain[3]["plain"] % products == 0
     assert kernel[1] == plain[1] > 0 and kernel[2] == plain[2]
     _close(kernel[0], plain[0])
     assert float(jnp.abs(plain[0][1][0]["experts_down"]).max()) > 0

@@ -130,3 +130,54 @@ def test_the_zeros_before_the_rows_start_receive_no_cotangent(width):
     np.testing.assert_array_equal(dx[t - width:], kernel)
     np.testing.assert_array_equal(dx[:t - width], 0.0)
     np.testing.assert_array_equal(dk, x[t - width:])
+
+
+def plain_biased_conv(x, kernel, bias):
+    """The taps' float32 sums plus a bias a channel, rounded once."""
+    t, width = x.shape[0], kernel.shape[0]
+    padded = jnp.pad(x, ((width - 1, 0), (0, 0))).astype(jnp.float32)
+    y = sum(padded[i:i + t] * kernel[i].astype(jnp.float32) for i in range(width))
+    return (y + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+@pytest.mark.parametrize("t", [20, 4099])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_a_bias_a_channel_is_the_plain_forms_output_and_three_gradients(dtype, t):
+    """Nemotron-H's call: four taps and a bias a channel (``use_conv_bias``).
+    The bias joins the float32 sum before the one rounding, at every
+    position, the row's first too; output and the gradients of the operand,
+    the taps and the bias agree with ``jax.grad`` of the plain form to the
+    last bit (the same float32 sums in the same order), each in its
+    operand's dtype. Without a bias the rule is what it was: the other two
+    models' calls, above."""
+    x, kernel, dy = _operands(5 * t, t, 4, dtype)
+    bias = jax.random.normal(jax.random.PRNGKey(t), (CHANNELS,), jnp.float32).astype(dtype)
+    _equal(lm_layers.causal_conv(x, kernel, bias), plain_biased_conv(x, kernel, bias))
+    weighed = lambda conv: lambda *a: jnp.sum(
+        conv(*a).astype(jnp.float32) * dy.astype(jnp.float32))
+    got = jax.grad(weighed(lm_layers.causal_conv), argnums=(0, 1, 2))(x, kernel, bias)
+    want = jax.grad(weighed(plain_biased_conv), argnums=(0, 1, 2))(x, kernel, bias)
+    assert [g.dtype for g in got] == [x.dtype, kernel.dtype, bias.dtype]
+    _equal(got, want)
+    # the row's first position reads zeros and the bias
+    first = lm_layers.causal_conv(jnp.zeros_like(x), kernel, bias)
+    np.testing.assert_array_equal(
+        np.asarray(first, np.float32),
+        np.broadcast_to(np.asarray(bias, np.float32), first.shape))
+
+
+def test_a_biased_rule_under_checkpoint_inside_map_is_the_state_space_layers_call():
+    """As ``Mamba2`` calls it: a sequence at a time under ``jax.lax.map``,
+    SiLU behind it, rematerialised; the bias's gradient summed over rows."""
+    x, kernel, dy = _operands(11, 16, 4, jnp.float32, rows=(3,))
+    bias = jax.random.normal(jax.random.PRNGKey(12), (CHANNELS,), jnp.float32)
+
+    def loss(conv):
+        one = jax.checkpoint(lambda row, k, b: jax.nn.silu(conv(row, k, b)))
+        return lambda x, k, b: jnp.sum(
+            jax.lax.map(lambda row: one(row, k, b), x) * dy)
+
+    got = jax.grad(loss(lm_layers.causal_conv), argnums=(0, 1, 2))(x, kernel, bias)
+    want = jax.grad(loss(plain_biased_conv), argnums=(0, 1, 2))(x, kernel, bias)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)

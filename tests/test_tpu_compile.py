@@ -639,3 +639,80 @@ def test_the_laguna_round_program_fits_one_chip_at_the_cells_size(one_chip, monk
                   "moe.experts", "moe.combine", "embed", "lm_loss"):
         assert pre + scope + "/" in text, scope
     assert "ragged-dot" not in text
+
+
+def test_the_nemotron_round_program_fits_one_chip_at_the_cells_size(
+        one_chip, monkeypatch, caplog):
+    """``nemotron_3_nano_30b_a3b.fl4_seq8k``'s whole round program from its own
+    files (4 clients in sequence, 2 steps of 2 rows of 8,192 tokens in
+    micro-batches of one row, plain SGD on bfloat16 over the 528 M float32
+    masters, every layer's one half rematerialised), from shapes alone,
+    compiled for one described v5e with the bodies as the chip would choose
+    them: the chip's compiler refuses a program that does not fit its memory,
+    so the compile IS the check (11.66 GB "Total bytes used" in its memory
+    report at PR 48, step 0 of the issue, of the 15.75 GiB a chip gives). The
+    attention layer's core is two kernels, forward and backward, at 16 query
+    heads a key-value head; the three expert layers' grouped products are the
+    PLAIN batched body, since 1,856 is no whole number of lanes (no expert
+    kernel in the module; two products a layer counted, and the warning that
+    says so logged once); the three Mamba-2 layers' recurrences are the plain
+    chunks (no kernel exists), and every scope the cell's readers read is in
+    the module."""
+    from benchmark import run as bench, sut
+    from fedtpu import models
+    from fedtpu.core.round import init_state
+    from fedtpu.data.device import make_data_round_step
+    from fedtpu.models import lm_layers, nemotron_h
+    from fedtpu.obs.registry import get_global_registry
+    from fedtpu.ops import attention_kernels as ak
+    from fedtpu.ops import expert_kernels as ek
+
+    monkeypatch.setattr(ak, "_mode", lambda interpret: "mosaic")
+    monkeypatch.setattr(ek, "_mode", lambda interpret: "mosaic")
+    monkeypatch.setattr(lm_layers, "_PLAIN_WIDTHS_WARNED", set())
+    counted = lambda name, body: get_global_registry().counter(
+        name, labels={"body": body}).value
+    before = (counted(lm_layers.PRODUCTS_TRACED, "plain"),
+              counted(lm_layers.PRODUCTS_TRACED, "kernel"),
+              counted(nemotron_h.SSD_CORES_TRACED, "plain"))
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cell = bench.Cell(os.path.join(root, "BENCHMARK.json"),
+                      "nemotron_3_nano_30b_a3b.fl4_seq8k")
+    cfg = sut.round_config(cell.config, cell.traffic, cell.task)
+    t, clients = cell.config["seq_len"], cell.traffic["clients"]
+    shard = cell.config["rows_per_client"]
+    model = models.create(cfg.model, num_classes=cfg.num_classes, remat=cfg.remat,
+                          **dict(cfg.model_args))
+    state = jax.eval_shape(
+        lambda key: init_state(model, cfg, key, jnp.zeros((1, t), jnp.int32)),
+        jax.random.PRNGKey(0))
+    assert sum(math.prod(l.shape) for l in jax.tree.leaves(state.params)) == 528_092_736
+    step = jax.jit(make_data_round_step(
+        model, cfg, cfg.steps_per_round, shuffle=False, image_shape=(t,),
+        layout="gather"), donate_argnums=(0,))
+    shapes = (
+        state, jnp.zeros((clients * shard, t), jnp.int32),
+        jnp.zeros((clients * shard, t), jnp.int32),
+        jnp.zeros((clients, shard), jnp.int32), jnp.ones((clients, shard), bool),
+        jnp.ones((clients,), jnp.float32), jnp.ones((clients,), bool),
+        jax.random.PRNGKey(0))
+    shapes = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip), shapes)
+    text = step.lower(*shapes).compile().as_text()  # raises where it does not fit
+    kernels = collections.Counter(
+        re.search(r"%(\w+?)[.\d]* =", l).group(1) for l in _kernel_lines(text))
+    assert kernels == {
+        "latent_attention_core_fwd": 1, "latent_attention_core_bwd": 1}, kernels
+    # init_state's trace and the round program's: 3 expert layers x 2 stacks,
+    # 3 state-space layers, twice
+    assert counted(lm_layers.PRODUCTS_TRACED, "plain") - before[0] == 2 * 3 * 2
+    assert counted(lm_layers.PRODUCTS_TRACED, "kernel") == before[1]
+    assert counted(nemotron_h.SSD_CORES_TRACED, "plain") - before[2] == 2 * 3
+    said = [r.getMessage() for r in caplog.records if r.name == lm_layers.__name__]
+    assert len(said) == 1 and "1856" in said[0], said
+    pre = "fed.local_step.fwd_bwd."
+    for scope in ("mamba.proj", "mamba.conv", "mamba.core", "mamba.out", "attention",
+                  "attention.core", "moe.router", "moe.dispatch", "moe.experts",
+                  "moe.combine", "embed", "lm_loss"):
+        assert pre + scope + "/" in text, scope
+    assert "ragged-dot" not in text and "linear_attention" not in text

@@ -32,9 +32,10 @@ layers are dense; what is here takes arrays and plain values, never a model's
   start at a boundary of ``block_rows`` rows, so every block has one expert,
   the used blocks first. The grouped product has two bodies, one function of
   the same operands, chosen by :func:`fedtpu.ops.expert_kernels.takes` from
-  the backend and the shapes alone: on a TPU, at widths of whole lanes and
-  blocks of whole sublane tiles (every published size but Nemotron-H's
-  1,856), the kernels of
+  the backend and the shapes alone: on a TPU, at widths of a lane group or
+  more in whole sublane tiles and blocks of whole sublane tiles (every
+  published size: whole lanes, and Nemotron-H's 1,856 = 14.5 lane groups),
+  the kernels of
   :mod:`fedtpu.ops.expert_kernels`, which read a block's weights in place
   through a prefetched block-to-expert map and skip the blocks no pair fell
   in, forward and backward; everywhere else (the CPU, the tiny test models'
@@ -431,16 +432,18 @@ _PLAIN_WIDTHS_WARNED = set()
 def _warn_of_plain_products(width: int):
     """One warning a process and width, at trace time, where a TPU run takes
     the plain grouped products at an expert width of a lane group or more
-    (a width the kernels refuse: no whole number of lanes, 1,856 = 14.5 x
-    128): the run is right and slower than its neighbours, and says so."""
+    (shapes the kernels refuse: a width that is no whole number of sublane
+    tiles, weights of another dtype than the rows, a block of part tiles):
+    the run is right and slower than its neighbours, and says so."""
     if (expert_kernels.on_a_tpu() and width >= expert_kernels.LANES
             and width not in _PLAIN_WIDTHS_WARNED):
         _PLAIN_WIDTHS_WARNED.add(width)
         logging.getLogger(__name__).warning(
             "expert layer of width %d: the held experts' grouped products "
             "take the plain batched body on this TPU (fedtpu.ops."
-            "expert_kernels takes widths of whole %d-lane groups, rows and "
-            "weights of one dtype and blocks of whole sublane tiles)",
+            "expert_kernels takes widths of %d lanes or more in whole sublane "
+            "tiles, rows and weights of one dtype and blocks of whole sublane "
+            "tiles)",
             width, expert_kernels.LANES)
 
 

@@ -60,10 +60,10 @@ convolution's:
   ``experts_held = (lo, hi)`` says which experts live here (all by default);
   what the absent ones would add is left out and the partial sum goes on. The
   layer is :class:`fedtpu.models.lm_layers.ExpertLayer` in its two-matrix form
-  with this rule handed in (:func:`experts`). At the published width, 1,856 =
-  14.5 x 128, the held experts' products take the plain batched body on a TPU
-  too (:func:`fedtpu.ops.expert_kernels.takes` asks for whole lane groups),
-  and the run says so once.
+  with this rule handed in (:func:`experts`). The published width, 1,856 =
+  14.5 x 128 lanes, goes through the kernels on a TPU as every other model's
+  does (:func:`fedtpu.ops.expert_kernels.takes` asks for a lane group or more
+  in whole sublane tiles): a tile of the whole width, no padded copy.
 - ``*``: ``num_attention_heads`` query heads on ``num_key_value_heads``
   key-value heads of ``head_dim``, rotary turns over ``head_dim *
   partial_rotary_factor`` dimensions at ``rope_theta`` in the rotate-half

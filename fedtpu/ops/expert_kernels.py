@@ -16,8 +16,11 @@ consecutive blocks of ONE expert (the layout sorts by expert) reuse the tile
 they fetched. The live blocks come first; a dead block (``b >= live``)
 multiplies nothing and writes zeros, and its index maps repeat the last live
 block's, so no DMA is issued for it. The tile is the whole matrix where it
-fits (:func:`_columns`: it does at every published width, 2-6.3 MB), else
-the widest lane multiple that does.
+fits (:func:`_columns`: it does at every published width of whole lanes,
+2-6.3 MB), else the widest lane multiple that does and divides the width; a
+width no lane multiple divides (Nemotron-H's 1,856 = 14.5 x 128) goes whole,
+since a block dimension equal to the array's is legal whatever its size, and
+Mosaic pads its last half lane group in VMEM, where the padding enters no sum.
 
 Backward, one ``jax.custom_vjp``: ``d rows = d out x w^T`` is the same kernel
 on the transposed contraction (``expert_product_transposed``: the weight tile
@@ -33,8 +36,9 @@ repeat the index of the step before and do nothing, so nothing written is
 touched again). Residuals are ``rows``, the weights and the map.
 
 Which body runs: :func:`takes` says whether this module does, on a TPU backend
-at widths of whole lanes and a block of whole sublane tiles; the plain body
-everywhere else (``interpret`` as in :mod:`fedtpu.ops.pallas_kernels`). The
+at widths of a lane group or more in whole sublane tiles (every published
+size: whole lanes, and 1,856) and a block of whole sublane tiles; the plain
+body everywhere else (``interpret`` as in :mod:`fedtpu.ops.pallas_kernels`). The
 passes are jitted for the trace and the lowering alone (as
 :mod:`fedtpu.ops.delta_rule_kernels`'): a model's layers, its three products
 and the two traces differentiation makes share one lowered function a shape.
@@ -57,7 +61,10 @@ SCOPE = "fed.local_step.fwd_bwd.moe.experts"
 LANES = _LANES = 128
 _SUBLANES = 16  # a bfloat16 tile's rows; float32's 8 divide it
 # The most a weight tile may take (two of them are in flight): every published
-# matrix (6.3 MB the largest) goes whole.
+# matrix of whole lanes (6.3 MB the largest) goes whole. A width no lane
+# multiple divides cannot be cut and goes whole whatever it takes: 9.98 MB a
+# bfloat16 tile of [2688, 1856], 19.96 MB the gradient's float32 one beside
+# two output tiles of 9.98 MB, 64.1 MB scoped in all, under ``_VMEM_LIMIT``.
 _TILE_BYTES = 8 * 1024 * 1024
 # Two buffers each of a block's rows, a weight tile and the output, and the
 # float32 tile of the weights' gradient, pass the 16 MiB a kernel gets by
@@ -74,11 +81,13 @@ _LIVE, _FIRST, _LAST, _MISSED = 1, 2, 4, 8
 
 def _fits(rows, w, block) -> bool:
     """Calls the kernels are built for: ``rows [n_blocks * block, in]`` on
-    ``w [held, in, out]`` of one dtype, both widths whole lanes, a block of
-    whole sublane tiles."""
+    ``w [held, in, out]`` of one dtype, both widths a lane group or more in
+    whole sublane tiles (whole lanes or not), a block of whole sublane
+    tiles."""
     return (rows.ndim == 2 and w.ndim == 3 and rows.dtype == w.dtype
             and rows.shape[1] == w.shape[1]
-            and w.shape[1] % _LANES == 0 and w.shape[2] % _LANES == 0
+            and all(width >= _LANES and width % _SUBLANES == 0
+                    for width in w.shape[1:])
             and block % _SUBLANES == 0 and rows.shape[0] % block == 0)
 
 
@@ -97,7 +106,10 @@ def takes(rows, w, block, interpret: Optional[bool] = None) -> bool:
 def _columns(depth: int, width: int, itemsize: int) -> int:
     """The widest tile ``[depth, columns]`` of a ``[depth, width]`` matrix
     within ``_TILE_BYTES``: ``columns`` a lane multiple that divides
-    ``width``, a lane group at the least."""
+    ``width``, a lane group at the least; the whole ``width`` where no lane
+    multiple divides it."""
+    if width % _LANES:
+        return width
     fit = [c for c in range(_LANES, width + 1, _LANES)
            if width % c == 0 and depth * c * itemsize <= _TILE_BYTES]
     return fit[-1] if fit else _LANES
@@ -266,8 +278,9 @@ def grouped_product(rows, w, expert, live_blocks, block, out_dtype=None,
     hold, and ``expert`` is not read there."""
     if not _fits(rows, w, block):
         raise ValueError(
-            f"the kernels take rows and weights of one dtype at widths of "
-            f"whole lanes and blocks of {_SUBLANES}-row tiles, not "
+            f"the kernels take rows and weights of one dtype at widths of a "
+            f"lane group or more in whole {_SUBLANES}s and blocks of "
+            f"{_SUBLANES}-row tiles, not "
             f"block={block} on {jnp.shape(rows)} {rows.dtype} and "
             f"{jnp.shape(w)} {w.dtype}")
     expert = jnp.clip(expert.astype(jnp.int32), 0, w.shape[0] - 1)

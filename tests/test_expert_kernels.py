@@ -2,8 +2,10 @@
 (``fedtpu/ops/expert_kernels.py``) against a product written block by block,
 on the CPU through the Pallas interpreter: the output of an expert layer's
 three products and the gradients of the rows and of all three weight stacks at
-the four language cells' block, widths and held experts (a few blocks of
-rows); a chunk whose pairs all fall on one expert; an expert no pair fell on
+the five language cells' block, widths and held experts (a few blocks of
+rows; Nemotron-H's 1,856 is no whole number of lanes and is the output's width
+in two of the layer's products and the contraction in the third); a chunk
+whose pairs all fall on one expert; an expert no pair fell on
 (its weights' gradient is exactly zero, and finite); a chunk with no live
 block; a last block with one live row. Which body a product takes, and that
 the counter says so.
@@ -19,9 +21,11 @@ from fedtpu.obs.registry import get_global_registry
 from fedtpu.ops import expert_kernels as ek
 
 # (block, in, out, held) of laguna_s_2_1.fl4_seq8k, lfm2_24b_a2b.fl4_b8_seq4k,
-# qwen3_next_80b_a3b.fl4_seq8k and joyai_llm_flash.fl4_seq4k.
+# qwen3_next_80b_a3b.fl4_seq8k, joyai_llm_flash.fl4_seq4k and
+# nemotron_3_nano_30b_a3b.fl4_seq8k.
 CELLS = {"laguna": (128, 3072, 1024, 8), "lfm2": (1024, 2048, 1536, 8),
-         "qwen3_next": (128, 2048, 512, 16), "joyai": (256, 2048, 768, 8)}
+         "qwen3_next": (128, 2048, 512, 16), "joyai": (256, 2048, 768, 8),
+         "nemotron": (128, 2688, 1856, 8)}
 # Largest difference over the block-by-block product's largest magnitude.
 TOLERANCE = {"float32": 2e-5, "bfloat16": 2e-2}
 STACKS = ("rows", "w_gate", "w_up", "w_down")
@@ -115,13 +119,15 @@ def test_the_kernels_are_the_product_block_by_block_at_a_cells_shapes(cell):
     assert not np.asarray(kernel["rows"][live * block:]).any()
 
 
+@pytest.mark.parametrize("width", [128, 192])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_the_kernels_in_both_dtypes_at_a_small_shape(dtype):
+def test_the_kernels_in_both_dtypes_at_a_small_shape(dtype, width):
     """bfloat16 operands (float32 sums, the float32 cotangent of the last
-    product rounded as the MXU rounds it) agree to bfloat16 rounding."""
+    product rounded as the MXU rounds it) agree to bfloat16 rounding, at a
+    width of whole lanes and at one of a lane group and a half."""
     sizes = [40, 0, 16, 70]
     args, ct, expert, live = _operands(
-        jnp.dtype(dtype), 32, 256, 128, 4, sizes, n_blocks=8, seed=1)
+        jnp.dtype(dtype), 32, 256, width, 4, sizes, n_blocks=8, seed=1)
     kernel, plain = _both(args, ct, expert, live, 32)
     _same(kernel, plain, dtype)
 
@@ -194,8 +200,15 @@ def _rows_and_weights(dtype=jnp.float32, rows=64, d=128, width=256, held=4,
     ("xla", 16, {}, False),  # off a TPU: the plain body's, whatever the shapes
     ("mosaic", 128, dict(rows=9216, d=3072, width=1024, held=8), True),
     ("mosaic", 1024, dict(rows=40960, d=2048, width=1536, held=8), True),
-    ("mosaic", 16, dict(d=96), False),  # widths of part lanes
+    # 14.5 lane groups, 116 sublane tiles: as the output's width and as the
+    # contraction
+    ("mosaic", 128, dict(rows=9216, d=2688, width=1856, held=8), True),
+    ("mosaic", 128, dict(rows=9216, d=1856, width=2688, held=8), True),
+    ("mosaic", 16, dict(width=192), True),
+    ("mosaic", 16, dict(d=96), False),  # widths under a lane group
     ("mosaic", 16, dict(width=64), False),
+    ("mosaic", 16, dict(width=200), False),  # no whole number of sublane tiles
+    ("mosaic", 16, dict(d=136), False),
     ("mosaic", 8, {}, False),  # a block of half a bfloat16 tile
     ("mosaic", 48, {}, False),  # a block that does not divide the rows
     ("mosaic", 16, dict(w_dtype=jnp.bfloat16), False),  # a copy would be cast
@@ -205,9 +218,33 @@ def test_the_body_follows_backend_and_shapes(monkeypatch, mode, block, shapes, t
     assert ek.takes(*_rows_and_weights(**shapes), block) is taken
 
 
+# What ``_columns`` gave at the parent of PR 49 for a stack ``[held, in, out]``
+# (the forward product's tile, the transposed one's, the float32 gradient's),
+# for each cell's ``(in, out)`` stacks and its ``(out, in)`` one: a width of
+# whole lanes is cut as it was. Nemotron-H's goes whole where 1,856 is the
+# width cut (no lane multiple divides it) and in thirds where 2,688 is.
+COLUMNS = {
+    "laguna": ((1024, 3072, 512), (3072, 1024, 1536)),
+    "lfm2": ((1536, 2048, 768), (2048, 1536, 1024)),
+    "qwen3_next": ((512, 2048, 512), (2048, 512, 2048)),
+    "joyai": ((768, 2048, 768), (2048, 768, 2048)),
+    "nemotron": ((1856, 896, 1856), (896, 1856, 896)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(COLUMNS))
+def test_a_cells_tiles_are_the_ones_they_were(cell):
+    _, d, width, _ = CELLS[cell]
+    for (w_in, w_out), want in zip(((d, width), (width, d)), COLUMNS[cell]):
+        assert (ek._columns(w_in, w_out, 2), ek._columns(w_out, w_in, 2),
+                ek._columns(w_in, w_out, 4)) == want, (w_in, w_out)
+        for cols, whole in zip(want, (w_out, w_in, w_out)):
+            assert whole % cols == 0  # the grid is ``width // columns`` tiles
+
+
 def test_shapes_the_kernels_refuse_are_refused_before_a_trace():
     rows, w = _rows_and_weights(d=96)
-    with pytest.raises(ValueError, match="widths of whole lanes"):
+    with pytest.raises(ValueError, match="widths of a lane group or more"):
         ek.grouped_product(
             jnp.zeros(rows.shape), jnp.zeros(w.shape), jnp.zeros(4, jnp.int32),
             1, 16, interpret=True)

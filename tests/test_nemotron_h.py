@@ -236,18 +236,20 @@ def test_an_experts_form_is_stated_and_a_plain_tpu_body_is_said_once(monkeypatch
     """``routed_experts`` takes a gated expert's three stacks or a plain one's
     two with its activation, and refuses a mismatch; two products a layer are
     counted for the two-matrix form. Where the backend is a TPU (the test says
-    so where the program asks) and the width is a lane group or more but no
-    whole number of them (1,856), the plain body is taken and ONE warning a
+    so where the program asks), the published width 1,856 (14.5 lane groups,
+    116 sublane tiles) goes through the kernels and nothing is said; at a
+    width of a lane group or more that the kernels still refuse (1,000: no
+    whole number of sublane tiles) the plain body is taken and ONE warning a
     process names the width; a tiny width or the CPU says nothing."""
     import logging
 
     from fedtpu.ops import expert_kernels as ek
 
-    n, held = 8, 2
+    n, held = 16, 2  # a block of one whole sublane tile
     picked = jnp.zeros((n, held), bool).at[:, 0].set(True)
     gates = jnp.where(picked, 0.5, 0.0)
-    traced = lambda: get_global_registry().counter(
-        lm_layers.PRODUCTS_TRACED, labels={"body": "plain"}).value
+    traced = lambda body="plain": get_global_registry().counter(
+        lm_layers.PRODUCTS_TRACED, labels={"body": body}).value
 
     def layer(d, width, activation, stacks=None):
         w = [jax.ShapeDtypeStruct((held,) + s, jnp.float32) for s in
@@ -269,17 +271,23 @@ def test_an_experts_form_is_stated_and_a_plain_tpu_body_is_said_once(monkeypatch
 
     monkeypatch.setattr(lm_layers, "_PLAIN_WIDTHS_WARNED", set())
     with caplog.at_level(logging.WARNING, logger=lm_layers.__name__):
-        layer(128, 1856, lm_layers.relu2)  # the CPU: silent
+        layer(128, 1000, lm_layers.relu2)  # the CPU: silent
         assert not caplog.records
         monkeypatch.setattr(ek, "_mode", lambda interpret: "mosaic")
-        assert not ek.takes(jax.ShapeDtypeStruct((64, 128), jnp.float32),
-                            jax.ShapeDtypeStruct((held, 128, 1856), jnp.float32), 16)
+        rows = jax.ShapeDtypeStruct((64, 128), jnp.float32)
+        assert ek.takes(
+            rows, jax.ShapeDtypeStruct((held, 128, 1856), jnp.float32), 16)
+        assert not ek.takes(
+            rows, jax.ShapeDtypeStruct((held, 128, 1000), jnp.float32), 16)
         layer(128, 24, lm_layers.relu2)  # under a lane group: a test's width
+        before = traced(), traced("kernel")
+        layer(128, 1856, lm_layers.relu2)  # the published width: the kernels'
+        assert (traced(), traced("kernel")) == (before[0], before[1] + 2)
         assert not caplog.records
-        layer(128, 1856, lm_layers.relu2)
-        layer(128, 1856, lm_layers.relu2)  # a second layer of the same width
+        layer(128, 1000, lm_layers.relu2)
+        layer(128, 1000, lm_layers.relu2)  # a second layer of the same width
     said = [r.getMessage() for r in caplog.records]
-    assert len(said) == 1 and "1856" in said[0] and "plain" in said[0]
+    assert len(said) == 1 and "1000" in said[0] and "plain" in said[0]
 
 
 # ------------------------------------------------------------------ the model

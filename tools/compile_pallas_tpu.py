@@ -11,7 +11,8 @@ sandbox without spending chip time. Whether the program then RUNS is
    params — the ``-c Y`` hot path) with ``interpret=False``, proving Mosaic
    lowering + VMEM fit; and the held experts' grouped product
    (``fedtpu/ops/expert_kernels.py``), forward and both backward kernels, at
-   the four language cells' chunks;
+   the language cells' chunks (Nemotron-H's two stacks among them: a width of
+   14.5 lane groups as the output and as the contraction);
 2. the full single-chip federated round step (bench.py's exact config);
 3. the sharded 4-chip round step (shard_map + psum over the clients mesh) —
    the multichip program compiled for actual TPU hardware, not just the
@@ -86,13 +87,17 @@ def compile_kernels(dev):
     return results
 
 
-# The four language cells' expert layers, a chunk of each: (block, in, out,
-# held experts, blocks a chunk).
+# The language cells' expert layers, a chunk of each: (block, in, out, held
+# experts, blocks a chunk). Nemotron-H's two stacks are both here: 1,856 is no
+# whole number of lanes, and it is the output width of one and the
+# contraction of the other.
 EXPERT_CHUNKS = {
     "laguna_s_2_1.fl4_seq8k": (128, 3072, 1024, 8, 72),
     "lfm2_24b_a2b.fl4_b8_seq4k": (1024, 2048, 1536, 8, 40),
     "qwen3_next_80b_a3b.fl4_seq8k": (128, 2048, 512, 16, 80),
     "joyai_llm_flash.fl4_seq4k": (256, 2048, 768, 8, 24),
+    "nemotron_3_nano_30b_a3b.fl4_seq8k": (128, 2688, 1856, 8, 72),
+    "nemotron_3_nano_30b_a3b.fl4_seq8k.down": (128, 1856, 2688, 8, 72),
 }
 
 

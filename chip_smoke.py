@@ -23,7 +23,10 @@ only when every leg passed.
            bfloat16, 1,536 tokens, output and gradients within 2e-2 of the
            plain body's largest value; an expert layer's grouped products
            through the expert kernels against the plain batched product, at
-           a width of whole lanes and at one of a lane group and a half.
+           a width of whole lanes and at one of a lane group and a half;
+           Mamba-2's selective scan through its two kernels against the plain
+           chunks at the state-space cell's shape (8,192 tokens, 64 heads of
+           64, 8 groups, a state of 128).
   rotq     ``Federation(compression="rotq", delta_layout="flat")`` rounds.
   grpc     an in-process ``PrimaryServer`` + four ``serve_client`` agents
            over real localhost gRPC, flat layout, stream pipeline, top-k.
@@ -457,7 +460,56 @@ def leg_kernels():
     out.update(experts_against_plain(1536, 256, 128, 4, chunk=1024))
     out.update(experts_against_plain(
         1536, 256, 192, 4, chunk=1024, gated=False, counts=(385, 0, 129, 600)))
+    # Mamba-2's selective scan through its two kernels against the plain
+    # chunks at the state-space cell's real shape.
+    out.update(ssd_against_plain(8192, 64, 64, 8, 128, chunk=128))
     return out
+
+
+def ssd_against_plain(t, heads, p, groups, n, chunk, seed=0):
+    """Mamba-2's selective scan (``nemotron_h.selective_scan``) of ``t``
+    tokens, ``heads`` heads of ``p`` on ``groups`` groups of a state of ``n``
+    in chunks of ``chunk``, through the kernels of ``fedtpu.ops.ssd_kernels``
+    against the plain chunks, bfloat16, steps from a thousandth to a few (slow
+    and fast heads): ``y`` and the gradients of ``x, dt, A, B, C, D`` as shares
+    of the plain body's largest value, held to 2e-2; returns them under the
+    shape's name."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedtpu.models import nemotron_h as nh
+    from fedtpu.ops import ssd_kernels as sk
+
+    rng = np.random.default_rng(seed)
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    ops = (
+        draw(t, heads, p).astype(jnp.bfloat16),
+        jax.nn.softplus(3.0 * draw(t, heads) - 2.0), -jnp.exp(draw(heads)),
+        draw(t, groups, n).astype(jnp.bfloat16),
+        draw(t, groups, n).astype(jnp.bfloat16), draw(heads),
+    )
+    ct = draw(t, heads, p).astype(jnp.bfloat16)
+    name = f"selective_scan[{t},{heads},{p},{groups},{n}]"
+    require(sk.takes(*ops, chunk), f"the scan's kernels do not engage: {name}")
+    both = [
+        jax.jit(lambda *a, f=f: (lambda y, vjp: (y,) + vjp(ct))(*jax.vjp(f, *a)))
+        for f in (lambda *a: nh.selective_scan(*a, chunk),
+                  lambda *a: nh._plain_chunks(*a, chunk))
+    ]
+    require("tpu_custom_call" in both[0].lower(*ops).as_text(),
+            f"the selective scan did not lower through Mosaic: {name}")
+    t_scan, got = timed(lambda: both[0](*ops), jax.block_until_ready)
+    errs = [
+        float(jnp.max(jnp.abs(g.astype(jnp.float32) - w.astype(jnp.float32)))
+              / jnp.max(jnp.abs(w.astype(jnp.float32))))
+        for g, w in zip(got, both[1](*ops))
+    ]
+    print(f"{name}: y, dx, ddt, dA, dB, dC, dD differ from the plain chunks by "
+          f"{errs} of their largest value (limit 2e-2)", flush=True)
+    require(all(np.isfinite(errs)) and max(errs) <= 2e-2,
+            f"the scan's kernels differ from the plain chunks by {errs}: {name}")
+    return {f"{name}_first_s": round(t_scan, 3), f"{name}_max_rel_err": errs}
 
 
 def experts_against_plain(n, d, width, held, chunk, block=128, gated=True,

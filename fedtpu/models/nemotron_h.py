@@ -43,10 +43,22 @@ convolution's:
   row at the published sizes), and ``y_t += exp(L_t) S_start C_t``. A length
   the chunk does not divide is padded with steps of ``dt = 0``, which leave
   the state as it is. Operands of ``x``'s dtype go into the products, sums,
-  gates and the state are float32. ONE body, plain ``jax.numpy``, on every
-  backend, counted in ``fedtpu_ssd_cores_traced_total{body}`` as the other
-  cores are (``body="plain"``: no kernel computes a scalar decay a head;
-  :mod:`fedtpu.ops.delta_rule_kernels` is the delta rule's).
+  gates and the state are float32. ONE function with two bodies since PR 50,
+  chosen from the backend and the operands' shapes
+  (:func:`fedtpu.ops.ssd_kernels.takes`) and counted in
+  ``fedtpu_ssd_cores_traced_total{body}`` as the other cores are: on a TPU,
+  at a group's heads and a state of whole lanes, heads of a part of a lane
+  group and a chunk of 128 that divides the length (the published sizes on
+  8,192 tokens), :mod:`fedtpu.ops.ssd_kernels`' two kernels under one
+  ``jax.custom_vjp`` (``body="kernel"``: a chunk's decay matrices and a
+  group's float32 state stay in VMEM; the output and each chunk's float32
+  starting state, 67 + 134 MB a layer a row, are named for the
+  rematerialised layer's policy, so its backward pass runs no forward kernel
+  again); everywhere else (the CPU, the tiny twins' widths, a length the
+  chunk does not divide, the eight tokens a model is initialised on) the
+  plain ``jax.numpy`` chunks above, pad and all (``body="plain"``). SiLU
+  runs on ``x``, ``B`` and ``C`` apart, so that each is written once, as the
+  core reads it.
 - ``E``: ``s = sigmoid(W_r u)`` in float32 over ALL ``n_routed_experts``;
   chosen = the ``num_experts_per_tok`` largest of ``s + b`` (``n_group`` 1,
   ``topk_group`` 1: no group limit); ``g = routed_scaling_factor * s[chosen]
@@ -102,6 +114,7 @@ from fedtpu.models.lm_layers import (
     grouped_query_attention, held_range, no_pairs, register_language_model,
     relu2, rematerialised, rope_half, top_k_gates)
 from fedtpu.obs.registry import get_global_registry
+from fedtpu.ops import ssd_kernels
 
 KINDS = {"M": "mamba", "E": "moe", "*": "attention"}
 SSD_CORES_TRACED = "fedtpu_ssd_cores_traced_total"
@@ -179,10 +192,22 @@ def selective_scan(x, dt, A, B, C, D, chunk):
     positive, ``A [H]`` float32 and negative, ``B, C [T, G, N]`` (head ``h``
     reads group ``h // (H / G)``), ``D [H]`` float32. Returns ``y [T, H, P]``
     in ``x``'s dtype. Operands of ``x``'s dtype go into the products; sums,
-    decays and the state between chunks are float32."""
+    decays and the state between chunks are float32. One function of the same
+    operands by the body its shapes and the backend call for: the fused
+    kernels (:mod:`fedtpu.ops.ssd_kernels`) or the plain chunks below. Counted
+    in the process's registry by the body taken, once a core traced."""
+    kernel = ssd_kernels.takes(x, dt, A, B, C, D, chunk)
     get_global_registry().counter(
         SSD_CORES_TRACED, "selective state-space cores traced, by the body "
-        "taken", labels={"body": "plain"}).inc()
+        "taken", labels={"body": "kernel" if kernel else "plain"}).inc()
+    body = ssd_kernels.selective_scan if kernel else _plain_chunks
+    return body(x, dt, A, B, C, D, chunk)
+
+
+def _plain_chunks(x, dt, A, B, C, D, chunk):
+    """:func:`selective_scan` in plain ``jax.numpy``: every chunk's matrices at
+    once, a rematerialised scan over the chunks for the state; a length the
+    chunk does not divide is padded with steps of ``dt = 0``."""
     (t, heads, p), dtype, g = x.shape, x.dtype, B.shape[1]
     r, rest = divmod(heads, g)
     if rest:
@@ -273,13 +298,17 @@ class Mamba2(nn.Module):
         def one_sequence(args):
             xbc, dt = args
             with jax.named_scope(SCOPE + "mamba.conv"):
-                xbc = jax.nn.silu(causal_conv(xbc, taps, conv_bias))
+                xbc = causal_conv(xbc, taps, conv_bias)
+                # SiLU a part: the pass that makes x, B or C writes it as
+                # the core reads it, and no slice of the whole is copied
+                x_in, b_in, c_in = (
+                    jax.nn.silu(xbc[:, lo:hi]) for lo, hi in (
+                        (0, d_in), (d_in, d_in + g * n), (d_in + g * n, wide)))
             with jax.named_scope(SCOPE + "mamba.core"):
                 dt = jax.nn.softplus(f32(dt) + f32(dt_bias))
                 return selective_scan(
-                    xbc[:, :d_in].reshape(t, heads, p), dt, -jnp.exp(f32(a_log)),
-                    xbc[:, d_in:d_in + g * n].reshape(t, g, n),
-                    xbc[:, d_in + g * n:].reshape(t, g, n), f32(skip),
+                    x_in.reshape(t, heads, p), dt, -jnp.exp(f32(a_log)),
+                    b_in.reshape(t, g, n), c_in.reshape(t, g, n), f32(skip),
                     c.chunk_size)
 
         y = jax.lax.map(

@@ -661,8 +661,14 @@ def test_the_nemotron_round_program_fits_one_chip_at_the_cells_size(
     products are the expert kernels at the published width 1,856, which is no
     whole number of lanes (two products a layer counted under ``kernel``, none
     under ``plain``, no warning, no block's copy of an expert's matrix); the
-    three Mamba-2 layers' recurrences are the plain chunks (no kernel exists),
-    and every scope the cell's readers read is in the module."""
+    three Mamba-2 layers' recurrences are the scan's two kernels since PR 50,
+    one forward and one backward a layer (the output and the chunks' starting
+    states are kept, so the rematerialised forward pass runs none), both under
+    the scope ``mamba.core_roofline`` divides by, and no float32 tensor of a
+    chunk's decay matrices ``[64, 8, 8, 128, 128]`` is left in any order of
+    axes; ``init_state`` traces the model on eight tokens, which the chunk
+    does not divide, so its three cores are the plain chunks'; and every scope
+    the cell's readers read is in the module."""
     from benchmark import run as bench, sut
     from fedtpu import models
     from fedtpu.core.round import init_state
@@ -671,15 +677,18 @@ def test_the_nemotron_round_program_fits_one_chip_at_the_cells_size(
     from fedtpu.obs.registry import get_global_registry
     from fedtpu.ops import attention_kernels as ak
     from fedtpu.ops import expert_kernels as ek
+    from fedtpu.ops import ssd_kernels as sk
 
     monkeypatch.setattr(ak, "_mode", lambda interpret: "mosaic")
     monkeypatch.setattr(ek, "_mode", lambda interpret: "mosaic")
+    monkeypatch.setattr(sk, "_mode", lambda interpret: "mosaic")
     monkeypatch.setattr(lm_layers, "_PLAIN_WIDTHS_WARNED", set())
     counted = lambda name, body: get_global_registry().counter(
         name, labels={"body": body}).value
     before = (counted(lm_layers.PRODUCTS_TRACED, "plain"),
               counted(lm_layers.PRODUCTS_TRACED, "kernel"),
-              counted(nemotron_h.SSD_CORES_TRACED, "plain"))
+              counted(nemotron_h.SSD_CORES_TRACED, "plain"),
+              counted(nemotron_h.SSD_CORES_TRACED, "kernel"))
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     cell = bench.Cell(os.path.join(root, "BENCHMARK.json"),
                       "nemotron_3_nano_30b_a3b.fl4_seq8k")
@@ -713,13 +722,21 @@ def test_the_nemotron_round_program_fits_one_chip_at_the_cells_size(
         "latent_attention_core_fwd": 1, "latent_attention_core_bwd": 1,
         "expert_product": 2 * 2 * bodies,
         "expert_product_transposed": 2 * bodies,
-        "expert_weights_gradient": 2 * bodies}, kernels
+        "expert_weights_gradient": 2 * bodies,
+        "selective_scan_fwd": 3, "selective_scan_bwd": 3}, kernels
+    for line in _kernel_lines(text):
+        if "%selective_scan_" in line:  # where the roofline's reader finds them
+            assert sk.SCOPE + "/" in re.search(r'op_name="([^"]*)"', line).group(1)
+    for dims in re.findall(r"f32\[([0-9,]+)\]", text):
+        assert sorted(int(d) for d in dims.split(",")) != [8, 8, 64, 128, 128], dims
     assert not re.search(r"bf16\[72,(?:2688,1856|1856,2688)\]", text)  # a block's copy
     # init_state's trace and the round program's: 3 expert layers x 2 stacks,
-    # 3 state-space layers, twice
+    # twice; 3 state-space layers, the kernels in the round program's 8,192
+    # tokens and the plain chunks in init_state's eight
     assert counted(lm_layers.PRODUCTS_TRACED, "kernel") - before[1] == 2 * 3 * 2
     assert counted(lm_layers.PRODUCTS_TRACED, "plain") == before[0]
-    assert counted(nemotron_h.SSD_CORES_TRACED, "plain") - before[2] == 2 * 3
+    assert counted(nemotron_h.SSD_CORES_TRACED, "kernel") - before[3] == 3
+    assert counted(nemotron_h.SSD_CORES_TRACED, "plain") - before[2] == 3
     assert not [r.getMessage() for r in caplog.records if r.name == lm_layers.__name__]
     pre = "fed.local_step.fwd_bwd."
     for scope in ("mamba.proj", "mamba.conv", "mamba.core", "mamba.out", "attention",

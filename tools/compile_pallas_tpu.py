@@ -12,7 +12,9 @@ sandbox without spending chip time. Whether the program then RUNS is
    lowering + VMEM fit; and the held experts' grouped product
    (``fedtpu/ops/expert_kernels.py``), forward and both backward kernels, at
    the language cells' chunks (Nemotron-H's two stacks among them: a width of
-   14.5 lane groups as the output and as the contraction);
+   14.5 lane groups as the output and as the contraction); and Mamba-2's
+   selective scan (``fedtpu/ops/ssd_kernels.py``), forward and backward, at
+   the state-space cell's shapes;
 2. the full single-chip federated round step (bench.py's exact config);
 3. the sharded 4-chip round step (shard_map + psum over the clients mesh) —
    the multichip program compiled for actual TPU hardware, not just the
@@ -538,6 +540,48 @@ def _with_specs(tree, specs, mesh):
     )
 
 
+def compile_ssd_kernels(dev):
+    """Mamba-2's selective scan (``fedtpu/ops/ssd_kernels.py``) at the
+    state-space cell's shapes, bfloat16: 8,192 tokens, 64 heads of 64 on 8
+    groups of a state of 128 in chunks of 128, the forward kernel and the
+    backward one through Mosaic; ``vmem_bytes`` is the most either took of
+    the kernels' scoped limit."""
+    import re
+
+    from fedtpu.ops import ssd_kernels as sk
+
+    s = jax.sharding.SingleDeviceSharding(dev)
+    of = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype, sharding=s)
+    t, heads, p, groups, n, chunk = 8192, 64, 64, 8, 128, 128
+
+    def scan_and_gradients(x, dt, A, B, C, D, ct):
+        out, vjp = jax.vjp(lambda *a: sk.selective_scan(
+            *a, chunk, interpret=False), x, dt, A, B, C, D)
+        return (out,) + vjp(ct)
+
+    t0 = time.perf_counter()
+    compiled = jax.jit(scan_and_gradients).lower(
+        of(jnp.bfloat16, t, heads, p), of(jnp.float32, t, heads),
+        of(jnp.float32, heads), of(jnp.bfloat16, t, groups, n),
+        of(jnp.bfloat16, t, groups, n), of(jnp.float32, heads),
+        of(jnp.bfloat16, t, heads, p)).compile()
+    text = compiled.as_text()
+    kernels = sorted(set(re.findall(r"%(selective_scan_\w+?)[.\d]* = ", text)))
+    vmem = [int(size) for line in text.splitlines() if "%selective_scan_" in line
+            for size in re.findall(
+                r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', line)]
+    return [{
+        "artifact": "pallas:ssd_kernels:nemotron_3_nano_30b_a3b.fl4_seq8k",
+        "target": dev.device_kind,
+        "shape": {"x": [t, heads, p], "B": [t, groups, n], "chunk": chunk},
+        "kernels": kernels,
+        "vmem_bytes": max(vmem, default=0),
+        "compile_s": round(time.perf_counter() - t0, 2),
+        "ok": kernels == ["selective_scan_bwd", "selective_scan_fwd"],
+        **_mem(compiled),
+    }]
+
+
 def main():
     p = argparse.ArgumentParser()
     p.add_argument("--topology", default="v5e:2x2")
@@ -550,6 +594,7 @@ def main():
     for fn in (
         lambda: compile_kernels(dev),
         lambda: compile_expert_kernels(dev),
+        lambda: compile_ssd_kernels(dev),
         lambda: [compile_round_step(dev)],
         # The flagship model (MobileNet — the reference's hardcoded default,
         # src/main.py:69) at the bench scale, single chip.

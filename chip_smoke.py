@@ -467,7 +467,7 @@ def leg_kernels():
 
 
 def ssd_against_plain(t, heads, p, groups, n, chunk, seed=0):
-    """Mamba-2's selective scan (``nemotron_h.selective_scan``) of ``t``
+    """Mamba-2's selective scan (``mamba2.selective_scan``) of ``t``
     tokens, ``heads`` heads of ``p`` on ``groups`` groups of a state of ``n``
     in chunks of ``chunk``, through the kernels of ``fedtpu.ops.ssd_kernels``
     against the plain chunks, bfloat16, steps from a thousandth to a few (slow
@@ -478,7 +478,7 @@ def ssd_against_plain(t, heads, p, groups, n, chunk, seed=0):
     import jax.numpy as jnp
     import numpy as np
 
-    from fedtpu.models import nemotron_h as nh
+    from fedtpu.models import mamba2 as nh
     from fedtpu.ops import ssd_kernels as sk
 
     rng = np.random.default_rng(seed)

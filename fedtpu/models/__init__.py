@@ -51,6 +51,7 @@ from fedtpu.models.qwen3_next import Qwen3Next
 from fedtpu.models.lfm2_moe import Lfm2Moe
 from fedtpu.models.laguna import Laguna
 from fedtpu.models.nemotron_h import NemotronH
+from fedtpu.models.granite_hybrid import GraniteHybrid
 
 __all__ = [
     "available",
@@ -101,4 +102,5 @@ __all__ = [
     "Lfm2Moe",
     "Laguna",
     "NemotronH",
+    "GraniteHybrid",
 ]

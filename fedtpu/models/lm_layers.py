@@ -1,5 +1,8 @@
 """What the language models share (``joyai_llm_flash``, ``qwen3_next``,
-``lfm2_moe``, ``laguna``, ``nemotron_h``): the norm, the plain layers, the rotate-half rotary
+``lfm2_moe``, ``laguna``, ``nemotron_h``, ``granite_hybrid``; the Mamba-2 mixer
+the last two share is :mod:`fedtpu.models.mamba2`, beside this module): the
+norm (with its statistic handed in where a share of the channels norms:
+:func:`normed`), the plain layers, the rotate-half rotary
 turn (plain or YaRN's frequencies), the causal depthwise convolution, one
 sequence's causal softmax attention over the whole prefix or a window of it,
 the grouped-query body around it, the expert layer with its routed experts'
@@ -23,9 +26,10 @@ layers are dense; what is here takes arrays and plain values, never a model's
   the band is formed.
 - :func:`grouped_query_attention`: what every grouped-query softmax layer
   does between its projections and its output projection, whichever model's:
-  the rotary rule handed in (its turns inside or outside the core's scope),
-  the core a sequence at a time under the layer's ``.core`` scope, an
-  optional sigmoid gate.
+  the rotary rule handed in (its turns inside or outside the core's scope;
+  none for a model without positions), the core a sequence at a time under
+  the layer's ``.core`` scope at ``1 / sqrt(head)`` or the model's own scale,
+  an optional sigmoid gate.
 - :func:`routed_experts`: the (token, expert) pairs that fall on the HELD
   experts, sorted by expert and multiplied group by group, a chunk of
   ``chunk_pairs`` sorted pairs at a time: within a chunk each expert's pairs
@@ -65,7 +69,9 @@ layers are dense; what is here takes arrays and plain values, never a model's
   :func:`rematerialised`: the ONE place that says what a rematerialised part
   keeps.
 - :class:`DecoderStack`: embedding, blocks, final norm, head and loss, made
-  of :class:`Trunk`'s pieces; :func:`register_language_model`.
+  of :class:`Trunk`'s pieces, with a multiplier on the stream as it enters
+  and a divisor of the logits as they leave where a model has them;
+  :func:`register_language_model`.
 """
 
 from __future__ import annotations
@@ -99,10 +105,19 @@ CORES_BY_KIND = "fedtpu_attention_cores_by_kind_total"
 PRODUCTS_TRACED = "fedtpu_expert_products_traced_total"
 
 
+def normed(xf, mean_square, scale, eps):
+    """Float32 ``xf`` over the root of ``mean_square + eps``, times ``scale``:
+    RMSNorm with its statistic handed in, float32 out. Whoever holds only a
+    share of the channels a norm runs over hands in the mean over all of
+    them (:class:`fedtpu.models.mamba2.Mamba2`)."""
+    y = xf * jax.lax.rsqrt(mean_square + eps)
+    return y * scale.astype(jnp.float32)
+
+
 def _rms(x, scale, eps):
     xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+    return normed(xf, jnp.mean(xf * xf, axis=-1, keepdims=True), scale,
+                  eps).astype(x.dtype)
 
 
 class RMSNorm(nn.Module):
@@ -345,13 +360,15 @@ def attention_core(q_nope, q_rope, k_nope, k_rope, v, scale, q_block,
 
 
 def grouped_query_attention(q, k, v, rotary, q_block, gate=None, window=None,
-                            scope="attention", turn_in_core=True):
+                            scope="attention", turn_in_core=True, scale=None):
     """A grouped-query softmax layer between its projections and its output
     projection: ``q [B, T, KH, G, hd]`` (key-value head ``j`` serves the ``G``
     query heads ``q[:, :, j]``), ``k``, ``v [B, T, KH, hd]``, already normed
     where the model norms them. A sequence at a time, under ``<scope>.core``:
     ``rotary`` (one sequence's ``[T, ..., hd]`` to the same, the model's own
-    rule) turns q and k, then :func:`attention_core` at ``1 / sqrt(hd)``.
+    rule; ``None``: a model without positions, and nothing is turned) turns q
+    and k, then :func:`attention_core` at ``scale`` (``None``: ``1 /
+    sqrt(hd)``; Granite's is a config key).
     With ``turn_in_core=False`` the turns run under the caller's scope, and
     the core's holds scores, softmax and ``P v`` alone, which is what a core's
     roofline counts; the default keeps the hybrid's and LFM2's programs as
@@ -360,16 +377,18 @@ def grouped_query_attention(q, k, v, rotary, q_block, gate=None, window=None,
     or a head's width of them) multiplies the output by its sigmoid, in
     float32. Returns ``[B, T, KH * G * hd]``, the heads side by side."""
     b, t, kh, group, hd = q.shape
+    turn = rotary or (lambda a: a)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
 
     def one_sequence(args):
         q, k, v = args
         if not turn_in_core:
-            q, k = rotary(q), rotary(k)
+            q, k = turn(q), turn(k)
         with jax.named_scope(SCOPE + scope + ".core"):
             if turn_in_core:
-                q, k = rotary(q), rotary(k)
-            return attention_core(
-                q, None, k, None, v, 1.0 / math.sqrt(hd), q_block, window)
+                q, k = turn(q), turn(k)
+            return attention_core(q, None, k, None, v, scale, q_block, window)
 
     o = jax.lax.map(one_sequence, (q, k, v))  # [b, t, kh, group, hd]
     if gate is not None:
@@ -409,15 +428,13 @@ def register_language_model(name: str, sizes_cls):
     return constructor
 
 
-def held_range(experts_held, routed: int):
-    """``experts_held = (lo, hi)`` as a checked range of the ``routed``
-    experts a router scores; ``None``: all of them."""
-    lo, hi = experts_held or (0, routed)
-    if not 0 <= lo < hi <= routed:
-        raise ValueError(
-            f"experts_held={experts_held} is no range of the "
-            f"{routed} routed experts"
-        )
+def held_range(held, total: int, name="experts_held", things="routed experts"):
+    """A share ``held = (lo, hi)`` as a checked range of the ``total``
+    ``things`` a layer has (the experts a router scores, a mixer's heads);
+    ``None``: all of them. ``name``: the size that states it."""
+    lo, hi = held or (0, total)
+    if not 0 <= lo < hi <= total:
+        raise ValueError(f"{name}={held} is no range of the {total} {things}")
     return int(lo), int(hi)
 
 
@@ -732,15 +749,22 @@ def feed_forward(x, remat: bool, experts, dense=None):
         return rematerialised(ExpertLayer, remat)(**experts, name="moe")(x)
 
 
-@functools.partial(jax.checkpoint, static_argnums=(4,))
-def _row_loss_parts(h, targets, scale, kernel, eps):
+def _logits(h, scale, kernel, eps, logits_scaling):
+    """The final norm and the head: float32 logits, over ``logits_scaling``
+    where the model has one (``None``: no operation)."""
+    logits = jnp.dot(_rms(h, scale, eps), kernel.astype(h.dtype),
+                     preferred_element_type=jnp.float32)
+    return logits if logits_scaling is None else logits / logits_scaling
+
+
+@functools.partial(jax.checkpoint, static_argnums=(4, 5))
+def _row_loss_parts(h, targets, scale, kernel, eps, logits_scaling=None):
     """One row's final norm, head and cross-entropy, ``(sum, count, hits)``;
     the row's float32 logits ``[T, vocab]`` are made again in the backward
     pass, so that no step holds a whole batch of them."""
     with jax.named_scope(SCOPE + "lm_loss"):
-        logits = jnp.dot(_rms(h, scale, eps), kernel.astype(h.dtype),
-                         preferred_element_type=jnp.float32)
-        return next_token_ce_parts(logits, targets)
+        return next_token_ce_parts(
+            _logits(h, scale, kernel, eps, logits_scaling), targets)
 
 
 class Trunk:
@@ -764,6 +788,8 @@ class Trunk:
                 (stack.hidden_size, stack.vocab_size))
         with jax.named_scope(SCOPE + "embed"):
             h = self.embed(tokens)
+            if stack.embedding_multiplier is not None:
+                h = h * stack.embedding_multiplier
         if stack.tied_head:
             self.head = self.embed.embedding.T
         self.pairs, self.loads = [], []
@@ -781,9 +807,8 @@ class Trunk:
     def logits(self):
         """Evaluation: the next-token logits ``[B, T, vocab]`` in float32."""
         with jax.named_scope(SCOPE + "lm_loss"):
-            return jnp.dot(
-                _rms(self.h, self.norm_scale, self.stack.eps),
-                self.head.astype(self.h.dtype), preferred_element_type=jnp.float32)
+            return _logits(self.h, self.norm_scale, self.head, self.stack.eps,
+                           self.stack.logits_scaling)
 
     def head_rows(self, h, targets):
         """The final norm, the head and the cross-entropy of ``h`` against
@@ -791,7 +816,8 @@ class Trunk:
         ``[B]``."""
         return jax.lax.map(
             lambda a: _row_loss_parts(
-                a[0], a[1], self.norm_scale, self.head, self.stack.eps),
+                a[0], a[1], self.norm_scale, self.head, self.stack.eps,
+                self.stack.logits_scaling),
             (h, targets))
 
     def sow(self):
@@ -818,7 +844,11 @@ class DecoderStack(nn.Module):
     ``tied_head``: the head is the embedding's
     transpose, else a parameter of its own. ``final_norm_offset``: ``None``
     for a scale that enters as it is, from ones; a number for a scale of that
-    number plus a weight from zero (the hybrid's ``1 + w``)."""
+    number plus a weight from zero (the hybrid's ``1 + w``).
+    ``embedding_multiplier`` multiplies the stream as it enters and
+    ``logits_scaling`` divides the logits as they leave (Granite's two; the
+    tied head between them takes a gradient through both); ``None``: no
+    operation is emitted."""
 
     vocab_size: int
     hidden_size: int
@@ -827,6 +857,8 @@ class DecoderStack(nn.Module):
     tied_head: bool = False
     embedding_init: Callable = nn.initializers.normal(1.0)
     final_norm_offset: Optional[float] = None
+    embedding_multiplier: Optional[float] = None
+    logits_scaling: Optional[float] = None
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, targets=None):

@@ -15,50 +15,17 @@ convolution's:
   (``M``), the expert layer (``E``) or attention (``*``); a dense ``-`` layer
   is no part of this pattern and is refused. After the last layer one RMSNorm
   (``norm_eps``), then an untied head.
-- ``M``, with ``H = mamba_num_heads``, ``P = mamba_head_dim``, ``d_in = H P``
-  (4,096: NOT ``expand`` x hidden, 5,376, which would be 84 heads of 64),
-  ``G = n_groups``, ``N = ssm_state_size``: ``[z | xBC | dt] = W_in u``,
-  widths ``d_in | d_in + 2 G N | H``; ``xBC = silu(conv(xBC) + b_conv)``, a
-  causal depthwise convolution of ``conv_kernel`` taps
-  (:func:`fedtpu.models.lm_layers.causal_conv`, the hybrid's and LFM2's, with
-  a bias a channel); ``xBC = [x | B | C]``, ``x [T, H, P]``, ``B, C [T, G,
-  N]``, head ``h`` reads group ``h // (H / G)``; ``dt = softplus(dt +
-  dt_bias) [T, H]``, ``A = -exp(A_log) [H]``, float32, with no limit on
-  ``dt`` (the config has no ``time_step_limit``). A head's state ``S [P,
-  N]``, float32, ``S_0 = 0``: ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t
-  B_t^T``; ``y_t = S_t C_t + D x_t``. ``y = RMSNorm_groups(y * silu(z)) *
-  w``: the gate BEFORE the norm, the norm over each group's ``d_in / G``
-  channels (512); ``out = W_out y``. The state runs across the document
-  boundaries of a packed row.
-- The recurrence's training form (:func:`selective_scan`) takes a chunk of
-  ``chunk_size`` tokens at a time. With ``L_t`` the chunk's running sum of
-  ``dt A``: inside the chunk ``y_t += sum_{s <= t} exp(L_t - L_s) (C_t . B_s)
-  dt_s x_s``, one ``[chunk, chunk]`` decay matrix a head whose every exponent
-  is a difference ``L_t - L_s`` with ``s <= t``, never positive, times the
-  ``C B^T`` of the head's GROUP (computed once a group, never copied a head);
-  the chunk adds ``sum_s exp(L_end - L_s) dt_s x_s B_s^T`` to the state it
-  met, decayed by ``exp(L_end)``: a rematerialised ``lax.scan`` over the
-  chunks carries the float32 state and hands out each chunk's START state
-  (what its backward pass keeps: chunks x H x P x N x 4 B, 134 MB a layer a
-  row at the published sizes), and ``y_t += exp(L_t) S_start C_t``. A length
-  the chunk does not divide is padded with steps of ``dt = 0``, which leave
-  the state as it is. Operands of ``x``'s dtype go into the products, sums,
-  gates and the state are float32. ONE function with two bodies since PR 50,
-  chosen from the backend and the operands' shapes
-  (:func:`fedtpu.ops.ssd_kernels.takes`) and counted in
-  ``fedtpu_ssd_cores_traced_total{body}`` as the other cores are: on a TPU,
-  at a group's heads and a state of whole lanes, heads of a part of a lane
-  group and a chunk of 128 that divides the length (the published sizes on
-  8,192 tokens), :mod:`fedtpu.ops.ssd_kernels`' two kernels under one
-  ``jax.custom_vjp`` (``body="kernel"``: a chunk's decay matrices and a
-  group's float32 state stay in VMEM; the output and each chunk's float32
-  starting state, 67 + 134 MB a layer a row, are named for the
-  rematerialised layer's policy, so its backward pass runs no forward kernel
-  again); everywhere else (the CPU, the tiny twins' widths, a length the
-  chunk does not divide, the eight tokens a model is initialised on) the
-  plain ``jax.numpy`` chunks above, pad and all (``body="plain"``). SiLU
-  runs on ``x``, ``B`` and ``C`` apart, so that each is written once, as the
-  core reads it.
+- ``M``: :class:`fedtpu.models.mamba2.Mamba2`, the mixer this model shares
+  with ``granite_hybrid`` (its equations, the chunked training form of the
+  recurrence and its two bodies are that module's docstring), at ``H =
+  mamba_num_heads`` heads of ``P = mamba_head_dim``, ``d_in = H P`` (4,096:
+  NOT ``expand`` x hidden, 5,376, which would be 84 heads of 64), ``G =
+  n_groups`` groups on a state of ``N = ssm_state_size``, a convolution of
+  ``conv_kernel`` taps with a bias, chunks of ``chunk_size``, every head
+  held, the gated norm over each group's ``d_in / G`` channels (512), no
+  limit on ``dt`` (the config has no ``time_step_limit``). On a TPU at the
+  published sizes on 8,192 tokens the recurrence runs through
+  :mod:`fedtpu.ops.ssd_kernels`, the plain chunks everywhere else.
 - ``E``: ``s = sigmoid(W_r u)`` in float32 over ALL ``n_routed_experts``;
   chosen = the ``num_experts_per_tok`` largest of ``s + b`` (``n_group`` 1,
   ``topk_group`` 1: no group limit); ``g = routed_scaling_factor * s[chosen]
@@ -102,7 +69,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 from typing import Optional, Tuple
 
 import flax.linen as nn
@@ -110,14 +76,12 @@ import jax
 import jax.numpy as jnp
 
 from fedtpu.models.lm_layers import (
-    SCOPE, DecoderStack, Linear, RMSNorm, _rms, causal_conv, feed_forward,
+    SCOPE, DecoderStack, Linear, RMSNorm, feed_forward,
     grouped_query_attention, held_range, no_pairs, register_language_model,
     relu2, rematerialised, rope_half, top_k_gates)
-from fedtpu.obs.registry import get_global_registry
-from fedtpu.ops import ssd_kernels
+from fedtpu.models.mamba2 import Mamba2
 
 KINDS = {"M": "mamba", "E": "moe", "*": "attention"}
-SSD_CORES_TRACED = "fedtpu_ssd_cores_traced_total"
 BIAS_KEY = 20261003
 PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 
@@ -186,141 +150,6 @@ class Sizes:
         return KINDS[pattern[layer]]
 
 
-def selective_scan(x, dt, A, B, C, D, chunk):
-    """Mamba-2's selective state-space recurrence of one sequence, a chunk at
-    a time (module docstring). ``x [T, H, P]``, ``dt [T, H]`` float32 and
-    positive, ``A [H]`` float32 and negative, ``B, C [T, G, N]`` (head ``h``
-    reads group ``h // (H / G)``), ``D [H]`` float32. Returns ``y [T, H, P]``
-    in ``x``'s dtype. Operands of ``x``'s dtype go into the products; sums,
-    decays and the state between chunks are float32. One function of the same
-    operands by the body its shapes and the backend call for: the fused
-    kernels (:mod:`fedtpu.ops.ssd_kernels`) or the plain chunks below. Counted
-    in the process's registry by the body taken, once a core traced."""
-    kernel = ssd_kernels.takes(x, dt, A, B, C, D, chunk)
-    get_global_registry().counter(
-        SSD_CORES_TRACED, "selective state-space cores traced, by the body "
-        "taken", labels={"body": "kernel" if kernel else "plain"}).inc()
-    body = ssd_kernels.selective_scan if kernel else _plain_chunks
-    return body(x, dt, A, B, C, D, chunk)
-
-
-def _plain_chunks(x, dt, A, B, C, D, chunk):
-    """:func:`selective_scan` in plain ``jax.numpy``: every chunk's matrices at
-    once, a rematerialised scan over the chunks for the state; a length the
-    chunk does not divide is padded with steps of ``dt = 0``."""
-    (t, heads, p), dtype, g = x.shape, x.dtype, B.shape[1]
-    r, rest = divmod(heads, g)
-    if rest:
-        raise ValueError(f"{heads} heads are no multiple of {g} groups")
-    # A group's R heads side by side: [., G, R, .]
-    x, dt = x.reshape(t, g, r, p), dt.reshape(t, g, r)
-    A, D = A.reshape(g, r), D.reshape(g, r)
-    pad = -t % chunk
-    if pad:  # steps of dt = 0: the state stays, the rows are cut off again
-        x, dt, B, C = (jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
-                       for a in (x, dt, B, C))
-    n = (t + pad) // chunk
-    cut = lambda a: a.reshape((n, chunk) + a.shape[1:])
-    x, dt, B, C = cut(x), cut(dt), cut(B), cut(C)
-    f32 = dict(preferred_element_type=jnp.float32)
-
-    # Per head, time last: [n, G, R, C]
-    run = jnp.cumsum(jnp.moveaxis(dt * A, 1, -1), axis=-1)  # L
-    at = jnp.arange(chunk)
-    # exp(L_t - L_s) where s <= t, else 0: [n, G, R, C, C]
-    decay = jnp.exp(jnp.where(
-        at[:, None] >= at[None, :],
-        run[..., :, None] - run[..., None, :], -jnp.inf))
-    cb = jnp.einsum("ntgk,nsgk->ngts", C, B, **f32)  # a GROUP's, once
-    fed = x.astype(jnp.float32) * dt[..., None]  # dt_s x_s [n, C, G, R, P]
-    y = jnp.einsum("ngrts,nsgrp->ntgrp", (decay * cb[:, :, None]).astype(dtype),
-                   fed.astype(dtype), **f32)
-    # What a chunk adds to the state it met, and what it keeps of that one.
-    last = run[..., -1:]  # L_end [n, G, R, 1]
-    left = jnp.moveaxis(jnp.exp(last - run), -1, 1)[..., None]  # [n, C, G, R, 1]
-    added = jnp.einsum("nsgrp,nsgk->ngrpk", (fed * left).astype(dtype), B, **f32)
-    keep = jnp.exp(last)[..., None]  # [n, G, R, 1, 1]
-
-    @jax.checkpoint
-    def one_chunk(state, xs):
-        keep, added = xs
-        return keep * state + added, state  # the state the chunk STARTS from
-
-    _, start = jax.lax.scan(
-        one_chunk, jnp.zeros(added.shape[1:], jnp.float32), (keep, added))
-    read = jnp.einsum("ntgk,ngrpk->ntgrp", C, start.astype(dtype), **f32)
-    y = y + jnp.moveaxis(jnp.exp(run), -1, 1)[..., None] * read
-    y = y + D[:, :, None] * x.astype(jnp.float32)
-    return y.astype(dtype).reshape(n * chunk, heads, p)[:t]
-
-
-def _step_bias_init(sizes: Sizes):
-    """``dt_bias`` so that ``softplus(dt_bias)`` is log-uniform on
-    ``[time_step_min, time_step_max]`` and no less than ``time_step_floor``
-    (the family's initialiser)."""
-    lo, hi = math.log(sizes.time_step_min), math.log(sizes.time_step_max)
-
-    def init(key, shape, dtype=jnp.float32):
-        step = jnp.maximum(jnp.exp(jax.random.uniform(
-            key, shape, jnp.float32, lo, hi)), sizes.time_step_floor)
-        return (step + jnp.log(-jnp.expm1(-step))).astype(dtype)  # softplus^-1
-
-    return init
-
-
-class Mamba2(nn.Module):
-    sizes: Sizes
-
-    @nn.compact
-    def __call__(self, x):
-        c = self.sizes
-        b, t, d = x.shape
-        heads, p, g, n = (c.mamba_num_heads, c.mamba_head_dim, c.n_groups,
-                          c.ssm_state_size)
-        d_in, wide = heads * p, heads * p + 2 * g * n
-        taps = self.param(
-            "conv", nn.initializers.normal(1.0 / math.sqrt(c.conv_kernel)),
-            (c.conv_kernel, wide))
-        conv_bias = self.param(
-            "conv_bias", nn.initializers.zeros_init(), (wide,)
-        ) if c.use_conv_bias else None
-        dt_bias = self.param("dt_bias", _step_bias_init(c), (heads,))
-        a_log = self.param(
-            "A_log", lambda key, shape: jnp.log(
-                jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)), (heads,))
-        skip = self.param("D", nn.initializers.ones_init(), (heads,))
-        norm = self.param("norm", nn.initializers.ones_init(), (d_in,))
-        with jax.named_scope(SCOPE + "mamba.proj"):
-            zxbcdt = Linear(d_in + wide + heads, name="in_proj")(x)
-        z = zxbcdt[..., :d_in]
-        f32 = lambda a: a.astype(jnp.float32)
-
-        def one_sequence(args):
-            xbc, dt = args
-            with jax.named_scope(SCOPE + "mamba.conv"):
-                xbc = causal_conv(xbc, taps, conv_bias)
-                # SiLU a part: the pass that makes x, B or C writes it as
-                # the core reads it, and no slice of the whole is copied
-                x_in, b_in, c_in = (
-                    jax.nn.silu(xbc[:, lo:hi]) for lo, hi in (
-                        (0, d_in), (d_in, d_in + g * n), (d_in + g * n, wide)))
-            with jax.named_scope(SCOPE + "mamba.core"):
-                dt = jax.nn.softplus(f32(dt) + f32(dt_bias))
-                return selective_scan(
-                    x_in.reshape(t, heads, p), dt, -jnp.exp(f32(a_log)),
-                    b_in.reshape(t, g, n), c_in.reshape(t, g, n), f32(skip),
-                    c.chunk_size)
-
-        y = jax.lax.map(
-            one_sequence, (zxbcdt[..., d_in:d_in + wide], zxbcdt[..., d_in + wide:]))
-        with jax.named_scope(SCOPE + "mamba.out"):
-            gated = (y.reshape(b, t, d_in).astype(jnp.float32)
-                     * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
-            y = _rms(gated.reshape(b, t, g, d_in // g), norm.reshape(g, d_in // g),
-                     c.layer_norm_epsilon)
-            return Linear(d, name="out_proj")(y.reshape(b, t, d_in))
-
-
 class Attention(nn.Module):
     sizes: Sizes
 
@@ -341,6 +170,18 @@ class Attention(nn.Module):
             a, c.rope_theta, int(hd * c.partial_rotary_factor))
         return Linear(d, name="o_proj")(grouped_query_attention(
             q, k, v, rotary, c.attn_q_block, turn_in_core=False))
+
+
+def mamba(sizes: Sizes) -> dict:
+    """A Mamba-2 layer's fields of :class:`fedtpu.models.mamba2.Mamba2`,
+    every head held."""
+    c = sizes
+    return dict(
+        heads=c.mamba_num_heads, head_dim=c.mamba_head_dim, groups=c.n_groups,
+        state=c.ssm_state_size, conv_kernel=c.conv_kernel, chunk=c.chunk_size,
+        eps=c.layer_norm_epsilon, conv_bias=c.use_conv_bias,
+        step_min=c.time_step_min, step_max=c.time_step_max,
+        step_floor=c.time_step_floor)
 
 
 def selection_bias(layer: int, sizes: Sizes) -> jnp.ndarray:
@@ -379,11 +220,12 @@ class Block(nn.Module):
         kind = c.kind(self.layer)
         with jax.named_scope(SCOPE + kind):
             x = RMSNorm(c.layer_norm_epsilon, name="norm")(h)
-            if kind != "moe":
-                mixer, name = ((Mamba2, "mamba") if kind == "mamba"
-                               else (Attention, "self_attn"))
-                return (h + rematerialised(mixer, self.remat)(c, name=name)(x),
-                        ) + no_pairs()
+            if kind == "mamba":
+                return (h + rematerialised(Mamba2, self.remat)(
+                    **mamba(c), name="mamba")(x),) + no_pairs()
+            if kind == "attention":
+                return (h + rematerialised(Attention, self.remat)(
+                    c, name="self_attn")(x),) + no_pairs()
         y, pairs, load = feed_forward(x, self.remat, experts(c, self.layer))
         return h + y, pairs, load
 

@@ -2,7 +2,7 @@
 backward under one ``jax.custom_vjp``: a chunk's decay matrices and the
 running state stay in VMEM.
 
-What the plain chunks (``fedtpu.models.nemotron_h._plain_chunks``) compute, in
+What the plain chunks (``fedtpu.models.mamba2._plain_chunks``) compute, in
 the same arithmetic: operands of ``x``'s dtype into every product, float32
 accumulation; the step sizes, their running sums ``L``, every exponential, the
 decay matrices before their cast, the ``D`` skip and the state between chunks
@@ -366,7 +366,7 @@ def _scalars(dt, A, groups, chunk):
 
 def selective_scan(x, dt, A, B, C, D, chunk, interpret: Optional[bool] = None):
     """The selective scan of one sequence, the function
-    ``fedtpu.models.nemotron_h.selective_scan`` is, at the shapes
+    ``fedtpu.models.mamba2.selective_scan`` is, at the shapes
     :func:`takes` admits."""
     if not _fits(x, dt, A, B, C, D, chunk):
         raise ValueError(

@@ -66,8 +66,15 @@ def rng():
 # a cell of that traffic file and seven metrics. Its other lines are asserted,
 # word for word, by
 # tests/benchmark/test_nemotron_cell.py::test_the_earlier_cell_of_this_traffic_is_as_it_was.
+#
+# PR 51 meets it a third time with PR 48's cell: test_nemotron_cell.py's test
+# of the same name asserts, in ONE of its lines, that the traffic file
+# `fl4_seq8k` has three cells of which Nemotron's is the last; PR 51 appends a
+# fourth. Its other lines are asserted, word for word, by
+# tests/benchmark/test_granite_cell.py::test_the_earlier_cell_of_this_traffic_is_as_it_was.
 _ASSERTS_IT_IS_LAST = ("test_joyai_cell.py::test_the_cell_is_the_issues",
-                       "test_laguna_cell.py::test_the_cell_is_the_issues")
+                       "test_laguna_cell.py::test_the_cell_is_the_issues",
+                       "test_nemotron_cell.py::test_the_cell_is_the_issues")
 
 
 # tests/test_tpu_compile.py holds the suite's longest single test (a whole

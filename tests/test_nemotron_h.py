@@ -9,9 +9,9 @@ between TWO matrices, 4 query heads on 2 key-value heads, seven layers
 ``E M E M E M *`` (the published 6-12; the benchmark's cell holds 7-13, the
 same kinds one layer on), one half and one norm each.
 
-``selective_scan`` against the token-at-a-time rule, values and gradients;
-each kind of layer against the reference's, forward and gradient, in float32
-and in bfloat16; sixteen shares of the routed experts adding up to the uncut
+Each kind of layer against the reference's, forward and gradient, in float32
+and in bfloat16 (``selective_scan`` against the token-at-a-time rule is in
+``tests/test_mamba2.py``, beside the mixer, since PR 51); sixteen shares of the routed experts adding up to the uncut
 two-matrix layer; the program's tree; a federation's micro-batches. The whole
 model's loss and whole sequential rounds through ``Federation.step()`` are in
 ``tests/benchmark/test_nemotron_cell.py`` (the harness makes that comparison);
@@ -89,68 +89,6 @@ def _value_and_grads(f, *args):
     (_, out), grads = jax.jit(jax.value_and_grad(
         scalar, argnums=tuple(range(len(args))), has_aux=True))(*args)
     return out, grads
-
-
-# ------------------------------------------------------------- the recurrence
-def _token_at_a_time(x, dt, a, b, c, skip):
-    """``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``; ``y_t = S_t C_t + D
-    x_t``, a head's group read by its index, written here and not borrowed."""
-    t, heads, p = x.shape
-    per_group = heads // b.shape[1]
-
-    def token(state, xs):
-        x_t, dt_t, b_t, c_t = xs
-        b_h, c_h = (jnp.repeat(v, per_group, axis=0) for v in (b_t, c_t))
-        state = (jnp.exp(dt_t * a)[:, None, None] * state
-                 + (dt_t[:, None] * x_t)[:, :, None] * b_h[:, None, :])
-        return state, jnp.sum(state * c_h[:, None, :], -1) + skip[:, None] * x_t
-
-    return jax.lax.scan(
-        token, jnp.zeros((heads, p, b.shape[-1]), jnp.float32), (x, dt, b, c))[1]
-
-
-def _scan_operands(t, heads=8, p=8, groups=2, n=16):
-    dt = jax.nn.softplus(3.0 * _x(2, t, heads) - 2.0)  # 0.001 to 7: slow and fast heads
-    return (_x(1, t, heads, p), dt, -jnp.exp(_x(3, heads)), _x(4, t, groups, n),
-            _x(5, t, groups, n), _x(6, heads))
-
-
-@pytest.mark.parametrize("t,chunk", [(32, 12), (32, 16), (37, 8), (24, 64), (9, 128)])
-def test_the_chunked_scan_is_the_rule_token_by_token(t, chunk):
-    """Values and all six gradients in float32, at heads that share groups
-    (8 on 2), chunks that do and do not divide the length and one longer than
-    the row. 2e-5: float32 sums in another order (a chunk's decay is a
-    difference of running sums where the rule multiplies step by step)."""
-    operands = _scan_operands(t)
-    before = get_global_registry().counter(
-        prog.SSD_CORES_TRACED, labels={"body": "plain"}).value
-    ours = _value_and_grads(
-        lambda *a: prog.selective_scan(*a, chunk), *operands)
-    theirs = _value_and_grads(_token_at_a_time, *operands)
-    assert ours[0].shape == (t, 8, 8)
-    assert _rel(ours, theirs) <= 2e-5
-    assert get_global_registry().counter(
-        prog.SSD_CORES_TRACED, labels={"body": "plain"}).value > before
-
-
-def test_the_scan_carries_a_state_that_outlives_its_chunks():
-    """Heads that forget slowly (``dt A`` of -0.004 a step) see the FIRST
-    token at the last, chunks later: the output's gradient with respect to
-    ``x_0`` is nothing near zero there, and equals the rule's; no exponent is
-    positive (steps of 40 do not overflow)."""
-    t, chunk = 40, 8
-    x, dt, a, b, c, skip = _scan_operands(t)
-    dt = jnp.full_like(dt, 0.004)
-    a = -jnp.ones_like(a)
-    last = lambda f: jax.grad(lambda x: jnp.sum(f(x, dt, a, b, c, skip)[-1]))(x)[0]
-    ours = last(lambda *o: prog.selective_scan(*o, chunk))
-    theirs = last(_token_at_a_time)
-    assert float(jnp.abs(theirs).max()) > 1e-3
-    np.testing.assert_allclose(ours, theirs, rtol=1e-4, atol=1e-7)
-    y = prog.selective_scan(x, jnp.full_like(dt, 40.0), a, b, c, skip, chunk)
-    assert bool(jnp.isfinite(y).all())
-    with pytest.raises(ValueError, match="no multiple"):
-        prog.selective_scan(x[:, :7], dt[:, :7], a[:7], b, c, skip[:7], chunk)
 
 
 # ------------------------------------------------------------------ the layers

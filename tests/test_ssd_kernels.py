@@ -1,5 +1,5 @@
 """Mamba-2's fused selective scan (``fedtpu/ops/ssd_kernels.py``) against the
-plain chunks it replaces on a TPU (``nemotron_h._plain_chunks``) and against
+plain chunks it replaces on a TPU (``mamba2._plain_chunks``) and against
 the rule itself, a token at a time, on the CPU through the Pallas interpreter:
 the output and the gradient of every operand (x, dt, A, B, C, D), float32
 operands to float32 rounding and bfloat16 to bfloat16 rounding, at the
@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from fedtpu.models import lm_layers
-from fedtpu.models import nemotron_h as prog
+from fedtpu.models import mamba2 as prog
 from fedtpu.obs.registry import get_global_registry
 from fedtpu.ops import ssd_kernels as sk
 
@@ -215,10 +215,9 @@ def test_the_layer_trains_the_same_through_either_body(monkeypatch):
     every gradient through the kernels (interpreted) equal those through the
     plain chunks to float32 rounding, and the counter says which body a core
     took."""
-    sizes = prog.Sizes(
-        hidden_size=64, mamba_num_heads=4, mamba_head_dim=64, n_groups=2,
-        ssm_state_size=N, chunk_size=CHUNK)
-    layer = lm_layers.rematerialised(prog.Mamba2)(sizes)
+    layer = lm_layers.rematerialised(prog.Mamba2)(
+        heads=4, head_dim=64, groups=2, state=N, conv_kernel=4, chunk=CHUNK,
+        eps=1e-5)
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 2 * CHUNK, 64), jnp.float32)
     params = layer.init(jax.random.PRNGKey(6), x[:, :8])["params"]
 

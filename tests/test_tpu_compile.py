@@ -673,7 +673,7 @@ def test_the_nemotron_round_program_fits_one_chip_at_the_cells_size(
     from fedtpu import models
     from fedtpu.core.round import init_state
     from fedtpu.data.device import make_data_round_step
-    from fedtpu.models import lm_layers, nemotron_h
+    from fedtpu.models import lm_layers, mamba2
     from fedtpu.obs.registry import get_global_registry
     from fedtpu.ops import attention_kernels as ak
     from fedtpu.ops import expert_kernels as ek
@@ -687,8 +687,8 @@ def test_the_nemotron_round_program_fits_one_chip_at_the_cells_size(
         name, labels={"body": body}).value
     before = (counted(lm_layers.PRODUCTS_TRACED, "plain"),
               counted(lm_layers.PRODUCTS_TRACED, "kernel"),
-              counted(nemotron_h.SSD_CORES_TRACED, "plain"),
-              counted(nemotron_h.SSD_CORES_TRACED, "kernel"))
+              counted(mamba2.SSD_CORES_TRACED, "plain"),
+              counted(mamba2.SSD_CORES_TRACED, "kernel"))
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     cell = bench.Cell(os.path.join(root, "BENCHMARK.json"),
                       "nemotron_3_nano_30b_a3b.fl4_seq8k")
@@ -735,8 +735,8 @@ def test_the_nemotron_round_program_fits_one_chip_at_the_cells_size(
     # tokens and the plain chunks in init_state's eight
     assert counted(lm_layers.PRODUCTS_TRACED, "kernel") - before[1] == 2 * 3 * 2
     assert counted(lm_layers.PRODUCTS_TRACED, "plain") == before[0]
-    assert counted(nemotron_h.SSD_CORES_TRACED, "kernel") - before[3] == 3
-    assert counted(nemotron_h.SSD_CORES_TRACED, "plain") - before[2] == 3
+    assert counted(mamba2.SSD_CORES_TRACED, "kernel") - before[3] == 3
+    assert counted(mamba2.SSD_CORES_TRACED, "plain") - before[2] == 3
     assert not [r.getMessage() for r in caplog.records if r.name == lm_layers.__name__]
     pre = "fed.local_step.fwd_bwd."
     for scope in ("mamba.proj", "mamba.conv", "mamba.core", "mamba.out", "attention",
@@ -744,3 +744,83 @@ def test_the_nemotron_round_program_fits_one_chip_at_the_cells_size(
                   "moe.combine", "embed", "lm_loss"):
         assert pre + scope + "/" in text, scope
     assert "ragged-dot" not in text and "linear_attention" not in text
+
+
+def test_the_granite_round_program_fits_one_chip_at_the_cells_size(
+        one_chip, monkeypatch, caplog):
+    """``granite_4_0_h_micro.fl4_seq8k``'s whole round program from its own
+    files (4 clients in sequence, 2 steps of 2 rows of 8,192 tokens in
+    micro-batches of one row, plain SGD on bfloat16 over the 653 M float32
+    masters, each half of each layer rematerialised by itself), from shapes
+    alone, compiled for one described v5e with the bodies as the chip would
+    choose them: the chip's compiler refuses a program that does not fit its
+    memory, so the compile IS the check (12.29 GB "Total bytes used" in its
+    memory report at PR 51, step 0 of ISSUE 51, of the 15.75 GiB a chip
+    gives: two chips a layer stand). The attention layer's core is the two
+    fused kernels at 4 query heads a key-value head of 64 with no rotary
+    operand and the config's own scale; the nine Mamba-2 layers' recurrences
+    are the PLAIN chunks, in the round program's 8,192 tokens too, because the
+    published chunk of 256 is not the one the scan's kernels are built for,
+    and ONE warning says so, naming the chunk and the length; no expert
+    product is traced; and every scope the cell's readers read is in the
+    module."""
+    import logging
+
+    from benchmark import run as bench, sut
+    from fedtpu import models
+    from fedtpu.core.round import init_state
+    from fedtpu.data.device import make_data_round_step
+    from fedtpu.models import lm_layers, mamba2
+    from fedtpu.obs.registry import get_global_registry
+    from fedtpu.ops import attention_kernels as ak
+    from fedtpu.ops import ssd_kernels as sk
+
+    monkeypatch.setattr(ak, "_mode", lambda interpret: "mosaic")
+    monkeypatch.setattr(sk, "_mode", lambda interpret: "mosaic")
+    monkeypatch.setattr(mamba2, "_PLAIN_SCANS_WARNED", set())
+    counted = lambda name, body: get_global_registry().counter(
+        name, labels={"body": body}).value
+    names = ((mamba2.SSD_CORES_TRACED, "plain"), (mamba2.SSD_CORES_TRACED, "kernel"),
+             (lm_layers.CORES_TRACED, "plain"), (lm_layers.CORES_TRACED, "kernel"),
+             (lm_layers.PRODUCTS_TRACED, "plain"), (lm_layers.PRODUCTS_TRACED, "kernel"))
+    before = [counted(*n) for n in names]
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cell = bench.Cell(os.path.join(root, "BENCHMARK.json"),
+                      "granite_4_0_h_micro.fl4_seq8k")
+    cfg = sut.round_config(cell.config, cell.traffic, cell.task)
+    t, clients = cell.config["seq_len"], cell.traffic["clients"]
+    shard = cell.config["rows_per_client"]
+    model = models.create(cfg.model, num_classes=cfg.num_classes, remat=cfg.remat,
+                          **dict(cfg.model_args))
+    with caplog.at_level(logging.WARNING, logger=mamba2.__name__):
+        state = jax.eval_shape(
+            lambda key: init_state(model, cfg, key, jnp.zeros((1, t), jnp.int32)),
+            jax.random.PRNGKey(0))
+        assert sum(math.prod(l.shape) for l in jax.tree.leaves(state.params)) == 652_970_080
+        step = jax.jit(make_data_round_step(
+            model, cfg, cfg.steps_per_round, shuffle=False, image_shape=(t,),
+            layout="gather"), donate_argnums=(0,))
+        shapes = (
+            state, jnp.zeros((clients * shard, t), jnp.int32),
+            jnp.zeros((clients * shard, t), jnp.int32),
+            jnp.zeros((clients, shard), jnp.int32), jnp.ones((clients, shard), bool),
+            jnp.ones((clients,), jnp.float32), jnp.ones((clients,), bool),
+            jax.random.PRNGKey(0))
+        shapes = jax.tree.map(
+            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip), shapes)
+        text = step.lower(*shapes).compile().as_text()  # raises where it does not fit
+    kernels = collections.Counter(
+        re.search(r"%(\w+?)[.\d]* =", l).group(1) for l in _kernel_lines(text))
+    assert kernels == {"latent_attention_core_fwd": 1, "latent_attention_core_bwd": 1}
+    # init_state's trace (eight tokens: plain everywhere) and the round
+    # program's: nine scans, plain both times; the one softmax core plain in
+    # init_state and the kernels in the round program; no expert product
+    after = [counted(*n) for n in names]
+    assert [a - b for a, b in zip(after, before)] == [18, 0, 1, 1, 0, 0]
+    said = [r.getMessage() for r in caplog.records if r.name == mamba2.__name__]
+    assert len(said) == 1 and "256" in said[0] and "8192" in said[0]
+    pre = "fed.local_step.fwd_bwd."
+    for scope in ("mamba.proj", "mamba.conv", "mamba.core", "mamba.out", "attention",
+                  "attention.core", "dense_ffn", "embed", "lm_loss"):
+        assert pre + scope + "/" in text, scope
+    assert pre + "moe" not in text and "ragged-dot" not in text
